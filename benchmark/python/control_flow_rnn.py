@@ -22,6 +22,9 @@ def main():
     p.add_argument("--iters", type=int, default=5)
     args = p.parse_args()
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     import numpy as np
     import jax.numpy as jnp
     from mxtpu import gluon, nd

@@ -37,6 +37,9 @@ def main(argv=None):
     p.add_argument("--batch", type=int, default=1)
     args = p.parse_args(argv)
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     import numpy as np
 
     import jax

@@ -20,6 +20,9 @@ def main():
                    choices=["int8", "uint8", "auto"])
     args = p.parse_args()
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     import numpy as np
 
     from mxtpu import autograd, gluon, nd

@@ -21,6 +21,9 @@ def main():
     p.add_argument("--batch-size", type=int, default=64)
     args = p.parse_args()
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     import numpy as np
     import jax
     from jax.sharding import PartitionSpec as P

@@ -21,6 +21,9 @@ def main():
     p.add_argument("--kv-store", default="local")
     args = p.parse_args()
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     import mxtpu as mx
     from mxtpu import gluon, io
     from mxtpu.gluon import nn

@@ -69,6 +69,9 @@ def main():
     p.add_argument("--eval-iou", type=float, default=0.4)
     args = p.parse_args()
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     import numpy as np
 
     import mxtpu as mx

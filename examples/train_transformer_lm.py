@@ -45,6 +45,9 @@ def main(argv=None) -> float:
     p.add_argument("--micro-batches", type=int, default=1)
     args = p.parse_args(argv)
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     mx.rng.seed(0)
     data = make_corpus(args.vocab, args.corpus_len)
     T = args.seq_len

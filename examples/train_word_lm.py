@@ -110,6 +110,9 @@ def main(argv=None):
     p.add_argument("--cpu", action="store_true", help="force the CPU backend")
     args = p.parse_args(argv)
 
+    from mxtpu import compile_cache
+    compile_cache.place()      # before the first jit
+
     if args.cpu:
         import jax
         jax.config.update("jax_platforms", "cpu")

@@ -4,11 +4,12 @@ Two halves:
 
 * **FLOPs per compiled program** — :func:`estimate_step_flops` asks XLA's own
   cost model first (``lowered.compile().cost_analysis()['flops']`` — the same
-  source ``bench.py`` has always used for honest MFU) and falls back to an
-  analytic jaxpr walk counting ``dot_general``/``conv_general_dilated`` MACs
-  (``scan`` bodies × trip count) when the AOT path is unavailable. The
-  estimate is cached per step-cache entry by the caller; it is never computed
-  on the step hot path.
+  source ``bench.py`` has always used for honest MFU) and, when that fails,
+  logs why and walks the jaxpr counting ``dot_general``/
+  ``conv_general_dilated`` MACs (``scan`` bodies × trip count). It returns
+  the count WITH its source, and ``get_mfu_stats()["flops_source"]`` carries
+  it. The estimate is cached per step-cache entry by the caller; it is never
+  computed on the step hot path.
 * **Step-time ring** — :func:`record_step` appends one wall-clock step sample
   into a bounded ring (default 4096; ``MXTPU_STEP_RING``), from which
   :func:`get_mfu_stats` derives ``steps_per_sec``, ``p50_step_ms``,
@@ -28,6 +29,7 @@ host backend is a ratchet coordinate, not a hardware-utilization claim.
 
 from __future__ import annotations
 
+import logging
 import math
 import os
 import threading
@@ -36,14 +38,20 @@ from typing import Optional, Tuple
 
 from . import histogram
 
+_log = logging.getLogger("mxtpu.observability")
+
 __all__ = ["device_peak", "estimate_step_flops", "jaxpr_flops",
            "record_step", "set_step_flops", "get_step_flops",
            "get_mfu_stats", "reset_steps", "step_count", "PEAK_TFLOPS"]
 
-# documented bf16 peak TFLOP/s per chip kind (public spec sheets); the
-# canonical copy — bench.py imports this table
+# documented bf16 peak TFLOP/s per chip, keyed by the exact
+# ``jax.devices()[0].device_kind`` string; the canonical copy — bench.py
+# imports this table. Both spellings of each generation are the ones
+# jax._src.pallas.mosaic.tpu_info accepts as device kinds; the figures are
+# Google Cloud's per-chip numbers ("TPU v4" / "TPU v5e" / "TPU v5p" /
+# "TPU v6e" system-architecture pages).
 PEAK_TFLOPS = {
-    "TPU v5 lite": 197.0,   # v5e
+    "TPU v5 lite": 197.0,   # v5e; what the chip reports (chip_smoke, PR 21)
     "TPU v5e": 197.0,
     "TPU v5": 459.0,        # v5p
     "TPU v5p": 459.0,
@@ -62,20 +70,26 @@ def _cpu_peak_tflops() -> float:
 
 
 def device_peak() -> Tuple[str, Optional[float]]:
-    """``(device_kind, peak_tflops_or_None)`` for device 0. TPU kinds map
-    through :data:`PEAK_TFLOPS`; cpu gets the nominal ratchet heuristic
-    (see module docstring); anything else returns ``None`` (MFU undefined)."""
+    """``(device_kind, peak_tflops_or_None)`` for device 0. A TPU's kind is
+    looked up EXACTLY in :data:`PEAK_TFLOPS` and a TPU that is not listed
+    raises ``KeyError`` — a near match would put another chip's peak under
+    every MFU this process reports. cpu gets the nominal ratchet heuristic
+    (see module docstring); any other platform returns ``None`` (MFU
+    undefined)."""
     import jax
-    kind = jax.devices()[0].device_kind
-    peak = PEAK_TFLOPS.get(kind)
-    if peak is None:
-        for k, v in PEAK_TFLOPS.items():
-            if k in kind:
-                peak = v
-                break
-    if peak is None and "cpu" in kind.lower():
-        peak = _cpu_peak_tflops()
-    return kind, peak
+    dev = jax.devices()[0]
+    kind = dev.device_kind
+    if dev.platform == "tpu":
+        if kind not in PEAK_TFLOPS:
+            raise KeyError(
+                f"TPU device_kind {kind!r} has no entry in "
+                f"mxtpu.observability.flops.PEAK_TFLOPS "
+                f"({sorted(PEAK_TFLOPS)}); add its documented bf16 peak "
+                f"with the source")
+        return kind, PEAK_TFLOPS[kind]
+    if "cpu" in kind.lower():
+        return kind, _cpu_peak_tflops()
+    return kind, None
 
 
 # ---------------------------------------------------------------------------
@@ -133,32 +147,33 @@ def jaxpr_flops(jaxpr) -> float:
     return total
 
 
-def estimate_step_flops(jitted, avals) -> Optional[float]:
-    """FLOPs of one execution of ``jitted(*avals)``.
+def estimate_step_flops(jitted, avals) -> Tuple[Optional[float], Optional[str]]:
+    """``(flops, source)`` of one execution of ``jitted(*avals)``.
 
-    Primary: XLA cost analysis on the AOT-lowered program (exact fusion-aware
-    accounting; pays one extra lower+compile per unique signature, which is
-    why callers cache the result per step-cache entry and compute it OFF the
-    step path). Fallback: the analytic jaxpr walk. ``MXTPU_FLOPS_MODE``
-    selects ``xla`` (default), ``analytic``, or ``off``."""
+    ``source`` says where the number came from, so an MFU never rests on a
+    silently substituted count: ``"xla"`` is XLA's cost analysis of the
+    AOT-lowered program (exact, fusion-aware; pays one extra lower+compile
+    per unique signature, which is why callers cache the result per
+    step-cache entry and compute it OFF the step path); ``"analytic"`` is
+    the jaxpr walk, taken when ``MXTPU_FLOPS_MODE=analytic`` or when the
+    cost model failed or reported nothing — the failure is logged, not
+    swallowed. ``MXTPU_FLOPS_MODE=off`` gives ``(None, None)``."""
     mode = os.environ.get("MXTPU_FLOPS_MODE", "xla").lower()
     if mode in ("off", "0", "none"):
-        return None
+        return None, None
     if mode != "analytic":
         try:
             ca = jitted.lower(*avals).compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0]
-            flops = float(dict(ca or {}).get("flops", 0.0))
+            flops = float((ca or {}).get("flops", 0.0))
             if flops > 0:
-                return flops
+                return flops, "xla"
+            _log.warning("XLA cost analysis reported no flops; counting "
+                         "matmuls/convs from the jaxpr instead")
         except Exception:
-            pass  # AOT path unavailable on this backend: analytic below
-    try:
-        import jax
-        return jaxpr_flops(jax.make_jaxpr(jitted)(*avals))
-    except Exception:
-        return None
+            _log.warning("XLA cost analysis failed; counting matmuls/convs "
+                         "from the jaxpr instead", exc_info=True)
+    import jax
+    return jaxpr_flops(jax.make_jaxpr(jitted)(*avals)), "analytic"
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +191,7 @@ def _ring_cap() -> int:
 
 
 _ring: "deque" = deque(maxlen=_ring_cap())
-_state = {"flops_per_step": None, "total_steps": 0}
+_state = {"flops_per_step": None, "flops_source": None, "total_steps": 0}
 
 
 def record_step(seconds: float, flops: Optional[float] = None):
@@ -191,11 +206,13 @@ def record_step(seconds: float, flops: Optional[float] = None):
     histogram.record_value("step/fused_step_ms", float(seconds) * 1e3)
 
 
-def set_step_flops(flops: Optional[float]):
-    """Register the FLOPs of the CURRENT compiled step program (called by the
-    fit loop / bench once per traced signature, off the hot path)."""
+def set_step_flops(flops: Optional[float], source: Optional[str] = None):
+    """Register the FLOPs of the CURRENT compiled step program and where the
+    count came from (``estimate_step_flops``'s source; called by the fit
+    loop / bench once per traced signature, off the hot path)."""
     with _ring_lock:
         _state["flops_per_step"] = flops
+        _state["flops_source"] = source
 
 
 def get_step_flops() -> Optional[float]:
@@ -236,8 +253,11 @@ def get_mfu_stats(flops_per_step: Optional[float] = None) -> dict:
     with _ring_lock:
         samples = list(_ring)
         default_flops = _state["flops_per_step"]
+        source = _state["flops_source"]
     if flops_per_step is None:
         flops_per_step = default_flops
+    else:
+        source = "caller"
     times = sorted(s for s, _ in samples)
     n = len(times)
     wall = sum(times)
@@ -245,13 +265,10 @@ def get_mfu_stats(flops_per_step: Optional[float] = None) -> dict:
            "steps_per_sec": round(n / wall, 3) if wall > 0 else 0.0,
            "p50_step_ms": round(_percentile(times, 0.50) * 1e3, 3),
            "p99_step_ms": round(_percentile(times, 0.99) * 1e3, 3),
-           "flops_per_step": flops_per_step,
+           "flops_per_step": flops_per_step, "flops_source": source,
            "mfu": None, "device_kind": None, "peak_tflops": None}
-    try:
-        kind, peak = device_peak()
-        out["device_kind"], out["peak_tflops"] = kind, peak
-    except Exception:
-        peak = None
+    kind, peak = device_peak()
+    out["device_kind"], out["peak_tflops"] = kind, peak
     if n and wall > 0 and flops_per_step and peak:
         out["mfu"] = round((n * flops_per_step / wall) / (peak * 1e12), 6)
     return out

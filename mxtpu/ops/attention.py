@@ -21,9 +21,11 @@ over k blocks) without materializing T×T.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import os
+import threading
 from typing import Optional
 
 import jax
@@ -32,7 +34,8 @@ from jax import lax
 
 from .registry import register
 
-__all__ = ["attention_reference", "flash_attention", "flash_chunk"]
+__all__ = ["attention_reference", "flash_attention", "flash_chunk",
+           "partition_scope"]
 
 _NEG_INF = -1e30
 
@@ -491,6 +494,74 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
             dv[..., :D].reshape(B, H, Tk, D))
 
 
+# ---------------------------------------------------------------------------
+# more than one chip: the kernels shard_map themselves
+# ---------------------------------------------------------------------------
+# GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
+# automatically partitioned", first seen on the four-chip v5e host in PR 21):
+# on a multi-device mesh the kernel launch has to sit inside a shard_map.
+# Attention is independent over batch and heads, so the launch is split over
+# whatever mesh axes carry those two dims and every device runs the kernel on
+# its own (B/n, H/m, T, D) block with the FULL sequence.
+
+_partition = threading.local()
+
+
+@contextlib.contextmanager
+def partition_scope(mesh, spec):
+    """Tell the flash kernels how ``(B, H, ...)`` activations are split over
+    ``mesh`` while a step TRACES under jit (a tracer carries no sharding to
+    read it from). ``spec`` is a PartitionSpec whose first two entries name
+    the batch and head axes; ``DataParallelTrainer`` opens this around its
+    step. Concrete arrays need no scope — their own sharding says it."""
+    prev = getattr(_partition, "value", None)
+    _partition.value = (mesh, spec)
+    try:
+        yield
+    finally:
+        _partition.value = prev
+
+
+def _partition_for(q):
+    """``(mesh, P(batch axes, head axes))`` to shard_map a kernel launch
+    over, or None when ``q`` lives on one device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    scope = getattr(_partition, "value", None)
+    sharding = None if isinstance(q, jax.core.Tracer) \
+        else getattr(q, "sharding", None)       # host arrays carry none
+    if sharding is not None and len(sharding.device_set) == 1:
+        return None
+    if isinstance(sharding, NamedSharding):
+        mesh, spec = sharding.mesh, sharding.spec
+    elif scope is not None:
+        mesh, spec = scope
+    elif sharding is None:
+        return None     # traced outside any scope: one device, or jax raises
+    else:
+        raise NotImplementedError(
+            f"flash attention on an array spread over "
+            f"{len(sharding.device_set)} devices by a {type(sharding).__name__}"
+            f": open mxtpu.ops.attention.partition_scope(mesh, spec) so the "
+            f"kernel knows which mesh axes carry batch and heads")
+    if mesh.devices.size == 1:
+        return None
+    from ..parallel.fsdp import filter_spec
+    # batch and heads only: the kernel needs the whole sequence on a device
+    return mesh, filter_spec(P(*tuple(spec)[:2]), q.shape[:2], mesh)
+
+
+def _launch(local, args, n_out: int):
+    """Run ``local(*args)`` — every operand and result is ``(B, H, ...)`` —
+    directly on one device, or shard_mapped over batch and heads."""
+    part = _partition_for(args[0])
+    if part is None:
+        return local(*args)
+    from ..parallel.collectives import shard_map_compat
+    mesh, spec = part
+    return shard_map_compat(local, mesh, (spec,) * len(args),
+                            (spec,) * n_out)(*args)
+
+
 def _use_pallas(q, k) -> bool:
     if jax.default_backend() not in ("tpu",):
         return False
@@ -528,8 +599,10 @@ def flash_chunk(q, k, v, causal, scale):
     at eligible shapes, XLA fallback elsewhere; the custom vjp handles BOTH
     cotangents (out and lse), so lse-merges differentiate exactly."""
     if _use_pallas(q, k):
-        out, lse = _flash_attention_pallas(q, k, v, causal, scale)
-        return out, lse.reshape(q.shape[0], q.shape[1], q.shape[2])
+        def local(q, k, v):
+            out, lse = _flash_attention_pallas(q, k, v, causal, scale)
+            return out, lse.reshape(q.shape[:3])
+        return _launch(local, (q, k, v), 2)
     return _chunk_reference_lse(q, k, v, causal, scale)
 
 
@@ -542,10 +615,12 @@ def _flash_chunk_bwd(causal, scale, res, cots):
     q, k, v, out, lse = res
     g_o, g_lse = cots
     if _use_pallas(q, k):
-        B, H, T, _ = q.shape
-        lse2d = lse.reshape(B * H, T)
-        return _flash_backward_pallas(q, k, v, out, lse2d, g_o, causal, scale,
-                                      lse_cot=g_lse)
+        def local(q, k, v, out, lse, g_o, g_lse):
+            B, H, T, _ = q.shape
+            return _flash_backward_pallas(
+                q, k, v, out, lse.reshape(B * H, T), g_o, causal, scale,
+                lse_cot=g_lse)
+        return _launch(local, (q, k, v, out, lse, g_o, g_lse), 3)
     _, vjp = jax.vjp(lambda q_, k_, v_: _chunk_reference_lse(
         q_, k_, v_, causal, scale), q, k, v)
     return vjp((g_o, g_lse))

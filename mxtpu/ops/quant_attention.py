@@ -24,10 +24,10 @@ execution paths:
 Selection is ``MXTPU_DECODE_KERNEL=pallas|xla`` (engine kwarg > env; unset
 = auto: pallas on TPU, xla elsewhere), resolved ONCE per compiled program
 at build time — flipping the env between dispatches can never retrace a
-live engine program. A forced ``pallas`` at a Mosaic-illegal bucket (TOT
-not a 128-multiple on hardware) degrades to the xla path for that program
-rather than failing the engine mid-serve; off-TPU the kernel runs in
-interpret mode so the parity suite exercises the real kernel body on CPU.
+live engine program. Only auto chooses per bucket; an explicit ``pallas`` at
+a Mosaic-illegal bucket (TOT not a 128-multiple on hardware) raises rather
+than serve from the xla path under the pallas name. Off-TPU the kernel runs
+in interpret mode so the parity suite exercises the real kernel body on CPU.
 """
 
 from __future__ import annotations
@@ -76,17 +76,27 @@ def resolve_decode_kernel(mode=None, TOT: Optional[int] = None,
                           D: Optional[int] = None) -> str:
     """Concrete kernel for one compiled decode program, decided at BUILD
     time (the engine resolves its mode once per lifetime, so program-cache
-    keys stay (slots, bucket, chunk) and env flips never retrace). Auto is
-    pallas on TPU, xla elsewhere; a pallas request at a shape the kernel
-    can't tile (bucket legality per ``_legal_bucket``, head dim > 512)
-    degrades to xla for that program."""
+    keys stay (slots, bucket, chunk) and env flips never retrace). Only
+    auto chooses: pallas on TPU where the kernel can tile the shape (bucket
+    legality per ``_legal_bucket``, head dim <= 512), xla otherwise. An
+    EXPLICIT pallas request (``kernel='pallas'`` /
+    ``MXTPU_DECODE_KERNEL=pallas``) at a shape the kernel cannot tile raises
+    ``ValueError`` — it never runs another kernel under that name."""
     mode = decode_kernel_mode(mode)
     on_tpu = jax.default_backend() == "tpu"
+    explicit = mode is not None
     if mode is None:
         mode = "pallas" if on_tpu else "xla"
     if mode == "pallas" and TOT is not None:
         legal = (TOT % 128 == 0) if on_tpu else _legal_bucket(TOT)
         if not legal or (D is not None and D > 512):
+            if explicit:
+                raise ValueError(
+                    f"decode kernel 'pallas' was requested but cannot tile "
+                    f"KV bucket TOT={TOT}, head dim D={D}: it needs a "
+                    f"128-multiple bucket (interpreted off-TPU, a whole-axis "
+                    f"bucket <= 128 also passes) and D <= 512. Leave the "
+                    f"kernel unset and auto picks per bucket")
             return "xla"
     return mode
 

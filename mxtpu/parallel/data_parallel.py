@@ -21,6 +21,7 @@ import numpy as np
 from .. import autograd
 from .. import ndarray as nd_mod
 from ..ndarray.ndarray import NDArray
+from ..ops import attention as attention_ops
 from ..step_cache import build_update_all, cache_stats
 from . import fsdp as fsdp_mod
 from . import zero as zero_mod
@@ -158,9 +159,21 @@ class DataParallelTrainer:
                 return spec
         return P()
 
+    def _kernel_scope(self):
+        """Open while the forward runs or the step traces: GSPMD cannot
+        partition a Pallas kernel, so on a multi-device mesh the flash
+        kernels shard_map themselves over the axes that carry batch and
+        heads (``ops.attention.partition_scope``) — the composed layout's
+        head-activation spec under a ``layout_scope``, the data axes alone
+        otherwise."""
+        scope = fsdp_mod.current_layout()
+        spec = scope[0].head_activations() if scope is not None \
+            else P(data_axis_names(self.mesh))
+        return attention_ops.partition_scope(self.mesh, spec)
+
     def _collect(self, x_example):
         # ensure deferred params materialize
-        with autograd.predict_mode():
+        with autograd.predict_mode(), self._kernel_scope():
             self.block(x_example)
         named = list(self.block.collect_params().items())
         self._param_names = [n for n, p in named
@@ -225,9 +238,7 @@ class DataParallelTrainer:
         """Per-device param/grad/slot byte accounting (profiler
         ``get_memory_stats``), from the actual placed shardings."""
         params = [p.data().data for p in self._param_handles]
-        slots = [s for st in list(self._states) + list(self._zero_states)
-                 for s in (st or ()) if hasattr(s, "dtype")]
-        slots += [r for r in self._zero_residuals if r is not None]
+        slots = self.optimizer_slots()
         grad_bytes = sum(
             int(np.prod(p.shape)) * np.dtype(str(p.dtype)).itemsize
             for p in params)
@@ -445,8 +456,9 @@ class DataParallelTrainer:
         self._last_avals = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
             if hasattr(a, "shape") else a, args)
-        (new_params, new_auxs, new_states, new_zstates, new_zres,
-         loss) = self._step_fn(*args)
+        with self._kernel_scope():          # the first call traces
+            (new_params, new_auxs, new_states, new_zstates, new_zres,
+             loss) = self._step_fn(*args)
         for p, v in zip(self._param_handles, new_params):
             p._data._data = v
             p._data._version += 1
@@ -509,15 +521,15 @@ class DataParallelTrainer:
                 shape = sh.shard_shape(shape)
             return int(np.prod(shape)) * np.dtype(str(arr.dtype)).itemsize \
                 if len(shape) else np.dtype(str(arr.dtype)).itemsize
-        total = 0
-        for st in list(self._states) + list(self._zero_states):
-            for s in (st or ()):
-                if hasattr(s, "dtype"):
-                    total += per_device(s)
-        for r in self._zero_residuals:
-            if r is not None:
-                total += per_device(r)
-        return total
+        return sum(per_device(s) for s in self.optimizer_slots())
+
+    def optimizer_slots(self) -> List:
+        """Every optimizer-state array the step carries (per-param slots,
+        ZeRO bucket slots, compression residuals), as placed on the mesh.
+        Valid after the first step."""
+        slots = [s for st in list(self._states) + list(self._zero_states)
+                 for s in (st or ()) if hasattr(s, "dtype")]
+        return slots + [r for r in self._zero_residuals if r is not None]
 
     def step(self, x, y) -> float:
         return float(self.step_async(x, y).data)
@@ -536,16 +548,21 @@ class DataParallelTrainer:
         from ..device_feed import DeviceFeed
         return DeviceFeed(batches, depth=depth, placement=self.mesh)
 
+    def lowered(self):
+        """The step program AOT-lowered at the last step's signature (a
+        ``jax.stages.Lowered``): ``.as_text()`` shows which kernels the step
+        really calls, ``.compile()`` feeds :meth:`cost_analysis`. Valid after
+        the first step."""
+        if self._step_fn is None or not hasattr(self, "_last_avals"):
+            raise RuntimeError("run at least one step first")
+        with self._kernel_scope():
+            return self._step_fn.lower(*self._last_avals)
+
     def cost_analysis(self) -> dict:
         """XLA's own cost model for the compiled step (flops, bytes accessed).
         Valid after the first step; used by bench.py for honest MFU accounting.
         The lowering/compile for the analysis is cached (first call only)."""
-        if self._step_fn is None or not hasattr(self, "_last_avals"):
-            raise RuntimeError("run at least one step first")
         if not hasattr(self, "_cost_cache"):
-            compiled = self._step_fn.lower(*self._last_avals).compile()
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0]
-            self._cost_cache = dict(ca) if ca else {}
+            self._cost_cache = dict(
+                self.lowered().compile().cost_analysis() or {})
         return self._cost_cache

@@ -71,11 +71,9 @@ def gpipe(stage_fn: Callable, stacked_params, x, mesh: Optional[Mesh] = None,
                                    [(i, (i + 1) % S) for i in range(S)])
             return shifted, y_t
 
-        carry0 = jnp.zeros_like(stream_loc[0])
-        try:  # newer jax: carries that become device-varying must start varied
-            carry0 = lax.pvary(carry0, axis_name)
-        except AttributeError:
-            pass
+        # a carry that becomes device-varying must start varying
+        carry0 = lax.pcast(jnp.zeros_like(stream_loc[0]), axis_name,
+                           to="varying")
         _, ys = lax.scan(step, carry0, stream_loc)
         return ys                                        # (n_steps, B, ...)
 

@@ -266,6 +266,11 @@ class ServingEngine:
         if kv_dtype is None:
             kv_dtype = os.environ.get("MXTPU_SERVING_KV_DTYPE") or None
         self._kv_dtype = jnp.zeros((0,), kv_dtype or jnp.float32).dtype
+        if not jnp.issubdtype(self._kv_dtype, jnp.floating):
+            # a plain integer cache would store K/V rows CAST to integers
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r} is not a float dtype; quantized KV "
+                f"storage is quant='int8_kv' / 'fp8_kv'")
         # what get_serving_stats()/ServingHandoff report as the page storage
         self._kv_dtype_str = self._quant.kv or self._kv_dtype.name
         self.slots = slots if slots else _env_int("MXTPU_SERVING_SLOTS", 4)

@@ -693,9 +693,9 @@ class StepExecutor:
 
     # -- FLOP accounting ---------------------------------------------------
     def program_flops(self) -> Optional[float]:
-        """FLOPs of ONE execution of the current compiled step program (XLA
-        cost analysis; analytic conv/matmul jaxpr count as fallback —
-        ``observability.flops.estimate_step_flops``). Lazy and cached per
+        """FLOPs of ONE execution of the current compiled step program
+        (``observability.flops.estimate_step_flops``; which source counted
+        them is in ``get_mfu_stats()["flops_source"]``). Lazy and cached per
         cache entry: the first call after a trace pays one AOT lower+compile,
         subsequent calls are a dict read — callers (fit epoch logs, bench)
         keep this OFF the step hot path."""
@@ -704,9 +704,9 @@ class StepExecutor:
             return None
         if "flops" not in entry:
             from .observability import flops as flops_mod
-            entry["flops"] = flops_mod.estimate_step_flops(entry["jitted"],
-                                                           entry["avals"])
-            flops_mod.set_step_flops(entry["flops"])
+            entry["flops"], source = flops_mod.estimate_step_flops(
+                entry["jitted"], entry["avals"])
+            flops_mod.set_step_flops(entry["flops"], source)
         return entry["flops"]
 
     def audit_entry(self):

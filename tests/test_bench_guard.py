@@ -1,10 +1,10 @@
-"""Tier-1 bench guard (BENCH_r05 regression class: the cpu-fallback child
-crashed with rc=1 initializing the very backend it was escaping, and the
-broken bench rode along silently for a round).
+"""Tier-1 bench guard (regression class: a round-5 capture lost every leg
+to an rc=1 at backend init, and the broken bench rode along silently for a
+round).
 
-Contract: ``bench.py`` run as the CPU-fallback child (``MXTPU_BENCH_FALLBACK=1``
-— the exact re-exec environment ``main()`` builds) must exit 0 and emit ONE
-parseable JSON line on stdout with the fallback harness's full key set.
+Contract: ``bench.py`` on its explicit CPU harness (``MXTPU_BENCH_FALLBACK=1``;
+``main()`` never re-executes itself onto it) must exit 0 and emit ONE
+parseable JSON line on stdout with that harness's full key set.
 ``MXTPU_BENCH_SMOKE=1`` shrinks iteration counts so this runs in tier-1 time;
 the code path (imports, backend pin, every scenario, JSON emission) is the
 full one."""
@@ -23,7 +23,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run_fallback_bench(tmp_path, extra_env=None, args=()):
     env = conftest.subprocess_env()
-    # the exact env main()'s re-exec builds for the fallback child
+    # the explicit CPU harness
     env["MXTPU_BENCH_FALLBACK"] = "1"
     env["MXTPU_BENCH_SMOKE"] = "1"
     # ratchet candidates land in the test's tmp dir, never the repo file
@@ -277,7 +277,7 @@ def test_bench_cpu_fallback_exits_zero_and_emits_json(tmp_path):
 
 def test_bench_leg_failure_yields_partial_json(tmp_path):
     """A scenario raising a (simulated) transient backend error — the
-    BENCH_r05 crash shape — must NOT erase the scoreboard: the failing leg
+    round-5 crash shape — must NOT erase the scoreboard: the failing leg
     emits ``{"error": ...}``, a leg failing once is recovered by the shared
     ``retry_transient`` policy, and every other leg ships in an exit-0 JSON
     line."""

@@ -336,12 +336,35 @@ def test_estimate_step_flops_xla_and_analytic_agree(monkeypatch):
              jax.ShapeDtypeStruct((64, 128), jnp.float32))
     expect = 2 * 32 * 128 * 64
     monkeypatch.setenv("MXTPU_FLOPS_MODE", "analytic")
-    assert flops.estimate_step_flops(fn, avals) == expect
+    assert flops.estimate_step_flops(fn, avals) == (expect, "analytic")
     monkeypatch.setenv("MXTPU_FLOPS_MODE", "xla")
-    got = flops.estimate_step_flops(fn, avals)
-    assert got == pytest.approx(expect, rel=0.01)
+    got, source = flops.estimate_step_flops(fn, avals)
+    assert got == pytest.approx(expect, rel=0.01) and source == "xla"
     monkeypatch.setenv("MXTPU_FLOPS_MODE", "off")
-    assert flops.estimate_step_flops(fn, avals) is None
+    assert flops.estimate_step_flops(fn, avals) == (None, None)
+
+
+def test_estimate_step_flops_names_the_analytic_source_when_xla_fails(caplog):
+    """A failed cost model is logged and the count is labelled analytic —
+    never passed off as XLA's."""
+    import jax
+    import jax.numpy as jnp
+
+    class Broken:
+        def __init__(self, fn):
+            self._fn = fn
+
+        def lower(self, *avals):
+            raise RuntimeError("no AOT here")
+
+        def __call__(self, *args):
+            return self._fn(*args)
+
+    avals = (jax.ShapeDtypeStruct((8, 8), jnp.float32),) * 2
+    with caplog.at_level("WARNING", logger="mxtpu.observability"):
+        got = flops.estimate_step_flops(Broken(lambda a, b: a @ b), avals)
+    assert got == (2 * 8 * 8 * 8, "analytic")
+    assert "cost analysis failed" in caplog.text
 
 
 def test_fused_step_program_flops_nonzero():

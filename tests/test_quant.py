@@ -262,6 +262,13 @@ def test_kv_dtype_plumbs_bf16(net, trace, refs):
     assert outs == refs[:2]
 
 
+def test_integer_kv_dtype_is_refused(net):
+    """``kv_dtype="int8"`` would store K/V rows cast to integers; quantized
+    storage is ``quant="int8_kv"``."""
+    with pytest.raises(ValueError, match="int8_kv"):
+        ServingEngine(net, slots=2, kv_dtype="int8")
+
+
 def test_serving_config_carries_quant(net):
     # config plumbing only (the full decode path under int8_kv is covered
     # by the fixture runs above) — no need to start the engine

@@ -175,20 +175,32 @@ def test_decode_kernel_mode_validation(monkeypatch):
         qa.decode_kernel_mode("cuda")
 
 
-def test_resolve_decode_kernel_degrades_at_illegal_shapes(monkeypatch):
+def test_resolve_decode_kernel_only_auto_chooses(monkeypatch):
     monkeypatch.delenv("MXTPU_DECODE_KERNEL", raising=False)
     on_tpu = jax.default_backend() == "tpu"
-    # auto: backend decides
+    # auto: backend decides, and degrades per bucket where it cannot tile
     assert qa.resolve_decode_kernel() == ("pallas" if on_tpu else "xla")
-    # forced pallas at a legal bucket sticks
+    assert qa.resolve_decode_kernel(None, TOT=136, D=16) == "xla"
+    assert qa.resolve_decode_kernel(None, TOT=256, D=600) == "xla"
+    # explicit pallas at a legal bucket sticks
     assert qa.resolve_decode_kernel("pallas", TOT=128, D=16) == "pallas"
     # bucket 96: whole-axis blocks are interpret-legal only — on hardware
-    # the resolver must degrade (sub-128 vector loads are Mosaic-illegal)
-    want = "xla" if on_tpu else "pallas"
-    assert qa.resolve_decode_kernel("pallas", TOT=96, D=16) == want
-    # a non-tileable bucket and an oversized head dim both degrade
-    assert qa.resolve_decode_kernel("pallas", TOT=136, D=16) == "xla"
-    assert qa.resolve_decode_kernel("pallas", TOT=256, D=600) == "xla"
+    # sub-128 vector loads are Mosaic-illegal
+    if on_tpu:
+        with pytest.raises(ValueError, match="TOT=96"):
+            qa.resolve_decode_kernel("pallas", TOT=96, D=16)
+    else:
+        assert qa.resolve_decode_kernel("pallas", TOT=96, D=16) == "pallas"
+    # an explicit request that cannot be met raises — it never hands back
+    # xla under the pallas name (kwarg and env alike)
+    with pytest.raises(ValueError, match="TOT=136"):
+        qa.resolve_decode_kernel("pallas", TOT=136, D=16)
+    with pytest.raises(ValueError, match="D=600"):
+        qa.resolve_decode_kernel("pallas", TOT=256, D=600)
+    monkeypatch.setenv("MXTPU_DECODE_KERNEL", "pallas")
+    with pytest.raises(ValueError, match="TOT=136"):
+        qa.resolve_decode_kernel(None, TOT=136, D=16)
+    monkeypatch.delenv("MXTPU_DECODE_KERNEL")
     assert qa.resolve_decode_kernel("xla", TOT=256, D=16) == "xla"
 
 
