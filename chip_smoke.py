@@ -23,9 +23,12 @@ per-leg containment. Without a TPU it exits 1 before building a model. The
 only CPU mode is ``--rehearsal`` (``tiny`` preset, kernels interpreted): it
 checks this file's control flow in the sandbox and in tier 1, never speed.
 
-The last line of stdout is one JSON object; the full report also lands in
-``chiprun_out/chip_smoke/report.json``. Nothing here is a measurement of
-speed: wall times are reported so a cold and a warm run can be told apart.
+The last line of stdout is the verdict, one JSON object with exactly the
+keys ``ok`` and ``device`` (platform, kind, count as JAX reports them). The
+line before it is the JSON summary of the run (ending ``"claim": null``); the
+full report also lands in ``chiprun_out/chip_smoke/report.json``. A run that
+fails prints neither. Nothing here is a measurement of speed: wall times are
+reported so a cold and a warm run can be told apart.
 """
 
 from __future__ import annotations
@@ -610,7 +613,12 @@ def main(argv=None) -> int:
     sz = SIZES["chip" if on_chip else "rehearsal"]
 
     import jaxlib
-    from mxtpu import compile_cache
+    try:
+        from mxtpu import compile_cache
+    except ImportError as e:
+        print(f"chip_smoke: needs the mxtpu package beside it in {HERE} "
+              f"({e})", file=sys.stderr)
+        return 1
     cache_dir = compile_cache.place()
     counter = CompileCounter()
     devs = jax.devices()
@@ -685,6 +693,8 @@ def main(argv=None) -> int:
         "kernels": {f"{r['kernel']} @ {r['shape']}": r["status"]
                     for r in report["legs"]["kernels"]["rows"]},
         "multichip": report["multichip"], "claim": None}))
+    # the verdict: last line, these two keys and no other
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

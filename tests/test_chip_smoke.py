@@ -37,9 +37,14 @@ def _run_smoke(args, tmp_path, virtual_devices=0):
 def test_rehearsal_walks_every_leg_and_marks_its_output(tmp_path):
     p = _run_smoke(["--rehearsal"], tmp_path, virtual_devices=4)
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
-    doc = json.loads(p.stdout.strip().splitlines()[-1])
+    summary, verdict = p.stdout.strip().splitlines()[-2:]
+    # the last line is the verdict: exactly these keys, the device as JAX
+    # reports it; the summary of the run is the line before
+    device = {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert json.loads(verdict) == {"ok": True, "device": device}
+    doc = json.loads(summary)
     assert doc["ok"] is True and doc["rehearsal"] is True
-    assert doc["device"] == {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert doc["device"] == device
     assert doc["compile_cache"]["dir"] == str(tmp_path / "jax_cache")
     assert doc["losses"][-1] < doc["losses"][0]
     assert doc["kernels"] and set(doc["kernels"].values()) == {"compiled"}
