@@ -95,10 +95,10 @@ SIZES = {
     ),
 }
 
-FLASH_FWD = "_flash_fwd_kernel"
-FLASH_BWD_SPLIT = ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel")
-FLASH_BWD_FUSED = "_flash_bwd_fused_kernel"
-DEQUANT_DECODE = "_dequant_decode_kernel"
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_SPLIT = ("flash_bwd_dq", "flash_bwd_dkv")
+FLASH_BWD_FUSED = "flash_bwd_fused"
+DEQUANT_DECODE = "decode_attn_quant"
 
 
 def say(msg: str) -> None:

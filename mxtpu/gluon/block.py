@@ -21,6 +21,8 @@ import threading
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+import jax
+
 from .. import ndarray as nd_mod
 from ..jit import CachedOp, export_stablehlo
 from ..ndarray.ndarray import NDArray
@@ -83,6 +85,11 @@ class Block:
         self._prefix, self._params = _BlockScope.create(prefix, params, hint)
         self._name = self._prefix[:-1] if self._prefix.endswith("_") else self._prefix
         self._scope = _BlockScope(self)
+        # the device scope ``forward`` runs under: the name the parent
+        # registers this block under (``block3``, ``attn``, ``ffn1``), the
+        # class name for a root. Not the prefix: its counters differ from
+        # one instance to the next, and a trace reader's pattern must not
+        self._scope_name = type(self).__name__
         self._children: "OrderedDict[str, Block]" = OrderedDict()
         self._forward_hooks: List[Callable] = []
         self._forward_pre_hooks: List[Callable] = []
@@ -93,6 +100,7 @@ class Block:
             existing = self.__dict__.get("_children")
             if existing is not None:
                 existing[name] = value
+                value._scope_name = name
         elif isinstance(value, Parameter):
             params = self.__dict__.get("_params")
             if params is not None:
@@ -100,7 +108,9 @@ class Block:
         super().__setattr__(name, value)
 
     def register_child(self, block: "Block", name: Optional[str] = None):
-        self._children[name or str(len(self._children))] = block
+        name = name or str(len(self._children))
+        self._children[name] = block
+        block._scope_name = name
 
     def register_forward_hook(self, hook):
         self._forward_hooks.append(hook)
@@ -208,13 +218,20 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        out = self._scoped_forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
 
     def forward(self, *args):
         raise NotImplementedError
+
+    def _scoped_forward(self, *args, **kwargs):
+        """``forward`` under this block's ``jax.named_scope``: metadata on
+        every operation traced inside (``block3/attn/q_proj/...``), which
+        the compiled program and the device trace carry; adds no operation."""
+        with jax.named_scope(self._scope_name):
+            return self.forward(*args, **kwargs)
 
     def hybridize(self, active: bool = True, **kwargs):
         """No-op on plain Blocks except recursing into children (reference parity)."""
@@ -280,7 +297,7 @@ class HybridBlock(Block):
                 params = [p.data() for p in self.collect_params().values()
                           if p._data is not None]
                 self._cached_op = CachedOp(
-                    lambda *xs: self.forward(*xs), params=params,
+                    self._scoped_forward, params=params,
                     static_alloc=self._flags.get("static_alloc", False),
                     static_shape=self._flags.get("static_shape", False))
             return self._cached_op(*args)

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import math
 
+import jax
+
 from ... import ndarray as nd
 from ..block import HybridBlock
 from ..contrib.nn import MultiHeadAttention, _layout_constrain
@@ -100,8 +102,9 @@ class TransformerLM(HybridBlock):
             raise ValueError(f"sequence length {T} exceeds max_len "
                              f"{self._max_len}")
         h = self.embedding(tokens)
-        pos = nd.slice_axis(self.pos_embed.data(), axis=0, begin=0, end=T)
-        h = h + nd.reshape(pos, (1, T, self._units))
+        with jax.named_scope("embed"):
+            pos = nd.slice_axis(self.pos_embed.data(), axis=0, begin=0, end=T)
+            h = h + nd.reshape(pos, (1, T, self._units))
         # composed-flagship layout: activations ride the SpecLayout table
         # (sequence-sharded through the block stack under a layout_scope,
         # identity otherwise)
@@ -112,10 +115,11 @@ class TransformerLM(HybridBlock):
         if not self._tie:
             return self.head(h)
         # tied softmax head: logits = h · Eᵀ over the embedding table
-        w = self.embedding.weight.data()
-        flat = nd.reshape(h, (B * T, self._units))
-        return nd.reshape(nd.dot(flat, w, transpose_b=True),
-                          (B, T, self._vocab))
+        with jax.named_scope("head"):
+            w = self.embedding.weight.data()
+            flat = nd.reshape(h, (B * T, self._units))
+            return nd.reshape(nd.dot(flat, w, transpose_b=True),
+                              (B, T, self._vocab))
 
     # -- autoregressive decoding (TPU-first: one jitted scan, static KV
     # cache — no per-token dispatch, no dynamic shapes) ---------------------

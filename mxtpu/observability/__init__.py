@@ -7,9 +7,12 @@ instrumentation point the framework already had — the fused-step cache, the
 DeviceFeed producer, the ZeRO comm path, the async checkpoint writer — into
 **spans on one step timeline**:
 
-* :mod:`.tracer` — per-thread span recorder (lock-free-ish bounded rings;
-  near-zero cost when off; ``MXTPU_TRACE=1`` or ``profiler.set_state('run')``
-  arms it; spans mirror into ``jax.profiler.TraceAnnotation``).
+* :mod:`.tracer` — per-thread span recorder: every span opens its
+  ``jax.profiler.TraceAnnotation`` (so spans reach any ``jax.profiler``
+  session unarmed) and counts into the totals by name
+  (``profiler.get_span_totals()``); ``MXTPU_TRACE=1`` or
+  ``profiler.set_state('run')`` arms the lock-free-ish bounded rings, whose
+  events carry ``id`` and ``parent``.
 * :mod:`.export` — chrome-trace JSON serialization (pid/tid rows per thread,
   metadata names, per-request swim-lanes, the ``profiler.dump()``/
   ``dumps()`` body, ``request_timeline``).
@@ -32,6 +35,19 @@ directly is for framework internals and tests.
 Span catalog (see docs/observability.md):
 
 ==========================  =================================================
+``train/step``              one ``DataParallelTrainer.step`` (args: step)
+``train/collect``           first call: eager forward, placement, slots
+``train/build``             first call: the step function is built
+``train/place``             the batch onto the mesh (``shard_batch``)
+``train/prepare``           scalars, PRNG key, argument lists
+``train/compile``           the step program's call when it traces
+``train/dispatch``          the step program's call otherwise
+``train/adopt``             handle swap, state swap, comm record
+``train/readback``          ``float(loss)``: the host waits for the device
+``jax/trace``               JAX traced a function (args: fun)
+``jax/lower``               JAX lowered a program to StableHLO (args: fun)
+``jax/compile``             XLA compiled, or the cache loaded (args: fun)
+``jax/cache_hit``           instant: persistent compile cache hit
 ``step/compile``            trace+lower+compile of a fused step
 ``step/execute``            one cache-hit fused-step dispatch
 ``feed/transfer``           DeviceFeed producer staging one batch
@@ -55,6 +71,13 @@ Span catalog (see docs/observability.md):
 ``serving/drained``         instant: handoff complete (args: ids)
 ``serving/adopted``         instant: adoption complete (args: ids)
 ==========================  =================================================
+
+Names on the device (a ``jax.profiler`` trace's operations): Pallas kernels
+``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``flash_bwd_fused``,
+``decode_attn_quant``; scopes ``<RootBlock>/block<i>/attn/q_proj`` … from
+``Block.__call__`` (the name the parent registered the child under),
+``embed``, ``head`` (``TransformerLM``), ``loss``, ``optimizer``,
+``optimizer/zero`` (``DataParallelTrainer``).
 """
 
 from . import (exporter, export, flight, flops, histogram, metrics,  # noqa

@@ -22,7 +22,7 @@ from typing import List, Optional
 from . import tracer
 
 __all__ = ["collect_events", "chrome_trace", "write_chrome_trace",
-           "aggregate", "request_timeline", "request_lane_events",
+           "request_timeline", "request_lane_events",
            "REQUIRED_SPAN_KEYS", "REQUEST_LANE_PID"]
 
 # the schema contract tests validate exported "X" events against
@@ -54,6 +54,12 @@ def collect_events(legacy_events: Optional[List[dict]] = None) -> List[dict]:
             e = dict(ev)
             e["pid"] = pid
             e["tid"] = tid
+            if "id" in e:
+                # the span's id and its parent's travel in ``args`` (a
+                # top-level ``id`` means an async event to the viewers; the
+                # serving spans' ``args.id`` is the request's)
+                e["args"] = dict(e.get("args") or {}, span_id=e.pop("id"),
+                                 parent_id=e.pop("parent"))
             events.append(e)
     for ev in legacy_events or []:
         e = dict(ev)
@@ -144,20 +150,3 @@ def write_chrome_trace(fname: str, payload: dict) -> str:
         json.dump(payload, f)
     os.replace(tmp, fname)   # readers never observe a torn dump
     return fname
-
-
-def aggregate(events: List[dict]) -> dict:
-    """Per-name duration stats over "X" spans:
-    ``{name: [count, total_ms, min_ms, max_ms]}`` — the data behind the
-    reference's aggregate-stats table (``profiler.get_summary()``)."""
-    stats: dict = {}
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        s = stats.setdefault(e["name"], [0, 0.0, float("inf"), 0.0])
-        dur = e.get("dur", 0.0) / 1000.0  # us -> ms
-        s[0] += 1
-        s[1] += dur
-        s[2] = min(s[2], dur)
-        s[3] = max(s[3], dur)
-    return stats

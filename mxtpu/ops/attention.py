@@ -379,6 +379,7 @@ def _flash_attention_pallas(q, k, v, causal: bool, scale: float,
             jax.ShapeDtypeStruct((B * H, T, Dp), q.dtype),
             jax.ShapeDtypeStruct((B * H, 8, T), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(qq, kk, vv)
     return out[..., :D].reshape(B, H, T, D), lse[:, 0, :]
@@ -441,6 +442,7 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
                 jax.ShapeDtypeStruct((B * H, Tk, Dp), k.dtype),
                 jax.ShapeDtypeStruct((B * H, Tk, Dp), v.dtype),
             ],
+            name="flash_bwd_fused",
             interpret=interpret,
         )(qq, kk, vv, gg, lse, delta)
         return (dq[..., :D].reshape(B, H, T, D),
@@ -462,6 +464,7 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
         ],
         out_specs=pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, Dp), q.dtype),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(qq, kk, vv, gg, lse, delta)
 
@@ -486,6 +489,7 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
             jax.ShapeDtypeStruct((B * H, Tk, Dp), k.dtype),
             jax.ShapeDtypeStruct((B * H, Tk, Dp), v.dtype),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(qq, kk, vv, gg, lse, delta)
 

@@ -191,6 +191,7 @@ def _decode_pallas(q, kd, ks, vd, vs, pc, scale: float, interpret: bool):
         ],
         out_specs=pl.BlockSpec((1, 8, dp), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, 8, dp), q.dtype),
+        name="decode_attn_quant",
         interpret=interpret,
     )(lim, q8, kd2, ks2, vd2, vs2)
     return out[:, 0, :D].reshape(S, H, D)

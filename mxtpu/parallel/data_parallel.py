@@ -21,6 +21,7 @@ import numpy as np
 from .. import autograd
 from .. import ndarray as nd_mod
 from ..ndarray.ndarray import NDArray
+from ..observability import metrics, tracer
 from ..ops import attention as attention_ops
 from ..step_cache import build_update_all, cache_stats
 from . import fsdp as fsdp_mod
@@ -142,6 +143,7 @@ class DataParallelTrainer:
             zero_mod.comm_dtype_of(compression_params)  # validate the kind
         self._compression_params = compression_params
         self._step_fn = None
+        self._t = 0
         self._params: List = []
         self._states: List = []
         self._zero_layout = None
@@ -292,7 +294,10 @@ class DataParallelTrainer:
                         p._data._version += 1
                     with autograd.pause(train_mode=True):
                         out = block(nd_mod.NDArray(xb))
-                        loss = loss_fn(out, nd_mod.NDArray(yb))
+                        # around the loss alone: around value_and_grad every
+                        # backward operation would read "loss"
+                        with jax.named_scope("loss"):
+                            loss = loss_fn(out, nd_mod.NDArray(yb))
                     new_auxs = [p._data._data for p in aux_handles]
                     return jnp.mean(loss.data), new_auxs
 
@@ -372,21 +377,22 @@ class DataParallelTrainer:
                     (loss_val, new_auxs), grads = jax.value_and_grad(
                         loss_of, has_aux=True)(list(params))
                     packed = None
-                if zero_update is not None:
-                    new_params, new_zstates, new_zres = zero_update(
-                        list(params), list(grads), zstates, zres,
-                        lr, wd, rescale, clip, t, packed_grads=packed)
-                else:
-                    new_params = list(params)
-                    new_zstates, new_zres = zstates, zres
+                new_params = list(params)
+                new_zstates, new_zres = zstates, zres
                 new_states = [()] * len(param_handles)
-                if pt:
-                    sub_w, sub_st = update_pt(
-                        [new_params[i] for i in pt], [grads[i] for i in pt],
-                        [states[i] for i in pt], lr, wd, rescale, clip, t)
-                    for j, i in enumerate(pt):
-                        new_params[i] = sub_w[j]
-                        new_states[i] = sub_st[j]
+                with jax.named_scope("optimizer"):
+                    if zero_update is not None:
+                        new_params, new_zstates, new_zres = zero_update(
+                            new_params, list(grads), zstates, zres,
+                            lr, wd, rescale, clip, t, packed_grads=packed)
+                    if pt:
+                        sub_w, sub_st = update_pt(
+                            [new_params[i] for i in pt],
+                            [grads[i] for i in pt], [states[i] for i in pt],
+                            lr, wd, rescale, clip, t)
+                        for j, i in enumerate(pt):
+                            new_params[i] = sub_w[j]
+                            new_states[i] = sub_st[j]
                 return (new_params, new_auxs, new_states, new_zstates,
                         new_zres, loss_val)
             finally:
@@ -414,19 +420,29 @@ class DataParallelTrainer:
                           None),
             out_shardings=(self._param_sh, repl, self._state_sh, zstate_sh,
                            zres_sh, repl))
+        self._comm_step = self._comm_record()
 
     def step_async(self, x, y) -> NDArray:
         """One SPMD train step; returns the loss WITHOUT a host sync, so callers
         can keep the device queue full (JAX async dispatch ≈ the reference
         engine's lazy push; WaitToRead happens when the caller materializes the
         loss)."""
+        with tracer.span("train/step", args={"step": self._t + 1}):
+            return self._issue(x, y)
+
+    def _issue(self, x, y) -> NDArray:
+        """Everything of one step up to the handle swap, under the caller's
+        ``train/step`` span; every span carries the step's number."""
         x = x if isinstance(x, NDArray) else nd_mod.array(x)
         y = y if isinstance(y, NDArray) else nd_mod.array(y)
-        if self._step_fn is None:
+        step = {"step": self._t + 1}
+        built = self._step_fn is None
+        if built:
             self._stats.miss()
-            self._collect(x)
-            self._build()
-            self._t = 0
+            with tracer.span("train/collect", args=step):
+                self._collect(x)
+            with tracer.span("train/build", args=step):
+                self._build()
         else:
             self._stats.hit()
         if self.micro_batches > 1 and x.shape[0] % self.micro_batches:
@@ -434,50 +450,61 @@ class DataParallelTrainer:
                 f"batch size {x.shape[0]} is not divisible by "
                 f"micro_batches={self.micro_batches}; pad or drop the tail "
                 f"batch (ImageRecordIter marks it with .pad)")
-        xs = shard_batch(x, self.mesh).data
-        ys = shard_batch(y, self.mesh).data
-        self._t += 1
-        opt = self.optimizer
-        lr = jnp.asarray(opt.learning_rate, jnp.float32)
-        wd = jnp.asarray(opt.wd, jnp.float32)
-        # grads are mean-loss grads already; rescale stays 1 (clip honors the
-        # optimizer's clip_gradient, a static variant inside update_all)
-        rescale = jnp.float32(1.0)
-        clip = jnp.float32(opt.clip_gradient
-                           if opt.clip_gradient is not None else 0.0)
-        key = jax.random.key(self._t)
-        params = [p.data().data for p in self._param_handles]
-        auxs = [p.data().data for p in self._aux_handles]
-        args = (params, auxs, self._states, self._zero_states,
-                self._zero_residuals, xs, ys, lr, wd, rescale, clip,
-                key, self._t)
-        # keep only avals (shape/dtype) for cost_analysis — holding the real
-        # arrays would pin the previous step's buffers in HBM
-        self._last_avals = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
-            if hasattr(a, "shape") else a, args)
-        with self._kernel_scope():          # the first call traces
+        with tracer.span("train/place", args=step):
+            xs = shard_batch(x, self.mesh).data
+            ys = shard_batch(y, self.mesh).data
+        with tracer.span("train/prepare", args=step):
+            self._t += 1
+            opt = self.optimizer
+            lr = jnp.asarray(opt.learning_rate, jnp.float32)
+            wd = jnp.asarray(opt.wd, jnp.float32)
+            # grads are mean-loss grads already; rescale stays 1 (clip honors
+            # the optimizer's clip_gradient, a static variant inside
+            # update_all)
+            rescale = jnp.float32(1.0)
+            clip = jnp.float32(opt.clip_gradient
+                               if opt.clip_gradient is not None else 0.0)
+            key = jax.random.key(self._t)
+            params = [p.data().data for p in self._param_handles]
+            auxs = [p.data().data for p in self._aux_handles]
+            args = (params, auxs, self._states, self._zero_states,
+                    self._zero_residuals, xs, ys, lr, wd, rescale, clip,
+                    key, self._t)
+            batch_sig = (xs.shape, xs.dtype, ys.shape, ys.dtype)
+            # a new signature: the call below traces and compiles
+            traces = built or batch_sig != self._avals_batch
+            if traces:
+                # keep only avals (shape/dtype) for cost_analysis — holding
+                # the real arrays would pin the previous step's buffers in
+                # HBM; they change only with the batch's shape
+                self._avals_batch = batch_sig
+                self._last_avals = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                    if hasattr(a, "shape") else a, args)
+        with tracer.span("train/compile" if traces else "train/dispatch",
+                         args=step), self._kernel_scope():
             (new_params, new_auxs, new_states, new_zstates, new_zres,
              loss) = self._step_fn(*args)
-        for p, v in zip(self._param_handles, new_params):
-            p._data._data = v
-            p._data._version += 1
-        for p, v in zip(self._aux_handles, new_auxs):
-            p._data._data = v
-            p._data._version += 1
-        self._states = new_states
-        self._zero_states = new_zstates
-        self._zero_residuals = new_zres
-        self.optimizer.num_update = self._t
-        self._record_comm()
+        with tracer.span("train/adopt", args=step):
+            for p, v in zip(self._param_handles, new_params):
+                p._data._data = v
+                p._data._version += 1
+            for p, v in zip(self._aux_handles, new_auxs):
+                p._data._data = v
+                p._data._version += 1
+            self._states = new_states
+            self._zero_states = new_zstates
+            self._zero_residuals = new_zres
+            self.optimizer.num_update = self._t
+            metrics.record_comm_step(**self._comm_step)
         return NDArray(loss)
 
-    def _record_comm(self):
-        """Per-step comm accounting (profiler.get_comm_stats): analytic
-        per-device ring bytes — reduce-scatter + all-gather legs on the ZeRO
-        path, the full-allreduce equivalent on the replicated path — so the
-        two paths are directly comparable."""
-        from .. import profiler
+    def _comm_record(self) -> dict:
+        """One step's comm accounting (profiler.get_comm_stats), worked out
+        once when the step is built: analytic per-device ring bytes —
+        reduce-scatter + all-gather legs on the ZeRO path, the full-allreduce
+        equivalent on the replicated path — so the two paths are directly
+        comparable."""
         n = data_size(self.mesh)
         if self.zero and self._zero_layout is not None:
             c = self._zero_layout.step_comm()
@@ -500,15 +527,13 @@ class DataParallelTrainer:
                 frac = (nf - 1) / nf if nf > 1 else 0.0
                 c["bytes_gathered"] += int(2 * fsdp_bytes * frac)
                 c["bytes_reduced"] += int(fsdp_bytes * frac)
-            profiler.record_comm_step(zero=True, allreduce_bytes=0, **c)
-        else:
-            frac = 2.0 * (n - 1) / n if n > 1 else 0.0
-            grad_bytes = sum(
-                int(np.prod(p.data().shape))
-                * np.dtype(str(p.data().dtype)).itemsize
-                for p in self._param_handles)
-            profiler.record_comm_step(dp=n,
-                                      allreduce_bytes=int(grad_bytes * frac))
+            return dict(c, zero=True, allreduce_bytes=0)
+        frac = 2.0 * (n - 1) / n if n > 1 else 0.0
+        grad_bytes = sum(
+            int(np.prod(p.data().shape))
+            * np.dtype(str(p.data().dtype)).itemsize
+            for p in self._param_handles)
+        return {"dp": n, "allreduce_bytes": int(grad_bytes * frac)}
 
     def optimizer_state_bytes(self) -> int:
         """Optimizer-slot bytes RESIDENT PER DEVICE (the ZeRO-1 headline
@@ -531,8 +556,24 @@ class DataParallelTrainer:
                  for s in (st or ()) if hasattr(s, "dtype")]
         return slots + [r for r in self._zero_residuals if r is not None]
 
+    def optimizer_state_by_param(self) -> dict:
+        """``{parameter name: tuple of its optimizer slots, each in the
+        parameter's own shape}`` (Adam: first and second moment), whichever
+        way the step carries them: ZeRO's packed buckets are unpacked through
+        the layout. Valid after the first step."""
+        by_index = dict(enumerate(self._states))
+        if self._zero_layout is not None:
+            by_index.update(jax.jit(
+                lambda st: zero_mod.unpack_states(self._zero_layout, st))(
+                    self._zero_states))
+        return {n: tuple(by_index[i])
+                for i, n in enumerate(self._param_names)}
+
     def step(self, x, y) -> float:
-        return float(self.step_async(x, y).data)
+        with tracer.span("train/step", args={"step": self._t + 1}):
+            loss = self._issue(x, y)
+            with tracer.span("train/readback", args={"step": self._t}):
+                return float(loss.data)
 
     def device_feed(self, batches, depth: Optional[int] = None):
         """Wrap an iterable of ``(x, y)`` batches (or ``DataBatch``es) in a

@@ -360,6 +360,25 @@ def _unpack_bucket(new_w_full, b: ZeroBucket, n: int):
     return outs
 
 
+def unpack_states(layout: ZeroLayout,
+                  states: Sequence[Tuple]) -> Dict[int, Tuple]:
+    """``{parameter index: its optimizer slots in the parameter's own
+    shape}`` for every bucketed parameter: each bucket-shaped slot is
+    de-interleaved as the updated weights are; a slot of another shape (a
+    scalar schedule) belongs to the whole bucket and is handed to each of
+    its parameters as it is. Traceable."""
+    out: Dict[int, Tuple] = {}
+    for b, st in zip(layout.buckets, states):
+        per_slot = [
+            [flat.reshape(shape) for flat, shape in
+             zip(_unpack_bucket(s, b, layout.dp), b.shapes)]
+            if getattr(s, "shape", None) == (b.padded,)
+            else [s] * len(b.indices) for s in st]
+        for k, i in enumerate(b.indices):
+            out[i] = tuple(slot[k] for slot in per_slot)
+    return out
+
+
 def init_zero_states(opt, layout: ZeroLayout, param_raws, mesh: Mesh,
                      with_residual: bool = False):
     """Create per-bucket optimizer slots, placed data-sharded (1/N resident
@@ -475,6 +494,8 @@ def build_zero_update(opt, layout: ZeroLayout, mesh: Mesh,
     clipped = opt.clip_gradient is not None
     thr = float((compression_params or {}).get("threshold", 0.5))
 
+    # the bucket update's device operations read "<caller's scope>/zero"
+    @jax.named_scope("zero")
     def zero_update(params, grads, states, residuals, lr, wd, rescale, clip, t,
                     packed_grads=None):
         new_params = list(params)

@@ -21,9 +21,10 @@ This module is the user-facing FACADE over :mod:`mxtpu.observability`:
   ``reset_*`` for checkpoint, device-feed, comm, sanitizer) is re-exported
   unchanged from ``observability.metrics``.
 
-Tracing is opt-in — ``MXTPU_TRACE=1`` (the ``MXNET_PROFILER_AUTOSTART``
-analogue) or ``profiler.set_state('run')`` — and the off path is a single
-bool test per instrumentation point. The legacy Domain/Task/Counter/Marker
+The span ring is opt-in — ``MXTPU_TRACE=1`` (the ``MXNET_PROFILER_AUTOSTART``
+analogue) or ``profiler.set_state('run')``; unarmed, a span still opens its
+``TraceAnnotation`` (so any ``jax.profiler`` session carries it) and counts
+into ``get_span_totals()``. The legacy Domain/Task/Counter/Marker
 objects keep their original always-on local event list (``_state['events']``)
 AND emit real spans onto the unified timeline when tracing is armed.
 """
@@ -158,8 +159,8 @@ def resume(profile_process: str = "worker"):
 
 
 def reset_trace():
-    """Drop every recorded span/event and unfreeze a finished dump (tests,
-    back-to-back bench legs)."""
+    """Drop every recorded span/event, zero the span totals and unfreeze a
+    finished dump (tests, back-to-back bench legs)."""
     _tracer.reset()
     with _stats_lock:
         _state["events"] = []
@@ -191,22 +192,32 @@ def dump(finished: bool = True, profile_process: str = "worker"):
     return fname
 
 
+def get_span_totals() -> dict:
+    """Count and summed seconds of every span name since the last
+    ``reset_trace()``, kept whether or not the ring is armed:
+    ``{name: {"count", "seconds", "min_s", "max_s", "by_parent": {name of the
+    span open on the thread when it began, or "": seconds}}}``. The framework's
+    spans, the legacy Domain/Task objects and JAX's compile phases
+    (``jax/trace``, ``jax/lower``, ``jax/compile``) all count."""
+    return _tracer.totals()
+
+
 def get_summary(sort_by: str = "total") -> str:
     """Aggregate-stats table (MXAggregateProfileStatsPrint / aggregate_stats.cc
-    parity): per-name count, total/avg/min/max duration over every recorded
-    span — the unified tracer's rings AND the legacy custom-object events."""
-    with _stats_lock:
-        legacy = list(_state["events"])
-    stats = _export.aggregate(_export.collect_events(legacy))
-    key = {"total": lambda kv: -kv[1][1], "count": lambda kv: -kv[1][0],
-           "avg": lambda kv: -(kv[1][1] / max(kv[1][0], 1)),
+    parity): per-name count, total/avg/min/max duration, printed from
+    :func:`get_span_totals`."""
+    key = {"total": lambda kv: -kv[1]["seconds"],
+           "count": lambda kv: -kv[1]["count"],
+           "avg": lambda kv: -(kv[1]["seconds"] / kv[1]["count"]),
            "name": lambda kv: kv[0]}[sort_by]
     lines = [f"{'Name':<40s}{'Count':>8s}{'Total(ms)':>12s}{'Avg(ms)':>10s}"
              f"{'Min(ms)':>10s}{'Max(ms)':>10s}"]
     lines.append("-" * len(lines[0]))
-    for name, (cnt, tot, mn, mx) in sorted(stats.items(), key=key):
-        lines.append(f"{name:<40s}{cnt:>8d}{tot:>12.3f}{tot/cnt:>10.3f}"
-                     f"{mn:>10.3f}{mx:>10.3f}")
+    for name, t in sorted(get_span_totals().items(), key=key):
+        tot = t["seconds"] * 1e3
+        lines.append(f"{name:<40s}{t['count']:>8d}{tot:>12.3f}"
+                     f"{tot / t['count']:>10.3f}{t['min_s'] * 1e3:>10.3f}"
+                     f"{t['max_s'] * 1e3:>10.3f}")
     return "\n".join(lines)
 
 

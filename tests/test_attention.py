@@ -409,8 +409,30 @@ def test_flash_lowers_for_tpu_only_inside_shard_map(engage_kernels):
     with A.partition_scope(mesh, P("dp")):
         text = lower().as_text()
     assert sorted(re.findall(r'kernel_name = "([^"]+)"', text)) == [
-        "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel", "_flash_fwd_kernel"]
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
     assert "tensor<4x128x128xbf16>" in text     # (8/4 batch x 2 heads, T, Dp)
+
+
+@pytest.mark.parametrize("bwd,names", [
+    ("split", ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]),
+    ("fused", ["flash_bwd_fused", "flash_fwd"])])
+def test_flash_kernels_carry_their_names_in_the_tpu_lowering(
+        engage_kernels, monkeypatch, bwd, names):
+    """Each ``pallas_call`` passes ``name=``: the custom call's
+    ``kernel_name`` and the ``op_name`` of the HLO instruction (what the
+    device trace shows) tell forward from dq from dk/dv."""
+    import re
+    monkeypatch.setenv("MXTPU_FLASH_BWD", bwd)
+    engage_kernels(interpret=False)
+    av = jax.ShapeDtypeStruct((2, 2, 128, 64), jnp.bfloat16)
+    f = jax.jit(jax.grad(lambda q, k, v: _sq_loss(q, k, v),
+                         argnums=(0, 1, 2)))
+    text = f.trace(av, av, av).lower(lowering_platforms=("tpu",)).as_text(
+        debug_info=True)
+    assert sorted(re.findall(r'kernel_name = "([^"]+)"', text)) == names
+    for name in names:
+        assert re.search(r'loc\("[^"]*\(%s\)[^"]*pallas_call' % name, text) \
+            or re.search(r'loc\("[^"]*%s[^"]*"' % name, text), name
 
 
 @pytest.mark.multi_device(4)

@@ -3,7 +3,7 @@ tracer, chrome-trace export, and MFU accounting (ISSUE 6).
 
 Contracts future PRs cannot silently break:
 
-* the tracing-OFF fast path records nothing (and a traced 2-epoch LeNet fit
+* the tracing-OFF path records nothing in the ring (and a traced 2-epoch LeNet fit
   is bit-exact with the untraced one — tracing observes, never perturbs);
 * spans nest correctly and land on per-thread rows (feed producer and
   checkpoint writer get their own named tid lanes);
@@ -55,17 +55,15 @@ def _clean_tracer():
 # ---------------------------------------------------------------------------
 
 
-def test_off_fast_path_records_nothing():
+def test_off_path_records_nothing_in_the_ring():
     assert not tracer.enabled()
-    null = tracer.span("step/execute")
-    with null:
+    with tracer.span("step/execute"):
         pass
-    # the off path hands back ONE shared no-op object — no per-call alloc;
-    # the bare (never-entered) span IS this test's subject
-    assert tracer.span("feed/transfer") is null  # mxtpu: ignore[R006]
     tracer.counter("feed/queue_depth", 3)
     tracer.instant("marker")
     assert all(not evs for _, _, evs, _ in tracer.snapshot_buffers())
+    # unarmed, a span still counts into the totals by name
+    assert profiler.get_span_totals()["step/execute"]["count"] == 1
 
 
 def test_spans_nest_on_one_thread():
@@ -182,6 +180,10 @@ def _fit_lenet(epochs=2, batch=16, n=64, ckpt_dir=None):
     mod.fit(it, num_epoch=epochs, optimizer="sgd",
             optimizer_params={"learning_rate": 0.05, "momentum": 0.9},
             epoch_end_callback=cb)
+    if ckpt_dir is not None:
+        # the writer thread records ckpt/write and ckpt/commit when a save
+        # ENDS: on a loaded box the last one is still in flight here
+        mgr.close()
     arg, aux = mod.get_params()
     return [v.asnumpy() for v in list(arg.values()) + list(aux.values())]
 

@@ -34,8 +34,10 @@ def _mlp(seed=0, in_units=10, hidden=32, classes=3):
 
 def _sorted_params(net_or_mod):
     if hasattr(net_or_mod, "collect_params"):
+        # in registration order: the prefixes count instances per process,
+        # and "dense10_" sorts before "dense9_"
         return [p.data().asnumpy()
-                for _, p in sorted(net_or_mod.collect_params().items())]
+                for p in net_or_mod.collect_params().values()]
     return [v.asnumpy()
             for _, v in sorted(net_or_mod.get_params()[0].items())]
 
