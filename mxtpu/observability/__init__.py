@@ -77,7 +77,9 @@ Names on the device (a ``jax.profiler`` trace's operations): Pallas kernels
 ``decode_attn_quant``; scopes ``<RootBlock>/block<i>/attn/q_proj`` … from
 ``Block.__call__`` (the name the parent registered the child under),
 ``embed``, ``head`` (``TransformerLM``), ``loss``, ``optimizer``,
-``optimizer/zero`` (``DataParallelTrainer``).
+``optimizer/zero`` (``DataParallelTrainer``; at a data degree of 1 only the
+flat leaves' update is under ``optimizer/zero``, a matrix's under
+``optimizer`` alone).
 """
 
 from . import (exporter, export, flight, flops, histogram, metrics,  # noqa

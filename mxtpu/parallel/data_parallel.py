@@ -126,9 +126,17 @@ class DataParallelTrainer:
         params are all-gathered back (parallel/zero.py). Works on any mesh —
         tensor-parallel-sharded params keep the per-param update; at stage 3
         shardable params are instead RESIDENT 1/N on the ``fsdp`` axis
-        (parallel/fsdp.py). ``compression_params`` (KVStore
+        (parallel/fsdp.py). Where the mesh's data degree is 1 there is
+        nothing to shard, and packing a matrix into a flat bucket is a
+        relayout that buys nothing: only parameters that are flat already
+        (biases, norm scales) are bucketed there, every matrix takes the
+        per-param update in its own shape and layout, and
+        ``profiler.get_comm_stats()["shard_bytes_per_device"]`` shrinks to
+        the flat leaves' bytes. ``compression_params`` (KVStore
         ``set_gradient_compression`` dict: type 2bit|fp16|bf16) lowers the
-        bucket payload with an error-feedback residual."""
+        bucket payload with an error-feedback residual; both live on the
+        bucket path, so with compression every replicated parameter stays
+        bucketed at every degree, 1 included."""
         self.block = block
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -205,8 +213,19 @@ class DataParallelTrainer:
         if self.zero:
             # replicated params bucket into data-sharded flat slots; tp- and
             # fsdp-sharded params keep the per-param update below (their
-            # slots follow the param's sharding, so fsdp slots are 1/N too)
-            eligible = [sh.spec == P() for sh in self._param_sh]
+            # slots follow the param's sharding, so fsdp slots are 1/N too).
+            # At a data degree of 1 a bucket shards nothing and packing a
+            # matrix is a relayout, so only leaves that are flat already
+            # stay bucketed (one output buffer in place of hundreds: the
+            # runtime allocates each one every step) and every matrix takes
+            # the per-param update; compression keeps all its buckets, where
+            # its residual lives (parallel/zero.py's docstring has the why)
+            flat_only = (data_size(self.mesh) == 1
+                         and self._compression_params is None)
+            eligible = [sh.spec == P()
+                        and (p.data().ndim <= 1 or not flat_only)
+                        for p, sh in zip(self._param_handles,
+                                         self._param_sh)]
             raws = [p.data().data for p in self._param_handles]
             self._zero_layout = zero_mod.ZeroLayout(
                 raws,

@@ -35,6 +35,25 @@ collectives against the remaining backward/update compute (the reference's
 push/pull priority-overlap trick becomes latency hiding for free) instead of
 serializing one monolithic all-reduce at the step boundary.
 
+A data degree of 1 shards nothing: no reduce-scatter, no all-gather, no 1/N
+of anything. What is left of a bucket is the packing, and on a TPU a
+``[d, 4d]`` matrix in its tiled layout is not a contiguous stretch of a flat
+array, so every pack and unpack of a matrix is a real relayout (a fifth of a
+1.3B model's step on one v5e, PERF.md PR 25). ``DataParallelTrainer``
+therefore passes ``eligible`` False for every matrix there and gives it the
+per-parameter update (``step_cache.build_update_all``: the same
+``_preprocess_grad`` + ``_kernel`` on the same numbers, so the result is
+bit-identical). Leaves that are flat already (biases, norm scales) stay
+bucketed: their packing is a plain copy, and one buffer in place of hundreds
+spares the runtime an allocation for each of them every step, which is what
+the host's dispatch time follows (PERF.md PR 25). Gradient compression is
+the exception: its quantisation and error-feedback residual exist only on
+the bucket path, so a trainer with ``compression_params`` keeps every
+replicated parameter bucketed at every degree. ``StepExecutor``
+(``step_cache.py``) still buckets everything at degree 1: its ``zopt:``
+checkpoint slots are what a resume onto another degree re-packs (ROADMAP
+S10).
+
 Eligibility: the optimizer must be **elementwise** (``Optimizer.elementwise``) —
 bucket packing must not change the math (SGD/NAG/Adam/RMSProp/…); norm-based
 (LBSGD) and noise-injecting (SGLD) optimizers fall back to the replicated path.

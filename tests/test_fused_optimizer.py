@@ -210,6 +210,7 @@ def test_feedforward_fit_predict_save_load(tmp_path):
     from mxtpu.symbol.symbol import _reset_names
     _reset_names()
     mx.rng.seed(0)   # init draws from the global RNG: make order-independent
+    np.random.seed(0)   # and the fit from numpy's: 3 of 12 states end under 0.8
 
     rng = np.random.RandomState(0)
     X = rng.rand(64, 8).astype(np.float32)

@@ -449,3 +449,34 @@ def test_zero_slots_restore_onto_different_dp_size(tmp_path, monkeypatch,
         mod4.update()                     # and training continues fine
     finally:
         parallel.set_default_mesh(None)
+
+
+# ---------------------------------------------------------------------------
+# at a data degree of 1 the matrices leave the buckets
+# (tests/test_one_chip_update.py); a degree above 1 keeps them all
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.multi_device(8)
+def test_degree_eight_step_keeps_its_buckets(dp_mesh):
+    profiler.reset_comm_stats()
+    net = _mlp(seed=9)
+    dpt = parallel.DataParallelTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(),
+        optimizer.Adam(learning_rate=0.01), dp_mesh)
+    rs = np.random.RandomState(9)
+    X, y = rs.randn(16, 10).astype(np.float32), \
+        rs.randint(0, 3, 16).astype(np.float32)
+    for _ in range(3):
+        dpt.step(nd.array(X), nd.array(y))
+    layout = dpt._zero_layout
+    assert dpt.zero and len(layout.buckets) == 1 and not layout.passthrough
+    assert all(st == () for st in dpt._states)
+    assert [s.shape for s in dpt._zero_states[0]] == \
+        [(layout.buckets[0].padded,)] * 2
+    c = profiler.get_comm_stats()
+    assert c["zero_steps"] == c["steps"] == 3 and c["dp"] == 8
+    assert c["bucket_count"] == 1 and c["bytes_reduced"] > 0
+    text = dpt.lowered().as_text(debug_info=True)
+    assert "optimizer/zero" in text
+    assert "stablehlo.concatenate" in text
