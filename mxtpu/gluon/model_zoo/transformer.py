@@ -19,6 +19,11 @@ tying) — one fewer V×d parameter and the standard LM configuration.
 Every layer is jit-friendly: static shapes, no data-dependent control flow,
 registered nd ops throughout so the imperative autograd tape records the same
 graph ``DataParallelTrainer`` traces under jit.
+
+This file is GPT-2's block only. Models built from other layer kinds (no
+position table, SwiGLU, Mamba, windowed / cross differential attention,
+gated memory units) are the sibling family ``hybrid_decoder.HybridDecoderLM``,
+which trains through the same trainer and has no serving steps yet.
 """
 
 from __future__ import annotations

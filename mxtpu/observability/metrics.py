@@ -722,3 +722,35 @@ def sanitizer_violations(stats: Optional[dict] = None) -> int:
 def reset_sanitizer_stats():
     with _stats_lock:
         _san.update(_SAN_ZERO)
+
+
+# ---------------------------------------------------------------------------
+# kernel paths (ops/attention.py, ops/ssm.py): Pallas or XLA, per call site
+# ---------------------------------------------------------------------------
+
+KERNEL_KINDS = ("flash", "flash_window", "ssm_scan")
+_kernel_paths = {kind: {"pallas": 0, "xla": 0} for kind in KERNEL_KINDS}
+
+
+def record_kernel_path(kind: str, pallas: bool):
+    """One call site of ``kind`` chose its path. Recorded where the choice
+    is made, which under ``jit`` is at TRACE time: once for each call site
+    of each traced program, and once for each eager call."""
+    with _stats_lock:
+        _kernel_paths[kind]["pallas" if pallas else "xla"] += 1
+
+
+def get_kernel_path_counts() -> dict:
+    """``{"flash" | "flash_window" | "ssm_scan": {"pallas": n, "xla": n}}``
+    since the last reset: how many call sites took the Pallas kernels and
+    how many the XLA formulation (another backend than the TPU, or a shape
+    the kernels do not take). A TPU step that should run kernels reads
+    ``xla == 0``."""
+    with _stats_lock:
+        return {kind: dict(row) for kind, row in _kernel_paths.items()}
+
+
+def reset_kernel_path_counts():
+    with _stats_lock:
+        for row in _kernel_paths.values():
+            row.update(pallas=0, xla=0)

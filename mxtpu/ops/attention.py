@@ -17,6 +17,18 @@ reference, which is equally fast at those sizes. The backward pass is the standa
 backward — forward saves the per-row log-sum-exp; two kernels recompute the
 probabilities per tile and accumulate dq (grid over q blocks) and dk/dv (grid
 over k blocks) without materializing T×T.
+
+Beyond plain multi-head attention the launches take: fewer key/value heads
+than query heads (query head ``h`` reads key/value head ``h // group``
+through the block index, no repeated copy of K or V; the backward makes dk
+and dv per query head and XLA adds a group's up), a value width other than
+the query/key width, and a causal sliding ``window`` (key ``j`` visible to
+query ``i`` iff ``0 <= i - j < window``). With a window the key blocks ride a
+third grid axis that only covers the blocks the window can touch, so blocks
+wholly outside it are neither fetched nor computed; those launches carry the
+names ``flash_fwd_window`` / ``flash_bwd_dq_window`` / ``flash_bwd_dkv_window``.
+Which path every call site took is counted
+(``profiler.get_kernel_path_counts()``).
 """
 
 from __future__ import annotations
@@ -34,8 +46,8 @@ from jax import lax
 
 from .registry import register
 
-__all__ = ["attention_reference", "flash_attention", "flash_chunk",
-           "partition_scope"]
+__all__ = ["attention_reference", "diff_attention", "flash_attention",
+           "flash_chunk", "partition_scope"]
 
 _NEG_INF = -1e30
 
@@ -147,7 +159,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    o0 = jnp.zeros((block_q, q.shape[1]), jnp.float32)
+    o0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
 
     q_start = qi * block_q
 
@@ -180,6 +192,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     # second-to-last dim to be 8-divisible (a bare (1, block_q) is illegal)
     lse_ref[0] = jnp.broadcast_to((m + jnp.log(l))[:, 0][None, :],
                                   (8, block_q))
+
+
+def _zeros_like_kv(k_blk, v_blk):
+    """Float32 zeros for dk and dv; one array where the widths agree."""
+    z = jnp.zeros(k_blk.shape, jnp.float32)
+    return z, (z if v_blk.shape == k_blk.shape
+               else jnp.zeros(v_blk.shape, jnp.float32))
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -255,8 +274,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return dk_new, dv_new
 
     start_qb = (k_start // block_q) if causal else 0
-    z = jnp.zeros((block_k, k_blk.shape[1]), jnp.float32)
-    dk, dv = lax.fori_loop(start_qb, num_qb, body, (z, z))
+    dk, dv = lax.fori_loop(start_qb, num_qb, body, _zeros_like_kv(k_blk, v_blk))
     # dk absorbed one factor of scale through q; no extra factor needed
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -330,8 +348,8 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_new = dk + jnp.dot(ds.T, q_blk, preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
-    z = jnp.zeros((block, k_i.shape[1]), jnp.float32)
-    dk, dv = lax.fori_loop(i if causal else 0, num_b, dkv_body, (z, z))
+    dk, dv = lax.fori_loop(i if causal else 0, num_b, dkv_body,
+                           _zeros_like_kv(k_i, v_i))
     # dk absorbed one factor of scale through q_blk; no extra factor needed
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -345,21 +363,307 @@ def _pad_d(x):
     return jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
 
 
+def _kv_head(group: int):
+    """Row of the flattened ``(batch x key/value heads)`` axis that row ``b``
+    of the flattened ``(batch x query heads)`` axis reads: ``group``
+    consecutive query heads share one key/value head."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
+def _sum_group(dx, B: int, Hkv: int, group: int, dtype):
+    """dk or dv made per QUERY head, ``(B * Hkv * group, Tk, D)``, added up
+    over the query heads of each key/value head: ``(B, Hkv, Tk, D)``."""
+    if group == 1:
+        return dx.reshape((B, Hkv) + dx.shape[1:])
+    dx = dx.reshape((B, Hkv, group) + dx.shape[1:])
+    return jnp.sum(dx.astype(jnp.float32), axis=2).astype(dtype)
+
+
+# -- causal sliding window: the key blocks ride a third grid axis -----------
+
+
+def _window_blocks(block: int, window: int) -> int:
+    """Key blocks a query block can see through a causal window: its own
+    and those that hold any of the ``window - 1`` keys before its first row."""
+    return -(-(window - 1) // block) + 1
+
+
+def _key_block(i, j, n_w: int):
+    """The key block that step ``j`` of query block ``i`` fetches: the one
+    it visits, or block 0 where that falls before the first (not computed
+    there: the kernels skip it)."""
+    return jnp.maximum(i - (n_w - 1) + j, 0)
+
+
+def _window_scores(q, k_blk, q_start, k_start, window: int):
+    s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
+    rows = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where((rows >= cols) & (rows - cols < window), s, _NEG_INF)
+
+
+def _flash_fwd_window_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
+                             l_ref, acc_ref, *, block: int, window: int,
+                             scale: float, n_w: int):
+    """One (batch·head, q-block, step) program: step ``j`` visits key block
+    ``qi - (n_w - 1) + j``, the diagonal block last; steps that fall before
+    the first key block do nothing. A row that sees no key of an early
+    block leaves garbage in ``l`` and ``acc`` there (``exp(-1e30 + 1e30)``);
+    the diagonal block, where every row sees itself, scales it to zero."""
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(1), pl.program_id(2)
+    kb = qi - (n_w - 1) + j
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(kb >= 0)
+    def _():
+        q = q_ref[0].astype(jnp.float32) * scale
+        s = _window_scores(q, k_ref[0].astype(jnp.float32), qi * block,
+                           kb * block, window)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = corr * acc_ref[...] + jnp.dot(
+            p, v_ref[0].astype(jnp.float32),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == n_w - 1)
+    def _():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = jnp.broadcast_to(
+            (m_ref[...] + jnp.log(l))[:, 0][None, :], (8, block))
+
+
+def _flash_bwd_dq_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                delta_ref, dq_ref, acc_ref, *, block: int,
+                                window: int, scale: float, n_w: int):
+    """dq for one q block, one visible key block a step."""
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(1), pl.program_id(2)
+    kb = qi - (n_w - 1) + j
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(kb >= 0)
+    def _():
+        q = q_ref[0].astype(jnp.float32) * scale
+        k_blk = k_ref[0].astype(jnp.float32)
+        s = _window_scores(q, k_blk, qi * block, kb * block, window)
+        p = jnp.exp(s - lse_ref[0, 0].astype(jnp.float32)[:, None])
+        dp = jnp.dot(do_ref[0].astype(jnp.float32),
+                     v_ref[0].astype(jnp.float32).T,
+                     preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, 0].astype(jnp.float32)[:, None])
+        acc_ref[...] += jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
+
+    @pl.when(j == n_w - 1)
+    def _():
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _flash_bwd_dkv_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                 delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                                 *, block: int, window: int, scale: float,
+                                 n_w: int, num_qb: int):
+    """dk/dv for one key block: step ``j`` visits query block ``kb + j``,
+    the ``n_w`` query blocks whose window reaches this key block."""
+    from jax.experimental import pallas as pl
+
+    kb, j = pl.program_id(1), pl.program_id(2)
+    qi = kb + j
+
+    @pl.when(j == 0)
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    @pl.when(qi < num_qb)
+    def _():
+        q = q_ref[0].astype(jnp.float32) * scale
+        do = do_ref[0].astype(jnp.float32)
+        s = _window_scores(q, k_ref[0].astype(jnp.float32), qi * block,
+                           kb * block, window)
+        p = jnp.exp(s - lse_ref[0, 0].astype(jnp.float32)[:, None])
+        dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
+        dp = jnp.dot(do, v_ref[0].astype(jnp.float32).T,
+                     preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, 0].astype(jnp.float32)[:, None])
+        dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+
+    @pl.when(j == n_w - 1)
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _window_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _flash_window_forward(qq, kk, vv, group: int, window: int, scale: float,
+                          block: int, interpret: bool):
+    """Forward launch over flattened, lane-padded ``(B·H, T, Dp)`` q and
+    ``(B·Hkv, T, D[v]p)`` k, v; returns padded ``(out, lse (B·H, 8, T))``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, Dp = qq.shape
+    Dvp = vv.shape[-1]
+    n_w = _window_blocks(block, window)
+    kv = _kv_head(group)
+
+    def key_block(i, j):
+        return _key_block(i, j, n_w)
+
+    return pl.pallas_call(
+        functools.partial(_flash_fwd_window_kernel, block=block,
+                          window=window, scale=scale, n_w=n_w),
+        grid=(BH, T // block, n_w),
+        in_specs=[
+            pl.BlockSpec((1, block, Dp), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block, Dp),
+                         lambda b, i, j: (kv(b), key_block(i, j), 0)),
+            pl.BlockSpec((1, block, Dvp),
+                         lambda b, i, j: (kv(b), key_block(i, j), 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block, Dvp), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 8, block), lambda b, i, j: (b, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, T, Dvp), qq.dtype),
+            jax.ShapeDtypeStruct((BH, 8, T), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, Dvp), jnp.float32)],
+        compiler_params=_window_params(),
+        name="flash_fwd_window",
+        interpret=interpret,
+    )(qq, kk, vv)
+
+
+def _flash_window_backward(qq, kk, vv, gg, lse, delta, group: int,
+                           window: int, scale: float, block: int,
+                           interpret: bool):
+    """Backward launches over the flattened, padded operands; dk and dv
+    come out per QUERY head."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, Dp = qq.shape
+    Dvp = vv.shape[-1]
+    n_w = _window_blocks(block, window)
+    num_qb = T // block
+    kv = _kv_head(group)
+
+    def key_block(i, j):
+        return _key_block(i, j, n_w)
+
+    def query_block(i, j):
+        return jnp.minimum(i + j, num_qb - 1)
+
+    dq = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_window_kernel, block=block,
+                          window=window, scale=scale, n_w=n_w),
+        grid=(BH, num_qb, n_w),
+        in_specs=[
+            pl.BlockSpec((1, block, Dp), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block, Dp),
+                         lambda b, i, j: (kv(b), key_block(i, j), 0)),
+            pl.BlockSpec((1, block, Dvp),
+                         lambda b, i, j: (kv(b), key_block(i, j), 0)),
+            pl.BlockSpec((1, block, Dvp), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 8, block), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 8, block), lambda b, i, j: (b, 0, i)),
+        ],
+        out_specs=pl.BlockSpec((1, block, Dp), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, T, Dp), qq.dtype),
+        scratch_shapes=[pltpu.VMEM((block, Dp), jnp.float32)],
+        compiler_params=_window_params(),
+        name="flash_bwd_dq_window",
+        interpret=interpret,
+    )(qq, kk, vv, gg, lse, delta)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_window_kernel, block=block,
+                          window=window, scale=scale, n_w=n_w,
+                          num_qb=num_qb),
+        grid=(BH, num_qb, n_w),
+        in_specs=[
+            pl.BlockSpec((1, block, Dp),
+                         lambda b, i, j: (b, query_block(i, j), 0)),
+            pl.BlockSpec((1, block, Dp), lambda b, i, j: (kv(b), i, 0)),
+            pl.BlockSpec((1, block, Dvp), lambda b, i, j: (kv(b), i, 0)),
+            pl.BlockSpec((1, block, Dvp),
+                         lambda b, i, j: (b, query_block(i, j), 0)),
+            pl.BlockSpec((1, 8, block),
+                         lambda b, i, j: (b, 0, query_block(i, j))),
+            pl.BlockSpec((1, 8, block),
+                         lambda b, i, j: (b, 0, query_block(i, j))),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block, Dp), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block, Dvp), lambda b, i, j: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, T, Dp), kk.dtype),
+            jax.ShapeDtypeStruct((BH, T, Dvp), vv.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((block, Dp), jnp.float32),
+                        pltpu.VMEM((block, Dvp), jnp.float32)],
+        compiler_params=_window_params(),
+        name="flash_bwd_dkv_window",
+        interpret=interpret,
+    )(qq, kk, vv, gg, lse, delta)
+    return dq, dk, dv
+
+
+def _windowed(window, Tk: int) -> bool:
+    """A window that reaches every earlier key is plain causal attention
+    and takes the causal kernels."""
+    return window is not None and window < Tk
+
+
 def _flash_attention_pallas(q, k, v, causal: bool, scale: float,
                             block_q: int = 512, block_k: int = 512,
-                            interpret: bool = False):
-    """Forward kernel launch; returns (out, lse). q,k,v: (B, H, T, D)."""
+                            interpret: bool = False, window=None):
+    """Forward kernel launch; returns (out, lse). q: (B, H, T, D); k:
+    (B, Hkv, Tk, D) with ``H % Hkv == 0``; v: (B, Hkv, Tk, Dv)."""
     from jax.experimental import pallas as pl
 
     B, H, T, D = q.shape
-    Tk = k.shape[2]
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    group = H // Hkv
     qq = _pad_d(q.reshape(B * H, T, D))
-    kk = _pad_d(k.reshape(B * H, Tk, D))
-    vv = _pad_d(v.reshape(B * H, Tk, D))
-    Dp = qq.shape[-1]
-    block_q = _pick_block(T, min(block_q, _block_cap(Dp)))
-    block_k = _pick_block(Tk, min(block_k, _block_cap(Dp)))
+    kk = _pad_d(k.reshape(B * Hkv, Tk, D))
+    vv = _pad_d(v.reshape(B * Hkv, Tk, Dv))
+    Dp, Dvp = qq.shape[-1], vv.shape[-1]
+    cap = _block_cap(max(Dp, Dvp))
+    block_q = _pick_block(T, min(block_q, cap))
+    block_k = _pick_block(Tk, min(block_k, cap))
+    if _windowed(window, Tk):
+        out, lse = _flash_window_forward(
+            qq, kk, vv, group, window, scale, min(block_q, block_k),
+            interpret)
+        return out[..., :Dv].reshape(B, H, T, Dv), lse[:, 0, :]
     grid = (B * H, T // block_q)
+    kv = _kv_head(group)
 
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k, causal=causal,
                                scale=scale)
@@ -368,29 +672,33 @@ def _flash_attention_pallas(q, k, v, causal: bool, scale: float,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, Dp), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk, Dp), lambda b, i: (kv(b), 0, 0)),
+            pl.BlockSpec((1, Tk, Dvp), lambda b, i: (kv(b), 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dvp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, Dp), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dvp), q.dtype),
             jax.ShapeDtypeStruct((B * H, 8, T), jnp.float32),
         ],
         name="flash_fwd",
         interpret=interpret,
     )(qq, kk, vv)
-    return out[..., :D].reshape(B, H, T, D), lse[:, 0, :]
+    return out[..., :Dv].reshape(B, H, T, Dv), lse[:, 0, :]
 
 
 def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
                            block_q: int = 512, block_k: int = 512,
-                           interpret: bool = False, lse_cot=None):
+                           interpret: bool = False, lse_cot=None,
+                           window=None):
     """Flash backward: dq via q-block grid, dk/dv via k-block grid (the
     default 'split' launch), or one fused grid doing both per tile when
     ``MXTPU_FLASH_BWD=fused`` and the shape is self-attention tiling.
+    Shapes as in the forward; with fewer key/value heads than query heads
+    the kernels make dk and dv per query head and a group's are added up
+    here.
 
     ``lse_cot`` (B,H,T): optional cotangent of the log-sum-exp output (ring
     merges differentiate through lse); it folds into the delta term exactly —
@@ -398,7 +706,8 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
     from jax.experimental import pallas as pl
 
     B, H, T, D = q.shape
-    Tk = k.shape[2]
+    Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    group = H // Hkv
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if lse_cot is not None:
         delta = delta - lse_cot.astype(jnp.float32)
@@ -410,15 +719,28 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
     lse = jnp.broadcast_to(
         lse.astype(row_dt).reshape(B * H, 1, T), (B * H, 8, T))
     qq = _pad_d(q.reshape(B * H, T, D))
-    kk = _pad_d(k.reshape(B * H, Tk, D))
-    vv = _pad_d(v.reshape(B * H, Tk, D))
-    gg = _pad_d(g.reshape(B * H, T, D))
-    Dp = qq.shape[-1]
+    kk = _pad_d(k.reshape(B * Hkv, Tk, D))
+    vv = _pad_d(v.reshape(B * Hkv, Tk, Dv))
+    gg = _pad_d(g.reshape(B * H, T, Dv))
+    Dp, Dvp = qq.shape[-1], vv.shape[-1]
     # same padded-D cap as the forward (blocks must match its VMEM budget)
-    block_q = _pick_block(T, min(block_q, _block_cap(Dp)))
-    block_k = _pick_block(Tk, min(block_k, _block_cap(Dp)))
+    cap = _block_cap(max(Dp, Dvp))
+    block_q = _pick_block(T, min(block_q, cap))
+    block_k = _pick_block(Tk, min(block_k, cap))
+    kv = _kv_head(group)
 
-    if _bwd_mode() == "fused" and T == Tk and block_q == block_k:
+    def unflatten(dq, dk, dv):
+        return (dq[..., :D].reshape(B, H, T, D),
+                _sum_group(dk[..., :D], B, Hkv, group, k.dtype),
+                _sum_group(dv[..., :Dv], B, Hkv, group, v.dtype))
+
+    if _windowed(window, Tk):
+        return unflatten(*_flash_window_backward(
+            qq, kk, vv, gg, lse, delta, group, window, scale,
+            min(block_q, block_k), interpret))
+
+    if (_bwd_mode() == "fused" and T == Tk and block_q == block_k
+            and group == 1):
         fused = functools.partial(_flash_bwd_fused_kernel, block=block_q,
                                   causal=causal, scale=scale)
         dq, dk, dv = pl.pallas_call(
@@ -427,27 +749,25 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
             in_specs=[
                 pl.BlockSpec((1, T, Dp), lambda b, i: (b, 0, 0)),
                 pl.BlockSpec((1, Tk, Dp), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, Tk, Dp), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, T, Dp), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, Tk, Dvp), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, T, Dvp), lambda b, i: (b, 0, 0)),
                 pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
                 pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, Dvp), lambda b, i: (b, i, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((B * H, T, Dp), q.dtype),
                 jax.ShapeDtypeStruct((B * H, Tk, Dp), k.dtype),
-                jax.ShapeDtypeStruct((B * H, Tk, Dp), v.dtype),
+                jax.ShapeDtypeStruct((B * H, Tk, Dvp), v.dtype),
             ],
             name="flash_bwd_fused",
             interpret=interpret,
         )(qq, kk, vv, gg, lse, delta)
-        return (dq[..., :D].reshape(B, H, T, D),
-                dk[..., :D].reshape(B, H, Tk, D),
-                dv[..., :D].reshape(B, H, Tk, D))
+        return unflatten(dq, dk, dv)
 
     dq_kernel = functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
                                   causal=causal, scale=scale)
@@ -456,9 +776,9 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
         grid=(B * H, T // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, Tk, Dp), lambda b, i: (kv(b), 0, 0)),
+            pl.BlockSpec((1, Tk, Dvp), lambda b, i: (kv(b), 0, 0)),
+            pl.BlockSpec((1, block_q, Dvp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),
         ],
@@ -475,27 +795,25 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
         grid=(B * H, Tk // block_k),
         in_specs=[
             pl.BlockSpec((1, T, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, T, Dp), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, Dp), lambda b, i: (kv(b), i, 0)),
+            pl.BlockSpec((1, block_k, Dvp), lambda b, i: (kv(b), i, 0)),
+            pl.BlockSpec((1, T, Dvp), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_k, Dvp), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tk, Dp), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, Dp), v.dtype),
+            jax.ShapeDtypeStruct((B * H, Tk, Dvp), v.dtype),
         ],
         name="flash_bwd_dkv",
         interpret=interpret,
     )(qq, kk, vv, gg, lse, delta)
 
-    return (dq[..., :D].reshape(B, H, T, D),
-            dk[..., :D].reshape(B, H, Tk, D),
-            dv[..., :D].reshape(B, H, Tk, D))
+    return unflatten(dq, dk, dv)
 
 
 # ---------------------------------------------------------------------------
@@ -575,7 +893,22 @@ def _use_pallas(q, k) -> bool:
     # is legal to *interpret* but real Mosaic rejects its sub-128 vector
     # loads ("index in dimension 2 is a multiple of 128", observed on v5e
     # with T=16, Dp=128) — and at those sizes the XLA path is just as fast.
-    return T == Tk and D <= 512 and T % 128 == 0
+    return (T == Tk and D <= 512 and T % 128 == 0
+            and q.shape[1] % k.shape[1] == 0)
+
+
+def _takes_kernels(q, k, v) -> bool:
+    return _use_pallas(q, k) and v.shape[3] <= 512
+
+
+def _count_path(k, window, pallas: bool) -> bool:
+    """A forward call site chose its path (its backward follows it); counted
+    (``profiler.get_kernel_path_counts()``), so a step that fell back to XLA
+    says so instead of only running slower."""
+    from ..observability import metrics
+    metrics.record_kernel_path(
+        "flash_window" if _windowed(window, k.shape[2]) else "flash", pallas)
+    return pallas
 
 
 def _chunk_reference_lse(q, k, v, causal, scale):
@@ -596,37 +929,66 @@ def _chunk_reference_lse(q, k, v, causal, scale):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_chunk(q, k, v, causal, scale):
+def _attention_xla(q, k, v, causal, scale, window):
+    """(normalized out, lse) via plain XLA for what ``_chunk_reference_lse``
+    does not take: a causal window, fewer key/value heads than query heads
+    (the query heads of a group ride an axis of their own; K and V are not
+    repeated)."""
+    if window is None and q.shape[1] == k.shape[1]:
+        return _chunk_reference_lse(q, k, v, causal, scale)
+    B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(B, Hkv, H // Hkv, T, D)
+    logits = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k) * scale
+    if causal or window is not None:
+        rows = lax.broadcasted_iota(jnp.int32, logits.shape[-2:], 0)
+        cols = lax.broadcasted_iota(jnp.int32, logits.shape[-2:], 1)
+        seen = rows >= cols
+        if window is not None:
+            seen = seen & (rows - cols < window)
+        logits = jnp.where(seen, logits, _NEG_INF)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.exp(logits - m)
+    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", p / l, v)
+    return (out.reshape(B, H, T, v.shape[3]),
+            (m + jnp.log(l))[..., 0].reshape(B, H, T))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_chunk(q, k, v, causal, scale, window=None):
     """One self-attention chunk returning (normalized out, lse (B,H,T)) —
     the composable unit ring attention merges across devices. Pallas on TPU
     at eligible shapes, XLA fallback elsewhere; the custom vjp handles BOTH
-    cotangents (out and lse), so lse-merges differentiate exactly."""
-    if _use_pallas(q, k):
+    cotangents (out and lse), so lse-merges differentiate exactly.
+    ``window`` (causal only): key ``j`` is visible to query ``i`` iff
+    ``0 <= i - j < window``."""
+    if _count_path(k, window, _takes_kernels(q, k, v)):
         def local(q, k, v):
-            out, lse = _flash_attention_pallas(q, k, v, causal, scale)
+            out, lse = _flash_attention_pallas(q, k, v, causal, scale,
+                                               window=window)
             return out, lse.reshape(q.shape[:3])
         return _launch(local, (q, k, v), 2)
-    return _chunk_reference_lse(q, k, v, causal, scale)
+    return _attention_xla(q, k, v, causal, scale, window)
 
 
-def _flash_chunk_fwd(q, k, v, causal, scale):
-    out, lse = flash_chunk(q, k, v, causal, scale)
+def _flash_chunk_fwd(q, k, v, causal, scale, window):
+    out, lse = flash_chunk(q, k, v, causal, scale, window)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_chunk_bwd(causal, scale, res, cots):
+def _flash_chunk_bwd(causal, scale, window, res, cots):
     q, k, v, out, lse = res
     g_o, g_lse = cots
-    if _use_pallas(q, k):
+    if _takes_kernels(q, k, v):
         def local(q, k, v, out, lse, g_o, g_lse):
             B, H, T, _ = q.shape
             return _flash_backward_pallas(
                 q, k, v, out, lse.reshape(B * H, T), g_o, causal, scale,
-                lse_cot=g_lse)
+                lse_cot=g_lse, window=window)
         return _launch(local, (q, k, v, out, lse, g_o, g_lse), 3)
-    _, vjp = jax.vjp(lambda q_, k_, v_: _chunk_reference_lse(
-        q_, k_, v_, causal, scale), q, k, v)
+    _, vjp = jax.vjp(lambda q_, k_, v_: _attention_xla(
+        q_, k_, v_, causal, scale, window), q, k, v)
     return vjp((g_o, g_lse))
 
 
@@ -634,13 +996,55 @@ flash_chunk.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
 
 
 @register("flash_attention", namespace="contrib", aliases=("attention",))
-def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None):
-    """Fused scaled-dot-product attention; q,k,v: (B, H, T, D).
+def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
+                    window: Optional[int] = None):
+    """Fused scaled-dot-product attention; q: (B, H, T, D), k: (B, Hkv, T, D)
+    with ``H % Hkv == 0`` (query head ``h`` reads key/value head
+    ``h // (H // Hkv)``), v: (B, Hkv, T, Dv).
 
     Pallas fwd+bwd on TPU at production shapes (any head dim ≤512 via lane
     padding; T % 128 == 0), XLA reference otherwise — numerically equivalent
-    paths. Thin wrapper over ``flash_chunk`` (the lse output's zero cotangent
-    folds away in bwd).
+    paths. ``window`` (needs ``causal``) keeps the ``window`` newest keys of
+    every query, itself included. Thin wrapper over ``flash_chunk`` (the lse
+    output's zero cotangent folds away in bwd).
     """
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    return flash_chunk(q, k, v, causal, s)[0]
+    return flash_chunk(q, k, v, causal, s, window)[0]
+
+
+@register("diff_attention", namespace="contrib")
+def diff_attention(q, k, v, lq1, lk1, lq2, lk2, gain, lambda_init: float = 0.8,
+                   window: Optional[int] = None, eps: float = 1e-5):
+    """Causal differential attention (Ye et al. 2024) over heads that pair
+    up. ``q``: ``(B, T, H, D)``; ``k``, ``v``: ``(B, T, Hkv, D)``; query pair
+    ``p`` = heads ``(2p, 2p + 1)`` reads key/value pair ``p // (H / Hkv)``::
+
+        o_p = (1 - lambda_init) * RMSNorm(S1 V - lam * S2 V) * gain
+        S1  = softmax(mask(q_2p k_2p'^T / sqrt(D))), S2 on the odd heads
+        V   = [v_2p', v_2p'+1]                         (2 D wide)
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+
+    Returns ``(B, T, H * D)``. Two flash launches (even and odd heads), each
+    with ``H / 2`` query heads on ``Hkv / 2`` key heads and a value twice as
+    wide as the keys; ``window`` as in :func:`flash_attention`."""
+    B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    scale = 1.0 / math.sqrt(D)
+    qp = q.reshape(B, T, H // 2, 2, D)
+    kp = k.reshape(B, T, Hkv // 2, 2, D)
+    vv = v.reshape(B, T, Hkv // 2, 2 * D).transpose(0, 2, 1, 3)
+
+    def side(i):
+        return flash_chunk(qp[:, :, :, i].transpose(0, 2, 1, 3),
+                           kp[:, :, :, i].transpose(0, 2, 1, 3), vv, True,
+                           scale, window)[0].astype(jnp.float32)
+
+    f32 = jnp.float32
+    lam = (jnp.exp(jnp.sum(lq1.astype(f32) * lk1.astype(f32)))
+           - jnp.exp(jnp.sum(lq2.astype(f32) * lk2.astype(f32))) + lambda_init)
+    x = side(0) - lam * side(1)                       # (B, H/2, T, 2D)
+    x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    x = x * gain.astype(f32) * (1.0 - lambda_init)
+    return x.transpose(0, 2, 1, 3).reshape(B, T, H * D).astype(q.dtype)

@@ -265,6 +265,7 @@ _ACTS = {
     "tanh": jnp.tanh,
     "softrelu": jax.nn.softplus,
     "softsign": lambda x: x / (1 + jnp.abs(x)),
+    "silu": jax.nn.silu,
 }
 
 

@@ -182,9 +182,14 @@ class DataParallelTrainer:
         return attention_ops.partition_scope(self.mesh, spec)
 
     def _collect(self, x_example):
-        # ensure deferred params materialize
-        with autograd.predict_mode(), self._kernel_scope():
-            self.block(x_example)
+        # deferred params materialize in an eager forward; a block whose
+        # every parameter holds data already (all widths given) needs none,
+        # and at real sizes that forward is dozens of one-operation programs
+        # to compile and run before the step's own
+        if any(p._data is None
+               for p in self.block.collect_params().values()):
+            with autograd.predict_mode(), self._kernel_scope():
+                self.block(x_example)
         named = list(self.block.collect_params().items())
         self._param_names = [n for n, p in named
                              if p._data is not None and p.grad_req != "null"]

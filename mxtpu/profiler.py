@@ -46,6 +46,7 @@ from .observability.metrics import (  # noqa: F401  (re-exported surface)
     _stats_lock,
     add_commit_hook,
     get_checkpoint_stats, get_comm_stats, get_feed_stats,
+    get_kernel_path_counts,
     get_memory_stats, get_quant_stats, get_resilience_stats,
     get_router_stats, get_sanitizer_stats, get_sched_stats,
     get_serving_stats,
@@ -53,11 +54,12 @@ from .observability.metrics import (  # noqa: F401  (re-exported surface)
     record_checkpoint_save, record_checkpoint_shard_write,
     record_collective, record_comm_step,
     record_feed_consume, record_feed_prefetch, record_feed_resident,
-    record_feed_transfer, record_memory_stats,
+    record_feed_transfer, record_kernel_path, record_memory_stats,
     record_quant_error, record_quant_matmuls, record_quant_range,
     record_resilience, record_router, record_sanitizer, record_sched,
     record_serving, record_serving_occupancy, record_tenant,
     reset_checkpoint_stats, reset_comm_stats, reset_feed_stats,
+    reset_kernel_path_counts,
     reset_memory_stats, reset_quant_stats, reset_resilience_stats,
     reset_router_stats, reset_sanitizer_stats, reset_sched_stats,
     reset_serving_stats,

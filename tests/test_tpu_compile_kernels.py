@@ -1,0 +1,80 @@
+"""The kernels of the hybrid family compiled at the benchmark's widths for a
+DESCRIBED v5e chip (none is attached: the TPU's compiler is installed here
+and raises what the chip's would: a slice not aligned to the tiling, more
+VMEM than a kernel may use). Nothing runs, so these say nothing of results or
+times. One file, a fixture that skips where no topology can be described: see
+the ``on-chip-measurement`` guide, section 2."""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+T, CHANNELS, STATES = 8192, 5120, 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def quiet_cache():
+    """A compile for a described device is written to the persistent cache
+    and cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+def _avals(one_chip, *shapes):
+    return [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+
+
+def test_scan_kernels_compile_at_the_cells_size(one_chip, quiet_cache):
+    from mxtpu.ops import ssm
+    bf, f32 = jnp.bfloat16, jnp.float32
+    avals = _avals(one_chip, ((1, T, CHANNELS), bf), ((1, T, CHANNELS), bf),
+                   ((CHANNELS, STATES), f32), ((1, T, STATES), bf),
+                   ((1, T, STATES), bf), ((CHANNELS,), f32))
+
+    def loss(*a):
+        return jnp.sum(ssm._scan_pallas(*a).astype(f32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        *avals).compile().as_text()
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+
+
+@pytest.mark.parametrize("window", [512, None])
+def test_flash_kernels_compile_at_the_cells_size(one_chip, quiet_cache,
+                                                 window):
+    """20 query heads of 64 on 10 key heads, the value 128 wide, T = 8192:
+    one of the two launches of a differential-attention layer."""
+    from mxtpu.ops import attention as A
+    bf = jnp.bfloat16
+    q, k, v = _avals(one_chip, ((1, 20, T, 64), bf), ((1, 10, T, 64), bf),
+                     ((1, 10, T, 128), bf))
+
+    def both(q, k, v, g):
+        out, lse = A._flash_attention_pallas(q, k, v, True, 0.125,
+                                             window=window)
+        return A._flash_backward_pallas(q, k, v, out, lse, g, True, 0.125,
+                                        window=window)
+
+    g, = _avals(one_chip, ((1, 20, T, 128), bf))
+    text = jax.jit(both).lower(q, k, v, g).compile().as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name + ("_window" if window else "") in text
