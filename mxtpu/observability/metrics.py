@@ -237,7 +237,8 @@ _MEM_ZERO = {"stage": 0, "data_degree": 1, "fsdp_degree": 1,
              "param_bytes_per_device": 0, "grad_bytes_per_device": 0,
              "slot_bytes_per_device": 0,
              "replicated_param_bytes": 0, "replicated_grad_bytes": 0,
-             "replicated_slot_bytes": 0}
+             "replicated_slot_bytes": 0,
+             "step_outputs": 0, "step_donated": 0}
 _mem = dict(_MEM_ZERO)
 
 
@@ -245,7 +246,10 @@ def record_memory_stats(**kwargs):
     """Per-device resident-byte accounting for params/grads/optimizer slots
     by ZeRO stage (``parallel.fsdp.measure_memory`` computes the figures from
     the actual placed shardings at trace time). ``replicated_*`` keys carry
-    the stage-0 equivalent the shrink ratio is quoted against."""
+    the stage-0 equivalent the shrink ratio is quoted against.
+    ``step_outputs`` / ``step_donated`` are ``DataParallelTrainer``'s: the
+    buffers its step hands back and how many of them are a donated
+    argument's, written in place (weights and slots then exist once)."""
     with _stats_lock:
         for k, v in kwargs.items():
             if k in _mem:

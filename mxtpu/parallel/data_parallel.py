@@ -19,17 +19,23 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import numpy as np
 
 from .. import autograd
+from ..analysis import sanitize
 from .. import ndarray as nd_mod
 from ..ndarray.ndarray import NDArray
 from ..observability import metrics, tracer
 from ..ops import attention as attention_ops
-from ..step_cache import build_update_all, cache_stats
+from ..step_cache import (build_update_all, cache_stats, create_distinct,
+                          donation_supported)
 from . import fsdp as fsdp_mod
 from . import zero as zero_mod
 from .mesh import (Mesh, data_axis_names, data_size, dp_size,
                    fsdp_axis_name, fsdp_size, get_default_mesh)
 
 __all__ = ["shard_batch", "replicate", "place", "DataParallelTrainer"]
+
+# positions of the step's arguments that it replaces, and so donates:
+# params, per-parameter slots, ZeRO slots, residuals (StepExecutor's four)
+_DONATED = (0, 2, 3, 4)
 
 
 def _place(raw, sharding: NamedSharding):
@@ -92,7 +98,25 @@ class DataParallelTrainer:
         loss = dpt.step(x_batch, y_batch)   # one jitted SPMD step
 
     The whole fwd+bwd+update is ONE XLA program: gradient all-reduce rides ICI and
-    overlaps backward; optimizer update is fused in (donated buffers).
+    overlaps backward; the optimizer update is fused in.
+
+    **The step donates what it replaces.** On a backend that donates
+    (``step_cache.donation_supported()``: every one but the CPU) the weights,
+    the per-parameter optimizer slots, ZeRO's bucket slots and the
+    compression residuals go into the step as donated arguments, and each new
+    value is written into the buffer of the old one: the runtime allocates
+    one fresh output a step (the loss) and weights and slots exist once.
+    The batch, the scalars and the auxiliary states are never donated. So an
+    array taken from ``p.data().data``, :meth:`optimizer_slots` or
+    :meth:`optimizer_state_by_param` is DELETED by the next step
+    (``StepExecutor``'s contract): read the accessors afresh after a step,
+    or copy (``np.asarray``) what has to outlive it. ``MXTPU_SANITIZE=donation``
+    names such a stale read on the CPU too, where nothing is donated.
+
+    The eager gradient buffers that ``Parameter.initialize`` attached are
+    released when the trainer takes the parameters over (the step never
+    writes them): ``p.grad()`` has nothing to hand out until an eager
+    backward runs again, which makes the buffer anew.
     """
 
     def __init__(self, block, loss_fn, optimizer, mesh: Optional[Mesh] = None,
@@ -190,7 +214,13 @@ class DataParallelTrainer:
                for p in self.block.collect_params().values()):
             with autograd.predict_mode(), self._kernel_scope():
                 self.block(x_example)
-        named = list(self.block.collect_params().items())
+        # a parameter that the block yields under two names (tied weights)
+        # reaches the step once: one buffer cannot be donated twice
+        named, seen = [], set()
+        for n, p in self.block.collect_params().items():
+            if id(p) not in seen:
+                seen.add(id(p))
+                named.append((n, p))
         self._param_names = [n for n, p in named
                              if p._data is not None and p.grad_req != "null"]
         self._param_handles = [p for n, p in named
@@ -214,7 +244,16 @@ class DataParallelTrainer:
             p._data._set_data(_place(p.data().data, sh))
         for p in self._aux_handles:
             p._data._set_data(_place(p.data().data, NamedSharding(self.mesh, P())))
+        # the step works its gradients out inside the program and never
+        # writes the eager buffers ``Parameter.initialize`` attached (float32
+        # even after a cast to bfloat16: four bytes a parameter of device
+        # memory that nothing reads). Released here; the parameters stay
+        # marked, and autograd makes a buffer again the first time an eager
+        # backward has a gradient to put there.
+        for p in self._param_handles:
+            p._data._grad = None
         repl = NamedSharding(self.mesh, P())
+        raws = [p.data().data for p in self._param_handles]
         if self.zero:
             # replicated params bucket into data-sharded flat slots; tp- and
             # fsdp-sharded params keep the per-param update below (their
@@ -231,7 +270,6 @@ class DataParallelTrainer:
                         and (p.data().ndim <= 1 or not flat_only)
                         for p, sh in zip(self._param_handles,
                                          self._param_sh)]
-            raws = [p.data().data for p in self._param_handles]
             self._zero_layout = zero_mod.ZeroLayout(
                 raws,
                 [getattr(p, "lr_mult", 1.0) for p in self._param_handles],
@@ -245,19 +283,22 @@ class DataParallelTrainer:
             passthrough = set(self._zero_layout.passthrough)
         else:
             passthrough = set(range(len(self._param_handles)))
-        self._states = [
-            self.optimizer.create_state(i, p.data()) if i in passthrough
-            else ()
-            for i, p in enumerate(self._param_handles)]
-        # optimizer state follows its param's sharding (same-shape moments etc.)
-        self._states = [tuple(_place(
-            s, sh if getattr(s, "shape", None) == p.data().shape else repl)
-            for s in st)
-            for p, sh, st in zip(self._param_handles, self._param_sh, self._states)]
-        self._state_sh = [tuple(
-            sh if getattr(s, "shape", None) == p.data().shape else repl
-            for s in st)
-            for p, sh, st in zip(self._param_handles, self._param_sh, self._states)]
+        # per-parameter slots in the parameter's shape follow its sharding;
+        # one program makes them all, so no two share a buffer (Adam hands
+        # out one zero array for both moments) and the step can donate each
+        def create(ws):
+            return [tuple(self.optimizer.create_state(i, NDArray(w)))
+                    if i in passthrough else () for i, w in enumerate(ws)]
+
+        self._states = create_distinct(
+            create,
+            lambda shapes: [
+                tuple(sh if s.shape == p.data().shape else repl for s in st)
+                for p, sh, st in zip(self._param_handles, self._param_sh,
+                                     shapes)],
+            raws)
+        self._state_sh = [tuple(s.sharding for s in st)
+                          for st in self._states]
         self._record_memory()
 
     def _record_memory(self):
@@ -434,16 +475,34 @@ class DataParallelTrainer:
         zres_sh = [self._zero_layout.shard_spec(self.mesh)
                    if r is not None else None
                    for r in self._zero_residuals] if self.zero else []
-        # NB: no donation — optimizer states may alias the same zero buffer (e.g.
-        # Adam's (m, v)) and XLA rejects donating one buffer twice; buffers are
-        # reclaimed by refcount anyway since the handles are swapped after the call.
+        # the step donates every argument it replaces, wherever the backend
+        # donates at all. In and out shardings are the same trees, so each
+        # donated leaf has an output to alias, and _collect made every leaf
+        # a buffer of its own. Never the batch (a caller's pool or
+        # DeviceFeed may hand it in again), the scalars, the key or the
+        # auxiliary states.
+        donate = _DONATED if donation_supported() else ()
         self._step_fn = jax.jit(
             step,
             in_shardings=(self._param_sh, repl, self._state_sh, zstate_sh,
                           zres_sh, batch, batch, repl, repl, repl, repl, repl,
                           None),
             out_shardings=(self._param_sh, repl, self._state_sh, zstate_sh,
-                           zres_sh, repl))
+                           zres_sh, repl),
+            donate_argnums=donate)
+        # what says it engaged, on every train/compile and train/dispatch
+        # span and in profiler.get_memory_stats(): the buffers a step hands
+        # back, and how many of them are a donated argument's, written in
+        # place (all but the loss and the auxiliary states, or none)
+        replaced = len(jax.tree.leaves(
+            (self._states, self._zero_states, self._zero_residuals))) \
+            + len(param_handles)
+        self._step_buffers = {
+            "outputs": replaced + len(aux_handles) + 1,
+            "donated": replaced if donate else 0}
+        metrics.record_memory_stats(
+            step_outputs=self._step_buffers["outputs"],
+            step_donated=self._step_buffers["donated"])
         self._comm_step = self._comm_record()
 
     def step_async(self, x, y) -> NDArray:
@@ -506,7 +565,8 @@ class DataParallelTrainer:
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
                     if hasattr(a, "shape") else a, args)
         with tracer.span("train/compile" if traces else "train/dispatch",
-                         args=step), self._kernel_scope():
+                         args=dict(step, **self._step_buffers)), \
+                self._kernel_scope():
             (new_params, new_auxs, new_states, new_zstates, new_zres,
              loss) = self._step_fn(*args)
         with tracer.span("train/adopt", args=step):
@@ -520,6 +580,14 @@ class DataParallelTrainer:
             self._zero_states = new_zstates
             self._zero_residuals = new_zres
             self.optimizer.num_update = self._t
+            if sanitize.enabled("donation"):
+                # the old weights and slots are gone on a backend that
+                # donates; poisoned, a stale read raises by name on the CPU
+                # too, where nothing was donated and it would read old values
+                sanitize.poison(
+                    jax.tree.leaves([args[i] for i in _DONATED]),
+                    origin="DataParallelTrainer's step (donate_argnums "
+                           "params/opt-state)")
             metrics.record_comm_step(**self._comm_step)
         return NDArray(loss)
 
@@ -575,7 +643,8 @@ class DataParallelTrainer:
     def optimizer_slots(self) -> List:
         """Every optimizer-state array the step carries (per-param slots,
         ZeRO bucket slots, compression residuals), as placed on the mesh.
-        Valid after the first step."""
+        Valid after the first step, and until the next: that one donates
+        them (see the class docstring)."""
         slots = [s for st in list(self._states) + list(self._zero_states)
                  for s in (st or ()) if hasattr(s, "dtype")]
         return slots + [r for r in self._zero_residuals if r is not None]
@@ -584,7 +653,9 @@ class DataParallelTrainer:
         """``{parameter name: tuple of its optimizer slots, each in the
         parameter's own shape}`` (Adam: first and second moment), whichever
         way the step carries them: ZeRO's packed buckets are unpacked through
-        the layout. Valid after the first step."""
+        the layout. Valid after the first step, and until the next: a slot
+        the step carries in the parameter's shape is handed out as it is,
+        and the next step donates it."""
         by_index = dict(enumerate(self._states))
         if self._zero_layout is not None:
             by_index.update(jax.jit(

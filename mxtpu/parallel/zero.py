@@ -404,23 +404,28 @@ def init_zero_states(opt, layout: ZeroLayout, param_raws, mesh: Mesh,
     per device). Slot shapes follow ``create_state`` on the flat bucket
     "weight" (so DCASGD's prev-weight copy, Nadam's scalar schedule, … all
     work); bucket-shaped slots shard over the data axes, scalar slots stay
-    replicated."""
-    from .data_parallel import _place
+    replicated. One program makes them all, so every slot and residual is a
+    buffer of its own and a step may donate each
+    (``step_cache.create_distinct``)."""
     from ..ndarray.ndarray import NDArray
-    shard = layout.shard_spec(mesh)
-    repl = layout.repl_spec(mesh)
-    states: List[Tuple] = []
-    residuals: List[Any] = []
-    for bi, b in enumerate(layout.buckets):
-        w_full = _bucket_weight(layout, b, param_raws)
-        st = opt.create_state(("zero", bi), NDArray(w_full))
-        placed = tuple(
-            _place(s, shard if getattr(s, "shape", None) == (b.padded,)
-                   else repl) for s in st)
-        states.append(placed)
-        residuals.append(_place(jnp.zeros((b.padded,), jnp.float32), shard)
-                         if with_residual else None)
-    return states, residuals
+    from ..step_cache import create_distinct
+    if not layout.buckets:
+        return [], []
+    residual_sh = layout.shard_spec(mesh) if with_residual else None
+
+    def create(raws):
+        states = [tuple(opt.create_state(
+            ("zero", bi), NDArray(_bucket_weight(layout, b, raws))))
+            for bi, b in enumerate(layout.buckets)]
+        residuals = [jnp.zeros((b.padded,), jnp.float32)
+                     if with_residual else None for b in layout.buckets]
+        return states, residuals
+
+    return create_distinct(
+        create,
+        lambda shapes: (state_shardings(layout, shapes[0], mesh),
+                        [residual_sh] * len(layout.buckets)),
+        list(param_raws))
 
 
 def state_shardings(layout: ZeroLayout, states, mesh: Mesh):

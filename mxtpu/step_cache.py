@@ -288,6 +288,17 @@ def unique_buffers(state: Tuple) -> Tuple:
     return tuple(copy(s) for s in state)
 
 
+def create_distinct(create: Callable, shardings_of: Callable, *args):
+    """``create(*args)`` run as ONE program, each array of its result a
+    buffer of its own placed on ``shardings_of(<the result's shapes>)``:
+    XLA hands no two results of a program the same buffer, nor an
+    argument's, so what ``create`` returns twice (Adam's one zero array for
+    both moments) or copies from an argument can be donated leaf by leaf.
+    :func:`unique_buffers` reaches the same by one eager copy a slot."""
+    shapes = jax.eval_shape(create, *args)
+    return jax.jit(create, out_shardings=shardings_of(shapes))(*args)
+
+
 # ---------------------------------------------------------------------------
 # StepExecutor
 # ---------------------------------------------------------------------------
