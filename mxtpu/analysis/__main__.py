@@ -11,7 +11,7 @@ over the canonical compiled programs instead of linting paths;
 ``--audit --expect-fail`` proves each audit invariant by seeding one
 violation per class and requiring its detection.  The tier-1 guards
 (``tests/test_analysis_guard.py``, ``tests/test_audit_guard.py``) run
-``python -m mxtpu.analysis mxtpu tests bench.py`` and ``--audit`` and
+``python -m mxtpu.analysis mxtpu tests`` and ``--audit`` and
 assert exit 0 — the committed tree stays self-lint- and audit-clean.
 """
 

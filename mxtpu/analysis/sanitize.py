@@ -150,7 +150,7 @@ def retrace_limit() -> int:
 
 @contextmanager
 def scope(spec: str, retrace_limit: Optional[int] = None):
-    """Temporarily arm a mode set (tests, ``bench.py --sanitize`` legs);
+    """Temporarily arm a mode set (``tests/test_analysis.py`` and others);
     restores the previous configuration and clears poisons on exit."""
     prev_active, prev_limit = _active, _retrace_limit
     configure(spec, retrace_limit=retrace_limit)

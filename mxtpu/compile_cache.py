@@ -2,7 +2,7 @@
 
 A chip run starts with no compiled code, and compiling the flagship step and
 the serving programs is most of a cold run's wall time, so every entry point
-(``chip_smoke.py``, ``bench.py``, the examples, the benchmark probes) calls
+(``chip_smoke.py``, ``benchmark/suite/run.py``, the examples) calls
 :func:`place` once before its first jit. The rule:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads that variable itself; the

@@ -3,9 +3,8 @@
 Two halves:
 
 * **FLOPs per compiled program** — :func:`estimate_step_flops` asks XLA's own
-  cost model first (``lowered.compile().cost_analysis()['flops']`` — the same
-  source ``bench.py`` has always used for honest MFU) and, when that fails,
-  logs why and walks the jaxpr counting ``dot_general``/
+  cost model first (``lowered.compile().cost_analysis()['flops']``) and, when
+  that fails, logs why and walks the jaxpr counting ``dot_general``/
   ``conv_general_dilated`` MACs (``scan`` bodies × trip count). It returns
   the count WITH its source, and ``get_mfu_stats()["flops_source"]`` carries
   it. The estimate is cached per step-cache entry by the caller; it is never
@@ -15,16 +14,14 @@ Two halves:
   :func:`get_mfu_stats` derives ``steps_per_sec``, ``p50_step_ms``,
   ``p99_step_ms``, and ``mfu`` against the detected chip's documented peak.
   ``Module.fit`` records every batch and logs the epoch roll-up;
-  ``Speedometer`` prints the rolling p50/p99; ``bench.py`` emits the ``"mfu"``
-  JSON block from the same source of truth.
+  ``Speedometer`` prints the rolling p50/p99; ``profiler.get_mfu_stats()``
+  hands out the same roll-up.
 
 Peak FLOP/s: the documented bf16 peak of the detected TPU generation
 (public spec sheets — fp32 convs execute as bf16 MXU passes, so bf16 is the
-denominator for both precisions). On CPU hosts there is no meaningful
-"documented peak"; a nominal per-core heuristic (``MXTPU_CPU_PEAK_TFLOPS``
-overridable, default 0.05 TF/core) keeps the MFU field *defined* so the bench
-regression ratchet can track it round-over-round — its absolute value on a
-host backend is a ratchet coordinate, not a hardware-utilization claim.
+denominator for both precisions). A device that is not a listed TPU (the CPU
+among them) has no documented peak: ``device_peak()`` gives ``None`` for it and
+``mfu`` is ``None``, while the step times are reported as on any device.
 """
 
 from __future__ import annotations
@@ -45,9 +42,8 @@ __all__ = ["device_peak", "estimate_step_flops", "jaxpr_flops",
            "get_mfu_stats", "reset_steps", "step_count", "PEAK_TFLOPS"]
 
 # documented bf16 peak TFLOP/s per chip, keyed by the exact
-# ``jax.devices()[0].device_kind`` string; the canonical copy — bench.py
-# imports this table. Both spellings of each generation are the ones
-# jax._src.pallas.mosaic.tpu_info accepts as device kinds; the figures are
+# ``jax.devices()[0].device_kind`` string. Both spellings of each generation
+# are the ones jax._src.pallas.mosaic.tpu_info accepts; the figures are
 # Google Cloud's per-chip numbers ("TPU v4" / "TPU v5e" / "TPU v5p" /
 # "TPU v6e" system-architecture pages).
 PEAK_TFLOPS = {
@@ -61,21 +57,12 @@ PEAK_TFLOPS = {
 }
 
 
-def _cpu_peak_tflops() -> float:
-    try:
-        per_core = float(os.environ.get("MXTPU_CPU_PEAK_TFLOPS", "0.05"))
-    except ValueError:
-        per_core = 0.05
-    return per_core * (os.cpu_count() or 1)
-
-
 def device_peak() -> Tuple[str, Optional[float]]:
     """``(device_kind, peak_tflops_or_None)`` for device 0. A TPU's kind is
     looked up EXACTLY in :data:`PEAK_TFLOPS` and a TPU that is not listed
     raises ``KeyError`` — a near match would put another chip's peak under
-    every MFU this process reports. cpu gets the nominal ratchet heuristic
-    (see module docstring); any other platform returns ``None`` (MFU
-    undefined)."""
+    every MFU this process reports. Any other platform, the CPU among them,
+    returns ``None`` (MFU undefined)."""
     import jax
     dev = jax.devices()[0]
     kind = dev.device_kind
@@ -87,8 +74,6 @@ def device_peak() -> Tuple[str, Optional[float]]:
                 f"({sorted(PEAK_TFLOPS)}); add its documented bf16 peak "
                 f"with the source")
         return kind, PEAK_TFLOPS[kind]
-    if "cpu" in kind.lower():
-        return kind, _cpu_peak_tflops()
     return kind, None
 
 
@@ -209,7 +194,7 @@ def record_step(seconds: float, flops: Optional[float] = None):
 def set_step_flops(flops: Optional[float], source: Optional[str] = None):
     """Register the FLOPs of the CURRENT compiled step program and where the
     count came from (``estimate_step_flops``'s source; called by the fit
-    loop / bench once per traced signature, off the hot path)."""
+    loop once per traced signature, off the hot path)."""
     with _ring_lock:
         _state["flops_per_step"] = flops
         _state["flops_source"] = source
@@ -226,8 +211,7 @@ def step_count() -> int:
 
 
 def reset_steps():
-    """Clear the ring + the fused-step histogram (epoch boundaries, bench
-    legs, tests)."""
+    """Clear the ring + the fused-step histogram (epoch boundaries, tests)."""
     with _ring_lock:
         _ring.clear()
         _state["total_steps"] = 0

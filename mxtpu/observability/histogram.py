@@ -36,7 +36,7 @@ from typing import Dict, List, Optional
 __all__ = ["LogHistogram", "record_value", "get_histogram",
            "get_histogram_stats", "reset_histograms", "QUANTILES"]
 
-# the quantile set every summary reports (serving stats, exporter, bench)
+# the quantile set every summary reports (serving stats, exporter)
 QUANTILES = ((0.50, "p50"), (0.90, "p90"), (0.99, "p99"), (0.999, "p999"))
 
 
@@ -188,8 +188,8 @@ def get_histogram_stats() -> Dict[str, dict]:
 
 
 def reset_histograms(prefix: Optional[str] = None) -> None:
-    """Drop histograms (all, or only names under ``prefix``) — tests, bench
-    legs, ``reset_serving_stats``."""
+    """Drop histograms (all, or only names under ``prefix``) — tests,
+    ``reset_serving_stats``."""
     with _hist_lock:
         if prefix is None:
             _hists.clear()

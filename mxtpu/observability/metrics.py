@@ -94,7 +94,7 @@ def record_checkpoint_restore():
 def get_checkpoint_stats() -> dict:
     """Checkpoint counters (saves/commits/restores, committed bytes, save
     latency, blocked-step time) — the observability contract of the async
-    checkpoint subsystem; bench.py's `checkpoint` scenario reads these."""
+    checkpoint subsystem (``tests/test_checkpoint_manager.py`` reads these)."""
     with _stats_lock:
         return dict(_ckpt)
 
@@ -159,15 +159,15 @@ def set_feed_depth(depth: int):
 def get_feed_stats() -> dict:
     """Input-pipeline counters (input-stall ms, transfer bytes/ms, queue-depth
     high-water mark, batches prefetched vs consumed) — the observability
-    contract of the device-feed pipeline. ``Speedometer`` prints these;
-    ``bench.py input_pipeline`` reads them as the stall-fraction source of
-    truth. Counters are monotone until :func:`reset_feed_stats`."""
+    contract of the device-feed pipeline. ``Speedometer`` prints these and
+    ``Module.fit`` logs them per epoch. Counters are monotone until
+    :func:`reset_feed_stats`."""
     with _stats_lock:
         return dict(_feed)
 
 
 def reset_feed_stats():
-    """Zero the feed counters (tests, per-epoch accounting, bench legs)."""
+    """Zero the feed counters (tests, per-epoch accounting)."""
     with _stats_lock:
         _feed.update(_FEED_ZERO)
 
@@ -192,7 +192,7 @@ def record_comm_step(bytes_reduced: int = 0, bytes_gathered: int = 0,
     analytic from the bucket layout and dp degree — ring collectives move
     (N-1)/N of the payload per device). The ZeRO path records reduce-scatter
     + all-gather legs; the replicated-psum path records the full all-reduce
-    equivalent, so the two are directly comparable in ``bench.py zero_dp``."""
+    equivalent, so the two are directly comparable (``get_comm_stats()``)."""
     with _stats_lock:
         _comm["steps"] += 1
         if zero:
@@ -218,8 +218,8 @@ def get_comm_stats() -> dict:
     """Per-step comm counters (bytes reduced/gathered, bucket count, shard
     bytes per device, dp degree, measured collective ms) — the observability
     contract of the ZeRO-1 gradient path. ``Speedometer`` prints the per-step
-    deltas; ``Module.fit`` logs them per epoch; ``bench.py zero_dp`` compares
-    the ZeRO legs against the replicated all-reduce accounting."""
+    deltas; ``Module.fit`` logs them per epoch; ``tests/test_zero_dp.py``
+    holds the ZeRO legs against the replicated all-reduce accounting."""
     with _stats_lock:
         return dict(_comm)
 
@@ -259,8 +259,8 @@ def record_memory_stats(**kwargs):
 def get_memory_stats() -> dict:
     """Latest memory accounting snapshot — the number that proves ZeRO-2/3
     actually shrinks the footprint. ``compile_cache_summary()`` prints it,
-    ``Module.fit`` logs it per epoch, and ``bench.py fsdp`` compares the
-    stages with it."""
+    ``Module.fit`` logs it per epoch, and ``tests/test_fsdp.py`` compares
+    the stages with it."""
     with _stats_lock:
         return dict(_mem)
 
@@ -303,9 +303,9 @@ def record_resilience(key: str, n=1):
 
 def get_resilience_stats() -> dict:
     """Resilience counters — the observability contract of the fault-
-    injection/retry/watchdog/supervisor stack. ``bench.py resilience`` emits
-    these as its JSON block; the guard tests assert injected faults left
-    fingerprints here."""
+    injection/retry/watchdog/supervisor stack. The exporter serves them;
+    the guard tests (``tests/test_resilience_guard.py``) assert injected
+    faults left fingerprints here."""
     with _stats_lock:
         return dict(_resil)
 
@@ -486,7 +486,7 @@ def get_serving_stats() -> dict:
     """Serving-engine counters (request lifecycle, decode steps, tokens out,
     TTFT/queue-wait accumulators, mean slot occupancy, KV promotions) — the
     observability contract of :class:`mxtpu.serving.ServingEngine`.
-    ``bench.py serving`` reads these; ``docs/serving.md`` has the diagnosis
+    ``engine.stats()`` reads these; ``docs/serving.md`` has the diagnosis
     guide (e.g. rejected≫0 → raise queue depth; occupancy≈1 with queue
     growth → raise MXTPU_SERVING_SLOTS). Latency keys are histogram-backed:
     the legacy ``<base>_last``/``<base>_total`` scalars stay, and each base
@@ -588,7 +588,7 @@ def record_router(key: str, n=1):
 
 def get_router_stats() -> dict:
     """Router counters — the observability contract of
-    :class:`mxtpu.serving.router.Router` (``bench.py serving`` reads
+    :class:`mxtpu.serving.router.Router` (``tests/test_router_guard.py`` reads
     these; the exporter serves them under the ``router`` block)."""
     with _stats_lock:
         return dict(_router)
@@ -710,8 +710,8 @@ def get_sanitizer_stats() -> dict:
     armed/tripped, retrace escalations, ownership assertions checked/
     tripped) — the observability contract of ``MXTPU_SANITIZE``.
     ``compile_cache_summary()`` prints them, ``Module.fit`` logs the
-    per-epoch deltas, and ``bench.py --sanitize`` emits them as the
-    ``"sanitizer"`` JSON block."""
+    per-epoch deltas, and ``profiler.dumps()`` carries them as the
+    ``"sanitizer"`` block."""
     with _stats_lock:
         return dict(_san)
 

@@ -132,15 +132,13 @@ class DataParallelTrainer:
         ``remat=True`` wraps the loss in ``jax.checkpoint`` (rematerialization:
         trade one extra forward's FLOPs for not keeping activations alive
         across fwd→bwd — the reference's mirror/memonger capability). Use when
-        activation memory approaches HBM capacity (large batch/sequence);
-        benchmark/python/mfu_probe.py quantifies the tradeoff.
+        activation memory approaches HBM capacity (large batch/sequence).
 
         ``micro_batches=k`` accumulates gradients over k micro-batches inside
         ONE jitted step (a ``lax.scan``): activation memory is that of
         batch/k while the optimizer sees the full-batch gradient — the
-        measured cure for the large-batch HBM-capacity cliff (mfu_probe:
-        b512 peaks at 15.3/16 GB HBM and loses 8% throughput to scheduling
-        pressure; k=4 keeps the b128 working set). Micro-batches take every
+        cure for the large-batch HBM-capacity cliff (rounds 3-5, a retired
+        runtime; not re-measured on the v5e). Micro-batches take every
         k-th row so each stays evenly dp-sharded.
 
         ``zero`` selects the ZeRO gradient/update path (default: the
@@ -696,7 +694,7 @@ class DataParallelTrainer:
 
     def cost_analysis(self) -> dict:
         """XLA's own cost model for the compiled step (flops, bytes accessed).
-        Valid after the first step; used by bench.py for honest MFU accounting.
+        Valid after the first step (``tests/test_trainer_donation.py`` calls it).
         The lowering/compile for the analysis is cached (first call only)."""
         if not hasattr(self, "_cost_cache"):
             self._cost_cache = dict(

@@ -12,8 +12,8 @@ per ZeRO (Rajbhandari et al., 2020) via ``MXTPU_ZERO_STAGE`` (see
   d-th chunk of every member param);
 * each per-param gradient is constrained to the data-axis sharding right after
   the backward — GSPMD converts the pending per-axis reduction into a
-  **reduce-scatter** (the partial-sum → sharded-consumer optimization)
-  (MULTICHIP_r05: reduce_scatter 64 MB = 464 ms vs allreduce 1117 ms) — and the
+  **reduce-scatter** (the partial-sum → sharded-consumer optimization;
+  not timed against an all-reduce on the v5e) — and the
   owned shards are packed with a ``shard_map`` local concat. The per-param
   constraint + explicit local pack is load-bearing: concatenating partial-sum
   gradients BEFORE the constraint trips a partitioner mis-reduction on

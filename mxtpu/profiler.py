@@ -162,7 +162,7 @@ def resume(profile_process: str = "worker"):
 
 def reset_trace():
     """Drop every recorded span/event, zero the span totals and unfreeze a
-    finished dump (tests, back-to-back bench legs)."""
+    finished dump (tests)."""
     _tracer.reset()
     with _stats_lock:
         _state["events"] = []

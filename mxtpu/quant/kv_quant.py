@@ -252,7 +252,7 @@ def block_slice(page, start: int, size: int):
 
 def cache_nbytes(caches) -> int:
     """Resident bytes of a cache/page (data + scales for QuantKV) — the
-    ``kv_bytes_resident`` stat and the bench shrink numerator."""
+    ``kv_bytes_resident`` stat."""
     if caches is None:
         return 0
     return int(caches.nbytes)
@@ -262,7 +262,7 @@ def page_nbytes(L: int, H: int, D: int, tokens: int, dtype=jnp.float32,
                 quant: Optional[str] = None) -> int:
     """Analytic bytes of ``tokens`` KV positions (both K and V) across all
     layers/heads — the PrefixCache block accounting and the fixed-HBM-budget
-    slot math in ``bench.py quant``."""
+    slot math behind :func:`shrink_vs_f32`."""
     rows = L * 2 * H * tokens
     if quant is None:
         return rows * D * jnp.dtype(dtype).itemsize

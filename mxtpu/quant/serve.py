@@ -75,7 +75,7 @@ class QuantSpec:
     @property
     def tag(self) -> str:
         """Stable human-readable tag ('fp32', 'int8_kv', 'int8_kv+int8_w',
-        ...) — the stats/bench label."""
+        ...) — the stats label."""
         parts = []
         if self.kv:
             parts.append(f"{self.kv}_kv")

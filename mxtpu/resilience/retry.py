@@ -9,9 +9,8 @@ and "your program is wrong". This module is that knowledge:
   fs hiccups / connection flakes) vs logic errors (TypeError & friends
   escalate immediately; retrying those only buries the traceback).
 * :func:`retry_transient` — bounded exponential backoff with deterministic
-  jitter around any callable. Adopted by ``dist.initialize``, the checkpoint
-  writer's shard-write/commit path, and ``bench.py run_leg`` (replacing its
-  ad-hoc one-retry).
+  jitter around any callable. Adopted by ``dist.initialize`` and the
+  checkpoint writer's shard-write/commit path.
 
 Knobs: ``MXTPU_RETRY_MAX`` (retries after the first attempt, default 3),
 ``MXTPU_RETRY_BACKOFF_S`` (base delay, default 0.5, doubling per retry,

@@ -15,7 +15,7 @@ Two modes:
 * ``mode="inline"`` (default) — ``fit_fn`` runs in this process inside the
   restart loop. Survives raised failures (injected faults, writer errors,
   collective flakes) but by nature not process death; cheap enough for
-  tier-1 and the bench's resilience leg.
+  tier-1.
 * ``mode="process"`` — each attempt is a fresh ``multiprocessing``
   *spawn* child (fork after JAX init is hazardous), so SIGKILL / preemption
   / watchdog ``os._exit(87)`` are all survivable. ``fit_fn`` must be a

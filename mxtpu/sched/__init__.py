@@ -18,7 +18,7 @@ engine is byte-identical to the plain FIFO path):
   respawn callable, with hysteresis, cooldown, and a dry-run mode.
 * :mod:`.replay` — deterministic bursty / diurnal / heavy-tail arrival
   traces over shared-prefix multi-tenant populations, the workload
-  behind ``bench.py traffic`` and its ``goodput_under_slo`` ratchet.
+  ``tests/test_router_guard.py`` replays through two replicas.
 
 See ``docs/serving.md`` (scheduling section) and
 ``docs/observability.md`` (autoscaler signal table).

@@ -1,7 +1,7 @@
 """Traffic-replay traces: seeded multi-tenant arrival processes.
 
 The millions-of-users scenario is not one queue of uniform arrivals, so
-the ``bench.py traffic`` leg (and any load test) drives the engine from a
+``tests/test_router_guard.py`` (and any load test) drives the engine from a
 :class:`TrafficTrace` built here: a deterministic, seeded list of
 :class:`TrafficRequest` with realistic shapes —
 
@@ -19,7 +19,7 @@ the ``bench.py traffic`` leg (and any load test) drives the engine from a
   would).
 
 Everything is derived from one ``random.Random(seed)``: the same (kind,
-seed, knobs) always yields byte-identical traces, so bench numbers are
+seed, knobs) always yields byte-identical traces, so results are
 comparable across runs and schedulers can be A/B'd on the *same* traffic.
 No jax imports — building a trace is free.
 """
@@ -137,7 +137,7 @@ def make_trace(kind: str = "bursty", seed: int = 0, *,
         raise ValueError(f"unknown trace kind {kind!r}; one of {KINDS}")
     # zlib.crc32, NOT hash(): str hashes are salted per process
     # (PYTHONHASHSEED), which would make "the same seed" yield a
-    # different trace every run and turn the bench ratchet into noise
+    # different trace every run
     key = f"{seed}|{kind}|{round(rate * 1e6)}|{round(duration_s * 1e6)}"
     rng = random.Random(zlib.crc32(key.encode()))
     if not tenants:

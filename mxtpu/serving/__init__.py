@@ -12,8 +12,7 @@ Two serving modes, one package:
   backpressure. ``submit()`` from any thread; greedy output is bit-exact
   with per-request ``TransformerLM.generate``.
 
-See ``docs/serving.md`` for architecture, knobs, and the latency/goodput
-methodology behind ``bench.py serving``.
+See ``docs/serving.md`` for architecture, knobs, and what is measured.
 """
 
 from .api import (CANCELLED, DONE, EXPIRED, PENDING, RUNNING, SHED, TIERS,

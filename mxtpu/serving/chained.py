@@ -2,8 +2,8 @@
 
 Per-call inference pays one jit dispatch per forward, and at small batch
 that fixed cost can gate serving below the chip's real rate (ResNet-50 b1:
-87 img/s per-call vs 589 chained in BENCH_r04, taken on rounds 3-4's runtime;
-not re-measured on the v5e builders have now). The reference has no
+87 img/s per-call vs 589 chained in rounds 3-5, a retired runtime; not
+re-measured on the v5e). The reference has no
 equivalent layer; here the amortization belongs IN the framework.
 
 ``ChainedPredictor`` compiles ONE program that scans over a stack of n
@@ -12,7 +12,7 @@ batches, so a chain of n forwards costs one dispatch + n compute steps.
 
 Use the PLAIN (non-hybridized) block: a hybridized CachedOp draws rng keys at
 its own trace time, which leaks tracers when traced inside the outer jit
-(bench.py inference docstring records the same constraint).
+(``tests/test_serving.py`` chains plain blocks for that reason).
 """
 
 from __future__ import annotations

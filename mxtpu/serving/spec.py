@@ -176,8 +176,7 @@ class ModelDrafter(Drafter):
     plain that turn.
 
     Pair it with the engine via ``SpecConfig(k=..., drafter=
-    ModelDrafter(draft_net))``; ``bench.py serving`` A/Bs it against the
-    default :class:`NgramDrafter` on the spec leg."""
+    ModelDrafter(draft_net))``."""
 
     BUCKETS = (8, 32, 64)
 

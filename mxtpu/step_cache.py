@@ -708,7 +708,7 @@ class StepExecutor:
         (``observability.flops.estimate_step_flops``; which source counted
         them is in ``get_mfu_stats()["flops_source"]``). Lazy and cached per
         cache entry: the first call after a trace pays one AOT lower+compile,
-        subsequent calls are a dict read — callers (fit epoch logs, bench)
+        subsequent calls are a dict read — callers (fit epoch logs)
         keep this OFF the step hot path."""
         entry = self._cache.get(self._last_sig)
         if entry is None or "avals" not in entry:

@@ -2,9 +2,8 @@
 
 Two contracts future PRs cannot silently break:
 
-1. **Self-lint clean** — ``python -m mxtpu.analysis mxtpu tests bench.py``
-   exits 0 on the committed tree (the library AND its tests AND the bench
-   harness).  A new unlocked counter dict, a stray host sync in a traced
+1. **Self-lint clean** — ``python -m mxtpu.analysis mxtpu tests``
+   exits 0 on the committed tree (the library AND its tests).  A new unlocked counter dict, a stray host sync in a traced
    step, or a swallowed producer error fails CI with the rule name and
    line, not a flaky hang three PRs later.  Findings a test legitimately
    stages (e.g. the observability off-path identity assert) carry an
@@ -33,12 +32,12 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_self_lint_clean():
-    """The committed tree — library, tests, bench harness — passes its own
+    """The committed tree — library and tests — passes its own
     linter (and the linter actually ran: a crash would exit 2/1 with
     output)."""
     p = subprocess.run(
         [sys.executable, "-m", "mxtpu.analysis", "mxtpu", "tests",
-         "bench.py", "--stats"],
+         "--stats"],
         cwd=_REPO, env=conftest.subprocess_env(),
         capture_output=True, text=True, timeout=300)
     assert p.returncode == 0, (

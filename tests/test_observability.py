@@ -284,13 +284,21 @@ def test_step_ring_percentiles_and_rate():
     assert flops.get_mfu_stats()["steps"] == 0
 
 
-def test_mfu_computed_against_cpu_heuristic_peak():
+def test_device_without_documented_peak_reports_no_mfu():
+    """The CPU is in no peak table: ``mfu`` is None there, never a figure
+    against an invented peak, and the step times are reported all the same."""
     kind, peak = flops.device_peak()
-    assert peak and peak > 0          # cpu hosts get the nominal ratchet peak
+    assert kind and peak is None
     flops.reset_steps()
-    flops.record_step(0.01)
+    for _ in range(4):
+        flops.record_step(0.01)
     s = flops.get_mfu_stats(flops_per_step=1e7)
-    assert s["mfu"] is not None and s["mfu"] > 0
+    assert s["mfu"] is None and s["peak_tflops"] is None
+    assert s["device_kind"] == kind and s["flops_per_step"] == 1e7
+    assert s["steps"] == 4
+    assert s["steps_per_sec"] == pytest.approx(100.0, rel=0.01)
+    assert s["p50_step_ms"] == pytest.approx(10.0, rel=0.01)
+    assert s["p99_step_ms"] == pytest.approx(10.0, rel=0.01)
     flops.reset_steps()
 
 

@@ -173,8 +173,7 @@ def test_dp_tp_trainer_matches_serial():
 def test_micro_batch_accumulation_matches_full_batch():
     """micro_batches=k: the optimizer sees the mean full-batch gradient, so a
     BN-free net must train identically (up to fp tolerance) to the k=1 step;
-    activation memory shrinks k-fold (the large-batch HBM-capacity cure,
-    benchmark/python/mfu_probe.py)."""
+    activation memory shrinks k-fold (the large-batch HBM-capacity cure)."""
     import numpy as np
 
     import mxtpu as mx

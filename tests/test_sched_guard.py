@@ -20,6 +20,7 @@ import pytest
 
 import mxtpu as mx
 from mxtpu import nd, profiler
+from mxtpu.sched import replay
 from mxtpu.sched.autoscale import AutoscalePolicy, Autoscaler
 from mxtpu.sched.policy import (DEFAULT_TIERS, SLOPolicy, SLOScheduler,
                                 TierSpec)
@@ -474,3 +475,37 @@ def test_scalar_prefill_warms_the_shed_estimator(net):
     assert st["prefill_ms_per_token"] is not None \
         and st["prefill_ms_per_token"] > 0
     assert st["decode_ms_per_token"] is not None
+
+
+# -- traffic replay traces (mxtpu.sched.replay) ------------------------------
+# Until PR 28 only a leg of the deleted ``bench`` harness and the router guard
+# built a trace; what a load test leans on is pinned here, a case per kind.
+
+
+@pytest.mark.parametrize("kind", replay.KINDS)
+def test_replay_trace_is_seeded_sorted_and_shares_tenant_prefixes(kind):
+    tenants = (replay.TenantProfile("chat", priority="interactive",
+                                    prefix_len=6, suffix_len=3, max_new=5,
+                                    deadline_s=9.0),
+               replay.TenantProfile("bulk", priority="batch", share=2.0,
+                                    prefix_len=4, suffix_len=2, max_new=7))
+    kw = dict(rate=20.0, duration_s=2.0, vocab=VOCAB, tenants=tenants)
+    a = replay.make_trace(kind, seed=3, **kw)
+    assert a == replay.make_trace(kind, seed=3, **kw)      # same seed, same trace
+    assert a.requests != replay.make_trace(kind, seed=4, **kw).requests
+    assert a.kind == kind and len(a) >= 4
+    times = [r.t for r in a.requests]
+    assert times == sorted(times) and 0.0 <= times[0] and times[-1] < 2.0
+    by_name = {t.name: t for t in tenants}
+    for r in a.requests:
+        t = by_name[r.tenant]
+        prefix = a.prefixes[r.tenant]
+        assert len(prefix) == t.prefix_len
+        assert r.prompt[:t.prefix_len] == prefix           # cache-hittable
+        assert len(r.prompt) > t.prefix_len
+        assert all(1 <= tok < VOCAB for tok in r.prompt)   # 0 is reserved
+        assert r.priority == t.priority and r.deadline_s == t.deadline_s
+        assert r.max_new >= 1
+    assert {r.tenant for r in a.requests} == {"chat", "bulk"}
+    with pytest.raises(ValueError, match="unknown trace kind"):
+        replay.make_trace("square", seed=3)

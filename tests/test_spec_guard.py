@@ -5,16 +5,17 @@ prefix-cache hits, int8 KV, greedy/sampled slot mixes, preemption
 park/resume, and a drain/adopt landing between verify turns — while a
 full run compiles at most ONE verify program per (slots, KV bucket, k).
 
-The speedup side (``accept_len_mean`` / ``spec_decode_speedup``) is
-ratcheted by ``bench.py serving``; here the stats contract is pinned
-structurally: drafted == accepted + rejected, the accept-length histogram
-mean exceeds 1.0 on draftable (repetitive) streams, and a spec-less
-engine never dispatches a verify program at all.
+The speedup side has no measurement on the chip yet (the benchmark has no
+serving cell); here the stats contract is pinned structurally: drafted ==
+accepted + rejected, the accept-length histogram mean exceeds 1.0 on
+draftable (repetitive) streams, and a spec-less engine never dispatches a
+verify program at all.
 
 Engines are deliberately scarce (each owns fresh jit wrappers and pays
 its own XLA compiles), so every test asserts several contracts at once.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -23,8 +24,8 @@ import pytest
 import mxtpu as mx
 from mxtpu import nd, profiler
 from mxtpu.gluon.model_zoo import transformer_lm
-from mxtpu.serving import (SamplingParams, ServingEngine, ServingHandoff,
-                           SpecConfig)
+from mxtpu.serving import (Drafter, ModelDrafter, SamplingParams,
+                           ServingEngine, ServingHandoff, SpecConfig)
 
 VOCAB = 50
 
@@ -205,30 +206,71 @@ def test_spec_park_resume_preemption_bit_exact(net):
     assert passes["bulk"] >= len(p_batch) + 48
 
 
+class _GatedOracle(Drafter):
+    """Proposes the reference continuation, so EVERY turn ends with a live
+    draft, and parks the scheduler thread inside the proposal that ends the
+    first turn ``gate`` or more tokens deep until ``release`` is set: the
+    request can neither finish nor start another turn before the test has
+    called ``drain()``."""
+
+    def __init__(self, n_prompt, ref, gate):
+        self._n_prompt, self._ref, self._gate = n_prompt, ref, gate
+        self.reached = threading.Event()
+        self.release = threading.Event()
+
+    def propose(self, context, k):
+        g = len(context) - self._n_prompt
+        if g >= self._gate and not self.reached.is_set():
+            self.reached.set()
+            self.release.wait(timeout=60)
+        return self._ref[g:g + k]
+
+
+class _NoDraft(Drafter):
+    def propose(self, context, k):
+        return []
+
+
 def test_spec_drain_adopt_mid_verify_and_specless_refusal(net):
     """Elastic handoff between verify turns: the handoff carries the spec
     schema ({'k'}) and each slot's un-verified draft, a spec-less
     successor REFUSES it (mirror of the parked-slots rule), and a spec
     successor resumes bit-exact — the draft proposed on the old engine is
-    verified on the new one."""
+    verified on the new one.
+
+    What ``drain()`` guarantees: it stops the scheduler BETWEEN turns, and a
+    turn ends with ``_propose_drafts``, so an entry's ``dlen`` is whatever
+    the drafter proposed for the stream as it then stood: a live draft when
+    it proposed one, 0 when it missed. This test used to poll with
+    ``time.sleep(0.001)`` for 24 tokens under the default n-gram drafter
+    and assert ``dlen > 0``; on jax / jaxlib 0.9.0 the tiny model's greedy
+    stream stands at a tail with no earlier occurrence (``... 36, 4``) at
+    the turn the poll lands on, the drafter misses, and ``dlen`` was 0 on
+    every run. A draft CAN be in flight, so the state is reached by
+    construction instead: ``_GatedOracle`` always proposes and holds the
+    scheduler inside the proposal until ``drain()`` is under way."""
     profiler.reset_serving_stats()
     rs = np.random.RandomState(31)
     prompt = _rep_prompt(rs, 4, 13)
     ref = _solo(net, prompt, 60)
 
+    drafter = _GatedOracle(len(prompt), ref, gate=24)
     eng = ServingEngine(net, slots=2, queue_depth=8, chunk=4,
-                        spec=SpecConfig(k=4)).start()
+                        spec=SpecConfig(k=4, drafter=drafter)).start()
     req = eng.submit(prompt, 60)
-    t0 = time.monotonic()
-    while len(req.tokens()) < 24:             # several verify turns deep
-        assert time.monotonic() - t0 < 300, "decode never started"
-        time.sleep(0.001)
+    assert drafter.reached.wait(timeout=300), "decode never got 24 deep"
+    # the timer only opens the gate once drain() has asked the loop to
+    # stop; were it early, the loop would run further turns, each of which
+    # ends with a live draft too: no assertion below rests on the 0.2 s
+    threading.Timer(0.2, drafter.release.set).start()
     handoff = eng.drain()
     assert handoff.spec == {"k": 4}
     assert handoff.in_flight == 1
     entry = handoff.entries[0]
-    assert entry["dlen"] > 0                  # genuine in-flight draft
-    assert len(entry["draft"]) == 4
+    done = len(req.tokens())
+    assert 24 <= done < 60
+    assert entry["dlen"] == 4                 # genuine in-flight draft
+    assert entry["draft"] == ref[done:done + 4]
 
     # spec-less successor refuses BEFORE touching any state, so the same
     # handoff still adopts cleanly afterwards
@@ -236,12 +278,41 @@ def test_spec_drain_adopt_mid_verify_and_specless_refusal(net):
     with pytest.raises(ValueError, match="draft"):
         bare.adopt(handoff)
 
+    # the successor proposes nothing of its own, so whatever it accepts is
+    # the draft that rode the handoff
+    before = profiler.get_serving_stats()
     eng2 = ServingEngine(net, slots=2, queue_depth=8, chunk=4,
-                         spec=SpecConfig(k=4))
+                         spec=SpecConfig(k=4, drafter=_NoDraft()))
     eng2.adopt(handoff)
     assert req.result(timeout=300) == ref     # hop mid-verify, bit-exact
     eng2.stop()
     stats = profiler.get_serving_stats()
+    assert stats["tokens_accepted"] - before["tokens_accepted"] == 4
+    assert stats["spec_dispatches"] - before["spec_dispatches"] == 1
     assert stats["drained"] == 1 and stats["adopted"] == 1
     assert stats["cancelled"] == 0 and stats["expired"] == 0
     assert stats["accept_len_mean"] > 1.0
+
+
+def test_model_drafter_self_draft_bit_exact(net):
+    """The draft-LM seam (``ModelDrafter``) under the same advisory
+    contract: the target drafts for itself from a context cut to the
+    drafter's buckets, so some proposals are wrong and are rejected, and
+    the stream is still bit-exact with solo; the drafter's own counters
+    agree with the serving stats' ledger. (Until PR 28 only a leg of the
+    deleted ``bench`` harness drove this class.)"""
+    profiler.reset_serving_stats()
+    rs = np.random.RandomState(37)
+    prompt = rs.randint(1, VOCAB, size=11).tolist()   # no n-gram to lean on
+    ref = _solo(net, prompt, 60)
+    drafter = ModelDrafter(net)
+    with ServingEngine(net, slots=2, queue_depth=8, chunk=4,
+                       spec=SpecConfig(k=4, drafter=drafter)) as eng:
+        assert eng.submit(prompt, 60).result(timeout=300) == ref
+        stats = profiler.get_serving_stats()
+    d = drafter.stats()
+    assert d["draft_lm_calls"] > 0
+    assert d["draft_lm_tokens"] >= stats["tokens_drafted"] > 0
+    assert stats["tokens_accepted"] > 0 and stats["spec_dispatches"] > 0
+    assert stats["tokens_accepted"] + stats["tokens_rejected"] \
+        == stats["tokens_drafted"]

@@ -1,6 +1,6 @@
 """TransformerLM model-zoo family: shapes, causality, weight tying, autograd,
 and end-to-end learning through DataParallelTrainer (the flagship training
-workload's correctness gate — the perf side lives in bench.py)."""
+workload's correctness gate — the perf side is ``benchmark/suite``)."""
 
 import numpy as np
 import pytest

@@ -11,8 +11,8 @@ reports algorithmic bandwidth (bytes reduced / time). Modes:
 * multi process (under ``tools/launch.py -n W``): ``allreduce_processes`` over
   the pod collective — the ``dist_sync``/ICI tier; busbw = 2(W-1)/W x algbw.
 
-Timing syncs by reading one device-side element back to the host (see
-bench.py docstring).
+Timing syncs by reading one device-side element back to the host, which
+waits for the work that produced it.
 """
 
 from __future__ import annotations
@@ -196,8 +196,7 @@ def main():
     compile_cache.place()      # before the first jit
     sizes = [float(s) for s in args.sizes_mb.split(",")]
     if args.virtual:
-        run_virtual(args.virtual, sizes, args.iters,
-                    args.artifact or "benchmark/bandwidth_virtual.json")
+        run_virtual(args.virtual, sizes, args.iters, args.artifact)
         return
     rows, multi = measure(sizes, args.iters, args.kv_type)
     tier = "dist allreduce" if multi else f"kvstore {args.kv_type}"
