@@ -72,13 +72,13 @@ SIZES = {
         # both decode programs take the Pallas kernel; the 64/160 prompt
         # buckets in between prefill through XLA
         int8=dict(slots=4, requests=((40, 80), (150, 100))),
-        # (T, dtype, MXTPU_FLASH_BWD, MXTPU_FLASH_LSE)
+        # (T, dtype, MXTPU_FLASH_LSE)
         flash=dict(B=4, H=16, D=64, cases=(
-            (1024, "bfloat16", "split", "f32"),
-            (2048, "bfloat16", "split", "f32"),
-            (1024, "float32", "split", "f32"),
-            (2048, "bfloat16", "fused", "f32"),
-            (2048, "bfloat16", "split", "bf16"))),
+            (1024, "bfloat16", "f32"),
+            (2048, "bfloat16", "f32"),
+            (1024, "float32", "f32"),
+            (2048, "float32", "f32"),
+            (2048, "bfloat16", "bf16"))),
         decode=dict(S=4, H=16, D=64, int8_tot=(128, 512, 2048), fp8_tot=512),
         multi=dict(B=32, serve_n=4),
     ),
@@ -88,16 +88,15 @@ SIZES = {
         serve=dict(slots=4, max_new=70, prompt_lens=(8, 40), n=2),
         int8=dict(slots=4, requests=((8, 70), (40, 88))),
         flash=dict(B=1, H=2, D=32, cases=(
-            (128, "float32", "split", "f32"),
-            (128, "bfloat16", "fused", "bf16"))),
+            (128, "float32", "f32"),
+            (128, "bfloat16", "bf16"))),
         decode=dict(S=2, H=2, D=32, int8_tot=(128,), fp8_tot=128),
         multi=dict(B=8, serve_n=2),
     ),
 }
 
 FLASH_FWD = "flash_fwd"
-FLASH_BWD_SPLIT = ("flash_bwd_dq", "flash_bwd_dkv")
-FLASH_BWD_FUSED = "flash_bwd_fused"
+FLASH_BWD = "flash_bwd_fused"
 DEQUANT_DECODE = "decode_attn_quant"
 
 
@@ -216,7 +215,7 @@ def leg_train(sz, B: int, on_chip: bool) -> tuple:
     H = net.blocks[0].attn._heads
     scores = f"tensor<{B}x{H}x{T}x{T}x" in text   # the reference's T×T matrix
     if on_chip:
-        for name in (FLASH_FWD,) + FLASH_BWD_SPLIT:
+        for name in (FLASH_FWD, FLASH_BWD):
             check(kernels[name] >= len(net.blocks),
                   f"train: lowered step calls {name} {kernels[name]}x, "
                   f"expected one per block ({len(net.blocks)}) — the XLA "
@@ -454,7 +453,7 @@ def leg_kernels(sz, on_chip: bool) -> list:
     fl = sz["flash"]
     B, H, D = fl["B"], fl["H"], fl["D"]
     scale = 1.0 / np.sqrt(D)
-    for T, dt, bwd, lse_dt in fl["cases"]:
+    for T, dt, lse_dt in fl["cases"]:
         rs = np.random.RandomState(T)
         q, k, v, g = (jnp.asarray(rs.randn(B, H, T, D), dt) for _ in range(4))
         with jax.default_matmul_precision("highest"):
@@ -470,14 +469,12 @@ def leg_kernels(sz, on_chip: bool) -> list:
             return (o,) + att._flash_backward_pallas(
                 q, k, v, o, lse, g, True, scale, interpret=interpret)
 
-        want = (FLASH_FWD,) + ((FLASH_BWD_FUSED,) if bwd == "fused"
-                               else FLASH_BWD_SPLIT)
-        # both options are environment-only and read at trace time
-        with environ(MXTPU_FLASH_BWD=bwd, MXTPU_FLASH_LSE=lse_dt):
+        # the option is environment-only and read at trace time
+        with environ(MXTPU_FLASH_LSE=lse_dt):
             rows.append(_try_kernel(
-                f"flash fwd+{bwd} bwd, lse {lse_dt}",
+                f"flash fwd+bwd, lse {lse_dt}",
                 f"B{B} H{H} T{T} D{D} {dt}", fwd_bwd, (q, k, v, g), ref,
-                want, KERNEL_REL_TOL[dt]))
+                (FLASH_FWD, FLASH_BWD), KERNEL_REL_TOL[dt]))
 
     # dequant decode against dequantize-then-attend
     dc = sz["decode"]

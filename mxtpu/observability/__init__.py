@@ -73,7 +73,8 @@ Span catalog (see docs/observability.md):
 ==========================  =================================================
 
 Names on the device (a ``jax.profiler`` trace's operations): Pallas kernels
-``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``flash_bwd_fused``,
+``flash_fwd``, ``flash_bwd_fused`` (the whole backward), with a window
+``flash_fwd_window``, ``flash_bwd_dq_window``, ``flash_bwd_dkv_window``;
 ``decode_attn_quant``; scopes ``<RootBlock>/block<i>/attn/q_proj`` … from
 ``Block.__call__`` (the name the parent registered the child under),
 ``embed``, ``head`` (``TransformerLM``), ``loss``, ``optimizer``,

@@ -13,10 +13,12 @@ contribute nothing to q·kᵀ and produce zero output columns, sliced off
 afterwards). Sequence lengths engage when T % 128 == 0 on real hardware
 (sub-128 whole-axis blocks pass in interpret mode but real Mosaic rejects
 their vector loads — observed on v5e); anything else falls back to the XLA
-reference, which is equally fast at those sizes. The backward pass is the standard flash
-backward — forward saves the per-row log-sum-exp; two kernels recompute the
-probabilities per tile and accumulate dq (grid over q blocks) and dk/dv (grid
-over k blocks) without materializing T×T.
+reference, which is equally fast at those sizes. The backward pass is the
+standard flash backward — forward saves the per-row log-sum-exp; one kernel
+(``flash_bwd_fused``, grid over k blocks) recomputes the probabilities of
+each score tile once and accumulates dk/dv for its k block and dq for the
+whole query row, the latter in a float32 VMEM scratch, without materializing
+T×T.
 
 Beyond plain multi-head attention the launches take: fewer key/value heads
 than query heads (query head ``h`` reads key/value head ``h // group``
@@ -123,17 +125,6 @@ def _block_cap(dp: int) -> int:
     return max(128, 512 * 128 // max(dp, 128))
 
 
-def _bwd_mode() -> str:
-    """Flash-backward launch shape: ``'split'`` (default — the validated
-    two-kernel dq then dk/dv pair) or ``'fused'`` (``MXTPU_FLASH_BWD=fused``
-    — one kernel per (batch·head, tile) computing dq for its q-tile AND
-    dk/dv for its k-tile, halving launches and re-streaming each opposing
-    tile once instead of twice across kernels). Long-context retune knob
-    (PR16 tentpole c); read at trace time, so flipping it retraces."""
-    return "fused" if os.environ.get(
-        "MXTPU_FLASH_BWD", "").strip().lower() == "fused" else "split"
-
-
 def _lse_store_dtype():
     """Storage dtype for the sublane-broadcast lse/delta rows the backward
     kernels stream: f32 (default, exact) or bf16 (``MXTPU_FLASH_LSE=bf16``)
@@ -201,48 +192,16 @@ def _zeros_like_kv(k_blk, v_blk):
                else jnp.zeros(v_blk.shape, jnp.float32))
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_k: int, causal: bool, scale: float):
-    """dq for one q block: loop K/V tiles, recompute P from the saved lse."""
-    from jax.experimental import pallas as pl
-
-    q = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
-    block_q = q.shape[0]
-    qi = pl.program_id(1)
-    q_start = qi * block_q
-    kv_len = k_ref.shape[1]
-    num_kb = kv_len // block_k
-
-    def body(kb, dq):
-        k_blk = k_ref[0, pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            rows = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)                       # masked entries underflow to 0
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
-
-    if causal:
-        last_kb = (q_start + block_q - 1) // block_k + 1
-        num_iter = jnp.minimum(num_kb, last_kb)
-    else:
-        num_iter = num_kb
-    dq0 = jnp.zeros((block_q, q.shape[1]), jnp.float32)
-    dq = lax.fori_loop(0, num_iter, body, dq0)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, causal: bool,
-                          scale: float):
-    """dk/dv for one k block: loop q tiles, recompute P from the saved lse."""
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, *, block_q: int,
+                      causal: bool, scale: float):
+    """The whole backward for one (batch·head, key tile) program: loop the
+    query tiles this key tile is visible to, make S, P, dP and dS ONCE per
+    tile from the saved lse, and feed all three gradients from them: five
+    matmuls a tile. dk and dv are carried through the loop; dq is summed
+    over the key tiles in ``dq_acc``, a float32 ``(T, Dp)`` scratch that
+    lives across the (sequential) key axis and is scaled and stored at the
+    last key tile."""
     from jax.experimental import pallas as pl
 
     k_blk = k_ref[0].astype(jnp.float32)           # (block_k, d)
@@ -250,109 +209,44 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     block_k = k_blk.shape[0]
     kb = pl.program_id(1)
     k_start = kb * block_k
-    t = q_ref.shape[1]
-    num_qb = t // block_q
+    num_qb = q_ref.shape[1] // block_q
+
+    @pl.when(kb == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     def body(qb, carry):
         dk, dv = carry
         qs = qb * block_q
-        q = q_ref[0, pl.dslice(qs, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, pl.dslice(qs, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.dslice(qs, block_q)].astype(jnp.float32)[:, None]
-        delta = delta_ref[0, 0, pl.dslice(qs, block_q)].astype(
-            jnp.float32)[:, None]
+        tile = pl.dslice(qs, block_q)
+        q = q_ref[0, tile, :].astype(jnp.float32) * scale
+        do = do_ref[0, tile, :].astype(jnp.float32)
+        lse = lse_ref[0, 0, tile].astype(jnp.float32)[:, None]
+        delta = delta_ref[0, 0, tile].astype(jnp.float32)[:, None]
         s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
         if causal:
             rows = qs + lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)
+        p = jnp.exp(s - lse)                       # masked entries underflow to 0
         dv_new = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
         dk_new = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dq_acc[tile, :] += jnp.dot(ds, k_blk,
+                                   preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
+    # causal: only query tiles from the diagonal on see this key tile
     start_qb = (k_start // block_q) if causal else 0
     dk, dv = lax.fori_loop(start_qb, num_qb, body, _zeros_like_kv(k_blk, v_blk))
     # dk absorbed one factor of scale through q; no extra factor needed
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
-
-def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            dq_ref, dk_ref, dv_ref, *, block: int,
-                            causal: bool, scale: float):
-    """One (batch·head, tile i) program producing dq for q-tile i AND dk/dv
-    for k-tile i (``MXTPU_FLASH_BWD=fused``). Requires self-attention
-    tiling (T == Tk, shared block). The two inner loops walk complementary
-    causal wedges — key tiles j <= i for dq, query tiles j >= i for dk/dv —
-    so together each program touches one full stripe of the T×T square and
-    the grid covers it exactly once, in half the kernel launches of the
-    split pair."""
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(1)
-    t = q_ref.shape[1]
-    num_b = t // block
-    i_start = i * block
-
-    q_i = q_ref[0, pl.dslice(i_start, block), :].astype(jnp.float32) * scale
-    do_i = do_ref[0, pl.dslice(i_start, block), :].astype(jnp.float32)
-    lse_i = lse_ref[0, 0, pl.dslice(i_start, block)].astype(
-        jnp.float32)[:, None]
-    delta_i = delta_ref[0, 0, pl.dslice(i_start, block)].astype(
-        jnp.float32)[:, None]
-    k_i = k_ref[0, pl.dslice(i_start, block), :].astype(jnp.float32)
-    v_i = v_ref[0, pl.dslice(i_start, block), :].astype(jnp.float32)
-
-    # -- dq for q-tile i: stream key tiles j (j <= i when causal) ----------
-    def dq_body(j, dq):
-        ks = j * block
-        k_blk = k_ref[0, pl.dslice(ks, block), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.dslice(ks, block), :].astype(jnp.float32)
-        s = jnp.dot(q_i, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            rows = i_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = ks + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse_i)
-        dp = jnp.dot(do_i, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_i)
-        return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
-
-    dq0 = jnp.zeros((block, q_i.shape[1]), jnp.float32)
-    dq = lax.fori_loop(0, jnp.minimum(num_b, i + 1) if causal else num_b,
-                       dq_body, dq0)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-    # -- dk/dv for k-tile i: stream query tiles j (j >= i when causal) -----
-    def dkv_body(j, carry):
-        dk, dv = carry
-        qs = j * block
-        q_blk = q_ref[0, pl.dslice(qs, block), :].astype(jnp.float32) * scale
-        do_blk = do_ref[0, pl.dslice(qs, block), :].astype(jnp.float32)
-        lse_blk = lse_ref[0, 0, pl.dslice(qs, block)].astype(
-            jnp.float32)[:, None]
-        delta_blk = delta_ref[0, 0, pl.dslice(qs, block)].astype(
-            jnp.float32)[:, None]
-        s = jnp.dot(q_blk, k_i.T, preferred_element_type=jnp.float32)
-        if causal:
-            rows = qs + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = i_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse_blk)
-        dv_new = dv + jnp.dot(p.T, do_blk, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_blk, v_i.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk)
-        dk_new = dk + jnp.dot(ds.T, q_blk, preferred_element_type=jnp.float32)
-        return dk_new, dv_new
-
-    dk, dv = lax.fori_loop(i if causal else 0, num_b, dkv_body,
-                           _zeros_like_kv(k_i, v_i))
-    # dk absorbed one factor of scale through q_blk; no extra factor needed
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _pad_d(x):
@@ -689,21 +583,41 @@ def _flash_attention_pallas(q, k, v, causal: bool, scale: float,
     return out[..., :Dv].reshape(B, H, T, Dv), lse[:, 0, :]
 
 
+def _bwd_vmem_bytes(T: int, Dp: int, Dvp: int, block_q: int, block_k: int,
+                    itemsize: int) -> int:
+    """VMEM the backward program holds, from its shapes: the query row's q,
+    dO and dq blocks and the key tile's k, v, dk, dv blocks (two buffers
+    each), the lse / delta rows, the float32 dq accumulator, and room for a
+    score tile's float32 temporaries (s, p, dp, ds, their transposes, the
+    mask's iotas) and the carried dk / dv. Never under the 16 MiB a v5e
+    program gets by default; the chip has 128 MiB."""
+    row = 2 * (2 * T * Dp + T * Dvp) * itemsize + 2 * 2 * 8 * T * 4
+    tile = 2 * 2 * block_k * (Dp + Dvp) * itemsize
+    acc = T * Dp * 4
+    temps = 10 * block_q * block_k * 4 \
+        + 4 * (block_q + block_k) * (Dp + Dvp) * 4
+    return max(16 << 20, row + tile + acc + temps + (2 << 20))
+
+
 def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
                            block_q: int = 512, block_k: int = 512,
                            interpret: bool = False, lse_cot=None,
                            window=None):
-    """Flash backward: dq via q-block grid, dk/dv via k-block grid (the
-    default 'split' launch), or one fused grid doing both per tile when
-    ``MXTPU_FLASH_BWD=fused`` and the shape is self-attention tiling.
-    Shapes as in the forward; with fewer key/value heads than query heads
-    the kernels make dk and dv per query head and a group's are added up
-    here.
+    """Flash backward: ONE launch, ``flash_bwd_fused``, over a (batch·head,
+    key tile) grid whose key axis is sequential; each score tile is visited
+    once and feeds dq, dk and dv (``_flash_bwd_kernel``). The query row's q
+    and dO stay in VMEM beside a float32 dq accumulator, so the launch asks
+    for the VMEM its shapes need (``_bwd_vmem_bytes``). With a causal
+    window the ``flash_bwd_dq_window`` / ``flash_bwd_dkv_window`` pair runs
+    instead. Shapes as in the forward; with fewer key/value heads than query
+    heads the kernels make dk and dv per query head and a group's are added
+    up here.
 
     ``lse_cot`` (B,H,T): optional cotangent of the log-sum-exp output (ring
     merges differentiate through lse); it folds into the delta term exactly —
     dS = P∘(dP - (Δ - dlse)) since ∂lse/∂S = P."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
@@ -739,81 +653,37 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
             qq, kk, vv, gg, lse, delta, group, window, scale,
             min(block_q, block_k), interpret))
 
-    if (_bwd_mode() == "fused" and T == Tk and block_q == block_k
-            and group == 1):
-        fused = functools.partial(_flash_bwd_fused_kernel, block=block_q,
-                                  causal=causal, scale=scale)
-        dq, dk, dv = pl.pallas_call(
-            fused,
-            grid=(B * H, T // block_q),
-            in_specs=[
-                pl.BlockSpec((1, T, Dp), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, Tk, Dp), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, Tk, Dvp), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, T, Dvp), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, Dvp), lambda b, i: (b, i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, T, Dp), q.dtype),
-                jax.ShapeDtypeStruct((B * H, Tk, Dp), k.dtype),
-                jax.ShapeDtypeStruct((B * H, Tk, Dvp), v.dtype),
-            ],
-            name="flash_bwd_fused",
-            interpret=interpret,
-        )(qq, kk, vv, gg, lse, delta)
-        return unflatten(dq, dk, dv)
-
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
-                                  causal=causal, scale=scale)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(B * H, T // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, Dp), lambda b, i: (kv(b), 0, 0)),
-            pl.BlockSpec((1, Tk, Dvp), lambda b, i: (kv(b), 0, 0)),
-            pl.BlockSpec((1, block_q, Dvp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, Dp), q.dtype),
-        name="flash_bwd_dq",
-        interpret=interpret,
-    )(qq, kk, vv, gg, lse, delta)
-
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                                   causal=causal, scale=scale)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    return unflatten(*pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
+                          scale=scale),
         grid=(B * H, Tk // block_k),
         in_specs=[
-            pl.BlockSpec((1, T, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (kv(b), i, 0)),
-            pl.BlockSpec((1, block_k, Dvp), lambda b, i: (kv(b), i, 0)),
-            pl.BlockSpec((1, T, Dvp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 8, T), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, T, Dp), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, Dp), lambda b, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_k, Dvp), lambda b, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, T, Dvp), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 8, T), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 8, T), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, Dvp), lambda b, i: (b, i, 0)),
+            # the whole row: written once, after the last key tile
+            pl.BlockSpec((1, T, Dp), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, Dp), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dvp), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((B * H, T, Dp), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tk, Dp), k.dtype),
             jax.ShapeDtypeStruct((B * H, Tk, Dvp), v.dtype),
         ],
-        name="flash_bwd_dkv",
+        scratch_shapes=[pltpu.VMEM((T, Dp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(
+                T, Dp, Dvp, block_q, block_k, q.dtype.itemsize)),
+        name="flash_bwd_fused",
         interpret=interpret,
-    )(qq, kk, vv, gg, lse, delta)
-
-    return unflatten(dq, dk, dv)
+    )(qq, kk, vv, gg, lse, delta))
 
 
 # ---------------------------------------------------------------------------

@@ -99,28 +99,94 @@ def test_flash_pallas_backward_matches_reference(D, causal):
     np.testing.assert_allclose(np.asarray(dv), np.asarray(rv), rtol=1e-3, atol=1e-4)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_fused_backward_matches_split(monkeypatch, causal):
-    """MXTPU_FLASH_BWD=fused (ISSUE 16 retune): the one-pass fused backward
-    (dq + dk/dv per tile in a single grid) is bit-identical to the split
-    pair — same f32 tile math, same accumulation order."""
+def _reference_out_lse(q, k, v, causal, scale):
+    """``attention_reference`` with grouped key/value heads repeated, and
+    the log-sum-exp of the same masked scores beside it."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * scale
+    if causal:
+        i = jnp.arange(s.shape[-2])[:, None]
+        s = jnp.where(i >= jnp.arange(s.shape[-1])[None, :], s, -jnp.inf)
+    return (attention_reference(q, k, v, causal=causal, scale=scale),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+# (H, Hkv, T, Tk, D, Dv, causal, dtype, lse cotangent). Blocks of 128, so
+# T = 256 is two query tiles and two key tiles: the dq accumulator is
+# revisited by the second key tile, dk / dv are carried over two query tiles
+ONE_PASS_CASES = [
+    (2, 2, 256, 256, 64, 64, True, "float32", False),
+    (2, 2, 256, 256, 64, 64, False, "float32", False),
+    (2, 2, 256, 256, 128, 128, True, "float32", False),
+    (2, 2, 256, 256, 128, 128, False, "float32", False),
+    (2, 2, 256, 384, 64, 64, False, "float32", False),     # T != Tk
+    (2, 2, 384, 128, 128, 128, False, "float32", True),    # one key tile
+    (4, 2, 256, 256, 64, 128, True, "float32", False),     # 2 : 1, Dv = 2 D
+    (4, 2, 256, 256, 64, 128, False, "float32", True),
+    (2, 2, 256, 256, 64, 64, True, "float32", True),
+    (2, 2, 256, 256, 64, 64, True, "bfloat16", False),
+    (4, 2, 256, 256, 64, 128, True, "bfloat16", True),
+]
+
+
+@pytest.mark.parametrize("H,Hkv,T,Tk,D,Dv,causal,dtype,lse_cot",
+                         ONE_PASS_CASES)
+def test_flash_one_pass_backward_matches_reference(H, Hkv, T, Tk, D, Dv,
+                                                   causal, dtype, lse_cot):
+    """``flash_bwd_fused`` (every score tile once, dq summed over the key
+    tiles in a VMEM scratch) against ``jax.vjp`` of the XLA reference."""
     from mxtpu.ops.attention import _flash_backward_pallas
-    B, H, T, D = 1, 2, 256, 64
-    q, k, v = _qkv(B=B, H=H, T=T, D=D, seed=6)
-    qa, ka, va = map(jnp.asarray, (q, k, v))
+    rs = np.random.RandomState(T + Tk + D + Dv)
+    q, k, v, g = (jnp.asarray(rs.randn(*shape).astype(np.float32)).astype(
+        dtype) for shape in ((1, H, T, D), (1, Hkv, Tk, D), (1, Hkv, Tk, Dv),
+                             (1, H, T, Dv)))
+    g_lse = jnp.asarray(rs.randn(1, H, T).astype(np.float32)) if lse_cot \
+        else None
     scale = 1.0 / np.sqrt(D)
-    g = jnp.asarray(
-        np.random.RandomState(7).randn(B, H, T, D).astype(np.float32))
-    out, lse = _flash_attention_pallas(qa, ka, va, causal=causal, scale=scale,
+    out, lse = _flash_attention_pallas(q, k, v, causal, scale, 128, 128,
                                        interpret=True)
-    monkeypatch.delenv("MXTPU_FLASH_BWD", raising=False)
-    split = _flash_backward_pallas(qa, ka, va, out, lse, g, causal, scale,
-                                   interpret=True)
-    monkeypatch.setenv("MXTPU_FLASH_BWD", "fused")
-    fused = _flash_backward_pallas(qa, ka, va, out, lse, g, causal, scale,
-                                   interpret=True)
-    for s, f in zip(split, fused):
-        np.testing.assert_array_equal(np.asarray(s), np.asarray(f))
+    got = _flash_backward_pallas(q, k, v, out, lse, g, causal, scale, 128,
+                                 128, interpret=True, lse_cot=g_lse)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    _, vjp = jax.vjp(lambda *a: _reference_out_lse(*a, causal, scale), *f32)
+    want = vjp((g.astype(jnp.float32),
+                jnp.zeros((1, H, T)) if g_lse is None else g_lse))
+    # bfloat16: the kernel reads the forward's ROUNDED output for delta and
+    # rounds what it stores
+    tol = 1e-4 if dtype == "float32" else 3e-2
+    for name, a, b, like in zip("qkv", got, want, (q, k, v)):
+        assert a.shape == like.shape and a.dtype == like.dtype, name
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b), rtol=10 * tol,
+            atol=tol * float(jnp.max(jnp.abs(b))), err_msg="d" + name)
+
+
+def _count_primitive(jaxpr, name: str) -> int:
+    """Equations called ``name`` in ``jaxpr`` and every jaxpr nested in it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_primitive(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_backward_is_one_launch_of_five_matmuls(causal):
+    """S and dP are made once per score tile: the backward is ONE Pallas
+    call whose body holds five ``dot_general``s (S, dV, dP, dK, dQ), where
+    a dq kernel beside a dk/dv kernel held seven."""
+    from mxtpu.ops.attention import _flash_backward_pallas
+    av = jax.ShapeDtypeStruct((1, 2, 256, 64), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, 256), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, o, lse, g: _flash_backward_pallas(
+        q, k, v, o, lse, g, causal, 0.125, 128, 128, interpret=True))(
+            av, av, av, av, lse, av).jaxpr
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["name"] == "flash_bwd_fused"
+    assert _count_primitive(calls[0].params["jaxpr"], "dot_general") == 5
 
 
 def test_flash_backward_bf16_lse_stays_close(monkeypatch):
@@ -390,7 +456,7 @@ def _sq_loss(q, k, v):
 def test_flash_lowers_for_tpu_only_inside_shard_map(engage_kernels):
     """The sandbox's view of the four-chip failure: lowering the sharded
     kernel for the TPU platform raises outside a partition scope and yields
-    three Mosaic calls on the per-device (B/4) block inside one."""
+    the two Mosaic calls on the per-device (B/4) block inside one."""
     import re
     from jax.sharding import NamedSharding, PartitionSpec as P
     from mxtpu.ops import attention as A
@@ -409,20 +475,16 @@ def test_flash_lowers_for_tpu_only_inside_shard_map(engage_kernels):
     with A.partition_scope(mesh, P("dp")):
         text = lower().as_text()
     assert sorted(re.findall(r'kernel_name = "([^"]+)"', text)) == [
-        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        "flash_bwd_fused", "flash_fwd"]
     assert "tensor<4x128x128xbf16>" in text     # (8/4 batch x 2 heads, T, Dp)
 
 
-@pytest.mark.parametrize("bwd,names", [
-    ("split", ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]),
-    ("fused", ["flash_bwd_fused", "flash_fwd"])])
-def test_flash_kernels_carry_their_names_in_the_tpu_lowering(
-        engage_kernels, monkeypatch, bwd, names):
+def test_flash_kernels_carry_their_names_in_the_tpu_lowering(engage_kernels):
     """Each ``pallas_call`` passes ``name=``: the custom call's
     ``kernel_name`` and the ``op_name`` of the HLO instruction (what the
-    device trace shows) tell forward from dq from dk/dv."""
+    device trace shows) tell the forward from the backward."""
     import re
-    monkeypatch.setenv("MXTPU_FLASH_BWD", bwd)
+    names = ["flash_bwd_fused", "flash_fwd"]
     engage_kernels(interpret=False)
     av = jax.ShapeDtypeStruct((2, 2, 128, 64), jnp.bfloat16)
     f = jax.jit(jax.grad(lambda q, k, v: _sq_loss(q, k, v),

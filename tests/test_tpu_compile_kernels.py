@@ -76,5 +76,7 @@ def test_flash_kernels_compile_at_the_cells_size(one_chip, quiet_cache,
 
     g, = _avals(one_chip, ((1, 20, T, 128), bf))
     text = jax.jit(both).lower(q, k, v, g).compile().as_text()
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert name + ("_window" if window else "") in text
+    for name in (("flash_fwd_window", "flash_bwd_dq_window",
+                  "flash_bwd_dkv_window") if window
+                 else ("flash_fwd", "flash_bwd_fused")):
+        assert name in text
