@@ -14,7 +14,13 @@ at the full width of the ``flagship`` preset (d1024, L8, H16, vocab 16384):
 3. serve, int8 KV — a second engine with ``quant="int8_kv"``; the decode
    program at a 128-multiple KV bucket must call the Pallas dequant kernel.
 4. kernels — every Pallas kernel the repo offers, alone, against the XLA
-   reference: compiled or refused, and the max error.
+   reference: compiled or refused, and the max error (the grouped matmuls
+   of an expert layer among them: uneven and empty groups, rows of no
+   group). Then a tiny sparse decoder (grouped-query window / full
+   attention, an expert layer holding a share of its experts, a shared
+   expert, the balancing bias) trains four steps through
+   ``DataParallelTrainer``: its grouped matmuls and flash launches must be
+   Pallas call sites.
 5. four chips, when there are four — leg 1 at B32 over dp=4 and a 2x2
    fsdp×tp serving engine, with where the bytes actually landed.
 
@@ -80,6 +86,13 @@ SIZES = {
             (2048, "float32", "f32"),
             (2048, "bfloat16", "bf16"))),
         decode=dict(S=4, H=16, D=64, int8_tot=(128, 512, 2048), fp8_tot=512),
+        # rows, K, N, rows a group (uneven, two empty, 211 rows of no group)
+        grouped=dict(M=2048, K=1024, N=2048,
+                     sizes=(417, 0, 696, 1, 0, 354, 125, 244)),
+        # a sparse decoder of the K-EXAONE family: widths in whole 128s
+        sparse=dict(units=256, head_dim=128, heads=2, ffn=512, moe_ffn=128,
+                    experts=16, held=(4, 5, 6, 7), top_k=4, vocab=1024,
+                    T=512, steps=4),
         multi=dict(B=32, serve_n=4),
     ),
     "rehearsal": dict(
@@ -91,6 +104,9 @@ SIZES = {
             (128, "float32", "f32"),
             (128, "bfloat16", "bf16"))),
         decode=dict(S=2, H=2, D=32, int8_tot=(128,), fp8_tot=128),
+        grouped=dict(M=256, K=128, N=128, sizes=(100, 0, 37, 80)),
+        sparse=dict(units=32, head_dim=8, heads=2, ffn=64, moe_ffn=16,
+                    experts=8, held=(2, 3), top_k=2, vocab=50, T=32, steps=4),
         multi=dict(B=8, serve_n=2),
     ),
 }
@@ -98,6 +114,7 @@ SIZES = {
 FLASH_FWD = "flash_fwd"
 FLASH_BWD = "flash_bwd_fused"
 DEQUANT_DECODE = "decode_attn_quant"
+GROUPED = ("moe_gmm", "moe_tgmm")
 
 
 def say(msg: str) -> None:
@@ -508,6 +525,37 @@ def leg_kernels(sz, on_chip: bool) -> list:
             (q, kd, ks, vd, vs, pc), [np.asarray(ref)], (DEQUANT_DECODE,),
             KERNEL_REL_TOL["float32"]))
 
+    # grouped matmuls (the expert products of a sparse layer): forward, dx
+    # and the per-group dw against a per-group loop at 'highest'
+    from mxtpu.ops import grouped_matmul as gm
+    gr = sz["grouped"]
+    M, K, N, sizes = gr["M"], gr["K"], gr["N"], gr["sizes"]
+    rs = np.random.RandomState(M)
+    x = jnp.asarray(rs.randn(M, K), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(len(sizes), K, N) * 0.05, jnp.bfloat16)
+    dy = jnp.asarray(rs.randn(M, N), jnp.bfloat16)
+    gs = jnp.asarray(sizes, jnp.int32)
+    xf, wf, dyf = (np.asarray(a, np.float32) for a in (x, w, dy))
+    ref = [np.zeros((M, N), np.float32), np.zeros((M, K), np.float32),
+           np.zeros(wf.shape, np.float32)]
+    at = 0
+    for g_, n in enumerate(sizes):
+        ref[0][at:at + n] = xf[at:at + n] @ wf[g_]
+        ref[1][at:at + n] = dyf[at:at + n] @ wf[g_].T
+        ref[2][g_] = xf[at:at + n].T @ dyf[at:at + n]
+        at += n
+
+    def grouped(x, w, dy, gs):
+        return (gm._past_the_groups(
+                    gm._gmm_pallas(x, w, gs, interpret=interpret), gs),
+                gm._past_the_groups(
+                    gm._gmm_pallas(dy, w, gs, True, interpret=interpret), gs),
+                gm._tgmm_pallas(x, dy, gs, interpret=interpret))
+
+    rows.append(_try_kernel(
+        "grouped matmul fwd+dx+dw", f"M{M} K{K} N{N} groups {sizes}",
+        grouped, (x, w, dy, gs), ref, GROUPED, KERNEL_REL_TOL["bfloat16"]))
+
     for r in rows:
         check(r["status"] == "compiled",
               f"kernel refused: {r['kernel']} {r['shape']}: {r.get('message')}")
@@ -520,6 +568,60 @@ def leg_kernels(sz, on_chip: bool) -> list:
             check(not missing, f"{r['kernel']} {r['shape']}: {missing} are "
                                f"not Mosaic calls in the lowered program")
     return rows
+
+
+# -- leg 4b: a tiny sparse decoder's train steps ------------------------------
+
+def leg_sparse_train(sz, on_chip: bool) -> dict:
+    """A few steps of a tiny sparse ``HybridDecoderLM`` (grouped-query window
+    / full attention, post-norm RMSNorm, an untied head, an expert layer that
+    holds a share of its experts, a shared expert, the balancing bias)
+    through ``DataParallelTrainer``: the loss falls, the bias moves, every
+    (token, expert) pair is counted, and on the chip the grouped matmuls and
+    the flash launches are Pallas call sites."""
+    import jax
+    import mxtpu as mx
+    from mxtpu import nd, optimizer, profiler
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    from mxtpu.parallel import DataParallelTrainer
+    from mxtpu.parallel.mesh import data_parallel_mesh
+    sp = sz["sparse"]
+    profiler.reset_kernel_path_counts()
+    mx.random.seed(0)
+    net = HybridDecoderLM(
+        sp["vocab"], ["attn_window", "attn_full", "attn_window"],
+        units=sp["units"], ffn_units=sp["ffn"], num_heads=sp["heads"],
+        num_kv_heads=1, head_dim=sp["head_dim"], window=sp["T"] // 4,
+        attention="gqa", qk_norm=True, rope_kinds=("attn_window",),
+        rope_theta=1e6, norm="rms", norm_position="post", tie_head=False,
+        mlp_kinds=["mlp", "moe", "moe"],
+        moe=dict(ffn_units=sp["moe_ffn"], num_experts=sp["experts"],
+                 top_k=sp["top_k"], held=sp["held"],
+                 shared_ffn_units=sp["moe_ffn"], routed_scale=2.5,
+                 bias_update_rate=0.03))
+    net.initialize()
+    if on_chip:
+        net.cast("bfloat16")
+    dpt = DataParallelTrainer(net, seq_loss,
+                              optimizer.Adam(learning_rate=1e-3),
+                              data_parallel_mesh(1))
+    seq = np.random.RandomState(0).randint(0, sp["vocab"], (1, sp["T"] + 1))
+    x, y = nd.array(seq[:, :-1]), nd.array(seq[:, 1:].astype(np.float32))
+    losses = [float(dpt.step(x, y)) for _ in range(sp["steps"])]
+    check(all(np.isfinite(losses)) and losses[-1] < losses[0],
+          f"sparse train: losses {losses}")
+    stats = profiler.get_moe_stats(net)
+    check(len(stats) == 2 and all(r["pairs"] > 0 and r["passes"] == 1
+                                  for r in stats), f"sparse train: {stats}")
+    bias = net.block1.moe.select_bias.data().asnumpy()
+    check(float(np.abs(bias).max()) > 0, "sparse train: the bias never moved")
+    paths = profiler.get_kernel_path_counts()
+    if on_chip:
+        for kind in ("flash", "flash_window", "grouped_matmul"):
+            check(paths[kind]["pallas"] > 0 and paths[kind]["xla"] == 0,
+                  f"sparse train: {kind} call sites {paths[kind]}")
+    return {"losses": [round(v, 4) for v in losses], "kernel_paths": paths,
+            "pairs": [r["pairs"] for r in stats]}
 
 
 # -- leg 5: four chips -------------------------------------------------------
@@ -662,6 +764,8 @@ def main(argv=None) -> int:
 
         rep, out = leg("kernels", leg_kernels, sz, on_chip)
         rep["rows"] = out
+        rep, out = leg("sparse_train", leg_sparse_train, sz, on_chip)
+        rep.update(out)
 
         if len(devs) >= 4:
             rep, out = leg("multichip", leg_multichip, sz, on_chip)

@@ -157,8 +157,12 @@ class Block:
         return self
 
     def cast(self, dtype):
+        """Every parameter to ``dtype`` but those made with
+        ``keep_float32``. Before ``initialize`` this only sets the type the
+        parameters will be made in."""
         for p in self.collect_params().values():
-            p.cast(dtype)
+            if not p.keep_float32:
+                p.cast(dtype)
         return self
 
     def apply(self, fn):

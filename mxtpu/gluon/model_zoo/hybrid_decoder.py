@@ -1,12 +1,22 @@
-"""Decoder-hybrid-decoder language models (SambaY; Phi-4-mini-flash-reasoning
-is the published instance): Mamba-1 state-space layers, differential
-attention over a window, over everything and across layers, and gated
-memory units, in one stack with no positional encoding of any kind.
+"""Decoder language models built from a list of layer kinds: a mixer kind, an
+MLP kind and a norm for each layer. Two published families are instances:
+decoder-hybrid-decoder models (SambaY; Phi-4-mini-flash-reasoning: Mamba-1
+state-space layers, differential attention over a window, over everything
+and across layers, gated memory units, no positional encoding of any kind)
+and sparse models with grouped-query window / full attention (K-EXAONE:
+``attention="gqa"``, ``norm="rms"``, ``norm_position="post"``, an untied
+head, ``mlp_kinds`` with ``"moe"``).
 
 A model is a list of layer kinds and the widths; nothing here is a preset.
-Every layer is ``h = x + Mixer(LN(x)); out = h + MLP(LN'(h))`` with a SwiGLU
-MLP (``W_down (up * silu(gate))``, no biases), then a LayerNorm and the tied
-head ``logits = h E^T``. The mixers, by kind:
+Every layer is ``h = x + Mixer(N(x)); out = h + MLP(N'(h))``, or with
+``norm_position="post"`` ``h = x + N(Mixer(x)); out = h + N'(MLP(h))``. ``N``
+is a LayerNorm or an RMSNorm; ``MLP`` is a SwiGLU (``W_down (up *
+silu(gate))``, no biases) or, where ``mlp_kinds[i] == "moe"``, the held
+experts of a sparse expert layer with its shared expert
+(``parallel.moe.SparseExperts``: child ``moe``, scopes ``block<i>/moe/route|
+dispatch|experts|combine|shared|balance``). After the last layer the norm
+and the head: the token table again (``logits = h E^T``), or a matrix of its
+own (``tie_head=False``, float32 logits). The mixers, by kind:
 
 ``mamba``
     ``[u, z] = W_in x``; ``u = silu(conv1d_causal(u) + b_c)``; ``[dt_r, B, C]
@@ -26,6 +36,14 @@ head ``logits = h E^T``. The mixers, by kind:
 ``gmu``
     ``W_out (m * silu(W_in x))``: no scan, no convolution.
 
+With ``attention="gqa"`` the kinds ``attn_window`` / ``attn_full`` are
+``GroupedQueryAttention`` under the same child names: a fused ``qkv``
+without bias, with ``qk_norm`` an RMSNorm over ``head_dim`` on every query
+and key head (scope ``qk_norm``), rotary positions (rotate-half, all of
+``head_dim``; scope ``rope``) in the kinds that ``rope_kinds`` names, plain
+softmax through ``flash_chunk`` with query head ``h`` on key/value head ``h
+// (H / Hkv)``, and ``out_proj`` without bias.
+
 Keys/values and the scan's output are made ONCE and read by every later
 layer that wants them: gradients flow back into the one producer from all
 its consumers. That is the training path, and the only one: there is no
@@ -39,15 +57,22 @@ from __future__ import annotations
 import math
 
 import jax
+import jax.numpy as jnp
 
 from ... import initializer
 from ... import ndarray as nd
+from ...ops import registry
+from ...ops.attention import flash_chunk
+from ...ops.nn import rms_norm
+from ...parallel.moe import SparseExperts
 from ..block import HybridBlock
-from ..nn.basic_layers import Dense, Embedding, LayerNorm
+from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
+                                SwiGLU)
 
-__all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS"]
+__all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS", "MLP_KINDS"]
 
 KINDS = ("mamba", "attn_window", "attn_full", "attn_cross", "gmu")
+MLP_KINDS = ("mlp", "moe")
 
 
 class _ALog(initializer.Initializer):
@@ -86,23 +111,6 @@ def _split(x, sizes):
         out.append(nd.slice_axis(x, axis=-1, begin=at, end=at + n))
         at += n
     return out
-
-
-class SwiGLU(HybridBlock):
-    """``W_down (up * silu(gate))`` with ``[gate, up] = W_gu x``, no biases."""
-
-    def __init__(self, units: int, ffn_units: int, prefix=None, params=None):
-        super().__init__(prefix=prefix, params=params)
-        self._ffn = ffn_units
-        with self.name_scope():
-            self.gate_up = Dense(2 * ffn_units, use_bias=False, flatten=False,
-                                 in_units=units)
-            self.down = Dense(units, use_bias=False, flatten=False,
-                              in_units=ffn_units)
-
-    def forward(self, x):
-        gate, up = _split(self.gate_up(x), (self._ffn, self._ffn))
-        return self.down(up * _silu(gate))
 
 
 class Mamba(HybridBlock):
@@ -166,9 +174,14 @@ class DiffAttention(HybridBlock):
                              in_units=units)
             self.out_proj = Dense(units, flatten=False,
                                   in_units=num_heads * head_dim)
+            # float32 whatever the model is cast to, as the published
+            # implementation keeps them: at their size (N(0, 0.1)) an Adam
+            # step of 3e-4 is under one bfloat16 step, so in bfloat16
+            # rounding alone would decide whether they ever move
             for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
                 setattr(self, name, self.params.get(
-                    name, shape=(head_dim,), init=initializer.Normal(0.1)))
+                    name, shape=(head_dim,), init=initializer.Normal(0.1),
+                    keep_float32=True))
             self.subln = self.params.get(
                 "subln", shape=(2 * head_dim,), init="ones")
 
@@ -192,6 +205,94 @@ class DiffAttention(HybridBlock):
         return self.out_proj(out), (k, v)
 
 
+@registry.register("as_float32", namespace="contrib")
+def as_float32(x):
+    """``x`` widened to float32, differentiably (``nd.cast`` has the
+    reference's semantics: no gradient on the imperative tape)."""
+    return x.astype(jnp.float32)
+
+
+_AS_FLOAT32 = registry.get_op("contrib.as_float32")
+
+
+def _rope(x, theta: float):
+    """Rotary positions 0..T-1 on ``x`` ``(B, T, heads, D)``, rotate-half
+    pairing (dimension ``i`` with ``i + D / 2``), angles in float32."""
+    T, half = x.shape[1], x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+@registry.register("gq_attention", namespace="contrib")
+def gq_attention(q, k, v, q_gain=None, k_gain=None, rope_theta: float = 0.0,
+                 window=None, eps: float = 1e-5):
+    """Causal grouped-query softmax attention. ``q``: ``(B, T, H, D)``;
+    ``k``, ``v``: ``(B, T, Hkv, D)``; query head ``h`` reads key/value head
+    ``h // (H / Hkv)``. ``q_gain`` / ``k_gain`` ``(D,)``: an RMSNorm over
+    ``D`` on every query and key head, before the positions. ``rope_theta``
+    > 0: rotary positions (rotate-half, all ``D`` dimensions) on q and k; 0:
+    none. Scores ``q k^T / sqrt(D)``, ``window`` as in ``flash_attention``.
+    Returns ``(B, T, H * D)``."""
+    B, T, H, D = q.shape
+    if q_gain is not None:
+        with jax.named_scope("qk_norm"):
+            q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
+    if rope_theta:
+        with jax.named_scope("rope"):
+            q, k = _rope(q, rope_theta), _rope(k, rope_theta)
+    out, _ = flash_chunk(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                         v.transpose(0, 2, 1, 3), True, 1.0 / math.sqrt(D),
+                         window)
+    return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+
+_GQ_ATTENTION = registry.get_op("contrib.gq_attention")
+
+
+class GroupedQueryAttention(HybridBlock):
+    """Grouped-query softmax attention over a window or over everything,
+    with or without rotary positions and a per-head RMSNorm of q and k;
+    ``forward`` returns ``(output, (k, v))`` as ``DiffAttention`` does."""
+
+    def __init__(self, units: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, window=None, rope_theta: float = 0.0,
+                 qk_norm: bool = False, norm_eps: float = 1e-5, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        if num_heads % num_kv_heads or head_dim % 2:
+            raise ValueError(
+                f"grouped-query attention: {num_heads} query heads on "
+                f"{num_kv_heads} key/value heads of {head_dim}")
+        self._heads, self._kv_heads, self._dim = num_heads, num_kv_heads, \
+            head_dim
+        self._attrs = dict(rope_theta=float(rope_theta), window=window,
+                           eps=norm_eps)
+        with self.name_scope():
+            self.qkv = Dense((num_heads + 2 * num_kv_heads) * head_dim,
+                             use_bias=False, flatten=False, in_units=units)
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  in_units=num_heads * head_dim)
+            self.q_norm = self.params.get(
+                "q_norm", shape=(head_dim,), init="ones") if qk_norm else None
+            self.k_norm = self.params.get(
+                "k_norm", shape=(head_dim,), init="ones") if qk_norm else None
+
+    def forward(self, x):
+        B, T, _ = x.shape
+        H, Hkv, D = self._heads, self._kv_heads, self._dim
+        q, k, v = _split(self.qkv(x), (H * D, Hkv * D, Hkv * D))
+        k, v = k.reshape((B, T, Hkv, D)), v.reshape((B, T, Hkv, D))
+        args = (q.reshape((B, T, H, D)), k, v)
+        if self.q_norm is not None:
+            args += (self.q_norm.data(), self.k_norm.data())
+        out = registry.invoke(_GQ_ATTENTION, *args, **self._attrs)
+        return self.out_proj(out), (k, v)
+
+
 class GatedMemoryUnit(HybridBlock):
     """``W_out (m * silu(W_in x))``: the memory ``m`` gated by this layer."""
 
@@ -210,37 +311,57 @@ class GatedMemoryUnit(HybridBlock):
 
 
 class HybridDecoderBlock(HybridBlock):
-    """One layer of ``kind``; its mixer is the child named by the kind, so a
-    device trace reads ``block3/attn_window/...``. ``shared`` is the stack's
-    hand-over: ``{"memory": y of the newest mamba, "kv": (k, v) of the
-    newest attn_full}``."""
+    """One layer of ``kind``; its mixer is the child named by the kind and
+    its MLP the child named by ``mlp_kind`` (``mlp`` or ``moe``), so a
+    device trace reads ``block3/attn_window/...``, ``block3/moe/experts``.
+    ``z`` holds the model's widths and options (``HybridDecoderLM`` builds
+    it). ``shared`` is the stack's hand-over: ``{"memory": y of the newest
+    mamba, "kv": (k, v) of the newest attn_full}``."""
 
-    def __init__(self, kind: str, layer_index: int, units: int,
-                 ffn_units: int, num_heads: int, num_kv_heads: int,
-                 head_dim: int, window: int, d_inner: int, d_state: int,
-                 d_conv: int, dt_rank: int, eps: float, prefix=None,
-                 params=None):
+    def __init__(self, kind: str, layer_index: int, mlp_kind: str, z: dict,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if kind not in KINDS:
             raise ValueError(f"unknown layer kind {kind!r}; one of {KINDS}")
-        self.kind = kind
+        if mlp_kind not in MLP_KINDS:
+            raise ValueError(f"unknown MLP kind {mlp_kind!r}; one of "
+                             f"{MLP_KINDS}")
+        self.kind, self.mlp_kind = kind, mlp_kind
+        self._post = z["norm_position"] == "post"
+        units, eps = z["units"], z["eps"]
+        norm = RMSNorm if z["norm"] == "rms" else LayerNorm
         with self.name_scope():
-            self.ln1 = LayerNorm(epsilon=eps, in_channels=units)
+            self.ln1 = norm(epsilon=eps, in_channels=units)
             if kind == "mamba":
-                mixer = Mamba(units, d_inner, d_state, d_conv, dt_rank)
+                mixer = Mamba(units, z["d_inner"], z["d_state"], z["d_conv"],
+                              z["dt_rank"])
             elif kind == "gmu":
-                mixer = GatedMemoryUnit(units, d_inner)
+                mixer = GatedMemoryUnit(units, z["d_inner"])
+            elif z["attention"] == "gqa":
+                if kind == "attn_cross":
+                    raise ValueError("grouped-query attention has no "
+                                     "attn_cross")
+                mixer = GroupedQueryAttention(
+                    units, z["num_heads"], z["num_kv_heads"], z["head_dim"],
+                    window=z["window"] if kind == "attn_window" else None,
+                    rope_theta=z["rope_theta"] if kind in z["rope_kinds"]
+                    else 0.0, qk_norm=z["qk_norm"], norm_eps=eps)
             else:
                 mixer = DiffAttention(
-                    units, num_heads, num_kv_heads, head_dim, layer_index,
-                    window=window if kind == "attn_window" else None,
+                    units, z["num_heads"], z["num_kv_heads"], z["head_dim"],
+                    layer_index,
+                    window=z["window"] if kind == "attn_window" else None,
                     cross=kind == "attn_cross", norm_eps=eps)
             setattr(self, kind, mixer)
-            self.ln2 = LayerNorm(epsilon=eps, in_channels=units)
-            self.mlp = SwiGLU(units, ffn_units)
+            self.ln2 = norm(epsilon=eps, in_channels=units)
+            if mlp_kind == "moe":
+                self.moe = SparseExperts(units, **z["moe"])
+            else:
+                self.mlp = SwiGLU(units, z["ffn_units"])
 
     def forward(self, x, shared):
-        mixer, h = getattr(self, self.kind), self.ln1(x)
+        mixer = getattr(self, self.kind)
+        h = x if self._post else self.ln1(x)
         if self.kind == "mamba":
             mixed, shared["memory"] = mixer(h)
         elif self.kind == "gmu":
@@ -251,8 +372,12 @@ class HybridDecoderBlock(HybridBlock):
             mixed, kv = mixer(h)
             if self.kind == "attn_full":
                 shared["kv"] = kv
+        mlp = getattr(self, self.mlp_kind)
+        if self._post:
+            h = x + self.ln1(mixed)
+            return h + self.ln2(mlp(h))
         h = x + mixed
-        return h + self.mlp(self.ln2(h))
+        return h + mlp(self.ln2(h))
 
 
 class HybridDecoderLM(HybridBlock):
@@ -261,35 +386,74 @@ class HybridDecoderLM(HybridBlock):
     Input ``(B, T)`` int tokens, output ``(B, T, vocab)`` logits; no position
     table, so ``T`` is bounded by memory alone. Trains through
     ``DataParallelTrainer`` like ``TransformerLM``. Multiples of 128 in ``T``
-    engage the flash kernels and ``d_inner % 128 == 0`` the scan kernels on
-    the TPU (``profiler.get_kernel_path_counts()`` says which ran).
+    engage the flash kernels, ``d_inner % 128 == 0`` the scan kernels and
+    widths in whole 128s the grouped-matmul kernels on the TPU
+    (``profiler.get_kernel_path_counts()`` says which ran).
 
     ``d_inner`` is the state-space layers' width (Mamba's ``expand *
     units``), shared by ``mamba`` and ``gmu`` since the one gates the
     other's output; ``dt_rank`` defaults to ``ceil(units / 16)``.
+
+    The layer spec beside ``layer_kinds``. ``attention="gqa"`` makes
+    ``attn_window`` / ``attn_full`` plain grouped-query softmax attention
+    (``GroupedQueryAttention``): ``qk_norm`` an RMSNorm over ``head_dim`` on
+    q and k, ``rope_kinds`` the kinds whose q and k get rotary positions
+    (base ``rope_theta``; the others get none). ``norm="rms"`` takes RMSNorm
+    for LayerNorm; ``norm_position="post"`` puts each norm on its
+    sub-layer's OUTPUT (``h = x + N(Mixer(x))``). ``tie_head=False`` gives
+    the head a matrix of its own (child ``head``) and float32 logits.
+    ``mlp_kinds`` names each layer's MLP, ``"mlp"`` (SwiGLU of
+    ``ffn_units``) or ``"moe"`` (``parallel.moe.SparseExperts`` built from
+    ``moe``, its keyword arguments after ``units``: ``ffn_units``,
+    ``num_experts``, ``top_k``, ``held``, ``shared_ffn_units``,
+    ``routed_scale``, ``bias_update_rate``).
     """
 
     def __init__(self, vocab_size: int, layer_kinds, units: int,
                  ffn_units: int, num_heads: int, num_kv_heads: int,
                  head_dim: int = 0, window: int = 512, d_inner: int = 0,
                  d_state: int = 16, d_conv: int = 4, dt_rank: int = 0,
-                 layer_norm_eps: float = 1e-5, prefix=None, params=None):
+                 layer_norm_eps: float = 1e-5, attention: str = "diff",
+                 qk_norm: bool = False, rope_kinds=(), rope_theta: float = 1e4,
+                 norm: str = "layer", norm_position: str = "pre",
+                 tie_head: bool = True, mlp_kinds=None, moe=None,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        for what, value, known in (
+                ("attention", attention, ("diff", "gqa")),
+                ("norm", norm, ("layer", "rms")),
+                ("norm_position", norm_position, ("pre", "post"))):
+            if value not in known:
+                raise ValueError(f"{what} {value!r}; one of {known}")
         self._vocab, self._units = vocab_size, units
         self.layer_kinds = tuple(layer_kinds)
+        self.mlp_kinds = tuple(mlp_kinds or ("mlp",) * len(self.layer_kinds))
+        if len(self.mlp_kinds) != len(self.layer_kinds):
+            raise ValueError(f"{len(self.layer_kinds)} layers, "
+                             f"{len(self.mlp_kinds)} MLP kinds")
+        if "moe" in self.mlp_kinds and not moe:
+            raise ValueError("mlp_kinds names an expert layer: give moe=")
+        z = dict(units=units, ffn_units=ffn_units, num_heads=num_heads,
+                 num_kv_heads=num_kv_heads,
+                 head_dim=head_dim or units // num_heads, window=window,
+                 d_inner=d_inner or 2 * units, d_state=d_state,
+                 d_conv=d_conv, dt_rank=dt_rank or -(-units // 16),
+                 eps=layer_norm_eps, attention=attention, qk_norm=qk_norm,
+                 rope_kinds=tuple(rope_kinds), rope_theta=rope_theta,
+                 norm=norm, norm_position=norm_position, moe=moe)
         with self.name_scope():
             self.embedding = Embedding(vocab_size, units,
                                        weight_initializer="normal")
             self.blocks = []
-            for i, kind in enumerate(self.layer_kinds):
-                blk = HybridDecoderBlock(
-                    kind, i, units, ffn_units, num_heads, num_kv_heads,
-                    head_dim or units // num_heads, window,
-                    d_inner or 2 * units, d_state, d_conv,
-                    dt_rank or -(-units // 16), layer_norm_eps)
+            for i, (kind, mlp_kind) in enumerate(zip(self.layer_kinds,
+                                                     self.mlp_kinds)):
+                blk = HybridDecoderBlock(kind, i, mlp_kind, z)
                 setattr(self, f"block{i}", blk)   # registers child + params
                 self.blocks.append(blk)
-            self.ln_f = LayerNorm(epsilon=layer_norm_eps, in_channels=units)
+            self.ln_f = (RMSNorm if norm == "rms" else LayerNorm)(
+                epsilon=layer_norm_eps, in_channels=units)
+            self.head = None if tie_head else Dense(
+                vocab_size, use_bias=False, flatten=False, in_units=units)
 
     def forward(self, tokens):
         B, T = tokens.shape
@@ -298,21 +462,16 @@ class HybridDecoderLM(HybridBlock):
         for blk in self.blocks:
             h = blk(h, shared)
         h = self.ln_f(h)
+        if self.head is not None:
+            # float32 logits: in a TPU step with bfloat16 logits XLA adds
+            # log_softmax's exponentials (a row of the vocabulary) in a
+            # bfloat16 reduce, and the loss reads low
+            return registry.invoke(_AS_FLOAT32, self.head(h))
         with jax.named_scope("head"):
             w = self.embedding.weight.data()
             flat = nd.reshape(h, (B * T, self._units))
             return nd.reshape(nd.dot(flat, w, transpose_b=True),
                               (B, T, self._vocab))
-
-    def cast(self, dtype):
-        """Every parameter to ``dtype`` but the lambda vectors, which stay
-        float32 as the published implementation keeps them: at their size
-        (N(0, 0.1)) an Adam step of 3e-4 is under one bfloat16 step, so in
-        bfloat16 rounding alone would decide whether they ever move."""
-        for name, p in self.collect_params().items():
-            if "lambda_" not in name:
-                p.cast(dtype)
-        return self
 
     def _no_decode(self, what: str):
         raise NotImplementedError(

@@ -2,8 +2,8 @@
 
 from .basic_layers import (Activation, BatchNorm, Dense, Dropout, ELU, Embedding,
                            Flatten, GELU, HybridLambda, HybridSequential,
-                           InstanceNorm, Lambda, LayerNorm, LeakyReLU, PReLU, SELU,
-                           Sequential, Swish)
+                           InstanceNorm, Lambda, LayerNorm, LeakyReLU, PReLU,
+                           RMSNorm, SELU, Sequential, SwiGLU, Swish)
 from .conv_layers import (AvgPool1D, AvgPool2D, AvgPool3D, Conv1D, Conv1DTranspose,
                           Conv2D, Conv2DTranspose, Conv3D, Conv3DTranspose,
                           GlobalAvgPool1D, GlobalAvgPool2D, GlobalAvgPool3D,

@@ -315,6 +315,41 @@ class LayerNorm(HybridBlock):
                             eps=self._eps)
 
 
+class RMSNorm(HybridBlock):
+    """``x / sqrt(mean(x^2) + eps) * gamma``: no mean taken off, no bias."""
+
+    def __init__(self, epsilon: float = 1e-5, in_channels: int = 0,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._eps = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init="ones")
+
+    def forward(self, x):
+        return nd.contrib.rms_norm(x, self.gamma.data(), eps=self._eps)
+
+
+class SwiGLU(HybridBlock):
+    """``W_down (up * silu(gate))`` with ``[gate, up] = W_gu x``, no biases."""
+
+    def __init__(self, units: int, ffn_units: int, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._ffn = ffn_units
+        with self.name_scope():
+            self.gate_up = Dense(2 * ffn_units, use_bias=False, flatten=False,
+                                 in_units=units)
+            self.down = Dense(units, use_bias=False, flatten=False,
+                              in_units=ffn_units)
+
+    def forward(self, x):
+        gate_up = self.gate_up(x)
+        gate = nd.slice_axis(gate_up, axis=-1, begin=0, end=self._ffn)
+        up = nd.slice_axis(gate_up, axis=-1, begin=self._ffn,
+                           end=2 * self._ffn)
+        return self.down(up * nd.Activation(gate, act_type="silu"))
+
+
 class InstanceNorm(HybridBlock):
     def __init__(self, axis: int = 1, epsilon: float = 1e-5, center: bool = True,
                  scale: bool = False, beta_initializer="zeros",

@@ -38,7 +38,8 @@ class Parameter:
     def __init__(self, name: str, grad_req: str = "write", shape=None, dtype="float32",
                  lr_mult: float = 1.0, wd_mult: float = 1.0, init=None,
                  allow_deferred_init: bool = False, differentiable: bool = True,
-                 stype: str = "default", grad_stype: str = "default"):
+                 stype: str = "default", grad_stype: str = "default",
+                 keep_float32: bool = False):
         self.name = name
         self.shape = tuple(shape) if shape is not None else None
         self.dtype = dtype
@@ -51,6 +52,9 @@ class Parameter:
         self._data: Optional[NDArray] = None
         self._deferred_init: Optional[tuple] = None  # (init, ctx)
         self.sharding = None  # optional pjit PartitionSpec (TPU-first extension)
+        # ``Block.cast`` leaves it as it is: a state, or a vector so small
+        # that a narrower type's rounding would decide whether it ever moves
+        self.keep_float32 = keep_float32
 
     # -- init --------------------------------------------------------------
     def _shape_complete(self) -> bool:

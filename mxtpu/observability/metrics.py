@@ -729,10 +729,11 @@ def reset_sanitizer_stats():
 
 
 # ---------------------------------------------------------------------------
-# kernel paths (ops/attention.py, ops/ssm.py): Pallas or XLA, per call site
+# kernel paths (ops/attention.py, ops/ssm.py, ops/grouped_matmul.py): Pallas
+# or XLA, per call site
 # ---------------------------------------------------------------------------
 
-KERNEL_KINDS = ("flash", "flash_window", "ssm_scan")
+KERNEL_KINDS = ("flash", "flash_window", "ssm_scan", "grouped_matmul")
 _kernel_paths = {kind: {"pallas": 0, "xla": 0} for kind in KERNEL_KINDS}
 
 
@@ -745,9 +746,9 @@ def record_kernel_path(kind: str, pallas: bool):
 
 
 def get_kernel_path_counts() -> dict:
-    """``{"flash" | "flash_window" | "ssm_scan": {"pallas": n, "xla": n}}``
-    since the last reset: how many call sites took the Pallas kernels and
-    how many the XLA formulation (another backend than the TPU, or a shape
+    """``{"flash" | "flash_window" | "ssm_scan" | "grouped_matmul":
+    {"pallas": n, "xla": n}}`` since the last reset: how many call sites
+    took the Pallas kernels and how many the XLA formulation (another backend than the TPU, or a shape
     the kernels do not take). A TPU step that should run kernels reads
     ``xla == 0``."""
     with _stats_lock:

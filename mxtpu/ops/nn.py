@@ -235,6 +235,16 @@ def _layer_norm(data, gamma, beta, axis: int = -1, eps: float = 1e-5):
     return out * gamma.reshape(shape) + beta.reshape(shape)
 
 
+@register("rms_norm", namespace="contrib")
+def rms_norm(x, gamma, eps: float = 1e-5):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, worked out
+    in float32 and returned in ``x``'s type."""
+    wide = x.astype(jnp.float32)
+    wide = wide * lax.rsqrt(jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+                            + eps)
+    return (wide * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
 @register("InstanceNorm", aliases=("instance_norm",))
 def _instance_norm(data, gamma, beta, eps: float = 1e-3):
     """src/operator/instance_norm-inl.h — per-(sample,channel) normalization (NC+)."""

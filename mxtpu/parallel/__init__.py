@@ -5,6 +5,12 @@ Comm/NCCL/ps-lite â†’ XLA collectives over ICI/DCN; DataParallelExecutorGroup â†
 SPMD steps; ``ctx_group`` model parallelism â†’ pjit shardings. Long-context sequence
 parallelism lives in ``ring_attention`` (K/V rotation, O(T/n) memory) and
 ``ulysses`` (all-to-all head/sequence reshuffle, 2 collectives).
+
+``moe`` holds two mixture-of-experts layers for two jobs: ``SparseExperts``,
+the dropless top-k layer a model trains with, as one chip of an
+expert-parallel deployment holds it (told which experts it holds, no
+exchange); and ``expert_parallel_ffn``, the older top-1, capacity-drop hook
+that shows the all-to-all exchange over an ``ep`` axis and nothing else.
 """
 
 from . import collectives
@@ -32,7 +38,7 @@ from .ulysses import ulysses_attention_inner, ulysses_self_attention
 from . import pipeline
 from .pipeline import gpipe
 from . import moe
-from .moe import expert_parallel_ffn
+from .moe import SparseExperts, expert_parallel_ffn
 from . import flagship
 from .flagship import (flagship_mesh, flagship_param_shardings,
                        flagship_pp_forward, train_flagship)

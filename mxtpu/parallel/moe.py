@@ -1,27 +1,299 @@
-"""Expert parallelism over an ``ep`` mesh axis — the EP hook (task mandate:
-real tp/pp/dp/sp/ep shardings; the reference predates MoE entirely).
+"""Mixtures of experts. Two layers live here, for two different jobs.
 
-Top-1-routed mixture-of-experts FFN in the canonical TPU formulation: tokens
-are ep-sharded, each device owns exactly one expert's weights, and dispatch/
-return ride ``lax.all_to_all`` over ICI — the same program structure as
+``SparseExperts`` is the expert layer of today's sparse models as ONE CHIP of
+an expert-parallel deployment holds it, and the one to train with: ``top_k``
+of ``num_experts`` SwiGLU experts a token by sigmoid scores, no token dropped
+whatever the routing, the chip told which experts it holds (``held``, a list
+of expert ids), an optional shared expert beside them and a selection bias
+that balances the load without an auxiliary loss. It routes over ALL the
+experts, renormalises over all the chosen ones, and computes the terms of
+the experts it holds with grouped matmuls (``ops/grouped_matmul.py``); what
+the absent experts would add is left out and the partial sum goes on. There
+is no exchange here and nothing stands in for one: the layer whose tokens
+travel over ``ep`` (ROADMAP M4's other half) is to be built on this one.
+
+``expert_parallel_ffn`` is the older EP hook (task mandate: real
+tp/pp/dp/sp/ep shardings; the reference predates MoE entirely): a FUNCTION,
+not a layer, that shows the exchange and nothing else. Top-1 routing by
+softmax, one ReLU expert a rank of the ``ep`` axis, tokens ep-sharded,
+dispatch and return over ``lax.all_to_all``: the program structure of
 GShard/Switch. Capacity-bounded: each expert accepts at most ``capacity``
 tokens per source device; overflow tokens pass through with a zero expert
-contribution (standard capacity-drop semantics).
+contribution (standard capacity-drop semantics). It cannot express k > 1,
+several experts a chip, a shared expert or a dropless routing; no model of
+the zoo uses it.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from .. import autograd
+from ..gluon.block import HybridBlock
+from ..gluon.nn.basic_layers import SwiGLU
+from ..ops import registry
+from ..ops.grouped_matmul import grouped_matmul
 from .collectives import all_to_all_array, shard_map_compat
 from .mesh import Mesh, get_default_mesh
 
-__all__ = ["expert_parallel_ffn"]
+__all__ = ["SparseExperts", "expert_parallel_ffn", "expert_rows"]
+
+
+# ---------------------------------------------------------------------------
+# the expert layer one chip holds
+# ---------------------------------------------------------------------------
+
+
+def expert_rows(tokens: int, num_experts: int, top_k: int, held: int) -> int:
+    """Rows of the static buffer a pass of ``SparseExperts`` works on: four
+    times the even share of (token, expert) pairs (``tokens * top_k * held /
+    num_experts``), in whole 512s, never more than the worst case (every
+    token picks ``min(top_k, held)`` held experts). The grouped kernels skip
+    the row tiles that hold no pair, so their time follows the pairs; the
+    gather, the SwiGLU and the scatter-add run over the buffer whatever it
+    holds. A step whose routing sends more pairs here than the buffer has
+    rows runs a further pass, at a whole pass's price: four times the even
+    share keeps that edge far from any load a balanced router visits."""
+    worst = tokens * min(top_k, held)
+    even = -(-tokens * top_k * held // num_experts)
+    return min(worst, -(-4 * even // 512) * 512)
+
+
+def _route(x, router_w, bias, top_k: int, scale: float):
+    """``(chosen experts (T, k), their weights (T, k) float32)``: sigmoid
+    scores in float32, the ``top_k`` largest of score + ``bias`` (which only
+    selects and has no gradient), weights ``scale`` times the chosen scores
+    over their sum."""
+    z = lax.dot_general(x, router_w, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(z)
+    _, chosen = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
+                          top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=1)
+    return chosen, scale * picked / jnp.sum(picked, axis=1, keepdims=True)
+
+
+def _one_pass(x, weights, w_gate_up, w_down, order, starts, ends, p,
+              rows: int, top_k: int):
+    """Rows ``p * rows ..`` of the sorted (token, expert) pairs: gather
+    their tokens, both grouped products, and the weighted rows added onto
+    their tokens. Returns ``(T, d)`` float32."""
+    lo = p * rows
+    pairs = lax.dynamic_slice(order, (lo,), (rows,))
+    token = pairs // top_k
+    sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+    with jax.named_scope("dispatch"):
+        xs = x[token]
+    with jax.named_scope("experts"):
+        ffn = w_down.shape[1]
+        gate_up = grouped_matmul(xs, w_gate_up, sizes)
+        act = gate_up[:, ffn:] * jax.nn.silu(gate_up[:, :ffn])
+        out = grouped_matmul(act, w_down, sizes)
+    with jax.named_scope("combine"):
+        live = lo + jnp.arange(rows) < ends[-1]
+        w_row = jnp.where(live, weights.reshape(-1)[pairs], 0.0)
+        return jnp.zeros(x.shape, jnp.float32).at[token].add(
+            out.astype(jnp.float32) * w_row[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_experts(x, weights, w_gate_up, w_down, order, starts, ends,
+                  rows: int, top_k: int):
+    """Every pass the step's pairs need, added up: one, unless the routing
+    sends more than ``rows`` pairs to the held experts. The loop's length is
+    a traced number, so nothing is dropped and nothing is paid for rows that
+    are not there. The backward runs the same passes again, each from its
+    inputs, so no row buffer outlives its pass: the layer keeps its input
+    and the routing between forward and backward and nothing as wide as
+    (pairs, ffn)."""
+    def one(p):
+        return _one_pass(x, weights, w_gate_up, w_down, order, starts, ends,
+                         p, rows, top_k)
+    return lax.fori_loop(1, -(-ends[-1] // rows),
+                         lambda p, y: y + one(p), one(0))
+
+
+def _held_experts_fwd(x, weights, w_gate_up, w_down, order, starts, ends,
+                      rows, top_k):
+    y = _held_experts(x, weights, w_gate_up, w_down, order, starts, ends,
+                      rows, top_k)
+    return y, (x, weights, w_gate_up, w_down, order, starts, ends)
+
+
+def _held_experts_bwd(rows, top_k, res, dy):
+    x, weights, w_gate_up, w_down, order, starts, ends = res
+
+    def one(p):
+        _, vjp = jax.vjp(
+            lambda *a: _one_pass(*a, order, starts, ends, p, rows, top_k),
+            x, weights, w_gate_up, w_down)
+        return vjp(dy)
+
+    grads = lax.fori_loop(
+        1, -(-ends[-1] // rows),
+        lambda p, g: jax.tree.map(jnp.add, g, one(p)), one(0))
+    return grads + (None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+@registry.register("sparse_experts", namespace="contrib", num_outputs=2)
+def sparse_experts(h, router_w, bias, w_gate_up, w_down, held=(),
+                   top_k: int = 1, scale: float = 1.0, rows: int = 0):
+    """The held experts' part of a sparse expert layer. ``h``: ``(..., d)``;
+    ``router_w``: ``(E, d)``; ``bias``: ``(E,)``, added to the scores for the
+    choice alone; ``w_gate_up``: ``(len(held), d, 2 f)``, ``w_down``:
+    ``(len(held), f, d)``, the experts ``held`` (ids among the ``E``) in
+    that order. Returns ``(y like h, count (E,) float32)``: ``y[t] = sum
+    over the chosen e that are held of w_e(t) down_e(up_e h * silu(gate_e
+    h))`` with ``w_e = scale * s_e / sum of the chosen s``, and the tokens
+    that chose each of the ``E`` experts, held or not (``count[held]`` are
+    the rows each held expert got). ``rows``: the static row buffer of a
+    pass (0: ``expert_rows``)."""
+    d, n_held = h.shape[-1], len(held)
+    x = h.reshape(-1, d)
+    T, E = x.shape[0], router_w.shape[0]
+    with jax.named_scope("route"):
+        chosen, weights = _route(x, router_w, bias, top_k, scale)
+        count = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(E), axis=0,
+                        dtype=jnp.int32)
+    with jax.named_scope("dispatch"):
+        # pairs sorted by held expert's slot, pairs of absent experts last
+        slot = np.full((E,), n_held, np.int32)
+        slot[list(held)] = np.arange(n_held)
+        key = jnp.asarray(slot)[chosen.reshape(-1)]
+        rows = rows or expert_rows(T, E, top_k, n_held)
+        worst = -(-T * min(top_k, n_held) // rows) * rows
+        order = jnp.argsort(key, stable=True)[:worst].astype(jnp.int32)
+        order = jnp.pad(order, (0, worst - order.shape[0]))
+        load = count[np.asarray(held, np.int32)]
+        ends = jnp.cumsum(load)
+    y = _held_experts(x, weights, w_gate_up, w_down, order, ends - load,
+                      ends, rows, top_k)
+    return (y.astype(h.dtype).reshape(h.shape),
+            lax.stop_gradient(count.astype(jnp.float32)))
+
+
+_SPARSE_EXPERTS = registry.get_op("contrib.sparse_experts")
+
+
+class SparseExperts(HybridBlock):
+    """``top_k`` of ``num_experts`` SwiGLU experts a token, of which this
+    chip holds ``held`` (a list of distinct expert ids; default all).
+    Sigmoid scores in float32, the choice by score + ``select_bias``,
+    weights ``routed_scale`` times the chosen scores over their sum, no
+    token dropped for any routing. ``shared_ffn_units`` > 0 adds a shared
+    expert (child ``shared``, a SwiGLU of that width every token goes
+    through, added unweighted): every chip of a deployment computes it
+    alike, so the shares' sum counts it once.
+
+    ``bias_update_rate`` > 0 balances the load without an auxiliary loss
+    (DeepSeek-V3's rule): every TRAINING forward ends with ``select_bias +=
+    rate * sign(tokens * top_k / num_experts - count)``, the tokens that
+    chose each of the ``num_experts`` experts against their even share, so
+    an expert with more than its share is chosen less in the next step; the
+    step itself, backward included, routes by the bias it began with. The
+    routing is over all the experts, so a chip that holds a share of them
+    counts the same numbers as every other and keeps the same bias with no
+    exchange. 0 (the default) leaves the bias as it was set.
+
+    Parameters: ``router`` ``(num_experts, units)``, ``gate_up``
+    ``(len(held), units, 2 ffn)``, ``down`` ``(len(held), ffn, units)``, the
+    shared expert's two matrices; auxiliary states (``grad_req="null"``,
+    float32 whatever the model is cast to, carried through a compiled step
+    as running statistics are), both ``(num_experts,)``: ``select_bias``
+    and ``count``, the tokens that chose each expert in the newest forward
+    (``stats()`` reads it).
+
+    One pass works on a static buffer of ``expert_rows`` (token, expert)
+    pairs, four times the even share; a step whose routing sends more pairs
+    here than that runs more passes, so memory is the buffer's and time the
+    pairs'. Scopes in a device trace: ``route``, ``dispatch``, ``experts``,
+    ``combine``, ``shared``, ``balance``."""
+
+    def __init__(self, units: int, ffn_units: int, num_experts: int,
+                 top_k: int, held=None, shared_ffn_units: int = 0,
+                 routed_scale: float = 1.0, bias_update_rate: float = 0.0,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        if bias_update_rate < 0:
+            raise ValueError(f"bias_update_rate {bias_update_rate} < 0")
+        held = tuple(range(num_experts) if held is None else held)
+        if not held or len(set(held)) != len(held) \
+                or min(held) < 0 or max(held) >= num_experts:
+            raise ValueError(f"held experts must be distinct ids inside "
+                             f"0..{num_experts - 1}: {list(held)}")
+        if not 0 < top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        self.held = held
+        self._top_k, self._experts = top_k, num_experts
+        self._scale, self._bias_rate = float(routed_scale), \
+            float(bias_update_rate)
+        self._rows = None       # the buffer's rows, once a forward has run
+        with self.name_scope():
+            self.router = self.params.get(
+                "router", shape=(num_experts, units), init="normal")
+            self.select_bias = self.params.get(
+                "select_bias", shape=(num_experts,), init="zeros",
+                grad_req="null", keep_float32=True)
+            self.count = self.params.get(
+                "count", shape=(num_experts,), init="zeros",
+                grad_req="null", keep_float32=True)
+            self.gate_up = self.params.get(
+                "gate_up", shape=(len(held), units, 2 * ffn_units),
+                init="normal")
+            self.down = self.params.get(
+                "down", shape=(len(held), ffn_units, units), init="normal")
+            self.shared = SwiGLU(units, shared_ffn_units) \
+                if shared_ffn_units else None
+
+    def forward(self, x):
+        tokens = math.prod(x.shape[:-1])
+        self._rows = expert_rows(tokens, self._experts, self._top_k,
+                                 len(self.held))
+        bias = self.select_bias.data()
+        y, count = registry.invoke(
+            _SPARSE_EXPERTS, x, self.router.data(), bias,
+            self.gate_up.data(), self.down.data(), held=self.held,
+            top_k=self._top_k, scale=self._scale, rows=self._rows)
+        with jax.named_scope("balance"):
+            if self._bias_rate and autograd.is_training():
+                even = tokens * self._top_k / self._experts
+                bias._set_data(bias.data + self._bias_rate
+                               * jnp.sign(even - count.data))
+            self.count.data()._set_data(count.data)
+        return y if self.shared is None else y + self.shared(x)
+
+    def stats(self) -> dict:
+        """Of the newest forward (zeros before the first): the (token,
+        expert) ``pairs`` the held experts got, how many of the ``held``
+        were ``active`` (got a row), the ``max_count`` and ``min_count`` of
+        tokens that chose any one of ALL the experts, the ``buffer_rows`` of
+        a pass and the ``passes`` the pairs took (None before the first
+        forward). Reads ``count`` from the device: ask between steps, not
+        inside a timed loop."""
+        count = self.count.data().asnumpy()
+        load = count[list(self.held)]
+        pairs = float(load.sum())
+        return {"name": self.name, "held": len(self.held), "pairs": pairs,
+                "active": int((load > 0).sum()),
+                "max_count": float(count.max()),
+                "min_count": float(count.min()), "buffer_rows": self._rows,
+                "passes": self._rows and max(1, -(-int(pairs) // self._rows))}
+
+
+# ---------------------------------------------------------------------------
+# the older hook: top-1, capacity drop, one expert a rank, all-to-all
+# ---------------------------------------------------------------------------
 
 
 def expert_parallel_ffn(router_w, w1, w2, x, mesh: Optional[Mesh] = None,

@@ -253,6 +253,23 @@ def dumps(reset: bool = False) -> str:
 # ---------------------------------------------------------------------------
 
 
+def get_moe_stats(block) -> list:
+    """``stats()`` of every ``parallel.moe.SparseExperts`` layer under
+    ``block`` (a model, or the layer itself), in the order the model holds
+    them: of its newest forward the (token, expert) ``pairs`` its held
+    experts got, how many of the ``held`` were ``active``, the
+    ``max_count`` and ``min_count`` of tokens that chose any one of all the
+    experts, the ``buffer_rows`` of a pass and the ``passes`` the pairs
+    took. The numbers are a state of the layer (``count``, one float an
+    expert) that rides the compiled step, so there is nothing to reset;
+    reading them fetches it from the device: ask between steps."""
+    from .parallel.moe import SparseExperts
+    rows = []
+    block.apply(lambda b: rows.append(b.stats())
+                if isinstance(b, SparseExperts) else None)
+    return rows
+
+
 def get_compile_stats() -> dict:
     """Per-cache {hits, traces, retraces} for every signature cache in the
     framework (fused training step, CachedOp/hybridize, symbol Executor

@@ -236,3 +236,35 @@ def test_import_mxtpu_imports_none_of_the_family():
     proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+# The sparse family (tests/test_kexaone.py) shares the block and the stack
+# with this one: this family's step has to trace to the program it traced to
+# before the layer spec grew an attention kind, a norm kind and position, an
+# untied head and expert layers (hash of the printed jaxpr of loss and
+# gradient at CFG, taken on the parent tree, commit 55b792c).
+PHI4_STEP = "cb95fae9884848c5"
+
+
+def test_phi4_step_traces_to_the_same_jaxpr(ref, system, weights, batch):
+    import hashlib
+    x, y = batch
+    net = system.build_net(CFG, weights, "float32")
+    handles = [p for p, _ in system.param_leaves(net)]
+    saved = [p._data._data for p in handles]
+
+    def loss_of(ps):
+        try:
+            for p, v in zip(handles, ps):
+                p._data._data = v
+            with autograd.pause(train_mode=True):
+                out = net(nd.NDArray(jnp.asarray(x)))
+                loss = system.system.seq_loss(
+                    out, nd.NDArray(jnp.asarray(y, jnp.float32)))
+            return jnp.mean(loss.data)
+        finally:
+            for p, v in zip(handles, saved):
+                p._data._data = v
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss_of))(saved))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PHI4_STEP

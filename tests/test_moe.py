@@ -84,3 +84,224 @@ def test_moe_grads_flow_to_experts():
         gnorm = float(jnp.abs(g1[e]).sum())
         if e in used:
             assert gnorm > 0, e
+
+
+# ---------------------------------------------------------------------------
+# SparseExperts: the expert layer one chip holds
+# ---------------------------------------------------------------------------
+
+SCALE = 2.5
+
+
+def _sparse_setup(E=16, k=4, d=32, f=48, tokens=96, bias_std=0.3, seed=0):
+    rs = np.random.RandomState(seed)
+    return dict(
+        h=jnp.asarray(rs.randn(2, tokens // 2, d), jnp.float32),
+        router=jnp.asarray(rs.randn(E, d), jnp.float32) * 0.3,
+        bias=jnp.asarray(rs.randn(E), jnp.float32) * bias_std,
+        gate_up=jnp.asarray(rs.randn(E, d, 2 * f), jnp.float32) * 0.2,
+        down=jnp.asarray(rs.randn(E, f, d), jnp.float32) * 0.2, k=k)
+
+
+def _sparse_oracle(s, held, bias_in_weights=False, use_bias=True):
+    """The uncut layer's terms of the experts ``held``: every held expert
+    on every token, weighted (zero where it was not chosen)."""
+    x = s["h"].reshape(-1, s["h"].shape[-1])
+    score = jax.nn.sigmoid(x @ s["router"].T)
+    biased = score + s["bias"]
+    _, chosen = jax.lax.top_k(biased if use_bias else score, s["k"])
+    picked = jnp.take_along_axis(biased if bias_in_weights else score,
+                                 chosen, axis=1)
+    weights = SCALE * picked / picked.sum(axis=1, keepdims=True)
+    f = s["down"].shape[1]
+    y = jnp.zeros_like(x)
+    for e in held:
+        gu = x @ s["gate_up"][e]
+        out = (gu[:, f:] * jax.nn.silu(gu[:, :f])) @ s["down"][e]
+        y = y + out * jnp.sum(jnp.where(chosen == e, weights, 0.0),
+                              axis=1)[:, None]
+    return y.reshape(s["h"].shape)
+
+
+def _sparse_layer(s, held, rows=0):
+    at = jnp.asarray(list(held))
+    y, count = moe.sparse_experts(
+        s["h"], s["router"], s["bias"], s["gate_up"][at], s["down"][at],
+        held=tuple(held), top_k=s["k"], scale=SCALE, rows=rows)
+    # every expert's tokens are counted, held or not
+    assert float(count.sum()) == s["h"].shape[0] * s["h"].shape[1] * s["k"]
+    return y, count[at]
+
+
+def test_sparse_selection_uses_the_bias_and_the_weights_do_not():
+    s = _sparse_setup()
+    held = range(16)
+    y, _ = _sparse_layer(s, held)
+    np.testing.assert_allclose(np.asarray(y), _sparse_oracle(s, held),
+                               rtol=1e-4, atol=1e-5)
+    # a layer that ignored the bias, or let it into the weights, is another
+    for wrong in (dict(use_bias=False), dict(bias_in_weights=True)):
+        gap = np.abs(np.asarray(y) - _sparse_oracle(s, held, **wrong)).max()
+        assert gap > 100 * 1e-4, (wrong, gap)
+
+
+@pytest.mark.parametrize("rows", [0, 16, 64])
+def test_sparse_shares_add_up_to_the_uncut_layer(rows):
+    """The guide's share test at the layer: 16 experts over 4 shares of 4
+    (and 2 of 8, and 4 of scattered ids), each share routing over all 16;
+    the shares' partial results add up to the uncut layer's, whatever the
+    row buffer (16 rows: many passes)."""
+    s = _sparse_setup(seed=3)
+    whole = _sparse_oracle(s, range(16))
+    for shares in ([range(f, f + 4) for f in range(0, 16, 4)],
+                   [range(0, 8), range(8, 16)],
+                   [[0, 5, 10, 15], [1, 4, 11, 14], [2, 7, 8, 13],
+                    [3, 6, 9, 12]]):
+        parts, loads = [], []
+        for held in shares:
+            y, load = _sparse_layer(s, held, rows)
+            np.testing.assert_allclose(np.asarray(y), _sparse_oracle(s, held),
+                                       rtol=1e-4, atol=1e-5)
+            parts.append(np.asarray(y))
+            loads.append(float(load.sum()))
+        np.testing.assert_allclose(sum(parts), whole, rtol=1e-4, atol=2e-5)
+        assert sum(loads) == 96 * s["k"]           # every pair is somewhere
+
+
+def _sparse_grads(s, fn):
+    def loss(h, router, gate_up, down):
+        t = dict(s, h=h, router=router, gate_up=gate_up, down=down)
+        return jnp.sum(jnp.sin(fn(t)))
+    return jax.grad(loss, argnums=(0, 1, 2, 3))(
+        s["h"], s["router"], s["gate_up"], s["down"])
+
+
+def test_sparse_drops_no_token_when_every_token_picks_held_experts():
+    """A bias that makes experts 4..7 every token's choice: 4 x 96 pairs
+    land on a share whose even load is a quarter of that. The default
+    buffer takes them in one pass (it is capped at the worst case), a small
+    one in many; nothing is dropped either way, gradients included."""
+    s = _sparse_setup(seed=5)
+    s["bias"] = s["bias"].at[4:8].add(10.0)
+    held = range(4, 8)
+    want = _sparse_oracle(s, held)
+    for rows in (0, 32):
+        y, load = _sparse_layer(s, held, rows)
+        assert float(load.sum()) == 96 * 4
+        np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-5)
+    ref = _sparse_grads(s, lambda t: _sparse_oracle(t, held))
+    # 32 rows: twelve passes; 384: the worst case in one
+    for rows in (32, 384):
+        got = _sparse_grads(s, lambda t: _sparse_layer(t, held, rows)[0])
+        for g, r in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=2e-3, atol=2e-5)
+
+
+@pytest.mark.parametrize("rows,passes", [(256, 1), (64, 3)])
+def test_sparse_gradients_either_side_of_the_buffer(rows, passes):
+    """A buffer under the worst case (384 rows) learns while it runs how
+    many passes the pairs take: one where they fit, more where they do not.
+    Both give the oracle's gradients."""
+    s = _sparse_setup(seed=3)
+    held = range(4)
+    pairs = float(_sparse_layer(s, held, rows)[1].sum())
+    assert -(-pairs // rows) == passes, pairs
+    got, ref = (_sparse_grads(s, fn) for fn in (
+        lambda t: _sparse_layer(t, held, rows)[0],
+        lambda t: _sparse_oracle(t, held)))
+    for g, r in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-3,
+                                   atol=2e-5)
+
+
+def test_sparse_block_keeps_counts_and_bias_as_states():
+    from mxtpu import autograd, nd, profiler
+    blk = moe.SparseExperts(32, 48, 16, 4, held=[4, 5, 6, 7],
+                            shared_ffn_units=24, routed_scale=SCALE)
+    blk.initialize()
+    zero = blk.stats()
+    assert zero["pairs"] == 0 and zero["buffer_rows"] is None \
+        and zero["passes"] is None
+    x = nd.array(np.random.RandomState(0).randn(2, 48, 32).astype(np.float32))
+    with autograd.record():
+        out = blk(x)
+        loss = nd.sum(out * out)
+    loss.backward()
+    for state in (blk.select_bias, blk.count):
+        assert state.grad_req == "null" and state.keep_float32 \
+            and state.shape == (16,)
+    assert float(nd.sum(nd.abs(blk.gate_up.grad())).asscalar()) > 0
+    assert float(nd.sum(nd.abs(
+        blk.shared.down.weight.grad())).asscalar()) > 0
+    assert blk.router.grad().shape == (16, 32)
+    # every token chose 4 of the 16 experts, and the layer counted them
+    count = blk.count.data().asnumpy()
+    assert count.sum() == 96 * 4 and count.dtype == np.float32
+    row, = profiler.get_moe_stats(blk)
+    assert row == blk.stats() and row["held"] == 4
+    assert row["pairs"] == count[4:8].sum() > 0
+    assert row["active"] == (count[4:8] > 0).sum()
+    assert row["max_count"] == count.max() >= 96 * 4 / 16 >= count.min() \
+        == row["min_count"]
+    assert row["buffer_rows"] == moe.expert_rows(96, 16, 4, 4) == 384
+    assert row["passes"] == 1
+    # the state holds the NEWEST forward's counts, training or not; a model
+    # cast to a narrower type keeps both states float32
+    with autograd.pause():
+        blk(x * 2.0)
+    assert blk.stats()["pairs"] == blk.count.data().asnumpy()[4:8].sum()
+    blk.cast("bfloat16")
+    assert blk.count.dtype == blk.select_bias.dtype == "float32"
+    assert blk.router.dtype == "bfloat16"
+    with pytest.raises(ValueError, match="distinct ids"):
+        moe.SparseExperts(32, 48, 16, 4, held=[1, 1])
+    with pytest.raises(ValueError, match="distinct ids"):
+        moe.SparseExperts(32, 48, 16, 4, held=[16])
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.02])
+def test_sparse_block_balances_the_load_through_the_bias(rate):
+    """``bias_update_rate``: every TRAINING forward moves each expert's
+    selection bias by the rate against its load's side of the even share
+    (all 16 experts, though 4 are held); repeated, the loads even out. A
+    rate of 0 and a forward outside training leave the bias alone."""
+    from mxtpu import autograd, nd
+    rs = np.random.RandomState(1)
+    blk = moe.SparseExperts(32, 48, 16, 4, held=range(4, 8),
+                            bias_update_rate=rate)
+    blk.initialize()
+    b0 = (rs.randn(16) * 0.1).astype(np.float32)
+    blk.select_bias.set_data(nd.array(b0))
+    blk.router.set_data(nd.array(rs.randn(16, 32).astype(np.float32) * 0.3))
+    x = nd.array(rs.randn(4, 64, 32).astype(np.float32))
+    even = 256 * 4 / 16
+
+    def counts():
+        s = jax.nn.sigmoid(jnp.asarray(x.asnumpy()).reshape(-1, 32)
+                           @ blk.router.data().data.T)
+        _, chosen = jax.lax.top_k(s + blk.select_bias.data().data, 4)
+        return np.bincount(np.asarray(chosen).ravel(), minlength=16)
+
+    c0 = counts()
+    blk(x)                                   # not training: nothing moves
+    np.testing.assert_array_equal(blk.select_bias.data().asnumpy(), b0)
+    with autograd.record():
+        out = blk(x)
+        loss = nd.sum(out * out)
+    loss.backward()                          # the step's own bias, not the new
+    assert float(nd.sum(nd.abs(blk.router.grad())).asscalar()) > 0
+    np.testing.assert_allclose(blk.select_bias.data().asnumpy(),
+                               b0 + rate * np.sign(even - c0), rtol=0,
+                               atol=1e-7)
+    assert blk.stats()["pairs"] == c0[4:8].sum()
+    assert blk.stats()["max_count"] == c0.max()
+    for _ in range(30):
+        with autograd.record():
+            blk(x)
+    if rate:
+        assert np.abs(counts() - even).max() < np.abs(c0 - even).max() / 2
+    else:
+        np.testing.assert_array_equal(counts(), c0)
+    with pytest.raises(ValueError, match="bias_update_rate"):
+        moe.SparseExperts(32, 48, 16, 4, bias_update_rate=-1.0)

@@ -80,3 +80,46 @@ def test_flash_kernels_compile_at_the_cells_size(one_chip, quiet_cache,
                   "flash_bwd_dkv_window") if window
                  else ("flash_fwd", "flash_bwd_fused")):
         assert name in text
+
+
+@pytest.mark.parametrize("window", [128, None])
+def test_flash_kernels_compile_at_the_sparse_cells_size(one_chip, quiet_cache,
+                                                        window):
+    """8 query heads of 128 on 1 key/value head, T = 4096, window 128 or
+    none: an attention layer of ``kexaone_train_t4096``."""
+    from mxtpu.ops import attention as A
+    bf = jnp.bfloat16
+    q, k, v, g = _avals(one_chip, ((1, 8, 4096, 128), bf),
+                        ((1, 1, 4096, 128), bf), ((1, 1, 4096, 128), bf),
+                        ((1, 8, 4096, 128), bf))
+    scale = 128 ** -0.5
+
+    def both(q, k, v, g):
+        out, lse = A._flash_attention_pallas(q, k, v, True, scale,
+                                             window=window)
+        return A._flash_backward_pallas(q, k, v, out, lse, g, True, scale,
+                                        window=window)
+
+    text = jax.jit(both).lower(q, k, v, g).compile().as_text()
+    for name in (("flash_fwd_window", "flash_bwd_dq_window",
+                  "flash_bwd_dkv_window") if window
+                 else ("flash_fwd", "flash_bwd_fused")):
+        assert name in text, name
+
+
+@pytest.mark.parametrize("K,N", [(6144, 4096), (2048, 6144)])
+def test_grouped_matmul_kernels_compile_at_the_sparse_cells_size(
+        one_chip, quiet_cache, K, N):
+    """8192 buffer rows over 8 held experts: gate/up (6144 -> 2 x 2048) and
+    down (2048 -> 6144), forward, dx and the per-group dw."""
+    from mxtpu.ops import grouped_matmul as G
+    bf = jnp.bfloat16
+    x, w, gs, dy = _avals(one_chip, ((8192, K), bf), ((8, K, N), bf),
+                          ((8,), jnp.int32), ((8192, N), bf))
+
+    def all_three(x, w, gs, dy):
+        return (G._gmm_pallas(x, w, gs), G._gmm_pallas(dy, w, gs, True),
+                G._tgmm_pallas(x, dy, gs))
+
+    text = jax.jit(all_three).lower(x, w, gs, dy).compile().as_text()
+    assert "moe_gmm" in text and "moe_tgmm" in text
