@@ -585,6 +585,7 @@ def leg_sparse_train(sz, on_chip: bool) -> dict:
     from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
     from mxtpu.parallel import DataParallelTrainer
     from mxtpu.parallel.mesh import data_parallel_mesh
+    from mxtpu.parallel.moe import _row_tile
     sp = sz["sparse"]
     profiler.reset_kernel_path_counts()
     mx.random.seed(0)
@@ -613,6 +614,9 @@ def leg_sparse_train(sz, on_chip: bool) -> dict:
     stats = profiler.get_moe_stats(net)
     check(len(stats) == 2 and all(r["pairs"] > 0 and r["passes"] == 1
                                   for r in stats), f"sparse train: {stats}")
+    # the dispatch and combine moved the pairs' row tiles, not the buffer
+    check(all(0 <= r["rows_moved"] - r["pairs"] < _row_tile(r["buffer_rows"])
+              for r in stats), f"sparse train: rows moved {stats}")
     bias = net.block1.moe.select_bias.data().asnumpy()
     check(float(np.abs(bias).max()) > 0, "sparse train: the bias never moved")
     paths = profiler.get_kernel_path_counts()
@@ -621,7 +625,8 @@ def leg_sparse_train(sz, on_chip: bool) -> dict:
             check(paths[kind]["pallas"] > 0 and paths[kind]["xla"] == 0,
                   f"sparse train: {kind} call sites {paths[kind]}")
     return {"losses": [round(v, 4) for v in losses], "kernel_paths": paths,
-            "pairs": [r["pairs"] for r in stats]}
+            "pairs": [r["pairs"] for r in stats],
+            "rows_moved": [r["rows_moved"] for r in stats]}
 
 
 # -- leg 5: four chips -------------------------------------------------------

@@ -56,12 +56,15 @@ def expert_rows(tokens: int, num_experts: int, top_k: int, held: int) -> int:
     """Rows of the static buffer a pass of ``SparseExperts`` works on: four
     times the even share of (token, expert) pairs (``tokens * top_k * held /
     num_experts``), in whole 512s, never more than the worst case (every
-    token picks ``min(top_k, held)`` held experts). The grouped kernels skip
-    the row tiles that hold no pair, so their time follows the pairs; the
-    gather, the SwiGLU and the scatter-add run over the buffer whatever it
-    holds. A step whose routing sends more pairs here than the buffer has
-    rows runs a further pass, at a whole pass's price: four times the even
-    share keeps that edge far from any load a balanced router visits."""
+    token picks ``min(top_k, held)`` held experts). The buffer is room, not
+    work: the gather into it, the grouped kernels and the reading of rows
+    out of it (and their transposes) touch the row tiles that hold a pair
+    and no other, so their time follows the pairs; only the SwiGLU between
+    the two products, and the clearing of the buffer before the gather,
+    run over the buffer whatever it holds. A step whose routing sends more
+    pairs here than the buffer has rows runs a further pass: four times the
+    even share keeps that edge far from any load a balanced router
+    visits."""
     worst = tokens * min(top_k, held)
     even = -(-tokens * top_k * held // num_experts)
     return min(worst, -(-4 * even // 512) * 512)
@@ -77,70 +80,227 @@ def _route(x, router_w, bias, top_k: int, scale: float):
     s = jax.nn.sigmoid(z)
     _, chosen = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
                           top_k)
-    picked = jnp.take_along_axis(s, chosen, axis=1)
+    # one chosen score and zeros: exact, and a dense fusion both ways where
+    # take_along_axis is a scalar gather and, backward, a scalar scatter-add
+    picked = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(s.shape[1]),
+                               s[:, None, :], 0.0), axis=-1)
     return chosen, scale * picked / jnp.sum(picked, axis=1, keepdims=True)
 
 
-def _one_pass(x, weights, w_gate_up, w_down, order, starts, ends, p,
-              rows: int, top_k: int):
-    """Rows ``p * rows ..`` of the sorted (token, expert) pairs: gather
-    their tokens, both grouped products, and the weighted rows added onto
-    their tokens. Returns ``(T, d)`` float32."""
-    lo = p * rows
-    pairs = lax.dynamic_slice(order, (lo,), (rows,))
-    token = pairs // top_k
-    sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+_ROW_TILE = 512     # rows a turn of the row loops moves
+
+
+def _row_tile(rows: int) -> int:
+    """The loops' granularity for a buffer of ``rows``: ``_ROW_TILE`` where
+    it divides the buffer, as it does every buffer of whole 512s."""
+    return math.gcd(rows, _ROW_TILE)
+
+
+def _pair_index(key, weights, n_held: int, top_k: int, worst: int):
+    """Where a step's (token, expert) pairs go and come from, int32 but
+    for the weights, from ``key`` ``(T * k,)``: pair ``t * k + j``'s held
+    slot, ``n_held`` for an absent expert's. ``order`` ``(worst,)``: the
+    pair ids sorted by slot, a slot's by token, absent ones last, and
+    ``w_sorted`` their ``weights`` (T, k) in that order, which carry no
+    gradient (``_held_experts`` writes the weights' own). ``place``
+    ``(T,)``: the sorted position of each token's FIRST held pair, -1 where
+    it has none, and ``first`` ``(T, k)`` bool: which of its choices that
+    pair is. ``more_at``, ``more_pair``: the sorted positions and the ids
+    of every FURTHER held pair of a token, ascending by position; past the
+    last of them ``T * k`` and 0, ``_ROW_TILE`` more than there can be.
+    Sorts and dense selections alone: a scalar gather or scatter costs the
+    chip more a number than a sort does."""
+    pairs = key.shape[0]
+    T = pairs // top_k
+    _, by_slot, w_sorted = lax.sort(
+        (key, jnp.arange(pairs, dtype=jnp.int32),
+         lax.stop_gradient(weights).reshape(-1)), num_keys=1, is_stable=True)
+    room = (0, max(0, worst - pairs))
+    at = jnp.argsort(by_slot).astype(jnp.int32).reshape(T, top_k)
+    held = (key < n_held).reshape(T, top_k)
+    first = held & (jnp.cumsum(held, axis=1) == 1)
+    place = jnp.where(jnp.any(first, axis=1),
+                      jnp.sum(jnp.where(first, at, 0), axis=1), -1)
+    more_at, more_pair = lax.sort(
+        (jnp.where(held & ~first, at, pairs).reshape(-1),
+         jnp.arange(pairs, dtype=jnp.int32)), num_keys=1)
+    most = T * (min(top_k, n_held) - 1)
+    return (jnp.pad(by_slot[:worst], room), jnp.pad(w_sorted[:worst], room),
+            place, first,
+            jnp.pad(more_at[:most], (0, _ROW_TILE), constant_values=pairs),
+            jnp.pad(more_pair[:most], (0, _ROW_TILE)))
+
+
+def _cleared(shape, dtype, count):
+    """Zeros, from a traced scalar that is 0 in every step (``count`` is
+    never negative). Constant zeros of all scopes are merged by XLA into
+    broadcasts that carry no name, and a device trace then books the
+    clearing of this layer's buffer under no scope at all."""
+    return jnp.broadcast_to(jnp.minimum(count, 0).astype(dtype), shape)
+
+
+def _take_rows(src, token, tiles, tile: int, into, weight=None,
+               dot: bool = False):
+    """``into`` with its first ``tiles`` row tiles replaced by ``src[token]``
+    (times ``weight``, in float32), a tile a turn; rows past them stay as
+    they came. With ``dot`` also the products of the gathered rows with the
+    rows they replace, ``(len(token),)`` float32, zero past those tiles."""
+    d = src.shape[1]
+
+    def turn(i, carry):
+        buf, dots = carry
+        at = i * tile
+        got = src[lax.dynamic_slice(token, (at,), (tile,))]
+        if dot:
+            old = lax.dynamic_slice(buf, (at, 0), (tile, d))
+            dots = lax.dynamic_update_slice(
+                dots, jnp.sum(got * old.astype(jnp.float32), axis=1), (at,))
+        if weight is not None:
+            got = got * lax.dynamic_slice(weight, (at,), (tile,))[:, None]
+        return (lax.dynamic_update_slice(buf, got.astype(buf.dtype), (at, 0)),
+                dots)
+
+    dots = jnp.zeros(token.shape, jnp.float32) if dot else None
+    return lax.fori_loop(0, tiles, turn, (into, dots))
+
+
+def _sum_rows(acc, src, lo, place, w_first, more_at, more_pair, top_k: int,
+              w_pair=None):
+    """``acc`` (None: nothing yet) plus, for every token, the sum of the
+    rows of ``src`` that its pairs have in this pass, ``src`` being rows
+    ``lo ..`` of the sorted pairs; float32 ``(T, d)``. A token's first pair
+    comes by a gather over the tokens (times ``w_first``, taken as zero for
+    a token whose first pair is not in this pass), which writes every
+    token's row, so there is no buffer of zeros to start from; only its
+    further pairs (times ``w_pair[pair]``, or 1) are added with repeated
+    indices, ``_ROW_TILE`` a turn for as many as this pass has."""
+    n = src.shape[0]
+    here = (place >= lo) & (place < lo + n)
+    rows = src[jnp.clip(place - lo, 0, n - 1)].astype(jnp.float32) \
+        * jnp.where(here, w_first, 0.0)[:, None]
+    acc = rows if acc is None else acc + rows
+    if more_at.shape[0] == _ROW_TILE:       # a token has one pair at most
+        return acc
+    a, b = jnp.sum(more_at < lo), jnp.sum(more_at < lo + n)
+
+    def turn(i, acc):
+        at = a + i * _ROW_TILE
+        pair = lax.dynamic_slice(more_pair, (at,), (_ROW_TILE,))
+        row = lax.dynamic_slice(more_at, (at,), (_ROW_TILE,)) - lo
+        w = jnp.where(at + jnp.arange(_ROW_TILE) < b,
+                      1.0 if w_pair is None else w_pair[pair], 0.0)
+        part = src[jnp.clip(row, 0, n - 1)].astype(jnp.float32) * w[:, None]
+        return acc.at[pair // top_k].add(part)
+
+    return lax.fori_loop(0, -(-(b - a) // _ROW_TILE), turn, acc)
+
+
+def _pass_rows(order, w_sorted, starts, ends, p, rows: int, top_k: int):
+    """Pass ``p`` takes rows ``p * rows ..`` of the sorted (token, expert)
+    pairs: ``(pairs, their tokens, their weights (zero past the last pair),
+    the held experts' rows among them, the row tiles that hold a pair)``."""
     with jax.named_scope("dispatch"):
-        xs = x[token]
+        lo = p * rows
+        pairs = lax.dynamic_slice(order, (lo,), (rows,))
+        sizes = jnp.clip(ends, lo, lo + rows) \
+            - jnp.clip(starts, lo, lo + rows)
+        live = jnp.clip(ends[-1] - lo, 0, rows)
+        w_row = jnp.where(jnp.arange(rows) < live,
+                          lax.dynamic_slice(w_sorted, (lo,), (rows,)), 0.0)
+        return (pairs, pairs // top_k, w_row, sizes,
+                -(-live // _row_tile(rows)))
+
+
+def _experts(xs, w_gate_up, w_down, sizes):
     with jax.named_scope("experts"):
         ffn = w_down.shape[1]
         gate_up = grouped_matmul(xs, w_gate_up, sizes)
         act = gate_up[:, ffn:] * jax.nn.silu(gate_up[:, :ffn])
-        out = grouped_matmul(act, w_down, sizes)
-    with jax.named_scope("combine"):
-        live = lo + jnp.arange(rows) < ends[-1]
-        w_row = jnp.where(live, weights.reshape(-1)[pairs], 0.0)
-        return jnp.zeros(x.shape, jnp.float32).at[token].add(
-            out.astype(jnp.float32) * w_row[:, None])
+        return grouped_matmul(act, w_down, sizes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held_experts(x, weights, w_gate_up, w_down, order, starts, ends,
-                  rows: int, top_k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_experts(x, weights, w_gate_up, w_down, index, rows: int,
+                  top_k: int):
     """Every pass the step's pairs need, added up: one, unless the routing
-    sends more than ``rows`` pairs to the held experts. The loop's length is
-    a traced number, so nothing is dropped and nothing is paid for rows that
-    are not there. The backward runs the same passes again, each from its
-    inputs, so no row buffer outlives its pass: the layer keeps its input
+    sends more than ``rows`` pairs to the held experts. ``index``:
+    ``_pair_index``'s six and the held experts' ``starts`` and ``ends``
+    among the sorted pairs. A pass gathers its pairs' tokens into the row
+    buffer a live tile a turn, multiplies them by their experts and sums
+    each token's weighted rows in float32 (``_sum_rows``): rows past the
+    last live tile are neither gathered nor read back. Every loop's length
+    is a traced number, so nothing is dropped and nothing is moved for
+    pairs that are not there. The backward runs the same passes again, each
+    from its inputs, with each movement's transpose written out (the
+    tokens' gradients gathered into the buffer a live tile a turn, the
+    rows' gradients summed onto their tokens as the forward sums the
+    rows), so no row buffer outlives its pass: the layer keeps its input
     and the routing between forward and backward and nothing as wide as
-    (pairs, ffn)."""
-    def one(p):
-        return _one_pass(x, weights, w_gate_up, w_down, order, starts, ends,
-                         p, rows, top_k)
-    return lax.fori_loop(1, -(-ends[-1] // rows),
-                         lambda p, y: y + one(p), one(0))
+    (pairs, ffn). Returns ``(T, d)`` float32."""
+    (order, w_sorted, place, first, more_at, more_pair, starts,
+     ends) = index
+    tile = _row_tile(rows)
+    with jax.named_scope("combine"):
+        w_first = jnp.sum(jnp.where(first, weights, 0.0), axis=1)
+
+    def one(p, y):
+        _, token, _, sizes, tiles = _pass_rows(
+            order, w_sorted, starts, ends, p, rows, top_k)
+        with jax.named_scope("dispatch"):
+            xs, _ = _take_rows(x, token, tiles, tile,
+                               _cleared((rows, x.shape[1]), x.dtype, tiles))
+        out = _experts(xs, w_gate_up, w_down, sizes)
+        with jax.named_scope("combine"):
+            return _sum_rows(y, out, p * rows, place, w_first, more_at,
+                             more_pair, top_k, weights.reshape(-1))
+
+    return lax.fori_loop(1, -(-ends[-1] // rows), one, one(0, None))
 
 
-def _held_experts_fwd(x, weights, w_gate_up, w_down, order, starts, ends,
-                      rows, top_k):
-    y = _held_experts(x, weights, w_gate_up, w_down, order, starts, ends,
-                      rows, top_k)
-    return y, (x, weights, w_gate_up, w_down, order, starts, ends)
+def _held_experts_fwd(x, weights, w_gate_up, w_down, index, rows, top_k):
+    y = _held_experts(x, weights, w_gate_up, w_down, index, rows, top_k)
+    return y, (x, weights, w_gate_up, w_down, index)
 
 
 def _held_experts_bwd(rows, top_k, res, dy):
-    x, weights, w_gate_up, w_down, order, starts, ends = res
+    x, weights, w_gate_up, w_down, index = res
+    (order, w_sorted, place, first, more_at, more_pair, starts,
+     ends) = index
+    tile = _row_tile(rows)
+    with jax.named_scope("dispatch"):
+        has_pair = jnp.any(first, axis=1).astype(jnp.float32)
 
-    def one(p):
-        _, vjp = jax.vjp(
-            lambda *a: _one_pass(*a, order, starts, ends, p, rows, top_k),
-            x, weights, w_gate_up, w_down)
-        return vjp(dy)
+    def one(p, dx, dweights):
+        pairs, token, w_row, sizes, tiles = _pass_rows(
+            order, w_sorted, starts, ends, p, rows, top_k)
+        with jax.named_scope("dispatch"):
+            xs, _ = _take_rows(x, token, tiles, tile,
+                               _cleared((rows, x.shape[1]), x.dtype, tiles))
+        out, experts_vjp = jax.vjp(
+            lambda *a: _experts(*a, sizes), xs, w_gate_up, w_down)
+        with jax.named_scope("combine"):
+            # out's buffer becomes its gradient's, a tile after each tile's
+            # products with dy are taken (the weights' gradient)
+            d_out, dw_row = _take_rows(dy, token, tiles, tile, out,
+                                       weight=w_row, dot=True)
+            dweights = dweights.at[pairs].add(dw_row)
+        dxs, d_gate_up, d_down = experts_vjp(d_out)
+        with jax.named_scope("dispatch"):
+            dx = _sum_rows(dx, dxs, p * rows, place, has_pair, more_at,
+                           more_pair, top_k)
+        return dx, dweights, d_gate_up, d_down
 
-    grads = lax.fori_loop(
-        1, -(-ends[-1] // rows),
-        lambda p, g: jax.tree.map(jnp.add, g, one(p)), one(0))
-    return grads + (None, None, None)
+    def more(p, grads):
+        dx, dweights, d_gate_up, d_down = one(p, *grads[:2])
+        return dx, dweights, grads[2] + d_gate_up, grads[3] + d_down
+
+    with jax.named_scope("combine"):
+        dweights = jnp.zeros((weights.size,), jnp.float32)
+    dx, dweights, d_gate_up, d_down = lax.fori_loop(
+        1, -(-ends[-1] // rows), more, one(0, None, dweights))
+    with jax.named_scope("dispatch"):
+        dx = dx.astype(x.dtype)
+    return (dx, dweights.reshape(weights.shape), d_gate_up, d_down, None)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -168,17 +328,18 @@ def sparse_experts(h, router_w, bias, w_gate_up, w_down, held=(),
                         dtype=jnp.int32)
     with jax.named_scope("dispatch"):
         # pairs sorted by held expert's slot, pairs of absent experts last
-        slot = np.full((E,), n_held, np.int32)
-        slot[list(held)] = np.arange(n_held)
-        key = jnp.asarray(slot)[chosen.reshape(-1)]
+        # (dense: a lookup in a table of slots is 32768 scalar gathers)
+        is_slot = chosen.reshape(-1, 1) == np.asarray(held, np.int32)
+        key = n_held + jnp.sum(
+            jnp.where(is_slot, np.arange(n_held, dtype=np.int32) - n_held, 0),
+            axis=1)
         rows = rows or expert_rows(T, E, top_k, n_held)
         worst = -(-T * min(top_k, n_held) // rows) * rows
-        order = jnp.argsort(key, stable=True)[:worst].astype(jnp.int32)
-        order = jnp.pad(order, (0, worst - order.shape[0]))
         load = count[np.asarray(held, np.int32)]
         ends = jnp.cumsum(load)
-    y = _held_experts(x, weights, w_gate_up, w_down, order, ends - load,
-                      ends, rows, top_k)
+        index = (*_pair_index(key, weights, n_held, top_k, worst),
+                 ends - load, ends)
+    y = _held_experts(x, weights, w_gate_up, w_down, index, rows, top_k)
     return (y.astype(h.dtype).reshape(h.shape),
             lax.stop_gradient(count.astype(jnp.float32)))
 
@@ -217,8 +378,19 @@ class SparseExperts(HybridBlock):
     One pass works on a static buffer of ``expert_rows`` (token, expert)
     pairs, four times the even share; a step whose routing sends more pairs
     here than that runs more passes, so memory is the buffer's and time the
-    pairs'. Scopes in a device trace: ``route``, ``dispatch``, ``experts``,
-    ``combine``, ``shared``, ``balance``."""
+    pairs'. What follows the pairs: the dispatch (tokens' rows gathered into
+    the buffer, a row tile that holds a pair a turn), the grouped products,
+    the combine (each token's weighted rows summed in float32: its first
+    pair's row by one gather over the tokens, its further pairs' rows added
+    with repeated indices, a tile of them a turn) and the transposes of
+    both (the tokens' gradients gathered into the buffer by live tiles; the
+    rows' gradients summed onto their tokens as the combine sums rows): no
+    row of the buffer past the last live tile is written or read
+    (``stats()["rows_moved"]``), and no sum starts from a buffer of zeros.
+    What still runs over the whole buffer: the SwiGLU between the two
+    products, the clearing of the buffer before the gather, and a weight
+    and a token id a row. Scopes in a device trace: ``route``,
+    ``dispatch``, ``experts``, ``combine``, ``shared``, ``balance``."""
 
     def __init__(self, units: int, ffn_units: int, num_experts: int,
                  top_k: int, held=None, shared_ffn_units: int = 0,
@@ -278,17 +450,22 @@ class SparseExperts(HybridBlock):
         expert) ``pairs`` the held experts got, how many of the ``held``
         were ``active`` (got a row), the ``max_count`` and ``min_count`` of
         tokens that chose any one of ALL the experts, the ``buffer_rows`` of
-        a pass and the ``passes`` the pairs took (None before the first
-        forward). Reads ``count`` from the device: ask between steps, not
-        inside a timed loop."""
+        a pass, the ``passes`` the pairs took and the ``rows_moved``: the
+        rows of the buffer that the dispatch filled and the combine read
+        back, which are the pairs in whole tiles of the row loops and reach
+        ``buffer_rows`` times ``passes`` only where the pairs do (all three
+        None before the first forward). Reads ``count`` from the device:
+        ask between steps, not inside a timed loop."""
         count = self.count.data().asnumpy()
         load = count[list(self.held)]
         pairs = float(load.sum())
+        tile = self._rows and _row_tile(self._rows)
         return {"name": self.name, "held": len(self.held), "pairs": pairs,
                 "active": int((load > 0).sum()),
                 "max_count": float(count.max()),
                 "min_count": float(count.min()), "buffer_rows": self._rows,
-                "passes": self._rows and max(1, -(-int(pairs) // self._rows))}
+                "passes": self._rows and max(1, -(-int(pairs) // self._rows)),
+                "rows_moved": tile and -(-int(pairs) // tile) * tile}
 
 
 # ---------------------------------------------------------------------------

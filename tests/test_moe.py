@@ -305,3 +305,124 @@ def test_sparse_block_balances_the_load_through_the_bias(rate):
         np.testing.assert_array_equal(counts(), c0)
     with pytest.raises(ValueError, match="bias_update_rate"):
         moe.SparseExperts(32, 48, 16, 4, bias_update_rate=-1.0)
+
+
+# ---------------------------------------------------------------------------
+# the dispatch / combine pair: rows gathered into the buffer a live tile at
+# a time, each token's rows summed by a gather of its first pair and a tiled
+# add of its further ones, both directions' transposes written out; against
+# the whole-buffer gather and ``.at[].add`` they replaced
+# ---------------------------------------------------------------------------
+
+
+def _pairs_setup(case, T=40, k=2, E=8, d=16, f=24, seed=0):
+    """A routing made by hand: ``chosen`` (T, k) expert ids, so the pairs
+    the held experts get are known, and the index set ``sparse_experts``
+    would make of it."""
+    rs = np.random.RandomState(seed)
+    chosen = np.stack([rs.permutation(E)[:k] for _ in range(T)])
+    if case == "all_held":
+        held = tuple(range(E))
+    elif case == "two_of_eight":
+        held = (2, 5)
+    elif case == "token_without_pair":
+        held = (0, 1, 2, 3)
+        chosen[::3] = [4, 6]                    # these tokens: absent only
+    else:                                       # one_expert
+        held = (3, 6)
+        chosen[:] = [6, 0]                      # all on held expert 6
+    slot = np.full((E,), len(held), np.int32)
+    slot[list(held)] = np.arange(len(held))
+    key = slot[chosen.reshape(-1)]
+    load = np.bincount(key, minlength=len(held) + 1)[:len(held)]
+    return dict(
+        x=jnp.asarray(rs.randn(T, d), jnp.float32),
+        weights=jnp.asarray(rs.rand(T, k) + 0.1, jnp.float32),
+        gate_up=jnp.asarray(rs.randn(len(held), d, 2 * f), jnp.float32) * 0.2,
+        down=jnp.asarray(rs.randn(len(held), f, d), jnp.float32) * 0.2,
+        key=key, order=np.argsort(key, kind="stable").astype(np.int32),
+        load=load, k=k, worst=T * min(k, len(held)))
+
+
+def _whole_buffer(s, rows):
+    """The oracle: every pass gathers its whole buffer's tokens, multiplies
+    and adds every row back with ``.at[].add``, differentiated by JAX."""
+    from mxtpu.ops.grouped_matmul import grouped_matmul
+    ends = np.cumsum(s["load"])
+    starts = ends - s["load"]
+    worst = -(-s["worst"] // rows) * rows
+    order = np.zeros((worst,), np.int32)
+    n = min(worst, len(s["order"]))
+    order[:n] = s["order"][:n]
+
+    def fn(x, weights, gate_up, down):
+        y = jnp.zeros(x.shape, jnp.float32)
+        for p in range(max(1, -(-int(ends[-1]) // rows))):
+            lo = p * rows
+            pairs = order[lo:lo + rows]
+            token = pairs // s["k"]
+            sizes = jnp.asarray(np.clip(ends, lo, lo + rows)
+                                - np.clip(starts, lo, lo + rows))
+            xs = x[token]
+            f = down.shape[1]
+            gu = grouped_matmul(xs, gate_up, sizes)
+            out = grouped_matmul(gu[:, f:] * jax.nn.silu(gu[:, :f]), down,
+                                 sizes)
+            w_row = jnp.where(lo + np.arange(rows) < ends[-1],
+                              weights.reshape(-1)[pairs], 0.0)
+            y = y.at[token].add(out * w_row[:, None])
+        return y
+
+    def ours(x, weights, gate_up, down):
+        index = (*moe._pair_index(jnp.asarray(s["key"]), weights,
+                                  len(s["load"]), s["k"], worst),
+                 jnp.asarray(starts, jnp.int32), jnp.asarray(ends, jnp.int32))
+        return moe._held_experts(x, weights, gate_up, down, index, rows,
+                                 s["k"])
+    return fn, ours
+
+
+@pytest.mark.parametrize("passes", [1, 3])
+@pytest.mark.parametrize("case", ["all_held", "two_of_eight",
+                                  "token_without_pair", "one_expert"])
+def test_rows_follow_the_pairs_like_the_whole_buffer(case, passes):
+    s = _pairs_setup(case)
+    pairs = int(s["load"].sum())
+    rows = s["worst"] if passes == 1 else -(-pairs // 3)
+    assert max(1, -(-pairs // rows)) == passes, (pairs, rows)
+    args = (s["x"], s["weights"], s["gate_up"], s["down"])
+    g = jnp.asarray(np.random.RandomState(9).randn(*s["x"].shape),
+                    jnp.float32)
+    oracle, ours = _whole_buffer(s, rows)
+    np.testing.assert_allclose(np.asarray(jax.jit(ours)(*args)),
+                               np.asarray(oracle(*args)), rtol=1e-5,
+                               atol=1e-6)
+    got, want = (jax.grad(lambda *a: jnp.sum(fn(*a) * g),
+                          argnums=(0, 1, 2, 3))(*args)
+                 for fn in (jax.jit(ours), oracle))
+    for name, a, b in zip(("x", "weights", "gate_up", "down"), got, want):
+        assert float(jnp.abs(b).max()) > 0, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("held,tokens", [(None, 96), ([4, 5, 6, 7], 96),
+                                         ([3], 4096)])
+def test_rows_moved_follows_the_pairs_and_not_the_buffer(held, tokens):
+    """``stats()["rows_moved"]``: the pairs of the newest forward in whole
+    tiles of the loops. A layer that holds every expert moves its whole
+    buffer, a share moves its pairs', far under the buffer's four-fold."""
+    from mxtpu import nd
+    blk = moe.SparseExperts(32, 48, 16, 4, held=held)
+    blk.initialize()
+    assert blk.stats()["rows_moved"] is None
+    blk(nd.array(np.random.RandomState(0).randn(1, tokens, 32)
+                 .astype(np.float32)))
+    row = blk.stats()
+    tile = moe._row_tile(row["buffer_rows"])
+    assert row["pairs"] <= row["rows_moved"] < row["pairs"] + tile
+    assert row["rows_moved"] % tile == 0
+    if held is None:
+        assert row["rows_moved"] == row["buffer_rows"] == tokens * 4
+    else:
+        assert row["rows_moved"] <= row["buffer_rows"] / 2, row
