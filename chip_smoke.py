@@ -20,7 +20,11 @@ at the full width of the ``flagship`` preset (d1024, L8, H16, vocab 16384):
    attention, an expert layer holding a share of its experts, a shared
    expert, the balancing bias) trains four steps through
    ``DataParallelTrainer``: its grouped matmuls and flash launches must be
-   Pallas call sites.
+   Pallas call sites. A second tiny decoder (gated short convolutions beside
+   grouped-query attention, an expert layer holding ALL its experts, a tied
+   head with float32 logits) trains a step twice, once as it runs and once
+   with every kernel site on its XLA formulation: first loss and every
+   parameter's first gradient must agree.
 5. four chips, when there are four — leg 1 at B32 over dp=4 and a 2x2
    fsdp×tp serving engine, with where the bytes actually landed.
 
@@ -93,6 +97,9 @@ SIZES = {
         sparse=dict(units=256, head_dim=128, heads=2, ffn=512, moe_ffn=128,
                     experts=16, held=(4, 5, 6, 7), top_k=4, vocab=1024,
                     T=512, steps=4),
+        # a conv / attention decoder of the LFM2 family, every expert held
+        conv_sparse=dict(units=256, head_dim=64, heads=4, kv_heads=2, ffn=512,
+                         moe_ffn=128, experts=8, top_k=2, vocab=1024, T=512),
         multi=dict(B=32, serve_n=4),
     ),
     "rehearsal": dict(
@@ -107,6 +114,8 @@ SIZES = {
         grouped=dict(M=256, K=128, N=128, sizes=(100, 0, 37, 80)),
         sparse=dict(units=32, head_dim=8, heads=2, ffn=64, moe_ffn=16,
                     experts=8, held=(2, 3), top_k=2, vocab=50, T=32, steps=4),
+        conv_sparse=dict(units=32, head_dim=8, heads=4, kv_heads=2, ffn=64,
+                         moe_ffn=16, experts=4, top_k=2, vocab=50, T=32),
         multi=dict(B=8, serve_n=2),
     ),
 }
@@ -629,6 +638,93 @@ def leg_sparse_train(sz, on_chip: bool) -> dict:
             "rows_moved": [r["rows_moved"] for r in stats]}
 
 
+# -- leg 4c: a tiny conv + all-held sparse decoder's step against XLA ---------
+
+@contextlib.contextmanager
+def xla_formulations():
+    """Every kernel site of a program traced inside takes its XLA
+    formulation: the ops ask ``jax.default_backend()`` and are told "cpu".
+    The arrays stay where they are, so on the chip XLA compiles those
+    formulations for the chip."""
+    import jax
+    saved = jax.default_backend
+    jax.default_backend = lambda: "cpu"
+    try:
+        yield
+    finally:
+        jax.default_backend = saved
+
+
+def leg_conv_sparse_train(sz, on_chip: bool) -> dict:
+    """One step of a tiny ``HybridDecoderLM`` of the third family (``conv``
+    and ``attn_full`` mixers, pre-norm RMSNorm, q/k norm and rotary
+    positions, a tied head with float32 logits, expert layers that hold ALL
+    their experts and no shared one) through ``DataParallelTrainer``, twice
+    from the same weights: as it runs (on the chip the flash launch and the
+    grouped matmuls are Pallas call sites) and with every kernel site on its
+    XLA formulation. The first loss and every parameter's first gradient
+    (Adam's first moment after one step) must agree; every token has
+    ``top_k`` held pairs."""
+    import mxtpu as mx
+    from mxtpu import nd, optimizer, profiler
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    from mxtpu.parallel import DataParallelTrainer
+    from mxtpu.parallel.mesh import data_parallel_mesh
+    cs = sz["conv_sparse"]
+    seq = np.random.RandomState(1).randint(0, cs["vocab"], (1, cs["T"] + 1))
+    x, y = nd.array(seq[:, :-1]), nd.array(seq[:, 1:].astype(np.float32))
+
+    def one_step():
+        mx.random.seed(3)           # the same draw both times
+        net = HybridDecoderLM(
+            cs["vocab"], ["conv", "attn_full", "conv"], units=cs["units"],
+            ffn_units=cs["ffn"], num_heads=cs["heads"],
+            num_kv_heads=cs["kv_heads"], head_dim=cs["head_dim"], d_conv=3,
+            attention="gqa", qk_norm=True, rope_kinds=("attn_full",),
+            rope_theta=1e6, norm="rms", float32_logits=True,
+            mlp_kinds=["mlp", "moe", "moe"],
+            moe=dict(ffn_units=cs["moe_ffn"], num_experts=cs["experts"],
+                     top_k=cs["top_k"], bias_update_rate=0.03,
+                     weight_eps=1e-6))
+        net.initialize()
+        if on_chip:
+            net.cast("bfloat16")
+        dpt = DataParallelTrainer(net, seq_loss,
+                                  optimizer.Adam(learning_rate=1e-3),
+                                  data_parallel_mesh(1))
+        loss = float(dpt.step(x, y))
+        moments = {name.split("_", 1)[1]: np.asarray(slots[0], np.float32)
+                   for name, slots in dpt.optimizer_state_by_param().items()}
+        return loss, moments, profiler.get_moe_stats(net)
+
+    profiler.reset_kernel_path_counts()
+    loss, moments, stats = one_step()
+    paths = profiler.get_kernel_path_counts()
+    with xla_formulations():
+        want_loss, want, _ = one_step()
+    check(all(r["pairs"] == cs["T"] * cs["top_k"] and r["passes"] == 1
+              and r["held"] == cs["experts"] for r in stats)
+          and len(stats) == 2, f"conv sparse train: {stats}")
+    tol = 3e-2 if on_chip else 1e-4     # bfloat16 against bfloat16 / float32
+    check(abs(loss - want_loss) <= tol * want_loss,
+          f"conv sparse train: first loss {loss} against XLA's {want_loss}")
+    check(set(moments) == set(want) and any("conv" in k for k in moments),
+          f"conv sparse train: parameters {sorted(moments)}")
+    gaps = {k: float(np.linalg.norm(moments[k] - want[k])
+                     / max(np.linalg.norm(want[k]), 1e-30)) for k in want}
+    worst = max(gaps, key=gaps.get)
+    check(gaps[worst] <= 3 * tol,
+          f"conv sparse train: first gradient of {worst} is "
+          f"{gaps[worst]:.4g} from XLA's")
+    if on_chip:
+        for kind in ("flash", "grouped_matmul"):
+            check(paths[kind]["pallas"] > 0 and paths[kind]["xla"] == 0,
+                  f"conv sparse train: {kind} call sites {paths[kind]}")
+    return {"loss": round(loss, 4), "xla_loss": round(want_loss, 4),
+            "worst_gradient_gap": [worst, round(gaps[worst], 5)],
+            "kernel_paths": paths, "pairs": [r["pairs"] for r in stats]}
+
+
 # -- leg 5: four chips -------------------------------------------------------
 
 def placement(arrays: dict, devices) -> dict:
@@ -770,6 +866,9 @@ def main(argv=None) -> int:
         rep, out = leg("kernels", leg_kernels, sz, on_chip)
         rep["rows"] = out
         rep, out = leg("sparse_train", leg_sparse_train, sz, on_chip)
+        rep.update(out)
+        rep, out = leg("conv_sparse_train", leg_conv_sparse_train, sz,
+                       on_chip)
         rep.update(out)
 
         if len(devs) >= 4:

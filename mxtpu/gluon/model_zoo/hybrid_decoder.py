@@ -1,11 +1,15 @@
 """Decoder language models built from a list of layer kinds: a mixer kind, an
-MLP kind and a norm for each layer. Two published families are instances:
+MLP kind and a norm for each layer. Three published families are instances:
 decoder-hybrid-decoder models (SambaY; Phi-4-mini-flash-reasoning: Mamba-1
 state-space layers, differential attention over a window, over everything
-and across layers, gated memory units, no positional encoding of any kind)
-and sparse models with grouped-query window / full attention (K-EXAONE:
+and across layers, gated memory units, no positional encoding of any kind),
+sparse models with grouped-query window / full attention (K-EXAONE:
 ``attention="gqa"``, ``norm="rms"``, ``norm_position="post"``, an untied
-head, ``mlp_kinds`` with ``"moe"``).
+head, ``mlp_kinds`` with ``"moe"``) and sparse models whose mixers are
+gated short convolutions beside grouped-query attention (LFM2's
+``lfm2_moe``: kinds ``conv`` and ``attn_full``, ``attention="gqa"``,
+``norm="rms"``, pre-norm, a tied head with float32 logits, every expert
+held and no shared one).
 
 A model is a list of layer kinds and the widths; nothing here is a preset.
 Every layer is ``h = x + Mixer(N(x)); out = h + MLP(N'(h))``, or with
@@ -35,6 +39,12 @@ own (``tie_head=False``, float32 logits). The mixers, by kind:
     newest ``attn_full`` handed on; own lambdas, norm and ``W_o``.
 ``gmu``
     ``W_out (m * silu(W_in x))``: no scan, no convolution.
+``conv``
+    a gated short convolution: ``[B, C, u] = W_in x`` (three equal chunks
+    of ``units``, in that order, no bias); ``v = conv1d_causal(B * u)``
+    (depthwise, ``d_conv`` taps, no bias, zeros before the first row);
+    output ``W_out (C * v)``. Both gates are plain products: no activation
+    function anywhere in it.
 
 With ``attention="gqa"`` the kinds ``attn_window`` / ``attn_full`` are
 ``GroupedQueryAttention`` under the same child names: a fused ``qkv``
@@ -71,7 +81,7 @@ from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
 
 __all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS", "MLP_KINDS"]
 
-KINDS = ("mamba", "attn_window", "attn_full", "attn_cross", "gmu")
+KINDS = ("mamba", "attn_window", "attn_full", "attn_cross", "gmu", "conv")
 MLP_KINDS = ("mlp", "moe")
 
 
@@ -310,6 +320,37 @@ class GatedMemoryUnit(HybridBlock):
         return self.out_proj(memory * _silu(self.in_proj(x)))
 
 
+class ShortConv(HybridBlock):
+    """A gated short convolution (LFM2's mixer): ``W_out (C * conv(B *
+    u))`` with ``[B, C, u] = W_in x`` and a depthwise causal convolution of
+    ``width`` taps, no bias anywhere. The two products and the convolution
+    run under the scope ``gate`` (a device trace reads
+    ``block<i>/conv/in_proj|gate|out_proj``). There is no kernel for it:
+    the gate is three elementwise passes over ``(T, units)`` values (under
+    0.1 ms at 4096 x 2048 bf16 and 819 GB/s), ``width`` shifted copies that
+    XLA fuses, and fuses partly INTO the projections' matmuls, so time
+    under ``gate`` is not the gate's own; the benchmark's
+    ``conv_mixer_roofline_pct.train`` sets the whole mixer against its
+    roofline."""
+
+    def __init__(self, units: int, width: int, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._units = units
+        with self.name_scope():
+            self.in_proj = Dense(3 * units, use_bias=False, flatten=False,
+                                 in_units=units)
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(units, width), init="normal")
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  in_units=units)
+
+    def forward(self, x):
+        B, C, u = _split(self.in_proj(x), (self._units,) * 3)
+        with jax.named_scope("gate"):
+            y = C * nd.contrib.causal_conv1d(B * u, self.conv_weight.data())
+        return self.out_proj(y)
+
+
 class HybridDecoderBlock(HybridBlock):
     """One layer of ``kind``; its mixer is the child named by the kind and
     its MLP the child named by ``mlp_kind`` (``mlp`` or ``moe``), so a
@@ -337,6 +378,8 @@ class HybridDecoderBlock(HybridBlock):
                               z["dt_rank"])
             elif kind == "gmu":
                 mixer = GatedMemoryUnit(units, z["d_inner"])
+            elif kind == "conv":
+                mixer = ShortConv(units, z["d_conv"])
             elif z["attention"] == "gqa":
                 if kind == "attn_cross":
                     raise ValueError("grouped-query attention has no "
@@ -366,6 +409,8 @@ class HybridDecoderBlock(HybridBlock):
             mixed, shared["memory"] = mixer(h)
         elif self.kind == "gmu":
             mixed = mixer(h, shared.get("memory"))
+        elif self.kind == "conv":
+            mixed = mixer(h)
         elif self.kind == "attn_cross":
             mixed, _ = mixer(h, shared.get("kv"))
         else:
@@ -392,7 +437,9 @@ class HybridDecoderLM(HybridBlock):
 
     ``d_inner`` is the state-space layers' width (Mamba's ``expand *
     units``), shared by ``mamba`` and ``gmu`` since the one gates the
-    other's output; ``dt_rank`` defaults to ``ceil(units / 16)``.
+    other's output; ``dt_rank`` defaults to ``ceil(units / 16)``. ``d_conv``
+    is the taps of every depthwise causal convolution: Mamba's, and the
+    ``conv`` kind's (LFM2's ``conv_L_cache``).
 
     The layer spec beside ``layer_kinds``. ``attention="gqa"`` makes
     ``attn_window`` / ``attn_full`` plain grouped-query softmax attention
@@ -401,12 +448,19 @@ class HybridDecoderLM(HybridBlock):
     (base ``rope_theta``; the others get none). ``norm="rms"`` takes RMSNorm
     for LayerNorm; ``norm_position="post"`` puts each norm on its
     sub-layer's OUTPUT (``h = x + N(Mixer(x))``). ``tie_head=False`` gives
-    the head a matrix of its own (child ``head``) and float32 logits.
+    the head a matrix of its own (child ``head``) and float32 logits;
+    ``float32_logits=True`` widens the TIED head's logits too (the default
+    leaves them in the model's type).
     ``mlp_kinds`` names each layer's MLP, ``"mlp"`` (SwiGLU of
     ``ffn_units``) or ``"moe"`` (``parallel.moe.SparseExperts`` built from
     ``moe``, its keyword arguments after ``units``: ``ffn_units``,
     ``num_experts``, ``top_k``, ``held``, ``shared_ffn_units``,
-    ``routed_scale``, ``bias_update_rate``).
+    ``routed_scale``, ``bias_update_rate``, ``weight_eps``).
+
+    Three families are built from it (the module's docstring has their
+    specs): decoder-hybrid-decoder, sparse grouped-query window / full
+    attention, and gated short convolutions (``conv``) beside grouped-query
+    attention with expert layers that hold all their experts.
     """
 
     def __init__(self, vocab_size: int, layer_kinds, units: int,
@@ -417,7 +471,7 @@ class HybridDecoderLM(HybridBlock):
                  qk_norm: bool = False, rope_kinds=(), rope_theta: float = 1e4,
                  norm: str = "layer", norm_position: str = "pre",
                  tie_head: bool = True, mlp_kinds=None, moe=None,
-                 prefix=None, params=None):
+                 float32_logits: bool = False, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         for what, value, known in (
                 ("attention", attention, ("diff", "gqa")),
@@ -426,6 +480,7 @@ class HybridDecoderLM(HybridBlock):
             if value not in known:
                 raise ValueError(f"{what} {value!r}; one of {known}")
         self._vocab, self._units = vocab_size, units
+        self._float32_logits = float32_logits
         self.layer_kinds = tuple(layer_kinds)
         self.mlp_kinds = tuple(mlp_kinds or ("mlp",) * len(self.layer_kinds))
         if len(self.mlp_kinds) != len(self.layer_kinds):
@@ -470,17 +525,21 @@ class HybridDecoderLM(HybridBlock):
         with jax.named_scope("head"):
             w = self.embedding.weight.data()
             flat = nd.reshape(h, (B * T, self._units))
-            return nd.reshape(nd.dot(flat, w, transpose_b=True),
-                              (B, T, self._vocab))
+            logits = nd.reshape(nd.dot(flat, w, transpose_b=True),
+                                (B, T, self._vocab))
+            if self._float32_logits:    # for the untied head's reason
+                logits = registry.invoke(_AS_FLOAT32, logits)
+            return logits
 
     def _no_decode(self, what: str):
         raise NotImplementedError(
             f"HybridDecoderLM.{what}: this family trains only. Decoding "
             f"needs a cache that holds, side by side, a window of keys for "
             f"attn_window layers, one attn_full layer's keys for every "
-            f"attn_cross layer, and scan and convolution states for mamba "
-            f"layers; the engine has one cache geometry (ROADMAP D1/D2, "
-            f"M3, M6). Layer kinds: {self.layer_kinds}")
+            f"attn_cross layer, scan and convolution states for mamba "
+            f"layers, and a conv layer's last d_conv - 1 rows a channel; "
+            f"the engine has one cache geometry (ROADMAP D1/D2, M3, M6). "
+            f"Layer kinds: {self.layer_kinds}")
 
     def generate(self, *args, **kwargs):
         self._no_decode("generate")

@@ -346,14 +346,17 @@ def selective_scan(u, dt, A, B, C, D, log_A: bool = False):
 
 
 @register("causal_conv1d", namespace="contrib")
-def causal_conv1d(x, weight, bias):
+def causal_conv1d(x, weight, bias=None):
     """Depthwise causal convolution along ``T``: ``x`` ``(batch, T,
-    channels)``, ``weight`` ``(channels, width)``, ``bias`` ``(channels,)``;
-    ``y_t = bias + sum_k weight[:, k] * x_{t - (width - 1) + k}`` with zeros
-    before the first row. ``width`` shifted copies, which XLA fuses."""
+    channels)``, ``weight`` ``(channels, width)``, ``bias`` ``(channels,)``
+    or none (optional: Mamba's layers have one, a gated short convolution
+    has none); ``y_t = bias + sum_k weight[:, k] * x_{t - (width - 1) + k}``
+    with zeros before the first row. ``width`` shifted copies, which XLA
+    fuses."""
     T, width = x.shape[1], weight.shape[1]
     padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    y = bias.astype(x.dtype)
+    y = None if bias is None else bias.astype(x.dtype)
     for k in range(width):
-        y = y + padded[:, k:k + T] * weight[:, k].astype(x.dtype)
+        term = padded[:, k:k + T] * weight[:, k].astype(x.dtype)
+        y = term if y is None else y + term
     return y

@@ -70,11 +70,12 @@ def expert_rows(tokens: int, num_experts: int, top_k: int, held: int) -> int:
     return min(worst, -(-4 * even // 512) * 512)
 
 
-def _route(x, router_w, bias, top_k: int, scale: float):
+def _route(x, router_w, bias, top_k: int, scale: float, eps: float = 0.0):
     """``(chosen experts (T, k), their weights (T, k) float32)``: sigmoid
     scores in float32, the ``top_k`` largest of score + ``bias`` (which only
     selects and has no gradient), weights ``scale`` times the chosen scores
-    over their sum."""
+    over their sum (plus ``eps`` where a family's router adds one; 0 adds
+    no operation)."""
     z = lax.dot_general(x, router_w, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(z)
@@ -84,7 +85,9 @@ def _route(x, router_w, bias, top_k: int, scale: float):
     # take_along_axis is a scalar gather and, backward, a scalar scatter-add
     picked = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(s.shape[1]),
                                s[:, None, :], 0.0), axis=-1)
-    return chosen, scale * picked / jnp.sum(picked, axis=1, keepdims=True)
+    weighted = scale * picked
+    total = jnp.sum(picked, axis=1, keepdims=True)
+    return chosen, weighted / (total + eps if eps else total)
 
 
 _ROW_TILE = 512     # rows a turn of the row loops moves
@@ -308,22 +311,23 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 @registry.register("sparse_experts", namespace="contrib", num_outputs=2)
 def sparse_experts(h, router_w, bias, w_gate_up, w_down, held=(),
-                   top_k: int = 1, scale: float = 1.0, rows: int = 0):
+                   top_k: int = 1, scale: float = 1.0, rows: int = 0,
+                   weight_eps: float = 0.0):
     """The held experts' part of a sparse expert layer. ``h``: ``(..., d)``;
     ``router_w``: ``(E, d)``; ``bias``: ``(E,)``, added to the scores for the
     choice alone; ``w_gate_up``: ``(len(held), d, 2 f)``, ``w_down``:
     ``(len(held), f, d)``, the experts ``held`` (ids among the ``E``) in
     that order. Returns ``(y like h, count (E,) float32)``: ``y[t] = sum
     over the chosen e that are held of w_e(t) down_e(up_e h * silu(gate_e
-    h))`` with ``w_e = scale * s_e / sum of the chosen s``, and the tokens
-    that chose each of the ``E`` experts, held or not (``count[held]`` are
-    the rows each held expert got). ``rows``: the static row buffer of a
-    pass (0: ``expert_rows``)."""
+    h))`` with ``w_e = scale * s_e / (sum of the chosen s + weight_eps)``,
+    and the tokens that chose each of the ``E`` experts, held or not
+    (``count[held]`` are the rows each held expert got). ``rows``: the
+    static row buffer of a pass (0: ``expert_rows``)."""
     d, n_held = h.shape[-1], len(held)
     x = h.reshape(-1, d)
     T, E = x.shape[0], router_w.shape[0]
     with jax.named_scope("route"):
-        chosen, weights = _route(x, router_w, bias, top_k, scale)
+        chosen, weights = _route(x, router_w, bias, top_k, scale, weight_eps)
         count = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(E), axis=0,
                         dtype=jnp.int32)
     with jax.named_scope("dispatch"):
@@ -351,8 +355,9 @@ class SparseExperts(HybridBlock):
     """``top_k`` of ``num_experts`` SwiGLU experts a token, of which this
     chip holds ``held`` (a list of distinct expert ids; default all).
     Sigmoid scores in float32, the choice by score + ``select_bias``,
-    weights ``routed_scale`` times the chosen scores over their sum, no
-    token dropped for any routing. ``shared_ffn_units`` > 0 adds a shared
+    weights ``routed_scale`` times the chosen scores over their sum (plus
+    ``weight_eps``, 0 unless the family's router adds one), no token dropped
+    for any routing. ``shared_ffn_units`` > 0 adds a shared
     expert (child ``shared``, a SwiGLU of that width every token goes
     through, added unweighted): every chip of a deployment computes it
     alike, so the shares' sum counts it once.
@@ -395,7 +400,7 @@ class SparseExperts(HybridBlock):
     def __init__(self, units: int, ffn_units: int, num_experts: int,
                  top_k: int, held=None, shared_ffn_units: int = 0,
                  routed_scale: float = 1.0, bias_update_rate: float = 0.0,
-                 prefix=None, params=None):
+                 weight_eps: float = 0.0, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if bias_update_rate < 0:
             raise ValueError(f"bias_update_rate {bias_update_rate} < 0")
@@ -410,6 +415,7 @@ class SparseExperts(HybridBlock):
         self._top_k, self._experts = top_k, num_experts
         self._scale, self._bias_rate = float(routed_scale), \
             float(bias_update_rate)
+        self._weight_eps = float(weight_eps)
         self._rows = None       # the buffer's rows, once a forward has run
         with self.name_scope():
             self.router = self.params.get(
@@ -436,7 +442,8 @@ class SparseExperts(HybridBlock):
         y, count = registry.invoke(
             _SPARSE_EXPERTS, x, self.router.data(), bias,
             self.gate_up.data(), self.down.data(), held=self.held,
-            top_k=self._top_k, scale=self._scale, rows=self._rows)
+            top_k=self._top_k, scale=self._scale, rows=self._rows,
+            weight_eps=self._weight_eps)
         with jax.named_scope("balance"):
             if self._bias_rate and autograd.is_training():
                 even = tokens * self._top_k / self._experts
@@ -449,7 +456,10 @@ class SparseExperts(HybridBlock):
         """Of the newest forward (zeros before the first): the (token,
         expert) ``pairs`` the held experts got, how many of the ``held``
         were ``active`` (got a row), the ``max_count`` and ``min_count`` of
-        tokens that chose any one of ALL the experts, the ``buffer_rows`` of
+        tokens that chose any one of ALL the experts, ``load_max`` (the
+        busiest HELD expert's rows over an expert's even share ``tokens *
+        top_k / num_experts``: the straggler among the groups of the grouped
+        products, 1 under an even routing), the ``buffer_rows`` of
         a pass, the ``passes`` the pairs took and the ``rows_moved``: the
         rows of the buffer that the dispatch filled and the combine read
         back, which are the pairs in whole tiles of the row loops and reach
@@ -458,12 +468,14 @@ class SparseExperts(HybridBlock):
         ask between steps, not inside a timed loop."""
         count = self.count.data().asnumpy()
         load = count[list(self.held)]
-        pairs = float(load.sum())
+        pairs, even = float(load.sum()), float(count.sum()) / count.size
         tile = self._rows and _row_tile(self._rows)
         return {"name": self.name, "held": len(self.held), "pairs": pairs,
                 "active": int((load > 0).sum()),
                 "max_count": float(count.max()),
-                "min_count": float(count.min()), "buffer_rows": self._rows,
+                "min_count": float(count.min()),
+                "load_max": float(load.max()) / even if even else 0.0,
+                "buffer_rows": self._rows,
                 "passes": self._rows and max(1, -(-int(pairs) // self._rows)),
                 "rows_moved": tile and -(-int(pairs) // tile) * tile}
 

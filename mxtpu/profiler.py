@@ -259,7 +259,8 @@ def get_moe_stats(block) -> list:
     them: of its newest forward the (token, expert) ``pairs`` its held
     experts got, how many of the ``held`` were ``active``, the
     ``max_count`` and ``min_count`` of tokens that chose any one of all the
-    experts, the ``buffer_rows`` of a pass, the ``passes`` the pairs took
+    experts, ``load_max`` (the busiest held expert's rows over an expert's
+    even share), the ``buffer_rows`` of a pass, the ``passes`` the pairs took
     and the ``rows_moved`` (the buffer rows the dispatch filled and the
     combine read, which follow the pairs). The numbers are a state of the layer (``count``, one float an
     expert) that rides the compiled step, so there is nothing to reset;

@@ -61,3 +61,38 @@ def subprocess_env(virtual_devices: int = 0):
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     return env
+
+
+@pytest.fixture
+def step_jaxpr_hash():
+    """``hash(net, system, x, y)``, as a fixture so that it is found by
+    directory (another suite's ``conftest`` may be the imported one)."""
+    return _step_jaxpr_hash
+
+
+def _step_jaxpr_hash(net, system, x, y) -> str:
+    """The first 16 hex digits of the hash of the printed jaxpr of loss and
+    gradient of a ``HybridDecoderLM`` ``net`` (a benchmark ``system`` module's)
+    on tokens ``x`` and targets ``y``: a family's step pinned to the program
+    it traced to before the layer spec grew for another family."""
+    import hashlib
+    import jax.numpy as jnp
+    from mxtpu import autograd, nd
+    handles = [p for p, _ in system.param_leaves(net)]
+    saved = [p._data._data for p in handles]
+
+    def loss_of(ps):
+        try:
+            for p, v in zip(handles, ps):
+                p._data._data = v
+            with autograd.pause(train_mode=True):
+                out = net(nd.NDArray(jnp.asarray(x)))
+                loss = system.system.seq_loss(
+                    out, nd.NDArray(jnp.asarray(y, jnp.float32)))
+            return jnp.mean(loss.data)
+        finally:
+            for p, v in zip(handles, saved):
+                p._data._data = v
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss_of))(saved))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]

@@ -246,25 +246,7 @@ def test_import_mxtpu_imports_none_of_the_family():
 PHI4_STEP = "cb95fae9884848c5"
 
 
-def test_phi4_step_traces_to_the_same_jaxpr(ref, system, weights, batch):
-    import hashlib
-    x, y = batch
+def test_phi4_step_traces_to_the_same_jaxpr(ref, system, weights, batch,
+                                            step_jaxpr_hash):
     net = system.build_net(CFG, weights, "float32")
-    handles = [p for p, _ in system.param_leaves(net)]
-    saved = [p._data._data for p in handles]
-
-    def loss_of(ps):
-        try:
-            for p, v in zip(handles, ps):
-                p._data._data = v
-            with autograd.pause(train_mode=True):
-                out = net(nd.NDArray(jnp.asarray(x)))
-                loss = system.system.seq_loss(
-                    out, nd.NDArray(jnp.asarray(y, jnp.float32)))
-            return jnp.mean(loss.data)
-        finally:
-            for p, v in zip(handles, saved):
-                p._data._data = v
-
-    text = str(jax.make_jaxpr(jax.value_and_grad(loss_of))(saved))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PHI4_STEP
+    assert step_jaxpr_hash(net, system, *batch) == PHI4_STEP

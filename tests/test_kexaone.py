@@ -342,3 +342,18 @@ def test_decoding_and_bad_specs_raise(system, weights):
     with pytest.raises(ValueError, match="norm_position"):
         HybridDecoderLM(32, ["attn_full"], 64, 128, 4, 2,
                         norm_position="sandwich")
+
+
+# The conv / all-held family (tests/test_lfm2.py) shares the block, the stack
+# and the expert layer with this one: this family's step has to trace to the
+# program it traced to before the layer spec grew a mixer kind, float32
+# logits for a tied head and the router's ``weight_eps`` (hash of the printed
+# jaxpr of loss and gradient at CFG, taken on the parent tree, commit
+# 8e34eb5).
+KEXAONE_STEP = "a2a6f78a7040f5b2"
+
+
+def test_kexaone_step_traces_to_the_same_jaxpr(ref, system, weights, batch,
+                                               step_jaxpr_hash):
+    net = system.build_net(CFG, weights, "float32")
+    assert step_jaxpr_hash(net, system, *batch) == KEXAONE_STEP

@@ -123,3 +123,39 @@ def test_grouped_matmul_kernels_compile_at_the_sparse_cells_size(
 
     text = jax.jit(all_three).lower(x, w, gs, dy).compile().as_text()
     assert "moe_gmm" in text and "moe_tgmm" in text
+
+
+def test_flash_kernels_compile_at_the_conv_cells_size(one_chip, quiet_cache):
+    """32 query heads of 64 on 8 key/value heads, T = 4096, causal over
+    everything: the attention layer of ``lfm2moe_train_t4096``."""
+    from mxtpu.ops import attention as A
+    bf = jnp.bfloat16
+    q, k, v, g = _avals(one_chip, ((1, 32, 4096, 64), bf),
+                        ((1, 8, 4096, 64), bf), ((1, 8, 4096, 64), bf),
+                        ((1, 32, 4096, 64), bf))
+
+    def both(q, k, v, g):
+        out, lse = A._flash_attention_pallas(q, k, v, True, 0.125)
+        return A._flash_backward_pallas(q, k, v, out, lse, g, True, 0.125)
+
+    text = jax.jit(both).lower(q, k, v, g).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+
+
+@pytest.mark.parametrize("K,N", [(2048, 3584), (1792, 2048)])
+def test_grouped_matmul_kernels_compile_at_the_conv_cells_size(
+        one_chip, quiet_cache, K, N):
+    """16384 buffer rows (every pair of 4096 tokens x 4) over all 32 experts:
+    gate/up (2048 -> 2 x 1792) and down (1792 -> 2048), widths in tiles of
+    1792, forward, dx and the per-group dw."""
+    from mxtpu.ops import grouped_matmul as G
+    bf = jnp.bfloat16
+    x, w, gs, dy = _avals(one_chip, ((16384, K), bf), ((32, K, N), bf),
+                          ((32,), jnp.int32), ((16384, N), bf))
+
+    def all_three(x, w, gs, dy):
+        return (G._gmm_pallas(x, w, gs), G._gmm_pallas(dy, w, gs, True),
+                G._tgmm_pallas(x, dy, gs))
+
+    text = jax.jit(all_three).lower(x, w, gs, dy).compile().as_text()
+    assert "moe_gmm" in text and "moe_tgmm" in text
