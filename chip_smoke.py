@@ -702,8 +702,10 @@ def leg_conv_sparse_train(sz, on_chip: bool) -> dict:
     paths = profiler.get_kernel_path_counts()
     with xla_formulations():
         want_loss, want, _ = one_step()
+    # every expert held: each token's rows summed by gathers, none added
     check(all(r["pairs"] == cs["T"] * cs["top_k"] and r["passes"] == 1
-              and r["held"] == cs["experts"] for r in stats)
+              and r["held"] == cs["experts"] and r["rows_added"] == 0
+              for r in stats)
           and len(stats) == 2, f"conv sparse train: {stats}")
     tol = 3e-2 if on_chip else 1e-4     # bfloat16 against bfloat16 / float32
     check(abs(loss - want_loss) <= tol * want_loss,

@@ -99,20 +99,27 @@ def _row_tile(rows: int) -> int:
     return math.gcd(rows, _ROW_TILE)
 
 
-def _pair_index(key, weights, n_held: int, top_k: int, worst: int):
+def _pair_index(key, weights, n_held: int, top_k: int, worst: int,
+                all_held: bool):
     """Where a step's (token, expert) pairs go and come from, int32 but
     for the weights, from ``key`` ``(T * k,)``: pair ``t * k + j``'s held
-    slot, ``n_held`` for an absent expert's. ``order`` ``(worst,)``: the
-    pair ids sorted by slot, a slot's by token, absent ones last, and
-    ``w_sorted`` their ``weights`` (T, k) in that order, which carry no
-    gradient (``_held_experts`` writes the weights' own). ``place``
-    ``(T,)``: the sorted position of each token's FIRST held pair, -1 where
-    it has none, and ``first`` ``(T, k)`` bool: which of its choices that
-    pair is. ``more_at``, ``more_pair``: the sorted positions and the ids
-    of every FURTHER held pair of a token, ascending by position; past the
-    last of them ``T * k`` and 0, ``_ROW_TILE`` more than there can be.
-    Sorts and dense selections alone: a scalar gather or scatter costs the
-    chip more a number than a sort does."""
+    slot, ``n_held`` for an absent expert's. ``(order, w_sorted, sums)``.
+    ``order`` ``(worst,)``: the pair ids sorted by slot, a slot's by token,
+    absent ones last, and ``w_sorted`` their ``weights`` (T, k) in that
+    order, which carry no gradient (``_held_experts`` writes the weights'
+    own). ``sums``: what ``_sum_rows`` finds a token's rows by, made of
+    ``at`` ``(T, k)``, every pair's sorted position (the sort's inverse).
+    ``all_held`` (the layer holds every expert, so every pair is held and a
+    token has exactly ``top_k``): ``(at,)`` and nothing else. Otherwise
+    nobody knows which tokens have a pair, so ``(place, first, more_at,
+    more_pair)``. ``place`` ``(T,)``: the sorted position of each token's
+    FIRST held pair, -1 where it has none, and ``first`` ``(T, k)`` bool:
+    which of its choices that pair is. ``more_at``, ``more_pair``: the
+    sorted positions and the ids of every FURTHER held pair of a token,
+    ascending by position; past the last of them ``T * k`` and 0,
+    ``_ROW_TILE`` more than there can be. Sorts and dense selections alone:
+    a scalar gather or scatter costs the chip more a number than a sort
+    does."""
     pairs = key.shape[0]
     T = pairs // top_k
     _, by_slot, w_sorted = lax.sort(
@@ -120,6 +127,9 @@ def _pair_index(key, weights, n_held: int, top_k: int, worst: int):
          lax.stop_gradient(weights).reshape(-1)), num_keys=1, is_stable=True)
     room = (0, max(0, worst - pairs))
     at = jnp.argsort(by_slot).astype(jnp.int32).reshape(T, top_k)
+    if all_held:
+        return (jnp.pad(by_slot[:worst], room),
+                jnp.pad(w_sorted[:worst], room), (at,))
     held = (key < n_held).reshape(T, top_k)
     first = held & (jnp.cumsum(held, axis=1) == 1)
     place = jnp.where(jnp.any(first, axis=1),
@@ -129,9 +139,9 @@ def _pair_index(key, weights, n_held: int, top_k: int, worst: int):
          jnp.arange(pairs, dtype=jnp.int32)), num_keys=1)
     most = T * (min(top_k, n_held) - 1)
     return (jnp.pad(by_slot[:worst], room), jnp.pad(w_sorted[:worst], room),
-            place, first,
-            jnp.pad(more_at[:most], (0, _ROW_TILE), constant_values=pairs),
-            jnp.pad(more_pair[:most], (0, _ROW_TILE)))
+            (place, first,
+             jnp.pad(more_at[:most], (0, _ROW_TILE), constant_values=pairs),
+             jnp.pad(more_pair[:most], (0, _ROW_TILE))))
 
 
 def _cleared(shape, dtype, count):
@@ -167,35 +177,68 @@ def _take_rows(src, token, tiles, tile: int, into, weight=None,
     return lax.fori_loop(0, tiles, turn, (into, dots))
 
 
-def _sum_rows(acc, src, lo, place, w_first, more_at, more_pair, top_k: int,
-              w_pair=None):
-    """``acc`` (None: nothing yet) plus, for every token, the sum of the
-    rows of ``src`` that its pairs have in this pass, ``src`` being rows
-    ``lo ..`` of the sorted pairs; float32 ``(T, d)``. A token's first pair
-    comes by a gather over the tokens (times ``w_first``, taken as zero for
-    a token whose first pair is not in this pass), which writes every
-    token's row, so there is no buffer of zeros to start from; only its
-    further pairs (times ``w_pair[pair]``, or 1) are added with repeated
-    indices, ``_ROW_TILE`` a turn for as many as this pass has."""
-    n = src.shape[0]
-    here = (place >= lo) & (place < lo + n)
-    rows = src[jnp.clip(place - lo, 0, n - 1)].astype(jnp.float32) \
-        * jnp.where(here, w_first, 0.0)[:, None]
-    acc = rows if acc is None else acc + rows
-    if more_at.shape[0] == _ROW_TILE:       # a token has one pair at most
-        return acc
-    a, b = jnp.sum(more_at < lo), jnp.sum(more_at < lo + n)
+def _sum_rows(sums, top_k: int, weights=None):
+    """``add(acc, src, lo)`` for a step's ``sums`` (``_pair_index``'s):
+    ``acc`` (None: nothing yet) plus, for every token, the sum of the rows
+    of ``src`` that its pairs have in this pass, each times its weight in
+    ``weights`` ``(T, k)`` (None: 1), ``src`` being rows ``lo ..`` of the
+    sorted pairs; float32 ``(T, d)``. One algorithm, a float32 sum of each
+    token's held rows by sorted position, in the two forms the layer's
+    static shape allows. Where every expert is held a token's pairs are
+    known: choice ``j``'s row comes by a gather over the tokens from
+    ``at[:, j]`` (weight zero for a pair outside this pass), ``top_k``
+    gathers that each write every token's row, so nothing starts from a
+    buffer of zeros and nothing is added with repeated indices. Otherwise
+    only a token's FIRST pair comes by such a gather (its weight taken as
+    zero where that pair is not in this pass or the token has none) and
+    its further pairs are added with repeated indices, ``_ROW_TILE`` a turn
+    for as many as this pass has: at a fraction of a pair a token ``top_k``
+    gathers would move mostly rows that carry nothing (PR 32 measured it
+    slower than the whole-buffer add), and at ``top_k`` pairs a token the
+    further pairs are three quarters of all rows, each four to eleven
+    times the cost of a gathered one (PR 33)."""
 
-    def turn(i, acc):
-        at = a + i * _ROW_TILE
-        pair = lax.dynamic_slice(more_pair, (at,), (_ROW_TILE,))
-        row = lax.dynamic_slice(more_at, (at,), (_ROW_TILE,)) - lo
-        w = jnp.where(at + jnp.arange(_ROW_TILE) < b,
-                      1.0 if w_pair is None else w_pair[pair], 0.0)
-        part = src[jnp.clip(row, 0, n - 1)].astype(jnp.float32) * w[:, None]
-        return acc.at[pair // top_k].add(part)
+    def gathered(acc, src, lo, at, w):
+        n = src.shape[0]
+        here = (at >= lo) & (at < lo + n)
+        rows = src[jnp.clip(at - lo, 0, n - 1)].astype(jnp.float32) \
+            * jnp.where(here, w, 0.0)[:, None]
+        return rows if acc is None else acc + rows
 
-    return lax.fori_loop(0, -(-(b - a) // _ROW_TILE), turn, acc)
+    if len(sums) == 1:                      # every expert is held
+        at, = sums
+
+        def add(acc, src, lo):
+            for j in range(top_k):
+                acc = gathered(acc, src, lo, at[:, j],
+                               1.0 if weights is None else weights[:, j])
+            return acc
+        return add
+
+    place, first, more_at, more_pair = sums
+    w_first = jnp.any(first, axis=1).astype(jnp.float32) if weights is None \
+        else jnp.sum(jnp.where(first, weights, 0.0), axis=1)
+
+    def add(acc, src, lo):
+        w_pair = None if weights is None else weights.reshape(-1)
+        n = src.shape[0]
+        acc = gathered(acc, src, lo, place, w_first)
+        if more_at.shape[0] == _ROW_TILE:   # a token has one pair at most
+            return acc
+        a, b = jnp.sum(more_at < lo), jnp.sum(more_at < lo + n)
+
+        def turn(i, acc):
+            at = a + i * _ROW_TILE
+            pair = lax.dynamic_slice(more_pair, (at,), (_ROW_TILE,))
+            row = lax.dynamic_slice(more_at, (at,), (_ROW_TILE,)) - lo
+            w = jnp.where(at + jnp.arange(_ROW_TILE) < b,
+                          1.0 if w_pair is None else w_pair[pair], 0.0)
+            part = src[jnp.clip(row, 0, n - 1)].astype(jnp.float32) \
+                * w[:, None]
+            return acc.at[pair // top_k].add(part)
+
+        return lax.fori_loop(0, -(-(b - a) // _ROW_TILE), turn, acc)
+    return add
 
 
 def _pass_rows(order, w_sorted, starts, ends, p, rows: int, top_k: int):
@@ -214,6 +257,19 @@ def _pass_rows(order, w_sorted, starts, ends, p, rows: int, top_k: int):
                 -(-live // _row_tile(rows)))
 
 
+def _one_pass(index, rows: int) -> bool:
+    """Whether a step's pairs take ONE pass whatever its routing: a layer
+    that holds every expert has exactly ``T * top_k`` pairs, and its buffer
+    holds them all (as ``expert_rows`` makes it). The loop over further
+    passes is not traced there: one that never turns still cost every
+    step of ``lfm2moe_train_t4096`` 1.3% (its carries are copied, and the
+    row buffers that pass through them are filled more slowly) and traced
+    and compiled the pass a second time. A share's pairs follow the
+    routing, and its loop stays."""
+    order, _, sums, _, _ = index
+    return len(sums) == 1 and order.shape[0] == rows
+
+
 def _experts(xs, w_gate_up, w_down, sizes):
     with jax.named_scope("experts"):
         ffn = w_down.shape[1]
@@ -227,24 +283,25 @@ def _held_experts(x, weights, w_gate_up, w_down, index, rows: int,
                   top_k: int):
     """Every pass the step's pairs need, added up: one, unless the routing
     sends more than ``rows`` pairs to the held experts. ``index``:
-    ``_pair_index``'s six and the held experts' ``starts`` and ``ends``
+    ``_pair_index``'s three and the held experts' ``starts`` and ``ends``
     among the sorted pairs. A pass gathers its pairs' tokens into the row
     buffer a live tile a turn, multiplies them by their experts and sums
-    each token's weighted rows in float32 (``_sum_rows``): rows past the
-    last live tile are neither gathered nor read back. Every loop's length
+    each token's weighted rows in float32 (``_sum_rows``, in the form the
+    index was made for: gathers alone where every expert is held, a gather
+    and a tiled add of the further pairs otherwise): rows past the last
+    live tile are neither gathered nor read back. Every loop's length
     is a traced number, so nothing is dropped and nothing is moved for
     pairs that are not there. The backward runs the same passes again, each
     from its inputs, with each movement's transpose written out (the
     tokens' gradients gathered into the buffer a live tile a turn, the
     rows' gradients summed onto their tokens as the forward sums the
-    rows), so no row buffer outlives its pass: the layer keeps its input
-    and the routing between forward and backward and nothing as wide as
-    (pairs, ffn). Returns ``(T, d)`` float32."""
-    (order, w_sorted, place, first, more_at, more_pair, starts,
-     ends) = index
+    rows, weights of 1), so no row buffer outlives its pass: the layer
+    keeps its input and the routing between forward and backward and
+    nothing as wide as (pairs, ffn). Returns ``(T, d)`` float32."""
+    order, w_sorted, sums, starts, ends = index
     tile = _row_tile(rows)
     with jax.named_scope("combine"):
-        w_first = jnp.sum(jnp.where(first, weights, 0.0), axis=1)
+        add_rows = _sum_rows(sums, top_k, weights)
 
     def one(p, y):
         _, token, _, sizes, tiles = _pass_rows(
@@ -254,9 +311,10 @@ def _held_experts(x, weights, w_gate_up, w_down, index, rows: int,
                                _cleared((rows, x.shape[1]), x.dtype, tiles))
         out = _experts(xs, w_gate_up, w_down, sizes)
         with jax.named_scope("combine"):
-            return _sum_rows(y, out, p * rows, place, w_first, more_at,
-                             more_pair, top_k, weights.reshape(-1))
+            return add_rows(y, out, p * rows)
 
+    if _one_pass(index, rows):
+        return one(0, None)
     return lax.fori_loop(1, -(-ends[-1] // rows), one, one(0, None))
 
 
@@ -267,11 +325,10 @@ def _held_experts_fwd(x, weights, w_gate_up, w_down, index, rows, top_k):
 
 def _held_experts_bwd(rows, top_k, res, dy):
     x, weights, w_gate_up, w_down, index = res
-    (order, w_sorted, place, first, more_at, more_pair, starts,
-     ends) = index
+    order, w_sorted, sums, starts, ends = index
     tile = _row_tile(rows)
     with jax.named_scope("dispatch"):
-        has_pair = jnp.any(first, axis=1).astype(jnp.float32)
+        add_rows = _sum_rows(sums, top_k)
 
     def one(p, dx, dweights):
         pairs, token, w_row, sizes, tiles = _pass_rows(
@@ -289,8 +346,7 @@ def _held_experts_bwd(rows, top_k, res, dy):
             dweights = dweights.at[pairs].add(dw_row)
         dxs, d_gate_up, d_down = experts_vjp(d_out)
         with jax.named_scope("dispatch"):
-            dx = _sum_rows(dx, dxs, p * rows, place, has_pair, more_at,
-                           more_pair, top_k)
+            dx = add_rows(dx, dxs, p * rows)
         return dx, dweights, d_gate_up, d_down
 
     def more(p, grads):
@@ -299,8 +355,11 @@ def _held_experts_bwd(rows, top_k, res, dy):
 
     with jax.named_scope("combine"):
         dweights = jnp.zeros((weights.size,), jnp.float32)
-    dx, dweights, d_gate_up, d_down = lax.fori_loop(
-        1, -(-ends[-1] // rows), more, one(0, None, dweights))
+    if _one_pass(index, rows):
+        dx, dweights, d_gate_up, d_down = one(0, None, dweights)
+    else:
+        dx, dweights, d_gate_up, d_down = lax.fori_loop(
+            1, -(-ends[-1] // rows), more, one(0, None, dweights))
     with jax.named_scope("dispatch"):
         dx = dx.astype(x.dtype)
     return (dx, dweights.reshape(weights.shape), d_gate_up, d_down, None)
@@ -341,8 +400,8 @@ def sparse_experts(h, router_w, bias, w_gate_up, w_down, held=(),
         worst = -(-T * min(top_k, n_held) // rows) * rows
         load = count[np.asarray(held, np.int32)]
         ends = jnp.cumsum(load)
-        index = (*_pair_index(key, weights, n_held, top_k, worst),
-                 ends - load, ends)
+        index = (*_pair_index(key, weights, n_held, top_k, worst,
+                              n_held == E), ends - load, ends)
     y = _held_experts(x, weights, w_gate_up, w_down, index, rows, top_k)
     return (y.astype(h.dtype).reshape(h.shape),
             lax.stop_gradient(count.astype(jnp.float32)))
@@ -385,16 +444,29 @@ class SparseExperts(HybridBlock):
     here than that runs more passes, so memory is the buffer's and time the
     pairs'. What follows the pairs: the dispatch (tokens' rows gathered into
     the buffer, a row tile that holds a pair a turn), the grouped products,
-    the combine (each token's weighted rows summed in float32: its first
-    pair's row by one gather over the tokens, its further pairs' rows added
-    with repeated indices, a tile of them a turn) and the transposes of
-    both (the tokens' gradients gathered into the buffer by live tiles; the
-    rows' gradients summed onto their tokens as the combine sums rows): no
-    row of the buffer past the last live tile is written or read
-    (``stats()["rows_moved"]``), and no sum starts from a buffer of zeros.
-    What still runs over the whole buffer: the SwiGLU between the two
-    products, the clearing of the buffer before the gather, and a weight
-    and a token id a row. Scopes in a device trace: ``route``,
+    the combine (each token's weighted rows summed in float32) and the
+    transposes of both (the tokens' gradients gathered into the buffer by
+    live tiles; the rows' gradients summed onto their tokens as the combine
+    sums rows): no row of the buffer past the last live tile is written or
+    read (``stats()["rows_moved"]``), and no sum starts from a buffer of
+    zeros. What still runs over the whole buffer: the SwiGLU between the
+    two products, the clearing of the buffer before the gather, and a
+    weight and a token id a row.
+
+    The sum of a token's rows takes one of two forms, chosen by the
+    layer's own static shape and by nothing else. A layer that holds EVERY
+    expert (``len(held) == num_experts``) knows each token's pairs: exactly
+    ``top_k``, choice ``j``'s at a sorted position the dispatch's sort
+    already gives, so it sums them by ``top_k`` gathers over the tokens and
+    adds nothing with repeated indices (``stats()["rows_added"]`` 0); three
+    quarters of its rows would otherwise be further pairs, each several
+    times the cost of a gathered row. A layer that holds a SHARE does not
+    know which tokens have a pair (a fraction of one a token at 8 of 128):
+    a token's first held pair comes by one gather over the tokens and only
+    its further pairs are added with repeated indices, a tile of them a
+    turn, where ``top_k`` gathers would move mostly rows that carry
+    nothing. There is no threshold between the two: a layer one expert
+    short of all takes the second. Scopes in a device trace: ``route``,
     ``dispatch``, ``experts``, ``combine``, ``shared``, ``balance``."""
 
     def __init__(self, units: int, ffn_units: int, num_experts: int,
@@ -460,16 +532,27 @@ class SparseExperts(HybridBlock):
         busiest HELD expert's rows over an expert's even share ``tokens *
         top_k / num_experts``: the straggler among the groups of the grouped
         products, 1 under an even routing), the ``buffer_rows`` of
-        a pass, the ``passes`` the pairs took and the ``rows_moved``: the
+        a pass, the ``passes`` the pairs took, the ``rows_moved``: the
         rows of the buffer that the dispatch filled and the combine read
         back, which are the pairs in whole tiles of the row loops and reach
-        ``buffer_rows`` times ``passes`` only where the pairs do (all three
-        None before the first forward). Reads ``count`` from the device:
-        ask between steps, not inside a timed loop."""
+        ``buffer_rows`` times ``passes`` only where the pairs do, and the
+        ``rows_added``: the most of those rows that were summed onto their
+        tokens with repeated indices, in the combine and again in the
+        dispatch's transpose. Those are the FURTHER pairs of tokens that
+        hold several: the pairs less the busiest held expert's (a token
+        chooses an expert once, so at least that many tokens hold a first
+        pair; ``count`` does not say which tokens hold one, so between
+        steps only this bound is known), 0 where a token holds one pair at
+        most, and 0 where every expert is held, whose layer sums by
+        gathers alone (all four None before the first forward). Reads
+        ``count`` from the device: ask between steps, not inside a timed
+        loop."""
         count = self.count.data().asnumpy()
         load = count[list(self.held)]
         pairs, even = float(load.sum()), float(count.sum()) / count.size
         tile = self._rows and _row_tile(self._rows)
+        by_gathers = len(self.held) == self._experts \
+            or min(self._top_k, len(self.held)) == 1
         return {"name": self.name, "held": len(self.held), "pairs": pairs,
                 "active": int((load > 0).sum()),
                 "max_count": float(count.max()),
@@ -477,7 +560,9 @@ class SparseExperts(HybridBlock):
                 "load_max": float(load.max()) / even if even else 0.0,
                 "buffer_rows": self._rows,
                 "passes": self._rows and max(1, -(-int(pairs) // self._rows)),
-                "rows_moved": tile and -(-int(pairs) // tile) * tile}
+                "rows_moved": tile and -(-int(pairs) // tile) * tile,
+                "rows_added": tile and (
+                    0 if by_gathers else int(pairs - load.max()))}
 
 
 # ---------------------------------------------------------------------------

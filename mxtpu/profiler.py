@@ -260,9 +260,11 @@ def get_moe_stats(block) -> list:
     experts got, how many of the ``held`` were ``active``, the
     ``max_count`` and ``min_count`` of tokens that chose any one of all the
     experts, ``load_max`` (the busiest held expert's rows over an expert's
-    even share), the ``buffer_rows`` of a pass, the ``passes`` the pairs took
-    and the ``rows_moved`` (the buffer rows the dispatch filled and the
-    combine read, which follow the pairs). The numbers are a state of the layer (``count``, one float an
+    even share), the ``buffer_rows`` of a pass, the ``passes`` the pairs took,
+    the ``rows_moved`` (the buffer rows the dispatch filled and the
+    combine read, which follow the pairs) and the ``rows_added`` (the most of
+    them that were added onto their tokens with repeated indices: 0 for a
+    layer that holds every expert, which sums by gathers). The numbers are a state of the layer (``count``, one float an
     expert) that rides the compiled step, so there is nothing to reset;
     reading them fetches it from the device: ask between steps."""
     from .parallel.moe import SparseExperts

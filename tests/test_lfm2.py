@@ -315,6 +315,7 @@ def test_all_held_layer_without_a_shared_expert_is_the_dense_definition(
     stats = blk.stats()
     assert stats["pairs"] == 48 * k == float(count.sum())
     assert stats["passes"] == 1 and stats["active"] <= E
+    assert stats["rows_added"] == 0 and stats["rows_moved"] == 48 * k
     assert stats["load_max"] == float(count.max()) / (48 * k / E) > 1
     assert stats["buffer_rows"] == expert_rows(48, E, k, E) == 48 * k
     np.testing.assert_array_equal(blk.count.data().asnumpy(),

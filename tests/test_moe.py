@@ -309,9 +309,10 @@ def test_sparse_block_balances_the_load_through_the_bias(rate):
 
 # ---------------------------------------------------------------------------
 # the dispatch / combine pair: rows gathered into the buffer a live tile at
-# a time, each token's rows summed by a gather of its first pair and a tiled
-# add of its further ones, both directions' transposes written out; against
-# the whole-buffer gather and ``.at[].add`` they replaced
+# a time, each token's rows summed by gathers over its ``top_k`` choices
+# where every expert is held, by a gather of its first pair and a tiled add
+# of its further ones otherwise, both directions' transposes written out;
+# against the whole-buffer gather and ``.at[].add`` they replaced
 # ---------------------------------------------------------------------------
 
 
@@ -341,7 +342,8 @@ def _pairs_setup(case, T=40, k=2, E=8, d=16, f=24, seed=0):
         gate_up=jnp.asarray(rs.randn(len(held), d, 2 * f), jnp.float32) * 0.2,
         down=jnp.asarray(rs.randn(len(held), f, d), jnp.float32) * 0.2,
         key=key, order=np.argsort(key, kind="stable").astype(np.int32),
-        load=load, k=k, worst=T * min(k, len(held)))
+        load=load, k=k, worst=T * min(k, len(held)),
+        all_held=len(held) == E)
 
 
 def _whole_buffer(s, rows):
@@ -375,18 +377,35 @@ def _whole_buffer(s, rows):
 
     def ours(x, weights, gate_up, down):
         index = (*moe._pair_index(jnp.asarray(s["key"]), weights,
-                                  len(s["load"]), s["k"], worst),
+                                  len(s["load"]), s["k"], worst,
+                                  s["all_held"]),
                  jnp.asarray(starts, jnp.int32), jnp.asarray(ends, jnp.int32))
         return moe._held_experts(x, weights, gate_up, down, index, rows,
                                  s["k"])
     return fn, ours
 
 
-@pytest.mark.parametrize("passes", [1, 3])
-@pytest.mark.parametrize("case", ["all_held", "two_of_eight",
-                                  "token_without_pair", "one_expert"])
-def test_rows_follow_the_pairs_like_the_whole_buffer(case, passes):
-    s = _pairs_setup(case)
+def _wide_row_adds(fn, *args, d):
+    """The ``scatter-add``s onto ``(tokens, d)`` operands in the jaxpr of
+    ``fn`` and everything it calls (loop bodies, a ``custom_vjp``'s two
+    halves): the adds of whole rows with repeated indices."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scatter-add" \
+                    and eqn.invars[0].aval.shape[1:] == (d,):
+                found.append(eqn.invars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _like_the_whole_buffer(s, passes):
+    """Output and all four gradients against the oracle's; returns the adds
+    of whole rows with repeated indices that forward + backward hold."""
     pairs = int(s["load"].sum())
     rows = s["worst"] if passes == 1 else -(-pairs // 3)
     assert max(1, -(-pairs // rows)) == passes, (pairs, rows)
@@ -404,6 +423,50 @@ def test_rows_follow_the_pairs_like_the_whole_buffer(case, passes):
         assert float(jnp.abs(b).max()) > 0, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
                                    atol=1e-5, err_msg=name)
+    return _wide_row_adds(
+        jax.grad(lambda *a: jnp.sum(ours(*a) * g), argnums=(0, 1, 2, 3)),
+        *args, d=s["x"].shape[1])
+
+
+@pytest.mark.parametrize("passes", [1, 3])
+@pytest.mark.parametrize("case", ["all_held", "two_of_eight",
+                                  "token_without_pair", "one_expert"])
+def test_rows_follow_the_pairs_like_the_whole_buffer(case, passes):
+    # only a layer that holds every expert adds no row with repeated indices
+    adds = _like_the_whole_buffer(_pairs_setup(case), passes)
+    assert (adds == []) == (case == "all_held"), adds
+
+
+@pytest.mark.parametrize("passes", [1, 3])
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_all_held_sums_a_tokens_rows_by_gathers_alone(k, passes):
+    """A layer that holds every expert knows each token's ``k`` pairs: the
+    combine and the dispatch's transpose gather them (a pair outside a pass
+    weighs nothing there), the oracle's output and all four gradients come
+    out, and nothing as wide as a row is added with repeated indices."""
+    s = _pairs_setup("all_held", k=k)
+    assert s["all_held"] and int(s["load"].sum()) == 40 * k
+    assert _like_the_whole_buffer(s, passes) == []
+
+
+@pytest.mark.parametrize("held", [None, [4, 5, 6, 7]])
+def test_only_a_share_adds_rows_with_repeated_indices(held):
+    """The same fact of the operator itself, forward + backward: the form
+    is chosen by ``len(held) == num_experts``, and by nothing else."""
+    s = _sparse_setup()
+    held = tuple(range(16) if held is None else held)
+
+    def layer(t):
+        at = jnp.asarray(held)
+        return moe.sparse_experts(
+            t["h"], t["router"], t["bias"], t["gate_up"][at], t["down"][at],
+            held=held, top_k=t["k"], scale=SCALE)[0]
+
+    adds = _wide_row_adds(
+        lambda h, router, gate_up, down: _sparse_grads(
+            dict(s, h=h, router=router, gate_up=gate_up, down=down), layer),
+        s["h"], s["router"], s["gate_up"], s["down"], d=32)
+    assert (adds == []) == (len(held) == 16), adds
 
 
 @pytest.mark.parametrize("held,tokens", [(None, 96), ([4, 5, 6, 7], 96),
@@ -411,11 +474,14 @@ def test_rows_follow_the_pairs_like_the_whole_buffer(case, passes):
 def test_rows_moved_follows_the_pairs_and_not_the_buffer(held, tokens):
     """``stats()["rows_moved"]``: the pairs of the newest forward in whole
     tiles of the loops. A layer that holds every expert moves its whole
-    buffer, a share moves its pairs', far under the buffer's four-fold."""
+    buffer, a share moves its pairs', far under the buffer's four-fold.
+    ``rows_added``: of those, the most that were added with repeated
+    indices: none where every expert is held (gathers alone) or a token
+    has one pair at most, the pairs past the busiest expert's otherwise."""
     from mxtpu import nd
     blk = moe.SparseExperts(32, 48, 16, 4, held=held)
     blk.initialize()
-    assert blk.stats()["rows_moved"] is None
+    assert blk.stats()["rows_moved"] is None is blk.stats()["rows_added"]
     blk(nd.array(np.random.RandomState(0).randn(1, tokens, 32)
                  .astype(np.float32)))
     row = blk.stats()
@@ -424,5 +490,12 @@ def test_rows_moved_follows_the_pairs_and_not_the_buffer(held, tokens):
     assert row["rows_moved"] % tile == 0
     if held is None:
         assert row["rows_moved"] == row["buffer_rows"] == tokens * 4
+        assert row["rows_added"] == 0
     else:
         assert row["rows_moved"] <= row["buffer_rows"] / 2, row
+        if len(held) == 1:      # one pair a token at most: nothing further
+            assert row["rows_added"] == 0
+        else:
+            load = blk.count.data().asnumpy()[held]
+            assert 0 < row["rows_added"] == load.sum() - load.max() \
+                <= row["rows_moved"]
