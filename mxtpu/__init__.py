@@ -17,13 +17,20 @@ Top-level layout mirrors the ``mx.*`` namespaces:
 * ``mxtpu.parallel`` — device meshes, collectives, sharded training (TPU-first, new)
 """
 
-import os as _os
+import time as _time
+
+_T_IMPORT = _time.perf_counter_ns()      # the import's own clock: its spans
+import os as _os                         # are recorded at the last line
 
 # pod bring-up MUST precede any backend-initializing import (see mxtpu/dist.py);
 # reference parity: ps-lite InitPSEnv runs at library load (kvstore.h:257)
 if _os.environ.get("DMLC_NUM_WORKER", "1") not in ("", "0", "1"):
     from . import dist as _dist
     _dist.auto_initialize()
+
+_t = _time.perf_counter_ns()
+import jax as _jax                       # 0 where the caller imported it
+_T_JAX = (_t, _time.perf_counter_ns() - _t)   # start, duration
 
 from .base import __version__
 from . import base
@@ -67,3 +74,13 @@ if "model" in globals():
     load_checkpoint = model.load_checkpoint
 if "attribute" in globals():
     AttrScope = attribute.AttrScope
+
+
+# what the import cost, by the names docs/observability.md gives them:
+# ``import/mxtpu`` from the first line to here with ``import/jax`` inside it,
+# and the host's memory at its end (no backend exists yet, and none is made)
+_import_span = observability.tracer.record_span(
+    "import/mxtpu", _T_IMPORT, _time.perf_counter_ns() - _T_IMPORT)
+observability.tracer.record_span("import/jax", *_T_JAX, parent=_import_span)
+observability.metrics.mark_memory("import/mxtpu")
+del _import_span, _t

@@ -16,6 +16,7 @@ read ``self.<param>.data()``.
 
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 from collections import OrderedDict
@@ -26,6 +27,7 @@ import jax
 from .. import ndarray as nd_mod
 from ..jit import CachedOp, export_stablehlo
 from ..ndarray.ndarray import NDArray
+from ..observability import metrics, tracer
 from .parameter import Parameter, ParameterDict
 
 _name_counter = threading.local()
@@ -75,6 +77,18 @@ class _BlockScope:
     def __exit__(self, *exc):
         _BlockScope._current.value = self._old
         return False
+
+
+@contextlib.contextmanager
+def _outermost(name: str, opener):
+    """``opener(name)`` (``tracer.span`` or ``metrics.marked_span``), or
+    nothing where a span of that name is open on the thread already: the
+    seconds of a name then add up to wall time."""
+    if tracer.is_open(name):
+        yield
+        return
+    with opener(name):
+        yield
 
 
 class Block:
@@ -152,17 +166,24 @@ class Block:
     # -- lifecycle ---------------------------------------------------------
     def initialize(self, init=None, ctx=None, verbose: bool = False,
                    force_reinit: bool = False):
-        self.collect_params().initialize(init=init, ctx=ctx, verbose=verbose,
-                                         force_reinit=force_reinit)
+        """Span ``net/initialize`` around the OUTERMOST call (a block whose
+        own ``initialize`` goes through its children's opens none again),
+        with a memory mark at its end."""
+        with _outermost("net/initialize", metrics.marked_span):
+            self.collect_params().initialize(
+                init=init, ctx=ctx, verbose=verbose,
+                force_reinit=force_reinit)
         return self
 
     def cast(self, dtype):
         """Every parameter to ``dtype`` but those made with
         ``keep_float32``. Before ``initialize`` this only sets the type the
-        parameters will be made in."""
-        for p in self.collect_params().values():
-            if not p.keep_float32:
-                p.cast(dtype)
+        parameters will be made in. Span ``net/cast`` around the outermost
+        call."""
+        with _outermost("net/cast", tracer.span):
+            for p in self.collect_params().values():
+                if not p.keep_float32:
+                    p.cast(dtype)
         return self
 
     def apply(self, fn):

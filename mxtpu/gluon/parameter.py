@@ -21,6 +21,7 @@ from ..base import dtype_np
 from ..context import Context, current_context
 from ..ndarray import ndarray as _nd
 from ..ndarray.ndarray import NDArray
+from ..observability import tracer
 
 
 class DeferredInitializationError(RuntimeError):
@@ -131,15 +132,20 @@ class Parameter:
         return [self._data.context]
 
     def set_data(self, data):
-        if self._data is None:
-            if self._deferred_init is not None:
-                self.shape = tuple(data.shape)
-                chosen, ctx = self._deferred_init
-                self._init_impl(chosen, ctx)
-            else:
-                raise RuntimeError(f"Parameter {self.name} not initialized")
-        src = data if isinstance(data, NDArray) else NDArray(data)
-        self._data._set_data(src.data.astype(self._data.dtype).reshape(self._data.shape))
+        """Install given values (span ``param/set_data``: how often, and the
+        seconds of the casts and reshapes it issues)."""
+        with tracer.span("param/set_data"):
+            if self._data is None:
+                if self._deferred_init is not None:
+                    self.shape = tuple(data.shape)
+                    chosen, ctx = self._deferred_init
+                    self._init_impl(chosen, ctx)
+                else:
+                    raise RuntimeError(
+                        f"Parameter {self.name} not initialized")
+            src = data if isinstance(data, NDArray) else NDArray(data)
+            self._data._set_data(
+                src.data.astype(self._data.dtype).reshape(self._data.shape))
 
     def zero_grad(self):
         if self._data is None or self._data._grad is None:

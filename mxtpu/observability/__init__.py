@@ -17,9 +17,13 @@ DeviceFeed producer, the ZeRO comm path, the async checkpoint writer — into
   metadata names, per-request swim-lanes, the ``profiler.dump()``/
   ``dumps()`` body, ``request_timeline``).
 * :mod:`.flops` — MFU accounting (XLA cost-analysis FLOPs with an analytic
-  conv/matmul fallback, bounded step-time ring → steps/s + p50/p99 + MFU).
+  conv/matmul fallback, bounded step ring → steps/s + p50/p99 + MFU; a
+  ``DataParallelTrainer`` step's row holds its parts:
+  ``profiler.get_step_timeline()``).
 * :mod:`.metrics` — the subsystem counter stores (checkpoint / feed / comm /
   sanitizer), moved here from ``profiler.py``; the profiler re-exports them.
+  The memory store also keeps the marks taken at the ends of phases
+  (``mark_memory``: host and device bytes; ``get_memory_stats()["marks"]``).
 * :mod:`.histogram` — bounded log-bucketed streaming histograms backing the
   serving latency percentiles (TTFT/queue-wait/prefill/first-decode/
   per-token) and fused-step times.
@@ -35,6 +39,11 @@ directly is for framework internals and tests.
 Span catalog (see docs/observability.md):
 
 ==========================  =================================================
+``import/mxtpu``            the package's import, first line to last
+``import/jax``              inside it: the package's first ``import jax``
+``net/initialize``          the outermost ``Block.initialize`` (+ mark)
+``net/cast``                the outermost ``Block.cast``
+``param/set_data``          one ``Parameter.set_data``
 ``train/step``              one ``DataParallelTrainer.step`` (args: step)
 ``train/collect``           first call: eager forward, placement, slots
 ``train/build``             first call: the step function is built
@@ -44,10 +53,12 @@ Span catalog (see docs/observability.md):
 ``train/dispatch``          the step program's call otherwise
 ``train/adopt``             handle swap, state swap, comm record
 ``train/readback``          ``float(loss)``: the host waits for the device
+``train/first_readback``    that wait after a call that traced (+ mark)
 ``jax/trace``               JAX traced a function (args: fun)
 ``jax/lower``               JAX lowered a program to StableHLO (args: fun)
 ``jax/compile``             XLA compiled, or the cache loaded (args: fun)
 ``jax/cache_hit``           instant: persistent compile cache hit
+``jax/cache_miss``          instant: compiled here, kept by the cache
 ``step/compile``            trace+lower+compile of a fused step
 ``step/execute``            one cache-hit fused-step dispatch
 ``feed/transfer``           DeviceFeed producer staging one batch
@@ -57,6 +68,7 @@ Span catalog (see docs/observability.md):
 ``ckpt/write``              serialize+fsync of one step (writer thread)
 ``ckpt/commit``             atomic rename+COMMIT marker (writer thread)
 ``feed/queue_depth``        counter: prefetch queue occupancy
+``memory/<key>``            counter: a memory mark's numbers (ring armed)
 ``serving/submit``          instant: request enqueued (args: id)
 ``serving/admit``           instant: request admitted to a slot (args: id)
 ``serving/prefix_hit``      instant: radix prefix-cache hit (args: id)
@@ -71,6 +83,12 @@ Span catalog (see docs/observability.md):
 ``serving/drained``         instant: handoff complete (args: ids)
 ``serving/adopted``         instant: adoption complete (args: ids)
 ==========================  =================================================
+
+Memory marks (``metrics.mark_memory``) end ``import/mxtpu``,
+``net/initialize``, ``train/collect`` / ``build`` / ``compile`` /
+``first_readback`` and the steps numbered 1, 2, 4, 8, ... (``train/step/<n>``).
+A step's row in the ring (``flops.STEP_ROW``): step, start_ns, place_s,
+prepare_s, dispatch_s, adopt_s, readback_s, step_s, traced, nivcsw.
 
 Names on the device (a ``jax.profiler`` trace's operations): Pallas kernels
 ``flash_fwd``, ``flash_bwd_fused`` (the whole backward), with a window

@@ -14,8 +14,11 @@ Two halves:
   :func:`get_mfu_stats` derives ``steps_per_sec``, ``p50_step_ms``,
   ``p99_step_ms``, and ``mfu`` against the detected chip's documented peak.
   ``Module.fit`` records every batch and logs the epoch roll-up;
-  ``Speedometer`` prints the rolling p50/p99; ``profiler.get_mfu_stats()``
-  hands out the same roll-up.
+  ``DataParallelTrainer.step`` records every step WITH its parts (a row of
+  :data:`STEP_ROW`), which :func:`get_step_timeline` hands out: a slow step
+  is then told by the side it waited on, the host's issuing or the device's
+  answer; ``Speedometer`` prints the rolling p50/p99;
+  ``profiler.get_mfu_stats()`` hands out the same roll-up.
 
 Peak FLOP/s: the documented bf16 peak of the detected TPU generation
 (public spec sheets — fp32 convs execute as bf16 MXU passes, so bf16 is the
@@ -39,7 +42,8 @@ _log = logging.getLogger("mxtpu.observability")
 
 __all__ = ["device_peak", "estimate_step_flops", "jaxpr_flops",
            "record_step", "set_step_flops", "get_step_flops",
-           "get_mfu_stats", "reset_steps", "step_count", "PEAK_TFLOPS"]
+           "get_mfu_stats", "get_step_timeline", "reset_steps", "step_count",
+           "PEAK_TFLOPS", "STEP_ROW"]
 
 # documented bf16 peak TFLOP/s per chip, keyed by the exact
 # ``jax.devices()[0].device_kind`` string. Both spellings of each generation
@@ -178,17 +182,36 @@ def _ring_cap() -> int:
 _ring: "deque" = deque(maxlen=_ring_cap())
 _state = {"flops_per_step": None, "flops_source": None, "total_steps": 0}
 
+# a step's row, as ``DataParallelTrainer.step`` records it: the step's
+# number, its start on the tracer's clock (``time.perf_counter_ns``), the
+# seconds of its ``train/place``, ``prepare``, ``dispatch`` (``compile``
+# where the call traced: ``traced``), ``adopt`` and ``readback``
+# (``first_readback``) spans and of the whole ``train/step``, and the calling
+# thread's involuntary context switches since the trainer's last step
+STEP_ROW = ("step", "start_ns", "place_s", "prepare_s", "dispatch_s",
+            "adopt_s", "readback_s", "step_s", "traced", "nivcsw")
 
-def record_step(seconds: float, flops: Optional[float] = None):
+
+def record_step(seconds: float, flops: Optional[float] = None,
+                row: Optional[dict] = None):
     """One training step's wall time (and, optionally, its FLOP count — when
-    omitted the last :func:`set_step_flops` value applies at read time).
+    omitted the last :func:`set_step_flops` value applies at read time —
+    and its ``row`` of :data:`STEP_ROW`, which the ring keeps as it is).
     Also lands in the bounded ``step/fused_step_ms`` log-bucket histogram
     (``observability.histogram``) so fused-step tails survive past the
     ring's window and export alongside the serving latency series."""
     with _ring_lock:
-        _ring.append((float(seconds), flops))
+        _ring.append((float(seconds), flops, row))
         _state["total_steps"] += 1
     histogram.record_value("step/fused_step_ms", float(seconds) * 1e3)
+
+
+def get_step_timeline() -> list:
+    """The rows (:data:`STEP_ROW`) of the steps the ring still holds, oldest
+    first: one a ``DataParallelTrainer.step``; a step recorded without a row
+    (``Module.fit``'s) is not among them."""
+    with _ring_lock:
+        return [dict(row) for _, _, row in _ring if row is not None]
 
 
 def set_step_flops(flops: Optional[float], source: Optional[str] = None):
@@ -242,7 +265,7 @@ def get_mfu_stats(flops_per_step: Optional[float] = None) -> dict:
         flops_per_step = default_flops
     else:
         source = "caller"
-    times = sorted(s for s, _ in samples)
+    times = sorted(s[0] for s in samples)
     n = len(times)
     wall = sum(times)
     out = {"steps": n,

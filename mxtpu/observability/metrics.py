@@ -15,10 +15,15 @@ guard for this contract).
 
 from __future__ import annotations
 
+import contextlib
+import os
+import resource
 import threading
+import time
 from typing import Dict, Optional
 
 from . import histogram as _hist
+from . import tracer as _tracer
 
 _stats_lock = threading.Lock()
 
@@ -235,11 +240,76 @@ def reset_comm_stats():
 
 _MEM_ZERO = {"stage": 0, "data_degree": 1, "fsdp_degree": 1,
              "param_bytes_per_device": 0, "grad_bytes_per_device": 0,
-             "slot_bytes_per_device": 0,
+             "slot_bytes_per_device": 0, "aux_bytes_per_device": 0,
              "replicated_param_bytes": 0, "replicated_grad_bytes": 0,
              "replicated_slot_bytes": 0,
              "step_outputs": 0, "step_donated": 0}
 _mem = dict(_MEM_ZERO)
+_marks: list = []            # [mark]: appended and cleared under _stats_lock
+
+# what a device's ``memory_stats()`` is asked for; a backend that reports
+# fewer (the CPU reports none) leaves the others out
+_DEVICE_KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+                "peak_bytes_reserved", "bytes_limit")
+_PAGE = os.sysconf("SC_PAGE_SIZE")
+
+
+def _host_bytes() -> dict:
+    """This process's resident bytes now (``/proc/self/statm``) and at their
+    peak (``getrusage``: kilobytes on Linux)."""
+    out = {"host_peak_rss_bytes":
+           resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}
+    try:
+        with open("/proc/self/statm") as f:
+            out["host_rss_bytes"] = int(f.read().split()[1]) * _PAGE
+    except (OSError, ValueError, IndexError):
+        pass                 # no procfs here: the peak alone
+    return out
+
+
+def _fullest_device() -> dict:
+    """``memory_stats()`` of the local device with the most bytes in use, as
+    the chip reports it; ``{}`` before a backend exists (asking JAX for its
+    devices would make one) and on a backend that reports none."""
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return {}
+    import jax
+    rows = [d.memory_stats() or {} for d in jax.local_devices()]
+    full = max(rows, key=lambda ms: ms.get("bytes_in_use", 0), default={})
+    return {k: int(full[k]) for k in _DEVICE_KEYS if k in full}
+
+
+def mark_memory(name: str) -> dict:
+    """One memory mark, at the END of a phase and never in a steady step
+    (``import/mxtpu``, ``net/initialize``, the trainer's ``train/collect`` /
+    ``build`` / ``compile`` / ``first_readback``, and ``train/step/<n>`` for
+    n = 1, 2, 4, 8, ...): ``{"name", "t_ns" (the tracer's clock),
+    "host_rss_bytes", "host_peak_rss_bytes"}`` and, once a backend exists,
+    the fullest local device's ``bytes_in_use``, ``peak_bytes_in_use``,
+    ``bytes_reserved``, ``peak_bytes_reserved``, ``bytes_limit``, each where
+    the backend reports it. ``get_memory_stats()["marks"]`` hands them out in order; with the
+    ring armed each number is also a counter track ``memory/<key>``. Beside
+    ``param_`` / ``slot_`` / ``aux_bytes_per_device`` a mark reads as owners:
+    in use = parameters + slots + auxiliary state + the rest (batches, a
+    pool); reserved = the loaded programs' temporaries; limit less both is
+    free."""
+    sizes = {**_host_bytes(), **_fullest_device()}
+    mark = {"name": name, "t_ns": time.perf_counter_ns(), **sizes}
+    with _stats_lock:
+        _marks.append(mark)
+    for key, value in sizes.items():
+        _tracer.counter("memory/" + key, value, cat="memory")
+    return mark
+
+
+@contextlib.contextmanager
+def marked_span(name: str, args: Optional[dict] = None):
+    """``tracer.span(name)`` for a set-up phase: once it has closed without
+    an error, a :func:`mark_memory` of its name."""
+    with _tracer.span(name, args=args) as span:
+        yield span
+    mark_memory(name)
 
 
 def record_memory_stats(**kwargs):
@@ -247,6 +317,9 @@ def record_memory_stats(**kwargs):
     by ZeRO stage (``parallel.fsdp.measure_memory`` computes the figures from
     the actual placed shardings at trace time). ``replicated_*`` keys carry
     the stage-0 equivalent the shrink ratio is quoted against.
+    ``aux_bytes_per_device`` is ``DataParallelTrainer``'s auxiliary state
+    (running statistics, routing counts and biases: what rides the step and
+    is no parameter's gradient).
     ``step_outputs`` / ``step_donated`` are ``DataParallelTrainer``'s: the
     buffers its step hands back and how many of them are a donated
     argument's, written in place (weights and slots then exist once)."""
@@ -260,14 +333,16 @@ def get_memory_stats() -> dict:
     """Latest memory accounting snapshot — the number that proves ZeRO-2/3
     actually shrinks the footprint. ``compile_cache_summary()`` prints it,
     ``Module.fit`` logs it per epoch, and ``tests/test_fsdp.py`` compares
-    the stages with it."""
+    the stages with it. ``"marks"``: every :func:`mark_memory` since the
+    last reset, in order."""
     with _stats_lock:
-        return dict(_mem)
+        return dict(_mem, marks=[dict(m) for m in _marks])
 
 
 def reset_memory_stats():
     with _stats_lock:
         _mem.update(_MEM_ZERO)
+        _marks.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -32,10 +32,11 @@ One call site, three sinks:
   id of the span open on that thread when it began) and the caller's ``args``.
 
 JAX's own compile phases arrive as spans too (``jax/trace``, ``jax/lower``,
-``jax/compile``, instant ``jax/cache_hit``) through one ``jax.monitoring``
-listener pair registered at import: JAX traces, lowers and compiles on the
-thread that made the call, so they land under whatever span is open there.
-They fire only on a compile path, never in a warm step.
+``jax/compile``, instants ``jax/cache_hit`` and ``jax/cache_miss``) through
+one ``jax.monitoring`` listener pair registered at import: JAX traces, lowers
+and compiles on the thread that made the call, so they land under whatever
+span is open there, instants too (``count_by_parent``). They fire only on a
+compile path, never in a warm step.
 
 Cost of ``with span(): pass`` on the v5e host: docs/observability.md.
 """
@@ -53,7 +54,7 @@ import jax
 
 __all__ = ["span", "instant", "counter", "record_span", "enabled", "start",
            "stop", "pause", "resume", "reset", "snapshot_buffers",
-           "buffer_capacity", "totals"]
+           "buffer_capacity", "totals", "is_open"]
 
 # ring capacity per thread (events); a 2-epoch traced fit generates a few
 # thousand spans, so the default keeps hours of steps without growing
@@ -188,9 +189,10 @@ def snapshot_buffers():
 def totals() -> dict:
     """Every span name seen since the last :func:`reset`, armed or not:
     ``{name: {"count", "seconds", "min_s", "max_s", "by_parent": {name of the
-    span open on the thread when it began, or "": seconds}}}``. Instants
-    count with no seconds; ``jax/trace`` counts the time no nested
-    ``jax/trace`` covers, so the seconds of a name add up to wall time."""
+    span open on the thread when it began, or "": seconds},
+    "count_by_parent": {the same names: count}}}``. Instants count with no
+    seconds; ``jax/trace`` counts the time no nested ``jax/trace`` covers,
+    so the seconds of a name add up to wall time."""
     with _reg_lock:
         rows = [kv for b in _buffers for kv in list(b.totals.items())]
     out: dict = {}
@@ -198,21 +200,31 @@ def totals() -> dict:
         t = out.get(name)
         if t is None:
             t = out[name] = {"count": 0, "seconds": 0.0, "min_s": lo / 1e9,
-                             "max_s": hi / 1e9, "by_parent": {}}
+                             "max_s": hi / 1e9, "by_parent": {},
+                             "count_by_parent": {}}
         t["count"] += n
         t["seconds"] += ns / 1e9
         t["min_s"] = min(t["min_s"], lo / 1e9)
         t["max_s"] = max(t["max_s"], hi / 1e9)
         t["by_parent"][parent] = t["by_parent"].get(parent, 0.0) + ns / 1e9
+        t["count_by_parent"][parent] = \
+            t["count_by_parent"].get(parent, 0) + n
     return out
+
+
+def is_open(name: str) -> bool:
+    """Whether a span of this name is open on the calling thread: how a
+    call that may nest in itself (``Block.initialize`` of a child) knows it
+    is not the outermost."""
+    return any(n == name for _, n in _buf().stack)
 
 
 # -- recording ---------------------------------------------------------------
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "_t0", "_ann", "_buf", "_id",
-                 "_parent")
+    __slots__ = ("name", "cat", "args", "t0_ns", "dur_ns", "_ann", "_buf",
+                 "_id", "_parent")
 
     def __init__(self, name: str, cat: Optional[str], args: Optional[dict]):
         self.name = name
@@ -233,17 +245,18 @@ class _Span:
         buf.stack.append((self._id, self.name))
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
-        self._t0 = time.perf_counter_ns()
+        self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter_ns() - self._t0
+        # kept on the span: whoever opened it reads what it measured
+        dur = self.dur_ns = time.perf_counter_ns() - self.t0_ns
         self._ann.__exit__(None, None, None)
         buf = self._buf
         buf.stack.pop()
         buf.count(self.name, self._parent[1], dur)
         if _enabled and not _paused:
-            buf.append(_event(self.name, "X", self.cat, self._t0, self._id,
+            buf.append(_event(self.name, "X", self.cat, self.t0_ns, self._id,
                               self._parent[0], self.args, dur=dur / 1e3))
         return False
 
@@ -277,17 +290,23 @@ def instant(name: str, cat: Optional[str] = None,
 
 def record_span(name: str, t0_ns: int, dur_ns: int,
                 cat: Optional[str] = None, args: Optional[dict] = None,
-                total_ns: Optional[int] = None):
+                total_ns: Optional[int] = None,
+                parent: Optional[tuple] = None) -> tuple:
     """Record an already-measured span under the span open on the calling
     thread (legacy Domain/Task/Frame objects and JAX's compile phases measure
     their own window). ``total_ns`` is what counts into the totals where that
-    is not the whole duration."""
+    is not the whole duration. Returns the span's ``(id, name)``, which a
+    caller that measured a span INSIDE this one passes as that one's
+    ``parent`` (the package's import times itself before a tracer exists)."""
     buf = _buf()
-    parent = buf.open_span()
+    if parent is None:
+        parent = buf.open_span()
+    span_id = next(_ids)
     buf.count(name, parent[1], dur_ns if total_ns is None else total_ns)
     if _enabled and not _paused:
-        buf.append(_event(name, "X", cat, t0_ns, next(_ids), parent[0],
+        buf.append(_event(name, "X", cat, t0_ns, span_id, parent[0],
                           args and dict(args), dur=dur_ns / 1e3))
+    return span_id, name
 
 
 def counter(name: str, value, cat: str = "counters"):
@@ -307,7 +326,10 @@ _JAX_PHASES = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower",
     "/jax/core/compile/backend_compile_duration": "jax/compile",
 }
-_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# a hit: the executable came from the persistent cache; a miss: XLA compiled
+# it in this process and the cache kept it
+_JAX_CACHE = {"/jax/compilation_cache/cache_hits": "jax/cache_hit",
+              "/jax/compilation_cache/cache_misses": "jax/cache_miss"}
 
 
 def _on_jax_duration(event: str, secs: float, **kwargs):
@@ -330,8 +352,9 @@ def _on_jax_duration(event: str, secs: float, **kwargs):
 
 
 def _on_jax_event(event: str, **kwargs):
-    if event == _JAX_CACHE_HIT:
-        instant("jax/cache_hit", cat="jax")
+    name = _JAX_CACHE.get(event)
+    if name is not None:
+        instant(name, cat="jax")
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)

@@ -10,6 +10,7 @@ backward compute (the reference's priority-overlap trick, for free).
 
 from __future__ import annotations
 
+import resource
 from typing import Callable, List, Optional, Sequence
 
 import jax
@@ -22,7 +23,7 @@ from .. import autograd
 from ..analysis import sanitize
 from .. import ndarray as nd_mod
 from ..ndarray.ndarray import NDArray
-from ..observability import metrics, tracer
+from ..observability import flops, metrics, tracer
 from ..ops import attention as attention_ops
 from ..step_cache import (build_update_all, cache_stats, create_distinct,
                           donation_supported)
@@ -36,6 +37,10 @@ __all__ = ["shard_batch", "replicate", "place", "DataParallelTrainer"]
 # positions of the step's arguments that it replaces, and so donates:
 # params, per-parameter slots, ZeRO slots, residuals (StepExecutor's four)
 _DONATED = (0, 2, 3, 4)
+
+# whose context switches a step's row counts: the calling thread's, where the
+# platform tells threads apart
+_RUSAGE_WHO = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
 
 
 def _place(raw, sharding: NamedSharding):
@@ -174,6 +179,8 @@ class DataParallelTrainer:
         self._compression_params = compression_params
         self._step_fn = None
         self._t = 0
+        # the thread's count at the last step's row (the first: from here)
+        self._nivcsw = resource.getrusage(_RUSAGE_WHO).ru_nivcsw
         self._params: List = []
         self._states: List = []
         self._zero_layout = None
@@ -500,7 +507,10 @@ class DataParallelTrainer:
             "donated": replaced if donate else 0}
         metrics.record_memory_stats(
             step_outputs=self._step_buffers["outputs"],
-            step_donated=self._step_buffers["donated"])
+            step_donated=self._step_buffers["donated"],
+            aux_bytes_per_device=sum(
+                fsdp_mod.per_device_bytes(p.data().data)
+                for p in aux_handles))
         self._comm_step = self._comm_record()
 
     def step_async(self, x, y) -> NDArray:
@@ -509,20 +519,23 @@ class DataParallelTrainer:
         engine's lazy push; WaitToRead happens when the caller materializes the
         loss)."""
         with tracer.span("train/step", args={"step": self._t + 1}):
-            return self._issue(x, y)
+            return self._issue(x, y)[0]
 
-    def _issue(self, x, y) -> NDArray:
+    def _issue(self, x, y) -> tuple:
         """Everything of one step up to the handle swap, under the caller's
-        ``train/step`` span; every span carries the step's number."""
+        ``train/step`` span; every span carries the step's number, and the
+        set-up phases end in a memory mark. Returns the loss, whether the
+        call traced, and the nanoseconds its four issuing spans measured
+        (place, prepare, dispatch or compile, adopt)."""
         x = x if isinstance(x, NDArray) else nd_mod.array(x)
         y = y if isinstance(y, NDArray) else nd_mod.array(y)
         step = {"step": self._t + 1}
         built = self._step_fn is None
         if built:
             self._stats.miss()
-            with tracer.span("train/collect", args=step):
+            with metrics.marked_span("train/collect", args=step):
                 self._collect(x)
-            with tracer.span("train/build", args=step):
+            with metrics.marked_span("train/build", args=step):
                 self._build()
         else:
             self._stats.hit()
@@ -531,10 +544,10 @@ class DataParallelTrainer:
                 f"batch size {x.shape[0]} is not divisible by "
                 f"micro_batches={self.micro_batches}; pad or drop the tail "
                 f"batch (ImageRecordIter marks it with .pad)")
-        with tracer.span("train/place", args=step):
+        with tracer.span("train/place", args=step) as place:
             xs = shard_batch(x, self.mesh).data
             ys = shard_batch(y, self.mesh).data
-        with tracer.span("train/prepare", args=step):
+        with tracer.span("train/prepare", args=step) as prepare:
             self._t += 1
             opt = self.optimizer
             lr = jnp.asarray(opt.learning_rate, jnp.float32)
@@ -562,12 +575,13 @@ class DataParallelTrainer:
                 self._last_avals = jax.tree.map(
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
                     if hasattr(a, "shape") else a, args)
-        with tracer.span("train/compile" if traces else "train/dispatch",
-                         args=dict(step, **self._step_buffers)), \
+        call = metrics.marked_span if traces else tracer.span
+        with call("train/compile" if traces else "train/dispatch",
+                  args=dict(step, **self._step_buffers)) as dispatch, \
                 self._kernel_scope():
             (new_params, new_auxs, new_states, new_zstates, new_zres,
              loss) = self._step_fn(*args)
-        with tracer.span("train/adopt", args=step):
+        with tracer.span("train/adopt", args=step) as adopt:
             for p, v in zip(self._param_handles, new_params):
                 p._data._data = v
                 p._data._version += 1
@@ -587,7 +601,8 @@ class DataParallelTrainer:
                     origin="DataParallelTrainer's step (donate_argnums "
                            "params/opt-state)")
             metrics.record_comm_step(**self._comm_step)
-        return NDArray(loss)
+        return NDArray(loss), traces, (place.dur_ns, prepare.dur_ns,
+                                       dispatch.dur_ns, adopt.dur_ns)
 
     def _comm_record(self) -> dict:
         """One step's comm accounting (profiler.get_comm_stats), worked out
@@ -663,10 +678,33 @@ class DataParallelTrainer:
                 for i, n in enumerate(self._param_names)}
 
     def step(self, x, y) -> float:
-        with tracer.span("train/step", args={"step": self._t + 1}):
-            loss = self._issue(x, y)
-            with tracer.span("train/readback", args={"step": self._t}):
-                return float(loss.data)
+        """One step ending in the loss on the host. The wait is
+        ``train/readback``, or ``train/first_readback`` after a call that
+        traced: that one loads the executable onto the device and runs it
+        for the first time. Every step leaves a row in the step ring
+        (``profiler.get_step_timeline()``, ``flops.STEP_ROW``); the first
+        wait and the steps numbered 1, 2, 4, 8, ... end in a memory mark
+        (inside ``train/step``: about 0.9 ms on a v5e host, so the row's
+        whole is what the caller's clock sees), no other step does."""
+        with tracer.span("train/step", args={"step": self._t + 1}) as whole:
+            loss, traced, (place, prepare, dispatch, adopt) = \
+                self._issue(x, y)
+            wait = metrics.marked_span if traced else tracer.span
+            with wait("train/first_readback" if traced else "train/readback",
+                      args={"step": self._t}) as readback:
+                value = float(loss.data)
+            if self._t & (self._t - 1) == 0:
+                metrics.mark_memory(f"train/step/{self._t}")
+        nivcsw = resource.getrusage(_RUSAGE_WHO).ru_nivcsw
+        flops.record_step(whole.dur_ns / 1e9, row={
+            "step": self._t, "start_ns": whole.t0_ns,
+            "place_s": place / 1e9, "prepare_s": prepare / 1e9,
+            "dispatch_s": dispatch / 1e9, "adopt_s": adopt / 1e9,
+            "readback_s": readback.dur_ns / 1e9,
+            "step_s": whole.dur_ns / 1e9, "traced": traced,
+            "nivcsw": nivcsw - self._nivcsw})
+        self._nivcsw = nivcsw
+        return value
 
     def device_feed(self, batches, depth: Optional[int] = None):
         """Wrap an iterable of ``(x, y)`` batches (or ``DataBatch``es) in a

@@ -68,6 +68,7 @@ from .observability.metrics import (  # noqa: F401  (re-exported surface)
 
 # MFU/step-latency surface (observability.flops is the store)
 get_mfu_stats = _flops.get_mfu_stats
+get_step_timeline = _flops.get_step_timeline
 record_step_time = _flops.record_step
 reset_step_times = _flops.reset_steps
 
