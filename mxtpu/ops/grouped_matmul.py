@@ -36,7 +36,7 @@ from jax import lax
 
 from .registry import register
 
-__all__ = ["grouped_matmul"]
+__all__ = ["grouped_matmul", "grouped_matmul_grads"]
 
 _ROW_TILE = 256      # rows of x a work item multiplies
 _TILE = 2048         # the K and N tiles, where they divide
@@ -293,3 +293,11 @@ def grouped_matmul(x, w, group_sizes):
     from ..observability import metrics
     metrics.record_kernel_path("grouped_matmul", _use_pallas(x, w))
     return _grouped(x, w, group_sizes)
+
+
+def grouped_matmul_grads(x, w, group_sizes, dy):
+    """``(dx, dw)`` of ``grouped_matmul(x, w, group_sizes)`` for its
+    output's gradient ``dy``, by the rule its ``custom_vjp`` has: for a
+    caller that writes its own backward and kept ``x``. The call site was
+    counted when its forward was made; this counts nothing."""
+    return _grouped_bwd((x, w, group_sizes), dy)[:2]

@@ -40,7 +40,7 @@ from .. import autograd
 from ..gluon.block import HybridBlock
 from ..gluon.nn.basic_layers import SwiGLU
 from ..ops import registry
-from ..ops.grouped_matmul import grouped_matmul
+from ..ops.grouped_matmul import grouped_matmul, grouped_matmul_grads
 from .collectives import all_to_all_array, shard_map_compat
 from .mesh import Mesh, get_default_mesh
 
@@ -260,22 +260,71 @@ def _pass_rows(order, w_sorted, starts, ends, p, rows: int, top_k: int):
 def _one_pass(index, rows: int) -> bool:
     """Whether a step's pairs take ONE pass whatever its routing: a layer
     that holds every expert has exactly ``T * top_k`` pairs, and its buffer
-    holds them all (as ``expert_rows`` makes it). The loop over further
-    passes is not traced there: one that never turns still cost every
-    step of ``lfm2moe_train_t4096`` 1.3% (its carries are copied, and the
-    row buffers that pass through them are filled more slowly) and traced
-    and compiled the pass a second time. A share's pairs follow the
-    routing, and its loop stays."""
+    holds them all (as ``expert_rows`` makes it). A static fact of the
+    layer, and what the two things that differ between the regimes follow.
+    The loop over further passes is not traced there: one that never turns
+    still cost every step of ``lfm2moe_train_t4096`` 1.3% (its carries are
+    copied, and the row buffers that pass through them are filled more
+    slowly) and traced and compiled the pass a second time. And the pass
+    keeps its two products for the backward (``_held_experts``): the one
+    pass there is can hand them over, and a layer that holds every expert
+    of a model that fits has the room. A share's pairs follow the routing:
+    its loop stays, how many passes it takes is a traced number (a loop
+    cannot hand each pass's rows to the backward), and it keeps nothing of
+    a pass."""
     order, _, sums, _, _ = index
     return len(sums) == 1 and order.shape[0] == rows
 
 
+def _swiglu(gate_up):
+    ffn = gate_up.shape[1] // 2
+    return gate_up[:, ffn:] * jax.nn.silu(gate_up[:, :ffn])
+
+
 def _experts(xs, w_gate_up, w_down, sizes):
+    """``(out, gate_up)``: the rows' two grouped products, the second of
+    the SwiGLU of the first."""
     with jax.named_scope("experts"):
-        ffn = w_down.shape[1]
         gate_up = grouped_matmul(xs, w_gate_up, sizes)
-        act = gate_up[:, ffn:] * jax.nn.silu(gate_up[:, :ffn])
-        return grouped_matmul(act, w_down, sizes)
+        return grouped_matmul(_swiglu(gate_up), w_down, sizes), gate_up
+
+
+def _experts_grads(xs, w_gate_up, w_down, sizes, gate_up, d_out):
+    """``_experts``' transpose from the ``gate_up`` its forward made, with
+    no product taken a second time: the four grouped products of the
+    backward (``grouped_matmul``'s own, by its rule) and one element-wise
+    pass over ``gate_up`` for the SwiGLU and its transpose. ``(dxs,
+    d_w_gate_up, d_w_down)``."""
+    with jax.named_scope("experts"):
+        act, swiglu_vjp = jax.vjp(_swiglu, gate_up)
+        d_act, d_down = grouped_matmul_grads(act, w_down, sizes, d_out)
+        d_gate_up, = swiglu_vjp(d_act)
+        return (*grouped_matmul_grads(xs, w_gate_up, sizes, d_gate_up),
+                d_down)
+
+
+def _passes(x, weights, w_gate_up, w_down, index, rows: int, top_k: int):
+    """``_held_experts``' forward: ``(y, kept)``, ``kept`` the one pass's
+    ``(gate_up, out)`` where ``_one_pass`` holds and ``()`` otherwise."""
+    order, w_sorted, sums, starts, ends = index
+    tile = _row_tile(rows)
+    with jax.named_scope("combine"):
+        add_rows = _sum_rows(sums, top_k, weights)
+
+    def one(p, y):
+        _, token, _, sizes, tiles = _pass_rows(
+            order, w_sorted, starts, ends, p, rows, top_k)
+        with jax.named_scope("dispatch"):
+            xs, _ = _take_rows(x, token, tiles, tile,
+                               _cleared((rows, x.shape[1]), x.dtype, tiles))
+        out, gate_up = _experts(xs, w_gate_up, w_down, sizes)
+        with jax.named_scope("combine"):
+            return add_rows(y, out, p * rows), (gate_up, out)
+
+    if _one_pass(index, rows):
+        return one(0, None)
+    return lax.fori_loop(1, -(-ends[-1] // rows), lambda p, y: one(p, y)[0],
+                         one(0, None)[0]), ()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -291,40 +340,42 @@ def _held_experts(x, weights, w_gate_up, w_down, index, rows: int,
     and a tiled add of the further pairs otherwise): rows past the last
     live tile are neither gathered nor read back. Every loop's length
     is a traced number, so nothing is dropped and nothing is moved for
-    pairs that are not there. The backward runs the same passes again, each
-    from its inputs, with each movement's transpose written out (the
-    tokens' gradients gathered into the buffer a live tile a turn, the
-    rows' gradients summed onto their tokens as the forward sums the
-    rows, weights of 1), so no row buffer outlives its pass: the layer
-    keeps its input and the routing between forward and backward and
-    nothing as wide as (pairs, ffn). Returns ``(T, d)`` float32."""
-    order, w_sorted, sums, starts, ends = index
-    tile = _row_tile(rows)
-    with jax.named_scope("combine"):
-        add_rows = _sum_rows(sums, top_k, weights)
+    pairs that are not there. The backward writes each movement's
+    transpose out (the tokens' gradients gathered into the buffer a live
+    tile a turn, the rows' gradients summed onto their tokens as the
+    forward sums the rows, weights of 1), and gathers ``xs`` again from the
+    input in either regime. What the layer keeps between forward and
+    backward beside its input and the routing follows ``_one_pass``:
 
-    def one(p, y):
-        _, token, _, sizes, tiles = _pass_rows(
-            order, w_sorted, starts, ends, p, rows, top_k)
-        with jax.named_scope("dispatch"):
-            xs, _ = _take_rows(x, token, tiles, tile,
-                               _cleared((rows, x.shape[1]), x.dtype, tiles))
-        out = _experts(xs, w_gate_up, w_down, sizes)
-        with jax.named_scope("combine"):
-            return add_rows(y, out, p * rows)
+    * one pass, always (every expert held): the pass's ``gate_up`` ``(rows,
+      2 ffn)`` and ``out`` ``(rows, d)`` in the layer's dtype
+      (``SparseExperts.stats()["kept_bytes"]``). The backward takes the
+      SwiGLU and its transpose in one element-wise pass over ``gate_up``,
+      turns ``out``'s buffer into its gradient's, and runs the four grouped
+      products a backward has: six a layer and step. The rows kept are the
+      rows a second forward would make, so the result is the same.
+    * a traced number of passes (a share): nothing as wide as (pairs, ffn).
+      No row buffer outlives its pass; the backward runs the same passes
+      again, each from its inputs, its two forward products a second time
+      (eight a layer and step). A loop cannot hand a pass's rows to the
+      backward, and where a chip holds a share of a model too large for it
+      the room is the scarcer thing (``kexaone_train_t4096``: 0.87 GB free).
 
-    if _one_pass(index, rows):
-        return one(0, None)
-    return lax.fori_loop(1, -(-ends[-1] // rows), one, one(0, None))
+    Returns ``(T, d)`` float32."""
+    return _passes(x, weights, w_gate_up, w_down, index, rows, top_k)[0]
 
 
 def _held_experts_fwd(x, weights, w_gate_up, w_down, index, rows, top_k):
-    y = _held_experts(x, weights, w_gate_up, w_down, index, rows, top_k)
-    return y, (x, weights, w_gate_up, w_down, index)
+    if _one_pass(index, rows):
+        y, kept = _passes(x, weights, w_gate_up, w_down, index, rows, top_k)
+    else:       # as it was: the passes stay one call in the step's program
+        y, kept = _held_experts(x, weights, w_gate_up, w_down, index, rows,
+                                top_k), ()
+    return y, (x, weights, w_gate_up, w_down, index, kept)
 
 
 def _held_experts_bwd(rows, top_k, res, dy):
-    x, weights, w_gate_up, w_down, index = res
+    x, weights, w_gate_up, w_down, index, kept = res
     order, w_sorted, sums, starts, ends = index
     tile = _row_tile(rows)
     with jax.named_scope("dispatch"):
@@ -336,8 +387,13 @@ def _held_experts_bwd(rows, top_k, res, dy):
         with jax.named_scope("dispatch"):
             xs, _ = _take_rows(x, token, tiles, tile,
                                _cleared((rows, x.shape[1]), x.dtype, tiles))
-        out, experts_vjp = jax.vjp(
-            lambda *a: _experts(*a, sizes), xs, w_gate_up, w_down)
+        if kept:
+            gate_up, out = kept
+            experts_vjp = functools.partial(
+                _experts_grads, xs, w_gate_up, w_down, sizes, gate_up)
+        else:
+            out, experts_vjp = jax.vjp(
+                lambda *a: _experts(*a, sizes)[0], xs, w_gate_up, w_down)
         with jax.named_scope("combine"):
             # out's buffer becomes its gradient's, a tile after each tile's
             # products with dy are taken (the weights' gradient)
@@ -466,7 +522,12 @@ class SparseExperts(HybridBlock):
     its further pairs are added with repeated indices, a tile of them a
     turn, where ``top_k`` gathers would move mostly rows that carry
     nothing. There is no threshold between the two: a layer one expert
-    short of all takes the second. Scopes in a device trace: ``route``,
+    short of all takes the second. The same fact decides what a training
+    forward keeps for its backward: a layer that holds every expert takes
+    one pass whatever the routing and keeps that pass's two products
+    (``stats()["kept_bytes"]``), so its backward multiplies nothing a
+    second time; a share keeps nothing of a pass and runs its passes again
+    (``_held_experts``). Scopes in a device trace: ``route``,
     ``dispatch``, ``experts``, ``combine``, ``shared``, ``balance``."""
 
     def __init__(self, units: int, ffn_units: int, num_experts: int,
@@ -544,15 +605,22 @@ class SparseExperts(HybridBlock):
         pair; ``count`` does not say which tokens hold one, so between
         steps only this bound is known), 0 where a token holds one pair at
         most, and 0 where every expert is held, whose layer sums by
-        gathers alone (all four None before the first forward). Reads
-        ``count`` from the device: ask between steps, not inside a timed
-        loop."""
+        gathers alone; and the ``kept_bytes``: what of a pass the layer
+        keeps from a training forward to its backward beyond its input and
+        the routing, which is the pass's two products, ``buffer_rows *
+        (2 ffn + units)`` numbers of the layer's dtype, where every expert
+        is held (its pairs take one pass whatever the routing, and its
+        backward multiplies nothing a second time) and 0 for a share (its
+        backward runs its passes again: ``_held_experts``). All five None
+        before the first forward. Reads ``count`` from the device: ask
+        between steps, not inside a timed loop."""
         count = self.count.data().asnumpy()
         load = count[list(self.held)]
         pairs, even = float(load.sum()), float(count.sum()) / count.size
         tile = self._rows and _row_tile(self._rows)
-        by_gathers = len(self.held) == self._experts \
-            or min(self._top_k, len(self.held)) == 1
+        all_held = len(self.held) == self._experts
+        by_gathers = all_held or min(self._top_k, len(self.held)) == 1
+        _, units, ffn2 = self.gate_up.shape
         return {"name": self.name, "held": len(self.held), "pairs": pairs,
                 "active": int((load > 0).sum()),
                 "max_count": float(count.max()),
@@ -562,7 +630,11 @@ class SparseExperts(HybridBlock):
                 "passes": self._rows and max(1, -(-int(pairs) // self._rows)),
                 "rows_moved": tile and -(-int(pairs) // tile) * tile,
                 "rows_added": tile and (
-                    0 if by_gathers else int(pairs - load.max()))}
+                    0 if by_gathers else int(pairs - load.max())),
+                "kept_bytes": self._rows and (
+                    self._rows * (ffn2 + units)
+                    * jnp.dtype(self.gate_up.dtype).itemsize
+                    if all_held else 0)}
 
 
 # ---------------------------------------------------------------------------

@@ -265,7 +265,9 @@ def get_moe_stats(block) -> list:
     the ``rows_moved`` (the buffer rows the dispatch filled and the
     combine read, which follow the pairs) and the ``rows_added`` (the most of
     them that were added onto their tokens with repeated indices: 0 for a
-    layer that holds every expert, which sums by gathers). The numbers are a state of the layer (``count``, one float an
+    layer that holds every expert, which sums by gathers) and the ``kept_bytes`` (what of a pass the layer keeps for its
+    backward beyond its input and routing: its two products where every expert is held, 0 for a share, whose backward
+    multiplies them again). The numbers are a state of the layer (``count``, one float an
     expert) that rides the compiled step, so there is nothing to reset;
     reading them fetches it from the device: ask between steps."""
     from .parallel.moe import SparseExperts

@@ -385,22 +385,24 @@ def _whole_buffer(s, rows):
     return fn, ours
 
 
-def _wide_row_adds(fn, *args, d):
-    """The ``scatter-add``s onto ``(tokens, d)`` operands in the jaxpr of
-    ``fn`` and everything it calls (loop bodies, a ``custom_vjp``'s two
-    halves): the adds of whole rows with repeated indices."""
-    found = []
-
+def _equations(fn, *args):
+    """Every equation of the jaxpr of ``fn`` and of everything it calls
+    (loop bodies, a ``custom_vjp``'s two halves)."""
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "scatter-add" \
-                    and eqn.invars[0].aval.shape[1:] == (d,):
-                found.append(eqn.invars[0].aval.shape)
+            yield eqn
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
+                yield from walk(sub)
 
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def _wide_row_adds(fn, *args, d):
+    """The ``scatter-add``s onto ``(tokens, d)`` operands in ``fn``: the
+    adds of whole rows with repeated indices."""
+    return [eqn.invars[0].aval.shape for eqn in _equations(fn, *args)
+            if eqn.primitive.name == "scatter-add"
+            and eqn.invars[0].aval.shape[1:] == (d,)]
 
 
 def _like_the_whole_buffer(s, passes):
@@ -449,23 +451,33 @@ def test_all_held_sums_a_tokens_rows_by_gathers_alone(k, passes):
     assert _like_the_whole_buffer(s, passes) == []
 
 
-@pytest.mark.parametrize("held", [None, [4, 5, 6, 7]])
-def test_only_a_share_adds_rows_with_repeated_indices(held):
-    """The same fact of the operator itself, forward + backward: the form
-    is chosen by ``len(held) == num_experts``, and by nothing else."""
-    s = _sparse_setup()
-    held = tuple(range(16) if held is None else held)
+def _traced_layer(held, rows=0):
+    """``sparse_experts`` of a ``_sparse_setup`` as ``_sparse_grads`` wants
+    it, with nothing that stops a trace (``_sparse_layer`` checks counts)."""
+    held = tuple(held)
 
     def layer(t):
         at = jnp.asarray(held)
         return moe.sparse_experts(
             t["h"], t["router"], t["bias"], t["gate_up"][at], t["down"][at],
-            held=held, top_k=t["k"], scale=SCALE)[0]
+            held=held, top_k=t["k"], scale=SCALE, rows=rows)[0]
+    return layer
 
-    adds = _wide_row_adds(
-        lambda h, router, gate_up, down: _sparse_grads(
-            dict(s, h=h, router=router, gate_up=gate_up, down=down), layer),
-        s["h"], s["router"], s["gate_up"], s["down"], d=32)
+
+def _grads_to_trace(s, layer):
+    """``(fn, *args)`` for ``_equations``: forward + backward of ``layer``."""
+    return (lambda h, router, gate_up, down: _sparse_grads(
+        dict(s, h=h, router=router, gate_up=gate_up, down=down), layer),
+        s["h"], s["router"], s["gate_up"], s["down"])
+
+
+@pytest.mark.parametrize("held", [None, [4, 5, 6, 7]])
+def test_only_a_share_adds_rows_with_repeated_indices(held):
+    """The same fact of the operator itself, forward + backward: the form
+    is chosen by ``len(held) == num_experts``, and by nothing else."""
+    s = _sparse_setup()
+    held = range(16) if held is None else held
+    adds = _wide_row_adds(*_grads_to_trace(s, _traced_layer(held)), d=32)
     assert (adds == []) == (len(held) == 16), adds
 
 
@@ -499,3 +511,73 @@ def test_rows_moved_follows_the_pairs_and_not_the_buffer(held, tokens):
             load = blk.count.data().asnumpy()[held]
             assert 0 < row["rows_added"] == load.sum() - load.max() \
                 <= row["rows_moved"]
+
+
+# ---------------------------------------------------------------------------
+# what the layer keeps for its backward: where its pairs take one pass
+# whatever the routing (every expert held, the buffer their worst case) the
+# pass's two products, and no product a second time; otherwise nothing of a
+# pass, and the backward runs its passes again
+# ---------------------------------------------------------------------------
+
+
+def _grouped_products(fn, *args):
+    """The ``ragged_dot`` / ``ragged_dot_general`` equations in ``fn``: what
+    a grouped product is off the chip."""
+    return [eqn.primitive.name for eqn in _equations(fn, *args)
+            if eqn.primitive.name.startswith("ragged_dot")]
+
+
+@pytest.mark.parametrize("rows,products", [(0, 6), (96, 8)])
+def test_one_pass_multiplies_nothing_a_second_time(rows, products):
+    """Forward + backward of a layer that holds every expert: at the default
+    buffer (the pairs' worst case, one pass) two products forward and the
+    backward's four; a buffer that forces several passes keeps nothing of
+    one, so its backward multiplies the two forward products again (its
+    jaxpr holds a pass twice: the first, and the body of the loop over the
+    further ones)."""
+    s = _sparse_setup()
+    found = _grouped_products(
+        *_grads_to_trace(s, _traced_layer(range(16), rows)))
+    traced = 2 if rows else 1           # the first pass and the loop's body
+    assert len(found) == products * traced, found
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_one_pass_form_gives_the_several_pass_forms_gradients(k):
+    """The kept rows are the rows a second forward would make: loss and
+    every gradient (the input's, the router's, both expert matrices') of
+    the one-pass form are the several-pass form's of the same layer."""
+    s = _sparse_setup(k=k, seed=3)
+    one, several = (_traced_layer(range(16), rows) for rows in (0, 32 * k))
+    np.testing.assert_allclose(float(jnp.sum(jnp.sin(one(s)))),
+                               float(jnp.sum(jnp.sin(several(s)))),
+                               rtol=1e-5)
+    for name, a, b in zip(("h", "router", "gate_up", "down"),
+                          _sparse_grads(s, one), _sparse_grads(s, several)):
+        assert float(jnp.abs(b).max()) > 0, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("held,dtype", [(None, "float32"),
+                                        (None, "bfloat16"),
+                                        ([4, 5, 6, 7], "float32")])
+def test_kept_bytes_are_the_one_pass_two_products(held, dtype):
+    """``stats()["kept_bytes"]``: ``rows * (2 f + d)`` numbers of the layer's
+    dtype where every expert is held at the default buffer, 0 for a share,
+    None before a forward; ``profiler.get_moe_stats`` carries it."""
+    from mxtpu import nd, profiler
+    blk = moe.SparseExperts(32, 48, 16, 4, held=held)
+    blk.initialize()
+    blk.cast(dtype)
+    assert blk.stats()["kept_bytes"] is None
+    blk(nd.array(np.random.RandomState(0).randn(2, 48, 32), dtype=dtype))
+    row, = profiler.get_moe_stats(blk)
+    rows = row["buffer_rows"]
+    if held is None:
+        assert rows == 96 * 4 and row["passes"] == 1
+        assert row["kept_bytes"] \
+            == rows * (2 * 48 + 32) * (4 if dtype == "float32" else 2)
+    else:
+        assert row["kept_bytes"] == 0
