@@ -24,7 +24,10 @@ at the full width of the ``flagship`` preset (d1024, L8, H16, vocab 16384):
    grouped-query attention, an expert layer holding ALL its experts, a tied
    head with float32 logits) trains a step twice, once as it runs and once
    with every kernel site on its XLA formulation: first loss and every
-   parameter's first gradient must agree.
+   parameter's first gradient must agree. A third (power-retention layers
+   on grouped heads of 128, every block recomputed in the backward) does
+   the same: its ``retention_fwd`` / ``retention_bwd`` launches against the
+   chunked ``lax`` form.
 5. four chips, when there are four — leg 1 at B32 over dp=4 and a 2x2
    fsdp×tp serving engine, with where the bytes actually landed.
 
@@ -100,6 +103,9 @@ SIZES = {
         # a conv / attention decoder of the LFM2 family, every expert held
         conv_sparse=dict(units=256, head_dim=64, heads=4, kv_heads=2, ffn=512,
                          moe_ffn=128, experts=8, top_k=2, vocab=1024, T=512),
+        # a retention decoder of the Brumby family: heads of 128, 3 chunks
+        retention=dict(units=256, head_dim=128, heads=4, kv_heads=2, ffn=512,
+                       vocab=1024, T=768),
         multi=dict(B=32, serve_n=4),
     ),
     "rehearsal": dict(
@@ -116,6 +122,8 @@ SIZES = {
                     experts=8, held=(2, 3), top_k=2, vocab=50, T=32, steps=4),
         conv_sparse=dict(units=32, head_dim=8, heads=4, kv_heads=2, ffn=64,
                          moe_ffn=16, experts=4, top_k=2, vocab=50, T=32),
+        retention=dict(units=32, head_dim=8, heads=4, kv_heads=2, ffn=64,
+                       vocab=50, T=32),
         multi=dict(B=8, serve_n=2),
     ),
 }
@@ -727,6 +735,76 @@ def leg_conv_sparse_train(sz, on_chip: bool) -> dict:
             "kernel_paths": paths, "pairs": [r["pairs"] for r in stats]}
 
 
+# -- leg 4d: a tiny retention decoder's step against its lax form -------------
+
+def leg_retention_train(sz, on_chip: bool) -> dict:
+    """One step of a tiny ``HybridDecoderLM`` of the fourth family (every
+    mixer a power-retention layer on grouped heads with q/k norm, rotary
+    positions and a decay gate; pre-norm RMSNorm, an untied head, every
+    block recomputed in the backward) through ``DataParallelTrainer``,
+    twice from the same weights: as it runs (on the chip the
+    ``retention_fwd`` / ``retention_bwd`` launches, the state carried over
+    three chunks) and with the op on its chunked ``lax`` form. The first
+    loss and every parameter's first gradient must agree."""
+    import mxtpu as mx
+    from mxtpu import nd, optimizer, profiler
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    from mxtpu.parallel import DataParallelTrainer
+    from mxtpu.parallel.mesh import data_parallel_mesh
+    rs = sz["retention"]
+    seq = np.random.RandomState(2).randint(0, rs["vocab"], (1, rs["T"] + 1))
+    x, y = nd.array(seq[:, :-1]), nd.array(seq[:, 1:].astype(np.float32))
+
+    def one_step():
+        mx.random.seed(5)           # the same draw both times
+        net = HybridDecoderLM(
+            rs["vocab"], ["retention"] * 2, units=rs["units"],
+            ffn_units=rs["ffn"], num_heads=rs["heads"],
+            num_kv_heads=rs["kv_heads"], head_dim=rs["head_dim"],
+            layer_norm_eps=1e-6, attention="gqa", qk_norm=True,
+            rope_kinds=("retention",), rope_theta=1e6, norm="rms",
+            tie_head=False, remat=True)
+        net.initialize()
+        if on_chip:
+            net.cast("bfloat16")
+        dpt = DataParallelTrainer(net, seq_loss,
+                                  optimizer.Adam(learning_rate=1e-3),
+                                  data_parallel_mesh(1))
+        loss = float(dpt.step(x, y))
+        return loss, {
+            name.split("_", 1)[1]: np.asarray(slots[0], np.float32)
+            for name, slots in dpt.optimizer_state_by_param().items()}
+
+    profiler.reset_kernel_path_counts()
+    profiler.reset_retention_stats()
+    loss, moments = one_step()
+    paths, stats = (profiler.get_kernel_path_counts(),
+                    profiler.get_retention_stats())
+    with xla_formulations():
+        want_loss, want = one_step()
+    tol = 3e-2 if on_chip else 1e-4     # bfloat16 against bfloat16 / float32
+    check(abs(loss - want_loss) <= tol * want_loss,
+          f"retention train: first loss {loss} against lax's {want_loss}")
+    check(set(moments) == set(want)
+          and any("gate" in k for k in moments),
+          f"retention train: parameters {sorted(moments)}")
+    gaps = {k: float(np.linalg.norm(moments[k] - want[k])
+                     / max(np.linalg.norm(want[k]), 1e-30)) for k in want}
+    worst = max(gaps, key=gaps.get)
+    check(gaps[worst] <= 3 * tol,
+          f"retention train: first gradient of {worst} is "
+          f"{gaps[worst]:.4g} from the lax form's")
+    check(stats["launches"] >= 2 and stats["state_bytes_kept"] > 0,
+          f"retention train: {stats}")
+    if on_chip:
+        check(paths["retention"]["pallas"] > 0
+              and paths["retention"]["xla"] == 0,
+              f"retention train: call sites {paths['retention']}")
+    return {"loss": round(loss, 4), "lax_loss": round(want_loss, 4),
+            "worst_gradient_gap": [worst, round(gaps[worst], 5)],
+            "kernel_paths": paths["retention"], "retention": stats}
+
+
 # -- leg 5: four chips -------------------------------------------------------
 
 def placement(arrays: dict, devices) -> dict:
@@ -871,6 +949,8 @@ def main(argv=None) -> int:
         rep.update(out)
         rep, out = leg("conv_sparse_train", leg_conv_sparse_train, sz,
                        on_chip)
+        rep.update(out)
+        rep, out = leg("retention_train", leg_retention_train, sz, on_chip)
         rep.update(out)
 
         if len(devs) >= 4:

@@ -1,5 +1,5 @@
 """Decoder language models built from a list of layer kinds: a mixer kind, an
-MLP kind and a norm for each layer. Three published families are instances:
+MLP kind and a norm for each layer. Four published families are instances:
 decoder-hybrid-decoder models (SambaY; Phi-4-mini-flash-reasoning: Mamba-1
 state-space layers, differential attention over a window, over everything
 and across layers, gated memory units, no positional encoding of any kind),
@@ -9,7 +9,11 @@ head, ``mlp_kinds`` with ``"moe"``) and sparse models whose mixers are
 gated short convolutions beside grouped-query attention (LFM2's
 ``lfm2_moe``: kinds ``conv`` and ``attn_full``, ``attention="gqa"``,
 ``norm="rms"``, pre-norm, a tied head with float32 logits, every expert
-held and no shared one).
+held and no shared one) and dense models whose every mixer is a
+power-retention layer (Brumby-14B: kind ``retention``, Qwen3's block
+otherwise: ``attention="gqa"``, ``qk_norm``, rotary positions,
+``norm="rms"``, pre-norm, an untied head; ``remat=True`` recomputes a block
+at a time in the backward).
 
 A model is a list of layer kinds and the widths; nothing here is a preset.
 Every layer is ``h = x + Mixer(N(x)); out = h + MLP(N'(h))``, or with
@@ -45,6 +49,21 @@ own (``tie_head=False``, float32 logits). The mixers, by kind:
     (depthwise, ``d_conv`` taps, no bias, zeros before the first row);
     output ``W_out (C * v)``. Both gates are plain products: no activation
     function anywhere in it.
+``retention``
+    gated power retention of degree 2 (``PowerRetention``;
+    ``ops/retention.py``). ``[q, k, v] = W_qkv x`` on ``H`` query heads and
+    ``Hkv`` key and value heads; q and k normed and rotated as under
+    ``attention="gqa"`` below (the same code); one decay a KEY/VALUE head a
+    token, float32: ``log g_t[j] = log_sigmoid(w_g[j] . x_t + b_g[j])``
+    (child ``gate``); for query head ``h`` in group ``j = h // (H / Hkv)``,
+    over ``s <= t``: ``a[t, s] = (q_t[h] . k_s[j] / sqrt(D))^2 * exp(log
+    g_{s+1}[j] + .. + log g_t[j])`` and ``y_t[h] = sum_s a[t, s] v_s[j] /
+    (sum_s a[t, s] + retention_eps)`` (1: one null key of average
+    weight, so the first rows fade in and nothing magnifies bf16's rounding);
+    output ``W_o concat_h y_t[h]``. Equal to a state: with ``phi(k)`` the ``D (D + 1) / 2`` products ``k_a k_b``, a
+    key/value head carries ``S_t = g_t S_{t-1} + phi(k_t) [v_t, 1]^T / D``
+    (8256 x 129 float32 at ``D`` = 128) and the query heads of a group read
+    that ONE state, which is how the op computes it: linear in ``T``.
 
 With ``attention="gqa"`` the kinds ``attn_window`` / ``attn_full`` are
 ``GroupedQueryAttention`` under the same child names: a fused ``qkv``
@@ -52,14 +71,16 @@ without bias, with ``qk_norm`` an RMSNorm over ``head_dim`` on every query
 and key head (scope ``qk_norm``), rotary positions (rotate-half, all of
 ``head_dim``; scope ``rope``) in the kinds that ``rope_kinds`` names, plain
 softmax through ``flash_chunk`` with query head ``h`` on key/value head ``h
-// (H / Hkv)``, and ``out_proj`` without bias.
+// (H / Hkv)``, and ``out_proj`` without bias. ``retention`` is built from
+the same projections, norm and positions whatever ``attention`` says.
 
 Keys/values and the scan's output are made ONCE and read by every later
 layer that wants them: gradients flow back into the one producer from all
 its consumers. That is the training path, and the only one: there is no
 decode cache for any of these kinds yet (``generate`` and the serving steps
 raise), because a cache here has to hold a window's keys, one layer's full
-keys for all cross layers, and scan and convolution states side by side.
+keys for all cross layers, scan and convolution states side by side, and a
+retention layer's 8256 x 129 matrix a key/value head.
 """
 
 from __future__ import annotations
@@ -69,11 +90,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ... import initializer
+from ... import autograd, initializer
 from ... import ndarray as nd
 from ...ops import registry
 from ...ops.attention import flash_chunk
 from ...ops.nn import rms_norm
+from ...ops.retention import power_retention
 from ...parallel.moe import SparseExperts
 from ..block import HybridBlock
 from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
@@ -81,8 +103,13 @@ from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
 
 __all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS", "MLP_KINDS"]
 
-KINDS = ("mamba", "attn_window", "attn_full", "attn_cross", "gmu", "conv")
+KINDS = ("mamba", "attn_window", "attn_full", "attn_cross", "gmu", "conv",
+         "retention")
 MLP_KINDS = ("mlp", "moe")
+# kinds whose block (with a dense MLP, which has no state) runs under
+# jax.checkpoint where asked: they neither hand anything on to later layers
+# nor read it. Only what a cell runs so is listed
+REMAT_KINDS = ("retention",)
 
 
 class _ALog(initializer.Initializer):
@@ -237,6 +264,20 @@ def _rope(x, theta: float):
                            axis=-1).astype(x.dtype)
 
 
+def _norm_rope(q, k, q_gain, k_gain, rope_theta: float, eps: float):
+    """What the grouped-query kinds do to ``q`` ``(B, T, H, D)`` and ``k``
+    ``(B, T, Hkv, D)`` before they meet: with gains an RMSNorm over ``D`` on
+    every head (scope ``qk_norm``), then with ``rope_theta`` > 0 rotary
+    positions (scope ``rope``)."""
+    if q_gain is not None:
+        with jax.named_scope("qk_norm"):
+            q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
+    if rope_theta:
+        with jax.named_scope("rope"):
+            q, k = _rope(q, rope_theta), _rope(k, rope_theta)
+    return q, k
+
+
 @registry.register("gq_attention", namespace="contrib")
 def gq_attention(q, k, v, q_gain=None, k_gain=None, rope_theta: float = 0.0,
                  window=None, eps: float = 1e-5):
@@ -248,12 +289,7 @@ def gq_attention(q, k, v, q_gain=None, k_gain=None, rope_theta: float = 0.0,
     none. Scores ``q k^T / sqrt(D)``, ``window`` as in ``flash_attention``.
     Returns ``(B, T, H * D)``."""
     B, T, H, D = q.shape
-    if q_gain is not None:
-        with jax.named_scope("qk_norm"):
-            q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
-    if rope_theta:
-        with jax.named_scope("rope"):
-            q, k = _rope(q, rope_theta), _rope(k, rope_theta)
+    q, k = _norm_rope(q, k, q_gain, k_gain, rope_theta, eps)
     out, _ = flash_chunk(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                          v.transpose(0, 2, 1, 3), True, 1.0 / math.sqrt(D),
                          window)
@@ -261,6 +297,33 @@ def gq_attention(q, k, v, q_gain=None, k_gain=None, rope_theta: float = 0.0,
 
 
 _GQ_ATTENTION = registry.get_op("contrib.gq_attention")
+
+
+@registry.register("gq_retention", namespace="contrib")
+def gq_retention(q, k, v, log_g, q_gain=None, k_gain=None,
+                 rope_theta: float = 0.0, eps: float = 1e-5,
+                 retention_eps=None):
+    """Gated power retention on grouped heads: ``gq_attention``'s norm and
+    positions on ``q`` and ``k``, then ``ops.retention.power_retention``
+    under the scope ``scan``. ``log_g`` ``(B, T, Hkv)`` float32;
+    ``retention_eps`` ``None`` is the op's own (``ops.retention.EPS``).
+    Returns ``(B, T, H * D)``."""
+    q, k = _norm_rope(q, k, q_gain, k_gain, rope_theta, eps)
+    with jax.named_scope("scan"):
+        return power_retention(q, k, v, log_g, retention_eps)
+
+
+@registry.register("decay_gate", namespace="contrib")
+def decay_gate(x, weight, bias):
+    """``log_sigmoid(x W^T + b)`` in float32 whatever ``x`` and ``W`` are
+    stored in: the log of one decay a head a token, ``(B, T, heads)``."""
+    logits = jnp.einsum("btd,hd->bth", x, weight,
+                        preferred_element_type=jnp.float32)
+    return jax.nn.log_sigmoid(logits + bias.astype(jnp.float32))
+
+
+_GQ_RETENTION = registry.get_op("contrib.gq_retention")
+_DECAY_GATE = registry.get_op("contrib.decay_gate")
 
 
 class GroupedQueryAttention(HybridBlock):
@@ -291,16 +354,86 @@ class GroupedQueryAttention(HybridBlock):
             self.k_norm = self.params.get(
                 "k_norm", shape=(head_dim,), init="ones") if qk_norm else None
 
-    def forward(self, x):
+    def _qkv_heads(self, x):
+        """``(q (B, T, H, D), k, v (B, T, Hkv, D))`` of the fused
+        projection, and the two gains where q and k are normed."""
         B, T, _ = x.shape
         H, Hkv, D = self._heads, self._kv_heads, self._dim
         q, k, v = _split(self.qkv(x), (H * D, Hkv * D, Hkv * D))
         k, v = k.reshape((B, T, Hkv, D)), v.reshape((B, T, Hkv, D))
-        args = (q.reshape((B, T, H, D)), k, v)
-        if self.q_norm is not None:
-            args += (self.q_norm.data(), self.k_norm.data())
-        out = registry.invoke(_GQ_ATTENTION, *args, **self._attrs)
+        heads = (q.reshape((B, T, H, D)), k, v)
+        if self.q_norm is None:
+            return heads, ()
+        return heads, (self.q_norm.data(), self.k_norm.data())
+
+    def forward(self, x):
+        (q, k, v), gains = self._qkv_heads(x)
+        out = registry.invoke(_GQ_ATTENTION, q, k, v, *gains, **self._attrs)
         return self.out_proj(out), (k, v)
+
+
+class _HalfLives(initializer.Initializer):
+    """``b[j] = logit(g0[j])`` with the half-lives ``ln 2 / -ln g0`` spaced
+    log-uniformly from ``lo`` to ``hi`` tokens over the heads: a trained
+    gated model's range of memories."""
+
+    def __init__(self, lo: float = 64.0, hi: float = 8192.0):
+        super().__init__(lo=lo, hi=hi)
+        self.lo, self.hi = lo, hi
+
+    def init_array(self, name, arr):
+        half = jnp.exp(jnp.linspace(math.log(self.lo), math.log(self.hi),
+                                    arr.shape[0], dtype=jnp.float32))
+        log_g0 = -math.log(2.0) / half
+        arr._set_data((log_g0 - jnp.log(-jnp.expm1(log_g0)))
+                      .astype(arr.dtype))
+
+
+class DecayGate(HybridBlock):
+    """One log-decay a head a token: ``log_sigmoid(W x + b)``, computed in
+    float32. The bias is kept in float32 whatever the model is cast to, as
+    the differential-attention lambdas are: at ``logit(g0)`` near 9 an Adam
+    step of 3e-4 is a hundredth of one bfloat16 step."""
+
+    def __init__(self, units: int, heads: int, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.weight = self.params.get(
+                "weight", shape=(heads, units), init="normal")
+            self.bias = self.params.get(
+                "bias", shape=(heads,), init=_HalfLives(), keep_float32=True)
+
+    def forward(self, x):
+        return registry.invoke(_DECAY_GATE, x, self.weight.data(),
+                               self.bias.data())
+
+
+class PowerRetention(GroupedQueryAttention):
+    """A power-retention layer of degree 2 on grouped heads:
+    ``GroupedQueryAttention``'s fused ``qkv``, q/k RMSNorm, rotary positions
+    and ``out_proj``, with the softmax replaced by
+    ``contrib.power_retention`` and its learned decay (child ``gate``: one
+    logit a KEY/VALUE head a token, so that a group shares a state).
+    ``forward`` returns the output alone: nothing is handed on. A device
+    trace reads ``block<i>/retention/qkv|qk_norm|rope|gate|scan|out_proj``."""
+
+    def __init__(self, units: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, rope_theta: float = 0.0,
+                 qk_norm: bool = False, norm_eps: float = 1e-5,
+                 retention_eps=None, prefix=None, params=None):
+        super().__init__(units, num_heads, num_kv_heads, head_dim,
+                         rope_theta=rope_theta, qk_norm=qk_norm,
+                         norm_eps=norm_eps, prefix=prefix, params=params)
+        self._attrs = dict(rope_theta=float(rope_theta), eps=norm_eps,
+                           retention_eps=retention_eps)
+        with self.name_scope():
+            self.gate = DecayGate(units, num_kv_heads)
+
+    def forward(self, x):
+        (q, k, v), gains = self._qkv_heads(x)
+        out = registry.invoke(_GQ_RETENTION, q, k, v, self.gate(x), *gains,
+                              **self._attrs)
+        return self.out_proj(out)
 
 
 class GatedMemoryUnit(HybridBlock):
@@ -380,6 +513,12 @@ class HybridDecoderBlock(HybridBlock):
                 mixer = GatedMemoryUnit(units, z["d_inner"])
             elif kind == "conv":
                 mixer = ShortConv(units, z["d_conv"])
+            elif kind == "retention":
+                mixer = PowerRetention(
+                    units, z["num_heads"], z["num_kv_heads"], z["head_dim"],
+                    rope_theta=z["rope_theta"] if kind in z["rope_kinds"]
+                    else 0.0, qk_norm=z["qk_norm"], norm_eps=eps,
+                    retention_eps=z["retention_eps"])
             elif z["attention"] == "gqa":
                 if kind == "attn_cross":
                     raise ValueError("grouped-query attention has no "
@@ -409,7 +548,7 @@ class HybridDecoderBlock(HybridBlock):
             mixed, shared["memory"] = mixer(h)
         elif self.kind == "gmu":
             mixed = mixer(h, shared.get("memory"))
-        elif self.kind == "conv":
+        elif self.kind in ("conv", "retention"):
             mixed = mixer(h)
         elif self.kind == "attn_cross":
             mixed, _ = mixer(h, shared.get("kv"))
@@ -457,10 +596,23 @@ class HybridDecoderLM(HybridBlock):
     ``num_experts``, ``top_k``, ``held``, ``shared_ffn_units``,
     ``routed_scale``, ``bias_update_rate``, ``weight_eps``).
 
-    Three families are built from it (the module's docstring has their
+    ``retention_eps`` is what the ``retention`` kind adds to the sum of a
+    row's weights before it divides by it; ``None`` leaves the op's own
+    (``ops.retention.EPS``, the one place that states it).
+
+    ``remat=True`` runs every block under ``jax.checkpoint`` where the step
+    is traced (``DataParallelTrainer``; not on the imperative tape): the
+    backward keeps each block's INPUT and computes the block again, a layer
+    at a time, for one more forward's operations. It is for models whose
+    kept activations do not fit beside their state (five 330M-parameter
+    layers at 8192 tokens keep 7.5 GB); only layers that hand nothing on
+    and hold no state take it (``REMAT_KINDS`` with a dense MLP).
+
+    Four families are built from it (the module's docstring has their
     specs): decoder-hybrid-decoder, sparse grouped-query window / full
-    attention, and gated short convolutions (``conv``) beside grouped-query
-    attention with expert layers that hold all their experts.
+    attention, gated short convolutions (``conv``) beside grouped-query
+    attention with expert layers that hold all their experts, and
+    power-retention layers (``retention``) in Qwen3's dense block.
     """
 
     def __init__(self, vocab_size: int, layer_kinds, units: int,
@@ -471,7 +623,8 @@ class HybridDecoderLM(HybridBlock):
                  qk_norm: bool = False, rope_kinds=(), rope_theta: float = 1e4,
                  norm: str = "layer", norm_position: str = "pre",
                  tie_head: bool = True, mlp_kinds=None, moe=None,
-                 float32_logits: bool = False, prefix=None, params=None):
+                 float32_logits: bool = False, remat: bool = False,
+                 retention_eps=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         for what, value, known in (
                 ("attention", attention, ("diff", "gqa")),
@@ -488,6 +641,13 @@ class HybridDecoderLM(HybridBlock):
                              f"{len(self.mlp_kinds)} MLP kinds")
         if "moe" in self.mlp_kinds and not moe:
             raise ValueError("mlp_kinds names an expert layer: give moe=")
+        self._remat = remat
+        if remat and (set(self.layer_kinds) - set(REMAT_KINDS)
+                      or "moe" in self.mlp_kinds):
+            raise ValueError(
+                f"remat=True recomputes blocks that hand nothing on and "
+                f"hold no state: kinds {REMAT_KINDS} with a dense MLP, not "
+                f"{self.layer_kinds} / {self.mlp_kinds}")
         z = dict(units=units, ffn_units=ffn_units, num_heads=num_heads,
                  num_kv_heads=num_kv_heads,
                  head_dim=head_dim or units // num_heads, window=window,
@@ -495,7 +655,8 @@ class HybridDecoderLM(HybridBlock):
                  d_conv=d_conv, dt_rank=dt_rank or -(-units // 16),
                  eps=layer_norm_eps, attention=attention, qk_norm=qk_norm,
                  rope_kinds=tuple(rope_kinds), rope_theta=rope_theta,
-                 norm=norm, norm_position=norm_position, moe=moe)
+                 norm=norm, norm_position=norm_position, moe=moe,
+                 retention_eps=retention_eps)
         with self.name_scope():
             self.embedding = Embedding(vocab_size, units,
                                        weight_initializer="normal")
@@ -514,8 +675,13 @@ class HybridDecoderLM(HybridBlock):
         B, T = tokens.shape
         h = self.embedding(tokens)
         shared = {}
+        remat = self._remat and not autograd.is_recording()
         for blk in self.blocks:
-            h = blk(h, shared)
+            if remat:   # the block again in the backward, from its input
+                h = nd.NDArray(jax.checkpoint(
+                    lambda x, blk=blk: blk(nd.NDArray(x), {}).data)(h.data))
+            else:
+                h = blk(h, shared)
         h = self.ln_f(h)
         if self.head is not None:
             # float32 logits: in a TPU step with bfloat16 logits XLA adds
@@ -537,7 +703,10 @@ class HybridDecoderLM(HybridBlock):
             f"needs a cache that holds, side by side, a window of keys for "
             f"attn_window layers, one attn_full layer's keys for every "
             f"attn_cross layer, scan and convolution states for mamba "
-            f"layers, and a conv layer's last d_conv - 1 rows a channel; "
+            f"layers, a conv layer's last d_conv - 1 rows a channel, and a "
+            f"retention layer's state (a head_dim (head_dim + 1) / 2 x "
+            f"(head_dim + 1) matrix, 8256 x 129 at 128, a key/value head a "
+            f"layer a slot); "
             f"the engine has one cache geometry (ROADMAP D1/D2, M3, M6). "
             f"Layer kinds: {self.layer_kinds}")
 

@@ -804,11 +804,13 @@ def reset_sanitizer_stats():
 
 
 # ---------------------------------------------------------------------------
-# kernel paths (ops/attention.py, ops/ssm.py, ops/grouped_matmul.py): Pallas
+# kernel paths (ops/attention.py, ops/ssm.py, ops/grouped_matmul.py,
+# ops/retention.py): Pallas
 # or XLA, per call site
 # ---------------------------------------------------------------------------
 
-KERNEL_KINDS = ("flash", "flash_window", "ssm_scan", "grouped_matmul")
+KERNEL_KINDS = ("flash", "flash_window", "ssm_scan", "grouped_matmul",
+                "retention")
 _kernel_paths = {kind: {"pallas": 0, "xla": 0} for kind in KERNEL_KINDS}
 
 
@@ -821,8 +823,8 @@ def record_kernel_path(kind: str, pallas: bool):
 
 
 def get_kernel_path_counts() -> dict:
-    """``{"flash" | "flash_window" | "ssm_scan" | "grouped_matmul":
-    {"pallas": n, "xla": n}}`` since the last reset: how many call sites
+    """``{"flash" | "flash_window" | "ssm_scan" | "grouped_matmul" |
+    "retention": {"pallas": n, "xla": n}}`` since the last reset: how many call sites
     took the Pallas kernels and how many the XLA formulation (another backend than the TPU, or a shape
     the kernels do not take). A TPU step that should run kernels reads
     ``xla == 0``."""
@@ -834,3 +836,30 @@ def reset_kernel_path_counts():
     with _stats_lock:
         for row in _kernel_paths.values():
             row.update(pallas=0, xla=0)
+
+
+_retention = {"launches": 0, "chunk": 0, "chunks": 0, "state_bytes_kept": 0}
+
+
+def record_retention_launch(chunk: int, chunks: int, state_bytes_kept: int):
+    """One call site of ``contrib.power_retention`` was traced (or run
+    eagerly): its chunk length, the chunks a sequence and the bytes of
+    chunk-start state its forward keeps for its backward."""
+    with _stats_lock:
+        _retention.update(launches=_retention["launches"] + 1, chunk=chunk,
+                          chunks=chunks, state_bytes_kept=state_bytes_kept)
+
+
+def get_retention_stats() -> dict:
+    """``{"launches", "chunk", "chunks", "state_bytes_kept"}``: call sites
+    of ``contrib.power_retention`` since the last reset, and the NEWEST
+    one's chunk length, chunks a sequence and bytes of chunk-start state
+    kept for the backward (one layer's: a model that recomputes a block at a
+    time holds that much at a time)."""
+    with _stats_lock:
+        return dict(_retention)
+
+
+def reset_retention_stats():
+    with _stats_lock:
+        _retention.update(launches=0, chunk=0, chunks=0, state_bytes_kept=0)

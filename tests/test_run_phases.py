@@ -22,7 +22,8 @@ from mxtpu.parallel.mesh import data_parallel_mesh
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SUITE = os.path.join(ROOT, "benchmark", "suite")
 CELLS = ["gpt2m_train_t1024", "cgpt13_train_t2048", "phi4flash_train_t8192",
-         "kexaone_train_t4096", "lfm2moe_train_t4096"]
+         "kexaone_train_t4096", "lfm2moe_train_t4096",
+         "brumby_train_t8192"]
 NEW_METRICS = [
     "import_s.train", "net_build_s.train", "first_run_s.train",
     "step_compiled_in_process.train", "device_reserved_gb.train",
@@ -363,6 +364,9 @@ def test_set_up_readers_read_the_programs_totals(ran, monkeypatch):
     totals = dict(ran["totals"], **{
         "import/mxtpu": {"seconds": 3.5}, "import/jax": {"seconds": 0.5},
         "net/cast": {"seconds": 0.25}, "param/set_data": {"seconds": 0.5}})
+    # a worker that ran an example first has a compile cache placed, and the
+    # fixture's step then counts as compiled in process: not this test's
+    totals.pop("jax/cache_miss", None)
     monkeypatch.setattr(scopes, "span_totals", lambda: totals)
     assert _reader("import_s.train").read(view) == pytest.approx(3.0)
     assert _reader("net_build_s.train").read(view) == pytest.approx(
@@ -370,7 +374,7 @@ def test_set_up_readers_read_the_programs_totals(ran, monkeypatch):
     assert _reader("first_run_s.train").read(view) == pytest.approx(
         totals["train/first_readback"]["seconds"])
     compiled = _reader("step_compiled_in_process.train").read
-    assert compiled(view) == 0              # nothing missed a cache here
+    assert compiled(view) == 0              # no miss, no compile here
     totals["jax/cache_miss"] = {"count_by_parent": {"train/compile": 1,
                                                     "": 40}}
     assert compiled(view) == 1

@@ -159,3 +159,26 @@ def test_grouped_matmul_kernels_compile_at_the_conv_cells_size(
 
     text = jax.jit(all_three).lower(x, w, gs, dy).compile().as_text()
     assert "moe_gmm" in text and "moe_tgmm" in text
+
+
+def test_retention_kernels_compile_at_the_retention_cells_size(one_chip,
+                                                               quiet_cache):
+    """40 query heads of 128 on 8 key/value heads, T = 8192: a mixer of
+    ``brumby_train_t8192``, forward and (under grad) backward, the state of
+    a key/value head (65 tiles of 128 x 128 float32) in VMEM beside its
+    bf16 copy and the kept chunk start's two buffers."""
+    from mxtpu.ops import retention as R
+    bf, f32 = jnp.bfloat16, jnp.float32
+    avals = _avals(one_chip, ((1, T, 40 * 128), bf), ((1, T, 8 * 128), bf),
+                   ((1, T, 8 * 128), bf), ((1, T, 8), f32))
+
+    def loss(*a):
+        return jnp.sum(R._retention_pallas(*a, R.EPS).astype(f32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *avals).compile()
+    text = compiled.as_text()
+    assert "retention_fwd" in text and "retention_bwd" in text
+    # linear in T: the chunk starts (0.55 GB) are the largest thing kept,
+    # and nothing the size of T x T (40 heads: 5.4 GB) or T x 8256 exists
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
