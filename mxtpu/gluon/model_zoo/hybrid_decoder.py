@@ -92,6 +92,7 @@ import jax.numpy as jnp
 
 from ... import autograd, initializer
 from ... import ndarray as nd
+from ...observability import metrics
 from ...ops import registry
 from ...ops.attention import flash_chunk
 from ...ops.nn import rms_norm
@@ -600,10 +601,15 @@ class HybridDecoderLM(HybridBlock):
     row's weights before it divides by it; ``None`` leaves the op's own
     (``ops.retention.EPS``, the one place that states it).
 
-    ``remat=True`` runs every block under ``jax.checkpoint`` where the step
-    is traced (``DataParallelTrainer``; not on the imperative tape): the
-    backward keeps each block's INPUT and computes the block again, a layer
-    at a time, for one more forward's operations. It is for models whose
+    ``remat=True`` runs every block BUT THE LAST under ``jax.checkpoint``
+    where the step is traced (``DataParallelTrainer``; not on the imperative
+    tape): the backward keeps each such block's INPUT and computes the block
+    again, a layer at a time, for one more forward's operations. The last
+    block runs as it is: its backward begins as soon as the head and the
+    loss are through, so recomputing it would rebuild at once what it
+    declined to keep, and free nothing (a one-layer model is the plain
+    program). ``profiler.get_remat_stats()`` says how many blocks the newest
+    traced step had and how many it recomputes. It is for models whose
     kept activations do not fit beside their state (five 330M-parameter
     layers at 8192 tokens keep 7.5 GB); only layers that hand nothing on
     and hold no state take it (``REMAT_KINDS`` with a dense MLP).
@@ -676,8 +682,13 @@ class HybridDecoderLM(HybridBlock):
         h = self.embedding(tokens)
         shared = {}
         remat = self._remat and not autograd.is_recording()
+        if remat:
+            metrics.record_remat(len(self.blocks), len(self.blocks) - 1)
         for blk in self.blocks:
-            if remat:   # the block again in the backward, from its input
+            # the block again in the backward, from its input; not the last
+            # one, whose backward comes first: its activations are live then
+            # either way
+            if remat and blk is not self.blocks[-1]:
                 h = nd.NDArray(jax.checkpoint(
                     lambda x, blk=blk: blk(nd.NDArray(x), {}).data)(h.data))
             else:

@@ -863,3 +863,30 @@ def get_retention_stats() -> dict:
 def reset_retention_stats():
     with _stats_lock:
         _retention.update(launches=0, chunk=0, chunks=0, state_bytes_kept=0)
+
+
+_remat = {"blocks": 0, "recomputed": 0}
+
+
+def record_remat(blocks: int, recomputed: int):
+    """A model that recomputes its blocks (``HybridDecoderLM(remat=True)``)
+    traced a step: how many blocks it has and how many of them run under
+    ``jax.checkpoint``. Recorded at TRACE time, as the kernel paths are; a
+    model that never asks records nothing."""
+    with _stats_lock:
+        _remat.update(blocks=blocks, recomputed=recomputed)
+
+
+def get_remat_stats() -> dict:
+    """``{"blocks", "recomputed"}`` of the NEWEST traced step that asked
+    for recomputation since the last reset: the model's blocks, and those
+    whose forward runs again in the backward (all but the last, whose
+    activations are live at its backward either way). Zeros where no such
+    step was traced."""
+    with _stats_lock:
+        return dict(_remat)
+
+
+def reset_remat_stats():
+    with _stats_lock:
+        _remat.update(blocks=0, recomputed=0)

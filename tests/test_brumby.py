@@ -358,31 +358,53 @@ def test_zeroing_the_carried_state_moves_the_loss_past_the_cells_limit(
     assert abs(local - whole) / whole > 2 * limits["loss_gap"]
 
 
+@pytest.mark.parametrize("layers", [1, 2, 3])
 def test_recomputing_a_block_at_a_time_gives_the_plain_steps_gradients(
-        ref, system, weights, batch):
-    """``remat=True``: each block under ``jax.checkpoint`` where the step is
-    traced; the first step's loss and gradient are the plain step's."""
-    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+        ref, system, batch, layers):
+    """``remat=True``: every block but the last under ``jax.checkpoint``
+    where the step is traced (the last one's backward comes first, so
+    recomputing it would free nothing); the first step's loss and gradient
+    are the plain step's, and the counter says what was recomputed."""
     x, y = batch
+    cfg = dict(CFG, num_hidden_layers=layers)
+    weights = ref.make_weights(cfg, 7, "float32")
     got = {}
     for remat in (False, True):
-        net = system.build_net(dict(CFG, recompute_blocks=remat), weights,
+        profiler.reset_remat_stats()
+        net = system.build_net(dict(cfg, recompute_blocks=remat), weights,
                                "float32")
         trainer = system.Trainer(net, ADAM)
         loss = float(trainer.step(*trainer.place(x, y)))
-        got[remat] = (loss, trainer.first_gradient_norm())
-        # (the op's chunked form checkpoints its chunks either way)
-        got[remat] += (trainer.dpt.lowered().as_text().count(
-            "optimization_barrier"),)
-    assert got[True][2] > got[False][2]
-    assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
-    assert got[True][1] == pytest.approx(got[False][1], rel=1e-5)
+        got[remat] = (loss, trainer.first_gradient_norm(),
+                      trainer.dpt.lowered().as_text(debug_info=True),
+                      profiler.get_remat_stats())
+    plain, again = got[False], got[True]
+    assert again[0] == pytest.approx(plain[0], rel=1e-6)
+    assert again[1] == pytest.approx(plain[1], rel=1e-5)
+    assert "rematted_computation/block" not in plain[2]
+    for i in range(layers):
+        assert (f"rematted_computation/block{i}/" in again[2]) \
+            == (i < layers - 1), i
+    if layers == 1:     # nothing to free: the plain program
+        assert again[2] == plain[2]
+    assert plain[3] == {"blocks": 0, "recomputed": 0}
+    assert again[3] == {"blocks": layers, "recomputed": layers - 1}
+    profiler.reset_remat_stats()
+    assert profiler.get_remat_stats() == {"blocks": 0, "recomputed": 0}
+
+
+def test_recomputation_leaves_the_tape_alone_and_refuses_other_kinds(
+        system, weights, batch):
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    x, _ = batch
     # on the imperative tape a block runs as it is
+    profiler.reset_remat_stats()
     net = system.build_net(dict(CFG, recompute_blocks=True), weights,
                            "float32")
     with autograd.record():
         out = net(nd.array(x))
     assert out.shape == (8, T, 96)
+    assert profiler.get_remat_stats() == {"blocks": 0, "recomputed": 0}
     with pytest.raises(ValueError, match="hand nothing on"):
         HybridDecoderLM(32, ["mamba", "retention"], 64, 128, 4, 2,
                         remat=True)
