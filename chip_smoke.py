@@ -27,7 +27,10 @@ at the full width of the ``flagship`` preset (d1024, L8, H16, vocab 16384):
    parameter's first gradient must agree. A third (power-retention layers
    on grouped heads of 128, every block recomputed in the backward) does
    the same: its ``retention_fwd`` / ``retention_bwd`` launches against the
-   chunked ``lax`` form.
+   chunked ``lax`` form. A fourth (Kimi-Delta-Attention layers of 128-wide
+   heads beside a latent-attention layer, expert layers under a
+   group-limited router) does the same: ``kda_fwd`` / ``kda_bwd`` over three
+   chunks and the flash launches at 192 / 128 against their ``lax`` forms.
 5. four chips, when there are four — leg 1 at B32 over dp=4 and a 2x2
    fsdp×tp serving engine, with where the bytes actually landed.
 
@@ -106,6 +109,14 @@ SIZES = {
         # a retention decoder of the Brumby family: heads of 128, 3 chunks
         retention=dict(units=256, head_dim=128, heads=4, kv_heads=2, ffn=512,
                        vocab=1024, T=768),
+        # a delta-rule / latent decoder of the Ling family: heads of 128, 3
+        # chunks, keys of 128 + 64 from a latent of 128, 16 experts in 4
+        # groups of which 2 are kept
+        kda=dict(units=256, head_dim=128, heads=2, ffn=512, moe_ffn=128,
+                 experts=16, held=(0, 1, 2, 3), top_k=2, n_group=4,
+                 topk_group=2, vocab=1024, T=384,
+                 mla=dict(latent_dim=128, nope_dim=128, rope_dim=64,
+                          v_dim=128)),
         multi=dict(B=32, serve_n=4),
     ),
     "rehearsal": dict(
@@ -124,6 +135,10 @@ SIZES = {
                          moe_ffn=16, experts=4, top_k=2, vocab=50, T=32),
         retention=dict(units=32, head_dim=8, heads=4, kv_heads=2, ffn=64,
                        vocab=50, T=32),
+        kda=dict(units=32, head_dim=8, heads=4, ffn=64, moe_ffn=16,
+                 experts=8, held=(0, 1), top_k=2, n_group=4, topk_group=2,
+                 vocab=50, T=32,
+                 mla=dict(latent_dim=16, nope_dim=8, rope_dim=4, v_dim=8)),
         multi=dict(B=8, serve_n=2),
     ),
 }
@@ -805,6 +820,94 @@ def leg_retention_train(sz, on_chip: bool) -> dict:
             "kernel_paths": paths["retention"], "retention": stats}
 
 
+# -- leg 4e: a tiny delta-rule / latent decoder's step against its lax form ---
+
+def leg_kda_train(sz, on_chip: bool) -> dict:
+    """One step of a tiny ``HybridDecoderLM`` of the fifth family (two
+    Kimi-Delta-Attention layers and a latent-attention layer, pre-norm
+    RMSNorm, an untied head) through ``DataParallelTrainer``, twice from the same
+    weights: as it runs (on the chip the ``kda_fwd`` / ``kda_bwd`` launches,
+    the state carried over three chunks, and the flash launches at keys of
+    192 and values of 128) and with every kernel site on its XLA
+    formulation (the delta rule's chunked ``lax`` form). The first loss and
+    every parameter's first gradient must agree, with dense MLPs in every
+    layer; the expert layers under their group-limited router run a step of
+    their own (their grouped matmuls Pallas call sites on the chip)."""
+    import mxtpu as mx
+    from mxtpu import nd, optimizer, profiler
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    from mxtpu.parallel import DataParallelTrainer
+    from mxtpu.parallel.mesh import data_parallel_mesh
+    ks = sz["kda"]
+    seq = np.random.RandomState(3).randint(0, ks["vocab"], (1, ks["T"] + 1))
+    x, y = nd.array(seq[:, :-1]), nd.array(seq[:, 1:].astype(np.float32))
+
+    def one_step(mlp_kinds=("mlp", "mlp", "mlp")):
+        mx.random.seed(7)           # the same draw both times
+        net = HybridDecoderLM(
+            ks["vocab"], ["kda", "kda", "mla"], units=ks["units"],
+            ffn_units=ks["ffn"], num_heads=ks["heads"],
+            num_kv_heads=ks["heads"], head_dim=ks["head_dim"], d_conv=4,
+            layer_norm_eps=1e-6, rope_theta=6e6, norm="rms", tie_head=False,
+            mla=dict(ks["mla"], interleave=True),
+            mlp_kinds=list(mlp_kinds),
+            moe=dict(ffn_units=ks["moe_ffn"], num_experts=ks["experts"],
+                     top_k=ks["top_k"], held=ks["held"],
+                     shared_ffn_units=ks["moe_ffn"], routed_scale=2.5,
+                     bias_update_rate=0.03, n_group=ks["n_group"],
+                     topk_group=ks["topk_group"]))
+        net.initialize()
+        if on_chip:
+            net.cast("bfloat16")
+        dpt = DataParallelTrainer(net, seq_loss,
+                                  optimizer.Adam(learning_rate=1e-3),
+                                  data_parallel_mesh(1))
+        loss = float(dpt.step(x, y))
+        return loss, {
+            name.split("_", 1)[1]: np.asarray(slots[0], np.float32)
+            for name, slots in dpt.optimizer_state_by_param().items()}, \
+            profiler.get_moe_stats(net)
+
+    profiler.reset_kernel_path_counts()
+    profiler.reset_kda_stats()
+    loss, moments, _ = one_step()
+    stats = profiler.get_kda_stats()
+    # the expert layers as they run, not compared: in bfloat16 a token's
+    # choice of experts turns on the last bit, and the router's gradient
+    # with it
+    sparse_loss, _, rows = one_step(("mlp", "moe", "moe"))
+    paths = profiler.get_kernel_path_counts()
+    with xla_formulations():
+        want_loss, want, _ = one_step()
+    tol = 3e-2 if on_chip else 1e-4     # bfloat16 against bfloat16 / float32
+    check(abs(loss - want_loss) <= tol * want_loss,
+          f"kda train: first loss {loss} against lax's {want_loss}")
+    check(set(moments) == set(want)
+          and any("dt_bias" in k for k in moments)
+          and any("latentattention" in k for k in moments),
+          f"kda train: parameters {sorted(moments)}")
+    gaps = {k: float(np.linalg.norm(moments[k] - want[k])
+                     / max(np.linalg.norm(want[k]), 1e-30)) for k in want}
+    worst = max(gaps, key=gaps.get)
+    check(gaps[worst] <= 3 * tol,
+          f"kda train: first gradient of {worst} is {gaps[worst]:.4g} from "
+          f"the lax form's")
+    check(stats["launches"] >= 2 and stats["state_bytes_kept"] > 0,
+          f"kda train: {stats}")
+    check(len(rows) == 2 and all(r["pairs"] > 0 for r in rows)
+          and np.isfinite(sparse_loss),
+          f"kda train: sparse loss {sparse_loss}, {rows}")
+    if on_chip:
+        for kind in ("kda", "flash", "grouped_matmul"):
+            check(paths[kind]["pallas"] > 0 and paths[kind]["xla"] == 0,
+                  f"kda train: {kind} call sites {paths[kind]}")
+    return {"loss": round(loss, 4), "lax_loss": round(want_loss, 4),
+            "sparse_loss": round(sparse_loss, 4),
+            "worst_gradient_gap": [worst, round(gaps[worst], 5)],
+            "kernel_paths": {k: paths[k] for k in ("kda", "flash")},
+            "kda": stats, "pairs": [r["pairs"] for r in rows]}
+
+
 # -- leg 5: four chips -------------------------------------------------------
 
 def placement(arrays: dict, devices) -> dict:
@@ -951,6 +1054,8 @@ def main(argv=None) -> int:
                        on_chip)
         rep.update(out)
         rep, out = leg("retention_train", leg_retention_train, sz, on_chip)
+        rep.update(out)
+        rep, out = leg("kda_train", leg_kda_train, sz, on_chip)
         rep.update(out)
 
         if len(devs) >= 4:

@@ -1,5 +1,5 @@
 """Decoder language models built from a list of layer kinds: a mixer kind, an
-MLP kind and a norm for each layer. Four published families are instances:
+MLP kind and a norm for each layer. Five published families are instances:
 decoder-hybrid-decoder models (SambaY; Phi-4-mini-flash-reasoning: Mamba-1
 state-space layers, differential attention over a window, over everything
 and across layers, gated memory units, no positional encoding of any kind),
@@ -13,7 +13,10 @@ held and no shared one) and dense models whose every mixer is a
 power-retention layer (Brumby-14B: kind ``retention``, Qwen3's block
 otherwise: ``attention="gqa"``, ``qk_norm``, rotary positions,
 ``norm="rms"``, pre-norm, an untied head; ``remat=True`` recomputes a block
-at a time in the backward).
+at a time in the backward) and sparse models whose mixers are
+Kimi-Delta-Attention layers beside latent attention (Ling-3.0's
+``bailing_hybrid``: kinds ``kda`` and ``mla``, ``norm="rms"``, pre-norm, an
+untied head, ``mlp_kinds`` with ``"moe"`` under a group-limited router).
 
 A model is a list of layer kinds and the widths; nothing here is a preset.
 Every layer is ``h = x + Mixer(N(x)); out = h + MLP(N'(h))``, or with
@@ -65,6 +68,33 @@ own (``tie_head=False``, float32 logits). The mixers, by kind:
     (8256 x 129 float32 at ``D`` = 128) and the query heads of a group read
     that ONE state, which is how the op computes it: linear in ``T``.
 
+``kda``
+    Kimi Delta Attention (``KimiDeltaAttention``; ``ops/kda.py``), ``H``
+    heads of ``D`` = ``head_dim``, keys and values as many as queries.
+    ``[q, k, v, g] = W_in x`` (four times ``H D``, no bias); ``q, k, v =
+    silu(conv1d_causal(.))`` (depthwise, ``d_conv`` taps, no bias); ``q_t <-
+    q_t / sqrt(|q_t|^2 + 1e-6) * D^-0.5`` and ``k_t <- k_t / sqrt(|k_t|^2 +
+    1e-6)`` over each head; float32: ``a_t[h, c] = kda_lower_bound *
+    sigmoid(exp(A_log[h]) * ((W_f x)_t[h, c] + dt_bias[h, c]))`` (so
+    ``kda_lower_bound < a < 0``) and ``beta_t[h] = sigmoid((W_beta
+    x)_t[h])``; a state ``S`` of ``D x D`` a head, zero at the start: ``S' =
+    Diag(exp(a_t)) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t - S'^T k_t)^T``,
+    ``o_t = S_t^T q_t``; output ``W_o (RMSNorm_D(o_t; gain) * sigmoid(g_t))``,
+    the norm over each head with one gain vector.
+``mla``
+    multi-head latent attention as it trains (``LatentAttention``; the
+    keyword ``mla``: ``latent_dim``, ``nope_dim``, ``rope_dim``, ``v_dim``,
+    ``interleave``). ``q = W_q x`` on ``H`` heads of ``nope_dim +
+    rope_dim``; ``[c | k_r] = W_kva x`` (``latent_dim + rope_dim``); ``c <-
+    RMSNorm(c)``; ``[k_nope | v] = W_kvb c`` a head; an RMSNorm with a gain
+    over each query head's whole width and over ``k_nope``; rotary
+    positions (base ``rope_theta``, neighbouring pairs with ``interleave``)
+    on the last ``rope_dim`` dimensions of every query head and on the ONE
+    ``k_r``, which every head appends to its ``k_nope``; ``softmax(q k^T /
+    sqrt(nope_dim + rope_dim))``, causal, times ``v``; each head's output
+    times ``sigmoid((W_a x)[head])``; ``W_o``. The flash kernels at q/k
+    ``nope_dim + rope_dim`` and v ``v_dim``.
+
 With ``attention="gqa"`` the kinds ``attn_window`` / ``attn_full`` are
 ``GroupedQueryAttention`` under the same child names: a fused ``qkv``
 without bias, with ``qk_norm`` an RMSNorm over ``head_dim`` on every query
@@ -79,8 +109,10 @@ layer that wants them: gradients flow back into the one producer from all
 its consumers. That is the training path, and the only one: there is no
 decode cache for any of these kinds yet (``generate`` and the serving steps
 raise), because a cache here has to hold a window's keys, one layer's full
-keys for all cross layers, scan and convolution states side by side, and a
-retention layer's 8256 x 129 matrix a key/value head.
+keys for all cross layers, scan and convolution states side by side, a
+retention layer's 8256 x 129 matrix a key/value head, a ``kda`` layer's
+``D x D`` matrix a head and an ``mla`` layer's latent rows (``_DECODE_STATE``
+has each kind's).
 """
 
 from __future__ import annotations
@@ -89,14 +121,17 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ... import autograd, initializer
 from ... import ndarray as nd
 from ...observability import metrics
 from ...ops import registry
 from ...ops.attention import flash_chunk
+from ...ops.kda import kda as _kda
 from ...ops.nn import rms_norm
 from ...ops.retention import power_retention
+from ...ops.ssm import causal_conv1d
 from ...parallel.moe import SparseExperts
 from ..block import HybridBlock
 from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
@@ -105,8 +140,23 @@ from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
 __all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS", "MLP_KINDS"]
 
 KINDS = ("mamba", "attn_window", "attn_full", "attn_cross", "gmu", "conv",
-         "retention")
+         "retention", "kda", "mla")
 MLP_KINDS = ("mlp", "moe")
+# what a decode cache would hold for a layer of each kind, a slot
+_DECODE_STATE = {
+    "mamba": "scan and convolution states",
+    "attn_window": "a window of keys and values",
+    "attn_full": "every key and value, which attn_cross layers read too",
+    "attn_cross": "nothing of its own (an earlier attn_full layer's keys)",
+    "gmu": "nothing of its own (an earlier mamba layer's output)",
+    "conv": "a conv layer's last d_conv - 1 rows a channel",
+    "retention": "a head_dim (head_dim + 1) / 2 x (head_dim + 1) matrix a "
+                 "key/value head (8256 x 129 at 128)",
+    "kda": "a head_dim x head_dim float32 matrix a head and the last d_conv "
+           "- 1 rows of q, k and v",
+    "mla": "a latent row and one rotary key a token (latent_dim + rope_dim "
+           "numbers), read through absorbed projections",
+}
 # kinds whose block (with a dense MLP, which has no state) runs under
 # jax.checkpoint where asked: they neither hand anything on to later layers
 # nor read it. Only what a cell runs so is listed
@@ -253,13 +303,25 @@ def as_float32(x):
 _AS_FLOAT32 = registry.get_op("contrib.as_float32")
 
 
-def _rope(x, theta: float):
-    """Rotary positions 0..T-1 on ``x`` ``(B, T, heads, D)``, rotate-half
-    pairing (dimension ``i`` with ``i + D / 2``), angles in float32."""
+def _rope(x, theta: float, interleave: bool = False, rotary_dim: int = 0):
+    """Rotary positions 0..T-1 on ``x`` ``(B, T, heads, D)``, angles in
+    float32. Rotate-half pairing (dimension ``i`` with ``i + D / 2``), or
+    with ``interleave`` neighbours (``2i`` with ``2i + 1``). ``rotary_dim``
+    > 0 turns the LAST ``rotary_dim`` dimensions alone (a latent-attention
+    head's positional part) and leaves the rest as they are."""
+    if rotary_dim and rotary_dim < x.shape[-1]:
+        keep = x.shape[-1] - rotary_dim
+        return jnp.concatenate(
+            [x[..., :keep], _rope(x[..., keep:], theta, interleave)], axis=-1)
     T, half = x.shape[1], x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = jnp.arange(T, dtype=jnp.float32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
+    if interleave:
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
@@ -437,6 +499,224 @@ class PowerRetention(GroupedQueryAttention):
         return self.out_proj(out)
 
 
+@registry.register("kda_gate", namespace="contrib", num_outputs=2)
+def kda_gate(x, f_weight, b_weight, a_log, dt_bias, lower_bound: float = -5.0):
+    """The delta rule's two gates, in float32 whatever ``x`` and the
+    matrices are stored in. ``(a (B, T, H, D), beta (B, T, H))``: the
+    log-decay a channel ``lower_bound * sigmoid(exp(A_log[h]) * ((W_f x)[h,
+    c] + dt_bias[h, c]))``, which lies in ``(lower_bound, 0)`` (the bound
+    ``ops.kda`` relies on), and ``sigmoid(W_beta x)``."""
+    H = a_log.shape[0]
+    f32 = jnp.float32
+    logits = jnp.einsum("btd,cd->btc", x, f_weight,
+                        preferred_element_type=f32)
+    logits = (logits + dt_bias.astype(f32)).reshape(x.shape[:2] + (H, -1))
+    a = lower_bound * jax.nn.sigmoid(
+        jnp.exp(a_log.astype(f32))[:, None] * logits)
+    beta = jax.nn.sigmoid(jnp.einsum("btd,hd->bth", x, b_weight,
+                                     preferred_element_type=f32))
+    return a, beta
+
+
+@registry.register("kda_scan", namespace="contrib")
+def kda_scan(qkv, conv_weight, a, beta, eps: float = 1e-6):
+    """``qkv`` ``(B, T, 3 H D)`` through its short convolution and SiLU
+    (scope ``conv``), q and k L2-normalised over each head (q scaled by ``D
+    ** -0.5``), then ``ops.kda`` (scope ``scan``). ``(B, T, H * D)``."""
+    B, T, _ = qkv.shape
+    H, D = a.shape[2:]
+    with jax.named_scope("conv"):
+        q, k, v = jnp.split(jax.nn.silu(causal_conv1d(qkv, conv_weight)), 3,
+                            axis=-1)
+    with jax.named_scope("scan"):
+        def unit(x, scale):
+            wide = x.reshape(B, T, H, D).astype(jnp.float32)
+            return (wide * (scale * lax.rsqrt(jnp.sum(
+                jnp.square(wide), axis=-1, keepdims=True) + eps))
+            ).astype(x.dtype)
+        return _kda(unit(q, D ** -0.5), unit(k, 1.0),
+                    v.reshape(B, T, H, D), a, beta)
+
+
+@registry.register("gated_head_norm", namespace="contrib")
+def gated_head_norm(o, gate, gain, heads: int, eps: float = 1e-6):
+    """``RMSNorm(o; gain) * sigmoid(gate)`` with the norm over each of the
+    ``heads`` heads' channels and one gain vector for all of them."""
+    B, T, W = o.shape
+    normed = rms_norm(o.reshape(B, T, heads, W // heads), gain, eps)
+    return normed.reshape(B, T, W) * jax.nn.sigmoid(gate)
+
+
+_KDA_GATE = registry.get_op("contrib.kda_gate")
+_KDA_SCAN = registry.get_op("contrib.kda_scan")
+_GATED_HEAD_NORM = registry.get_op("contrib.gated_head_norm")
+
+
+class _ChannelHalfLives(initializer.Initializer):
+    """``b = logit(ln 2 / (-bound * tau))`` with the half-lives ``tau`` spaced
+    log-uniformly from ``lo`` to ``hi`` tokens over the channels: under the
+    bounded gate ``bound * sigmoid(b)`` a channel then forgets half in
+    ``tau`` tokens. (At 0 every channel's gate is ``bound / 2``: a state
+    that forgets in one token.)"""
+
+    def __init__(self, bound: float = -5.0, lo: float = 16.0,
+                 hi: float = 4096.0):
+        super().__init__(bound=bound, lo=lo, hi=hi)
+        self.bound, self.lo, self.hi = bound, lo, hi
+
+    def init_array(self, name, arr):
+        n = math.prod(arr.shape)
+        half = jnp.exp(jnp.linspace(math.log(self.lo), math.log(self.hi), n,
+                                    dtype=jnp.float32))
+        p = math.log(2.0) / (-self.bound * half)
+        arr._set_data(jnp.log(p / (1.0 - p)).reshape(arr.shape)
+                      .astype(arr.dtype))
+
+
+class KimiDeltaAttention(HybridBlock):
+    """A Kimi-Delta-Attention layer: a gated delta rule whose decay is one
+    factor a key channel a token (``ops/kda.py``) on ``num_heads`` heads of
+    ``head_dim``, keys and values as many as queries. ``in_proj`` makes
+    ``[q, k, v, g]`` (four times ``num_heads * head_dim``, no bias); q, k
+    and v go through a depthwise causal convolution of ``d_conv`` taps and a
+    SiLU; q and k are L2-normalised a head; the decay and ``beta`` are
+    float32 (``contrib.kda_gate``: full matrices ``f_proj`` / ``b_proj``,
+    ``A_log`` a head and ``dt_bias`` a channel kept in float32); the output
+    is RMS-normed a head (one gain vector) and gated by ``sigmoid(g)``
+    before ``out_proj``. ``forward`` returns the output alone. A device
+    trace reads ``block<i>/kda/proj|conv|gate|scan|out``."""
+
+    def __init__(self, units: int, num_heads: int, head_dim: int,
+                 d_conv: int = 4, lower_bound: float = -5.0,
+                 norm_eps: float = 1e-6, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._heads, self._bound, self._eps = num_heads, float(lower_bound), \
+            norm_eps
+        wide = num_heads * head_dim
+        with self.name_scope():
+            self.in_proj = Dense(4 * wide, use_bias=False, flatten=False,
+                                 in_units=units)
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(3 * wide, d_conv), init="normal")
+            self.f_proj = self.params.get(
+                "f_proj", shape=(wide, units), init="normal")
+            self.b_proj = self.params.get(
+                "b_proj", shape=(num_heads, units), init="normal")
+            self.A_log = self.params.get(
+                "A_log", shape=(num_heads,), init="zeros", keep_float32=True)
+            self.dt_bias = self.params.get(
+                "dt_bias", shape=(wide,), keep_float32=True,
+                init=_ChannelHalfLives(lower_bound))
+            self.o_norm = self.params.get(
+                "o_norm", shape=(head_dim,), init="ones")
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  in_units=wide)
+
+    def forward(self, x):
+        with jax.named_scope("proj"):
+            qkv, g = _split(self.in_proj(x), (self.conv_weight.shape[0],
+                                              self.dt_bias.shape[0]))
+        with jax.named_scope("gate"):
+            a, beta = registry.invoke(
+                _KDA_GATE, x, self.f_proj.data(), self.b_proj.data(),
+                self.A_log.data(), self.dt_bias.data(),
+                lower_bound=self._bound)
+        o = registry.invoke(_KDA_SCAN, qkv, self.conv_weight.data(), a, beta)
+        with jax.named_scope("out"):
+            return self.out_proj(registry.invoke(
+                _GATED_HEAD_NORM, o, g, self.o_norm.data(),
+                heads=self._heads, eps=self._eps))
+
+
+@registry.register("latent_attention", namespace="contrib")
+def latent_attention(q, kv, k_rope, q_gain, k_gain, gate, nope_dim: int,
+                     rope_theta: float = 1e4, interleave: bool = True,
+                     eps: float = 1e-6):
+    """Causal softmax attention with keys and values expanded from a latent
+    (DeepSeek-V2's MLA, not absorbed: the training half). ``q`` ``(B, T, H,
+    nope + rope)``; ``kv`` ``(B, T, H, nope + v)``: each head's
+    position-free key and its value; ``k_rope`` ``(B, T, rope)``: ONE
+    rotary key, which every head appends to its own; ``q_gain`` / ``k_gain``:
+    an RMSNorm over a query head's whole width and over ``k_nope``, before
+    the positions; rotary positions on the last ``rope`` dimensions of q and
+    on ``k_rope``; scores ``q k^T / sqrt(nope + rope)``; ``gate`` ``(B, T,
+    H)`` logits: the head's output times their sigmoid. Returns ``(B, T, H *
+    v)``. Scopes ``rope`` (norms and positions) and ``attn``."""
+    B, T, H, W = q.shape
+    rope_dim = W - nope_dim
+    with jax.named_scope("rope"):
+        q = _rope(rms_norm(q, q_gain, eps), rope_theta, interleave, rope_dim)
+        k_nope = rms_norm(kv[..., :nope_dim], k_gain, eps)
+        k_rope = _rope(k_rope[:, :, None, :], rope_theta, interleave)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (B, T, H, rope_dim))], axis=-1)
+    with jax.named_scope("attn"):
+        qh, kh, vh = (x.transpose(0, 2, 1, 3)
+                      for x in (q, k, kv[..., nope_dim:]))
+        out, _ = flash_chunk(qh, kh, vh, True, 1.0 / math.sqrt(W), None)
+        out = out.transpose(0, 2, 1, 3) \
+            * jax.nn.sigmoid(gate)[..., None].astype(out.dtype)
+    return out.reshape(B, T, -1)
+
+
+_LATENT_ATTENTION = registry.get_op("contrib.latent_attention")
+
+
+class LatentAttention(HybridBlock):
+    """Multi-head latent attention as it TRAINS: ``q_proj`` makes ``num_heads``
+    queries of ``nope_dim + rope_dim`` (no query latent); ``kva_proj`` a
+    latent of ``latent_dim`` (RMS-normed, gain ``kv_norm``) and one rotary
+    key of ``rope_dim`` for all heads; ``kvb_proj`` expands the latent to
+    each head's ``nope_dim`` key and ``v_dim`` value; ``gate_proj`` one
+    logit a head a token, whose sigmoid scales the head's output; then
+    ``out_proj``. The flash kernels take q/k of ``nope_dim + rope_dim`` and
+    v of ``v_dim``. ``forward`` returns the output alone: nothing is handed
+    on. A device trace reads ``block<i>/mla/proj|rope|attn|out``."""
+
+    def __init__(self, units: int, num_heads: int, latent_dim: int,
+                 nope_dim: int, rope_dim: int, v_dim: int,
+                 rope_theta: float = 1e4, interleave: bool = True,
+                 norm_eps: float = 1e-6, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._heads, self._latent = num_heads, latent_dim
+        self._nope, self._rope, self._v = nope_dim, rope_dim, v_dim
+        self._attrs = dict(nope_dim=nope_dim, rope_theta=float(rope_theta),
+                           interleave=bool(interleave), eps=norm_eps)
+        with self.name_scope():
+            self.q_proj = Dense(num_heads * (nope_dim + rope_dim),
+                                use_bias=False, flatten=False, in_units=units)
+            self.kva_proj = Dense(latent_dim + rope_dim, use_bias=False,
+                                  flatten=False, in_units=units)
+            self.kv_norm = RMSNorm(epsilon=norm_eps, in_channels=latent_dim)
+            self.kvb_proj = Dense(num_heads * (nope_dim + v_dim),
+                                  use_bias=False, flatten=False,
+                                  in_units=latent_dim)
+            self.gate_proj = Dense(num_heads, use_bias=False, flatten=False,
+                                   in_units=units)
+            self.q_norm = self.params.get(
+                "q_norm", shape=(nope_dim + rope_dim,), init="ones")
+            self.k_norm = self.params.get(
+                "k_norm", shape=(nope_dim,), init="ones")
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  in_units=num_heads * v_dim)
+
+    def forward(self, x):
+        B, T, _ = x.shape
+        H = self._heads
+        with jax.named_scope("proj"):
+            q = self.q_proj(x).reshape((B, T, H, self._nope + self._rope))
+            latent, k_rope = _split(self.kva_proj(x),
+                                    (self._latent, self._rope))
+            kv = self.kvb_proj(self.kv_norm(latent)).reshape(
+                (B, T, H, self._nope + self._v))
+            gate = self.gate_proj(x)
+        out = registry.invoke(_LATENT_ATTENTION, q, kv, k_rope,
+                              self.q_norm.data(), self.k_norm.data(), gate,
+                              **self._attrs)
+        with jax.named_scope("out"):
+            return self.out_proj(out)
+
+
 class GatedMemoryUnit(HybridBlock):
     """``W_out (m * silu(W_in x))``: the memory ``m`` gated by this layer."""
 
@@ -520,6 +800,17 @@ class HybridDecoderBlock(HybridBlock):
                     rope_theta=z["rope_theta"] if kind in z["rope_kinds"]
                     else 0.0, qk_norm=z["qk_norm"], norm_eps=eps,
                     retention_eps=z["retention_eps"])
+            elif kind == "kda":
+                mixer = KimiDeltaAttention(
+                    units, z["num_heads"], z["head_dim"], z["d_conv"],
+                    lower_bound=z["kda_lower_bound"], norm_eps=eps)
+            elif kind == "mla":
+                if not z["mla"]:
+                    raise ValueError("an mla layer: give mla= (latent_dim, "
+                                     "nope_dim, rope_dim, v_dim)")
+                mixer = LatentAttention(
+                    units, z["num_heads"], rope_theta=z["rope_theta"],
+                    norm_eps=eps, **z["mla"])
             elif z["attention"] == "gqa":
                 if kind == "attn_cross":
                     raise ValueError("grouped-query attention has no "
@@ -549,7 +840,7 @@ class HybridDecoderBlock(HybridBlock):
             mixed, shared["memory"] = mixer(h)
         elif self.kind == "gmu":
             mixed = mixer(h, shared.get("memory"))
-        elif self.kind in ("conv", "retention"):
+        elif self.kind in ("conv", "retention", "kda", "mla"):
             mixed = mixer(h)
         elif self.kind == "attn_cross":
             mixed, _ = mixer(h, shared.get("kv"))
@@ -614,11 +905,13 @@ class HybridDecoderLM(HybridBlock):
     layers at 8192 tokens keep 7.5 GB); only layers that hand nothing on
     and hold no state take it (``REMAT_KINDS`` with a dense MLP).
 
-    Four families are built from it (the module's docstring has their
-    specs): decoder-hybrid-decoder, sparse grouped-query window / full
-    attention, gated short convolutions (``conv``) beside grouped-query
-    attention with expert layers that hold all their experts, and
-    power-retention layers (``retention``) in Qwen3's dense block.
+    ``kda_lower_bound`` bounds the ``kda`` kind's log-decay a channel;
+    ``mla`` holds the ``mla`` kind's widths (``latent_dim``, ``nope_dim``,
+    ``rope_dim``, ``v_dim`` and ``interleave`` for the pairing of its rotary
+    positions, whose base is ``rope_theta``).
+
+    ``len(KINDS)`` mixer kinds; the module's docstring has the specs of the
+    families built from them.
     """
 
     def __init__(self, vocab_size: int, layer_kinds, units: int,
@@ -630,7 +923,8 @@ class HybridDecoderLM(HybridBlock):
                  norm: str = "layer", norm_position: str = "pre",
                  tie_head: bool = True, mlp_kinds=None, moe=None,
                  float32_logits: bool = False, remat: bool = False,
-                 retention_eps=None, prefix=None, params=None):
+                 retention_eps=None, kda_lower_bound: float = -5.0,
+                 mla=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         for what, value, known in (
                 ("attention", attention, ("diff", "gqa")),
@@ -662,7 +956,8 @@ class HybridDecoderLM(HybridBlock):
                  eps=layer_norm_eps, attention=attention, qk_norm=qk_norm,
                  rope_kinds=tuple(rope_kinds), rope_theta=rope_theta,
                  norm=norm, norm_position=norm_position, moe=moe,
-                 retention_eps=retention_eps)
+                 retention_eps=retention_eps,
+                 kda_lower_bound=kda_lower_bound, mla=mla)
         with self.name_scope():
             self.embedding = Embedding(vocab_size, units,
                                        weight_initializer="normal")
@@ -709,17 +1004,13 @@ class HybridDecoderLM(HybridBlock):
             return logits
 
     def _no_decode(self, what: str):
+        states = "; ".join(f"{kind}: {_DECODE_STATE[kind]}"
+                           for kind in KINDS if kind in self.layer_kinds)
         raise NotImplementedError(
             f"HybridDecoderLM.{what}: this family trains only. Decoding "
-            f"needs a cache that holds, side by side, a window of keys for "
-            f"attn_window layers, one attn_full layer's keys for every "
-            f"attn_cross layer, scan and convolution states for mamba "
-            f"layers, a conv layer's last d_conv - 1 rows a channel, and a "
-            f"retention layer's state (a head_dim (head_dim + 1) / 2 x "
-            f"(head_dim + 1) matrix, 8256 x 129 at 128, a key/value head a "
-            f"layer a slot); "
-            f"the engine has one cache geometry (ROADMAP D1/D2, M3, M6). "
-            f"Layer kinds: {self.layer_kinds}")
+            f"needs a cache that holds, side by side, for this model's "
+            f"layers: {states}; the engine has one cache geometry (ROADMAP "
+            f"D1/D2, M3, M5, M6). Layer kinds: {self.layer_kinds}")
 
     def generate(self, *args, **kwargs):
         self._no_decode("generate")

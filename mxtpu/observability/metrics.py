@@ -805,12 +805,11 @@ def reset_sanitizer_stats():
 
 # ---------------------------------------------------------------------------
 # kernel paths (ops/attention.py, ops/ssm.py, ops/grouped_matmul.py,
-# ops/retention.py): Pallas
-# or XLA, per call site
+# ops/retention.py, ops/kda.py): Pallas or XLA, per call site
 # ---------------------------------------------------------------------------
 
 KERNEL_KINDS = ("flash", "flash_window", "ssm_scan", "grouped_matmul",
-                "retention")
+                "retention", "kda")
 _kernel_paths = {kind: {"pallas": 0, "xla": 0} for kind in KERNEL_KINDS}
 
 
@@ -824,7 +823,7 @@ def record_kernel_path(kind: str, pallas: bool):
 
 def get_kernel_path_counts() -> dict:
     """``{"flash" | "flash_window" | "ssm_scan" | "grouped_matmul" |
-    "retention": {"pallas": n, "xla": n}}`` since the last reset: how many call sites
+    "retention" | "kda": {"pallas": n, "xla": n}}`` since the last reset: how many call sites
     took the Pallas kernels and how many the XLA formulation (another backend than the TPU, or a shape
     the kernels do not take). A TPU step that should run kernels reads
     ``xla == 0``."""
@@ -863,6 +862,32 @@ def get_retention_stats() -> dict:
 def reset_retention_stats():
     with _stats_lock:
         _retention.update(launches=0, chunk=0, chunks=0, state_bytes_kept=0)
+
+
+_kda = {"launches": 0, "chunk": 0, "chunks": 0, "state_bytes_kept": 0}
+
+
+def record_kda_launch(chunk: int, chunks: int, state_bytes_kept: int):
+    """One call site of ``contrib.kda`` was traced (or run eagerly): its
+    chunk length, the chunks a sequence and the bytes of chunk-start state
+    its forward keeps for its backward."""
+    with _stats_lock:
+        _kda.update(launches=_kda["launches"] + 1, chunk=chunk,
+                    chunks=chunks, state_bytes_kept=state_bytes_kept)
+
+
+def get_kda_stats() -> dict:
+    """``{"launches", "chunk", "chunks", "state_bytes_kept"}``: call sites
+    of ``contrib.kda`` since the last reset, and the NEWEST one's chunk
+    length, chunks a sequence and bytes of chunk-start state kept for the
+    backward (ONE layer's: a model holds that much a ``kda`` layer)."""
+    with _stats_lock:
+        return dict(_kda)
+
+
+def reset_kda_stats():
+    with _stats_lock:
+        _kda.update(launches=0, chunk=0, chunks=0, state_bytes_kept=0)
 
 
 _remat = {"blocks": 0, "recomputed": 0}

@@ -1,0 +1,497 @@
+"""Kimi Delta Attention's recurrence (a gated delta rule whose decay is one
+factor a key CHANNEL a token) as chunked Pallas TPU kernels, forward and
+backward, with the same chunked algorithm in ``lax`` for every other backend
+and shape.
+
+Per batch row and head, a state ``S`` of ``D x D`` (key channel by value
+channel), zero at a sequence's start; with ``a_t`` ``(D,)`` the log-decays
+(not positive) and ``beta_t`` a scalar::
+
+    S' = Diag(exp(a_t)) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+The state is decayed channel by channel, then CORRECTED: what it holds under
+``k_t`` is erased by the share ``beta_t`` and ``v_t`` written in its place.
+Inside a chunk of ``C`` tokens that is a unit-lower-triangular system. With
+``g`` the cumulative log-decay from the chunk's first row on, ``A[t, s] =
+sum_c k_t[c] k_s[c] exp(g_t[c] - g_s[c])`` for ``s < t``, ``P[t, s]`` the same
+with ``q_t`` for ``s <= t``, and ``S0`` the state the chunk starts in::
+
+    (I + Diag(beta) A) U = Diag(beta) (V - (K exp(g)) S0)
+    O  = (Q exp(g)) S0 + P U
+    S1 = Diag(exp(g_C)) S0 + (K exp(g_C - g))^T U
+
+``exp(-g_s)`` alone overflows (``g`` reaches -5 a token), so ``A`` and ``P``
+are made a SUB-CHUNK of rows at a time (``SUB`` = 16) against that
+sub-chunk's first row ``r``: ``k_t exp(g_t - r)`` never passes 1 and ``k_s
+exp(r - g_s)`` never passes ``exp(75)`` for the keys the sub-chunk sees: the
+model's gate is bounded so (``a > -5``: 15 steps of it). Nothing tighter is
+relied on. The system is solved on the MXU, in float32: the 16 x 16 diagonal
+blocks by the nilpotent product ``(I - X)(I + X^2)(I + X^4)(I + X^8)``, then
+the blocks below them the same way (``X^(C / 16) = 0``).
+
+One grid step of a kernel is one (batch row, head, chunk); a head's chunks
+run in order with the state in VMEM. The forward KEEPS the state at every
+chunk start (float32, 64 KB a head a chunk: 67 MB a layer at 4096 tokens and
+32 heads); the backward walks the chunks from the last to the first, makes
+the chunk's matrices again and carries the state's gradient. The cumulative
+sums (``g`` from ``a``, and ``da`` from ``dg``) are XLA's, in float32.
+
+The token-by-token recurrence is not here: the benchmark's reference and the
+tests hold it.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register
+from .retention import _on_one_device
+
+__all__ = ["kda", "kda_stats"]
+
+_LANES = 128
+# v5e, one layer of the cell (T 4096, 32 heads of 128, bf16; my chip runs,
+# PR 41): ms a launch, forward / backward, beside the constant it was read at
+CHUNK = 128         # rows of T a grid step: 3.39 / 4.17. Every matrix of a
+                    # chunk is one 128 x 128 tile; the kept chunk starts (67
+                    # MB a layer) halve against 64. No other length was run
+SUB = 16            # rows of a sub-chunk: 15 steps of the gate's bound -5
+_CLAMP = 80.0       # exp's argument for the keys a sub-chunk does NOT see
+                    # (masked to zero afterwards): finite in float32
+VMEM_LIMIT = 64 * 2 ** 20
+
+_F32 = jnp.float32
+_HIGHEST = lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------------------
+# one chunk, on 2-D arrays: what the kernels and the lax form both run
+# ---------------------------------------------------------------------------
+
+
+def _iota(shape, axis):
+    return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _dot(a, b, dims, dt):
+    """A matmul with float32 accumulation on operands of ``dt``: float32
+    operands at full precision, anything narrower as the MXU takes it."""
+    return lax.dot_general(
+        a.astype(dt), b.astype(dt), (dims, ((), ())),
+        precision=_HIGHEST if dt == _F32 else None,
+        preferred_element_type=_F32)
+
+
+def _mm(a, b, dt=_F32):
+    return _dot(a, b, ((1,), (0,)), dt)
+
+
+def _nt(a, b, dt=_F32):     # a b^T
+    return _dot(a, b, ((1,), (1,)), dt)
+
+
+def _tn(a, b, dt=_F32):     # a^T b
+    return _dot(a, b, ((0,), (0,)), dt)
+
+
+def _column(row):
+    """``(1, n)`` -> ``(n, 1)`` without a transpose of a one-row tile."""
+    n = row.shape[1]
+    return jnp.sum(jnp.where(_iota((n, n), 0) == _iota((n, n), 1), row, 0.0),
+                   axis=1, keepdims=True)
+
+
+def _as_row(col):
+    n = col.shape[0]
+    return jnp.sum(jnp.where(_iota((n, n), 0) == _iota((n, n), 1), col, 0.0),
+                   axis=0, keepdims=True)
+
+
+def _blocks(x):
+    return [x[i:i + SUB] for i in range(0, x.shape[0], SUB)]
+
+
+def _chunk_parts(q, k, g, dt):
+    """The chunk's decayed operands and its two score matrices. ``q``, ``k``:
+    ``(C, D)``; ``g`` ``(C, D)`` float32, the cumulative log-decay from the
+    chunk's first row on. Returns a dict: ``kq`` / ``qq`` (rows times
+    ``exp(g - r)``, ``r`` their sub-chunk's first row), ``er`` that factor,
+    ``kk[i]`` (keys times ``e[i] = exp(r_i - g)``, zero for the keys
+    sub-chunk ``i`` does not see), ``A`` (strictly lower) and ``P``
+    (lower)."""
+    C, D = k.shape
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    firsts = [g[i:i + 1] for i in range(0, C, SUB)]
+    er = jnp.exp(g - jnp.concatenate(
+        [jnp.broadcast_to(r, (SUB, D)) for r in firsts], axis=0))
+    kq, qq = kf * er, qf * er
+    row = _iota((C, 1), 0)
+    e, kk, a_rows, p_rows = [], [], [], []
+    for i, r in enumerate(firsts):
+        ei = jnp.where(row < (i + 1) * SUB,
+                       jnp.exp(jnp.minimum(r - g, _CLAMP)), 0.0)
+        kki = kf * ei
+        both = _nt(jnp.concatenate([kq[i * SUB:(i + 1) * SUB],
+                                    qq[i * SUB:(i + 1) * SUB]], axis=0),
+                   kki, dt)                                   # (2 SUB, C)
+        e.append(ei)
+        kk.append(kki)
+        a_rows.append(both[:SUB])
+        p_rows.append(both[SUB:])
+    t, s = _iota((C, C), 0), _iota((C, C), 1)
+    return dict(qf=qf, kf=kf, er=er, kq=kq, qq=qq, e=e, kk=kk,
+                A=jnp.where(s < t, jnp.concatenate(a_rows, axis=0), 0.0),
+                P=jnp.where(s <= t, jnp.concatenate(p_rows, axis=0), 0.0))
+
+
+def _solve(n):
+    """``(I + n)^-1`` for a strictly lower triangular ``n`` ``(C, C)``,
+    float32 on the MXU: the diagonal blocks of ``SUB`` first (``X^SUB = 0``),
+    then the blocks below them (``M^(C / SUB) = 0``)."""
+    C = n.shape[0]
+    t, s = _iota((C, C), 0), _iota((C, C), 1)
+    eye = (t == s).astype(_F32)
+    shift = SUB.bit_length() - 1
+    near = jnp.where(jnp.right_shift(t, shift) == jnp.right_shift(s, shift),
+                     n, 0.0)
+    inv, p = eye - near, near
+    for _ in range(shift - 1):
+        p = _mm(p, p)
+        inv = _mm(inv, eye + p)
+    if C == SUB:
+        return inv
+    m = _mm(inv, n - near)
+    out, p, power = eye - m, m, 2
+    while power < C // SUB:
+        p = _mm(p, p)
+        out = _mm(out, eye + p)
+        power *= 2
+    return _mm(out, inv)
+
+
+def _chunk_forward(q, k, v, g, beta, s0, dt):
+    """``(o (C, D) float32, the state after the chunk)``; ``beta`` ``(C, 1)``
+    float32, ``s0`` ``(D, D)`` float32. Matmuls on the data take operands of
+    ``dt``; the triangular system is float32."""
+    C = k.shape[0]
+    z = _chunk_parts(q, k, g, dt)
+    eg, last = jnp.exp(g), g[C - 1:C]
+    u = _mm(_solve(beta * z["A"]),
+            beta * (v.astype(_F32) - _mm(z["kf"] * eg, s0, dt)), dt)
+    o = _mm(z["qf"] * eg, s0, dt) + _mm(z["P"], u, dt)
+    s1 = _column(jnp.exp(last)) * s0 \
+        + _tn(z["kf"] * jnp.exp(last - g), u, dt)
+    return o, s1
+
+
+def _chunk_backward(q, k, v, g, beta, s0, do, ds1, dt):
+    """The chunk again, and its transpose: ``(dq, dk, dv, dg, dbeta (C, 1),
+    ds0)``, all float32. ``dg`` is the gradient of the chunk's cumulative
+    log-decay ``g`` (the caller sums it back onto ``a``). A decayed operand
+    ``x exp(+-g)`` hands ``g`` the product of the operand and its gradient,
+    so ``dg`` needs no pass of its own."""
+    C = k.shape[0]
+    z = _chunk_parts(q, k, g, dt)
+    qf, kf, A, P = z["qf"], z["kf"], z["A"], z["P"]
+    eg, last = jnp.exp(g), g[C - 1:C]
+    elast = jnp.exp(last - g)
+    kt, qt, kbar = kf * eg, qf * eg, kf * elast
+    t, s = _iota((C, C), 0), _iota((C, C), 1)
+    tinv = _solve(beta * A)
+    vres = v.astype(_F32) - _mm(kt, s0, dt)
+    u = _mm(tinv, beta * vres, dt)
+    do = do.astype(_F32)
+
+    du = _tn(P, do, dt) + _mm(kbar, ds1, dt)
+    dp = jnp.where(s <= t, _nt(do, u, dt), 0.0)
+    dr = _tn(tinv, du, dt)
+    dn = jnp.where(s < t, -_nt(dr, u, dt), 0.0)
+    da_ = beta * dn
+    dbeta = jnp.sum(dn * A, axis=1, keepdims=True) \
+        + jnp.sum(dr * vres, axis=1, keepdims=True)
+    bdr = beta * dr
+    dqt = _nt(do, s0, dt)
+    dkt = -_nt(bdr, s0, dt)
+    dkbar = _nt(u, ds1, dt)
+    decay = _column(jnp.exp(last))
+    ds0 = _tn(qt, do, dt) + decay * ds1 - _tn(kt, bdr, dt)
+
+    # through A and P: a sub-chunk of rows at a time, as they were made
+    dkq, dqq, dk_col, g_col = [], [], 0.0, 0.0
+    for i in range(C // SUB):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        grad = jnp.concatenate([da_[rows], dp[rows]], axis=0)   # (2 SUB, C)
+        side = _mm(grad, z["kk"][i], dt)                        # (2 SUB, D)
+        dkq.append(side[:SUB])
+        dqq.append(side[SUB:])
+        dkk = _tn(grad, jnp.concatenate([z["kq"][rows], z["qq"][rows]],
+                                        axis=0), dt)            # (C, D)
+        dk_col = dk_col + dkk * z["e"][i]
+        g_col = g_col + dkk * z["kk"][i]
+    dkq, dqq = jnp.concatenate(dkq, axis=0), jnp.concatenate(dqq, axis=0)
+
+    dq = dqq * z["er"] + dqt * eg
+    dk = dkq * z["er"] + dk_col + dkt * eg + dkbar * elast
+    dg = z["qq"] * dqq + z["kq"] * dkq - g_col + qt * dqt + kt * dkt \
+        - kbar * dkbar
+    # the chunk's last row also decays the state and every key's write
+    dlast = _as_row(jnp.sum(decay * s0 * ds1, axis=1, keepdims=True)) \
+        + jnp.sum(kbar * dkbar, axis=0, keepdims=True)
+    dg = dg + jnp.where(_iota((C, 1), 0) == C - 1, dlast, 0.0)
+    return dq, dk, bdr, dg, dbeta, ds0
+
+
+# ---------------------------------------------------------------------------
+# the chunked algorithm in lax
+# ---------------------------------------------------------------------------
+
+
+def _chunk_cumsum(a, chunk: int):
+    """``(B, T, ...)`` float32 -> the cumulative sum along ``T`` from each
+    chunk's first row on."""
+    B, T = a.shape[:2]
+    return jnp.cumsum(a.astype(_F32).reshape((B, T // chunk, chunk)
+                                             + a.shape[2:]),
+                      axis=2).reshape(a.shape)
+
+
+def _kda_lax(q, k, v, a, beta, chunk: int):
+    """``_chunk_forward`` over ``(B, H)`` in a ``lax.scan`` over chunks that
+    carries the states; each chunk is a ``jax.checkpoint``, so JAX's
+    transpose keeps the chunk starts alone and walks them backwards."""
+    B, T, H, D = q.shape
+    c = min(chunk, -(-T // SUB) * SUB)
+    pad = -T % c
+    if pad:     # rows past T: no key, no value, no decay, no write
+        q, k, v, a = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for x in (q, k, v, a))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n = (T + pad) // c
+    g = _chunk_cumsum(a, c)
+
+    def by_chunk(x):        # (B, T, H, ...) -> (n, B, H, c, ...)
+        x = x.reshape((B, n, c) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
+
+    one = jax.vmap(jax.vmap(functools.partial(_chunk_forward, dt=q.dtype)))
+
+    @jax.checkpoint
+    def body(s0, xs):
+        o, s1 = one(*xs, s0)
+        return s1, o
+
+    xs = (by_chunk(q), by_chunk(k), by_chunk(v), by_chunk(g),
+          by_chunk(beta.astype(_F32))[..., None])
+    _, o = lax.scan(body, jnp.zeros((B, H, D, D), _F32), xs)
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)       # (B, n, c, H, D)
+    return o.reshape(B, T + pad, H * D)[:, :T].astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, s_ref):
+    """One (batch row, head, chunk); a head's chunks run in order and hand
+    the state on in ``s_ref``."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    s0 = s_ref[...]
+    s0_ref[0, 0, 0] = s0        # kept for the backward
+    o, s1 = _chunk_forward(q_ref[0], k_ref[0], v_ref[0], g_ref[0],
+                           _column(b_ref[0, 0, 0]), s0, q_ref.dtype)
+    o_ref[0] = o.astype(o_ref.dtype)
+    s_ref[...] = s1
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
+    """One (batch row, head, chunk), chunks from the last to the first:
+    ``ds_ref`` carries the gradient of the state at the END of the chunk in
+    hand."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    dq, dk, dv, dg, dbeta, ds0 = _chunk_backward(
+        q_ref[0], k_ref[0], v_ref[0], g_ref[0], _column(b_ref[0, 0, 0]),
+        s0_ref[0, 0, 0], do_ref[0], ds_ref[...], q_ref.dtype)
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dg_ref[0] = dg
+    db_ref[0, 0, 0] = _as_row(dbeta)
+    ds_ref[...] = ds0
+
+
+def _specs(chunk: int, order):
+    """Block specs of the operands as the model holds them: ``q`` .. ``g``
+    ``(B, T, H * D)`` (a head's channels side by side), ``beta`` and the kept
+    states by chunk. ``order`` maps the grid's chunk index to the chunk (the
+    backward runs them reversed)."""
+    from jax.experimental import pallas as pl
+    D = _LANES
+    return dict(
+        x=pl.BlockSpec((1, chunk, D), lambda b, h, c: (b, order(c), h)),
+        beta=pl.BlockSpec((1, 1, 1, 1, chunk),
+                          lambda b, h, c: (b, h, order(c), 0, 0)),
+        s=pl.BlockSpec((1, 1, 1, D, D),
+                       lambda b, h, c: (b, h, order(c), 0, 0)))
+
+
+def _beta_rows(beta, chunk: int):
+    """``(B, T, H)`` -> ``(B, H, T / chunk, 1, chunk)`` float32."""
+    B, T, H = beta.shape
+    return beta.astype(_F32).reshape(B, T // chunk, chunk, H) \
+        .transpose(0, 3, 1, 2)[:, :, :, None, :]
+
+
+def _params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT)
+
+
+def _forward_pallas(q, k, v, g, beta, interpret=False, chunk=None):
+    """``q``, ``k``, ``v``: ``(B, T, H * D)``; ``g`` like them, float32, the
+    chunks' cumulative log-decays; ``beta`` ``(B, T, H)``. Returns ``(o, S0)``:
+    the output like ``q`` and the state at the start of every chunk, ``(B,
+    H, T / chunk, D, D)`` float32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    chunk = chunk or CHUNK
+    B, T, H = beta.shape
+    D, n_c = _LANES, T // chunk
+    sp = _specs(chunk, lambda c: c)
+    return pl.pallas_call(
+        _fwd_kernel,
+        grid=(B, H, n_c),
+        in_specs=[sp["x"]] * 4 + [sp["beta"]],
+        out_specs=[sp["x"], sp["s"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((B, H, n_c, D, D), _F32)],
+        scratch_shapes=[pltpu.VMEM((D, D), _F32)],
+        compiler_params=_params(),
+        name="kda_fwd",
+        interpret=interpret,
+    )(q, k, v, g, _beta_rows(beta, chunk))
+
+
+def _backward_pallas(q, k, v, g, beta, s0, do, interpret=False, chunk=None):
+    """``(dq, dk, dv, dg, dbeta)``: the first three like ``q``, ``dg``
+    float32 like ``g`` (of the CUMULATIVE log-decay), ``dbeta`` ``(B, T,
+    H)`` float32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    chunk = chunk or CHUNK
+    B, T, H = beta.shape
+    D, n_c = _LANES, T // chunk
+    sp = _specs(chunk, lambda c: n_c - 1 - c)
+    dq, dk, dv, dg, db = pl.pallas_call(
+        _bwd_kernel,
+        grid=(B, H, n_c),
+        in_specs=[sp["x"]] * 4 + [sp["beta"], sp["s"], sp["x"]],
+        out_specs=[sp["x"]] * 4 + [sp["beta"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3
+        + [jax.ShapeDtypeStruct(q.shape, _F32),
+           jax.ShapeDtypeStruct((B, H, n_c, 1, chunk), _F32)],
+        scratch_shapes=[pltpu.VMEM((D, D), _F32)],
+        compiler_params=_params(),
+        name="kda_bwd",
+        interpret=interpret,
+    )(q, k, v, g, _beta_rows(beta, chunk), s0, do)
+    return dq, dk, dv, dg, db[:, :, :, 0, :].transpose(0, 2, 3, 1) \
+        .reshape(B, T, H)
+
+
+# ---------------------------------------------------------------------------
+# the op
+# ---------------------------------------------------------------------------
+
+
+def _use_pallas(q) -> bool:
+    """The kernels take heads of 128 (a lane tile), whole chunks and one
+    device; everything else, and every backend but the TPU, takes the
+    chunked ``lax`` form (counted:
+    ``profiler.get_kernel_path_counts()["kda"]``)."""
+    return (jax.default_backend() == "tpu" and q.shape[3] == _LANES
+            and q.shape[1] % CHUNK == 0 and _on_one_device(q))
+
+
+@jax.custom_vjp
+def _kda_pallas(q, k, v, a, beta):
+    return _forward_pallas(q, k, v, _chunk_cumsum(a, CHUNK), beta)[0]
+
+
+def _kda_pallas_fwd(q, k, v, a, beta):
+    g = _chunk_cumsum(a, CHUNK)
+    o, s0 = _forward_pallas(q, k, v, g, beta)
+    return o, (q, k, v, g, beta, s0)
+
+
+def _kda_pallas_bwd(res, do):
+    q, k, v, g, beta, s0 = res
+    dq, dk, dv, dg, dbeta = _backward_pallas(q, k, v, g, beta, s0, do)
+    # every later row of a chunk holds a_t in its cumulative sum
+    B, T, W = dg.shape
+    by = dg.reshape(B, T // CHUNK, CHUNK, W)
+    da = jnp.flip(jnp.cumsum(jnp.flip(by, 2), axis=2), 2).reshape(dg.shape)
+    return dq, dk, dv, da, dbeta.astype(beta.dtype)
+
+
+_kda_pallas.defvjp(_kda_pallas_fwd, _kda_pallas_bwd)
+
+
+def kda_stats(T: int, num_heads: int, head_dim: int = _LANES,
+              batch: int = 1) -> dict:
+    """What one launch does at these sizes: the chunk length, the chunks a
+    sequence, and the bytes of chunk-start state the forward keeps for the
+    backward (float32, a head a chunk, in the kernels and in the ``lax``
+    form's checkpointed scan alike)."""
+    chunk = min(CHUNK, -(-T // SUB) * SUB)
+    chunks = -(-T // chunk)
+    return {"chunk": chunk, "chunks": chunks,
+            "state_bytes_kept": batch * num_heads * chunks
+            * head_dim * head_dim * 4}
+
+
+@register("kda", namespace="contrib")
+def kda(q, k, v, a, beta):
+    """The gated delta rule with a decay a key channel, causal, from a zero
+    state. ``q``, ``k``, ``v``: ``(B, T, H, D)`` (``q`` and ``k`` as the
+    caller normed and scaled them); ``a`` ``(B, T, H, D)`` float32, each
+    channel's log-decay, in ``(-5, 0]``: a sub-chunk of 16 rows is computed
+    against its first row, which that bound keeps finite; ``beta`` ``(B, T,
+    H)``. Returns ``(B, T, H * D)``: ``o_t = S_t^T q_t`` with ``S_t = (I -
+    beta_t k_t k_t^T) Diag(exp(a_t)) S_{t-1} + beta_t k_t v_t^T``. Pallas
+    kernels with their own backward on the TPU where ``D == 128`` and ``T``
+    is whole chunks of ``CHUNK``; the same chunked algorithm in ``lax``
+    anywhere else. Memory is linear in ``T`` either way."""
+    from ..observability import metrics
+    pallas = _use_pallas(q)
+    metrics.record_kernel_path("kda", pallas)
+    B, T, H, D = q.shape
+    metrics.record_kda_launch(**kda_stats(T, H, D, B))
+    with jax.named_scope("kda"):
+        if pallas:
+            return _kda_pallas(
+                q.reshape(B, T, H * D), k.reshape(B, T, H * D),
+                v.reshape(B, T, H * D),
+                a.astype(_F32).reshape(B, T, H * D), beta)
+        return _kda_lax(q, k, v, a.astype(_F32), beta, CHUNK)

@@ -179,6 +179,10 @@ class DataParallelTrainer:
         self._compression_params = compression_params
         self._step_fn = None
         self._t = 0
+        # the step's scalar arguments as they stand on the device: name ->
+        # (host value, array), and (t, key) made while step t - 1 ran
+        self._scalars: dict = {}
+        self._key_ahead = None
         # the thread's count at the last step's row (the first: from here)
         self._nivcsw = resource.getrusage(_RUSAGE_WHO).ru_nivcsw
         self._params: List = []
@@ -550,15 +554,17 @@ class DataParallelTrainer:
         with tracer.span("train/prepare", args=step) as prepare:
             self._t += 1
             opt = self.optimizer
-            lr = jnp.asarray(opt.learning_rate, jnp.float32)
-            wd = jnp.asarray(opt.wd, jnp.float32)
+            lr = self._scalar("lr", opt.learning_rate)
+            wd = self._scalar("wd", opt.wd)
             # grads are mean-loss grads already; rescale stays 1 (clip honors
             # the optimizer's clip_gradient, a static variant inside
             # update_all)
-            rescale = jnp.float32(1.0)
-            clip = jnp.float32(opt.clip_gradient
-                               if opt.clip_gradient is not None else 0.0)
-            key = jax.random.key(self._t)
+            rescale = self._scalar("rescale", 1.0)
+            clip = self._scalar("clip", opt.clip_gradient
+                                if opt.clip_gradient is not None else 0.0)
+            ahead, self._key_ahead = self._key_ahead, None
+            key = ahead[1] if ahead is not None and ahead[0] == self._t \
+                else jax.random.key(self._t)
             params = [p.data().data for p in self._param_handles]
             auxs = [p.data().data for p in self._aux_handles]
             args = (params, auxs, self._states, self._zero_states,
@@ -592,6 +598,12 @@ class DataParallelTrainer:
             self._zero_states = new_zstates
             self._zero_residuals = new_zres
             self.optimizer.num_update = self._t
+            # the next step's key now, behind this step on the device: a
+            # caller that reads the loss back every step leaves the chip idle
+            # from the read-back to the next dispatch, and whatever is made
+            # between the two (a seed program, a transfer a scalar) adds its
+            # host round trip to every step
+            self._key_ahead = (self._t + 1, jax.random.key(self._t + 1))
             if sanitize.enabled("donation"):
                 # the old weights and slots are gone on a backend that
                 # donates; poisoned, a stale read raises by name on the CPU
@@ -603,6 +615,16 @@ class DataParallelTrainer:
             metrics.record_comm_step(**self._comm_step)
         return NDArray(loss), traces, (place.dur_ns, prepare.dur_ns,
                                        dispatch.dur_ns, adopt.dur_ns)
+
+    def _scalar(self, name: str, value):
+        """``value`` as a float32 scalar on the device: the array of the
+        step before, while the value stands (a constant learning rate is one
+        transfer a run, a schedule one a change)."""
+        held = self._scalars.get(name)
+        if held is None or held[0] != value:
+            held = self._scalars[name] = (value,
+                                          jnp.asarray(value, jnp.float32))
+        return held[1]
 
     def _comm_record(self) -> dict:
         """One step's comm accounting (profiler.get_comm_stats), worked out

@@ -70,17 +70,35 @@ def expert_rows(tokens: int, num_experts: int, top_k: int, held: int) -> int:
     return min(worst, -(-4 * even // 512) * 512)
 
 
-def _route(x, router_w, bias, top_k: int, scale: float, eps: float = 0.0):
+def _kept_groups(z, n_group: int, topk_group: int):
+    """``(T, n_group)`` bool: the ``topk_group`` groups a token may choose
+    from. The ``E`` selection scores ``z`` lie in ``n_group`` equal groups of
+    neighbours; a group's score is the sum of its two largest (DeepSeek-V3's
+    ``noaux_tc``)."""
+    T, E = z.shape
+    best, _ = lax.top_k(z.reshape(T, n_group, E // n_group), 2)
+    _, groups = lax.top_k(jnp.sum(best, axis=-1), topk_group)
+    return jnp.any(groups[..., None] == jnp.arange(n_group), axis=1)
+
+
+def _route(x, router_w, bias, top_k: int, scale: float, eps: float = 0.0,
+           n_group: int = 1, topk_group: int = 1):
     """``(chosen experts (T, k), their weights (T, k) float32)``: sigmoid
     scores in float32, the ``top_k`` largest of score + ``bias`` (which only
-    selects and has no gradient), weights ``scale`` times the chosen scores
-    over their sum (plus ``eps`` where a family's router adds one; 0 adds
-    no operation)."""
+    selects and has no gradient), with ``n_group`` > 1 among the
+    ``topk_group`` best groups alone (scope ``groups``); weights ``scale``
+    times the chosen scores over their sum (plus ``eps`` where a family's
+    router adds one; 0 adds no operation)."""
     z = lax.dot_general(x, router_w, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(z)
-    _, chosen = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
-                          top_k)
+    select = s + lax.stop_gradient(bias.astype(jnp.float32))
+    if n_group > 1:
+        with jax.named_scope("groups"):
+            kept = _kept_groups(select, n_group, topk_group)
+            select = jnp.where(jnp.repeat(kept, s.shape[1] // n_group,
+                                          axis=1), select, -jnp.inf)
+    _, chosen = lax.top_k(select, top_k)
     # one chosen score and zeros: exact, and a dense fusion both ways where
     # take_along_axis is a scalar gather and, backward, a scalar scatter-add
     picked = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(s.shape[1]),
@@ -427,7 +445,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 @registry.register("sparse_experts", namespace="contrib", num_outputs=2)
 def sparse_experts(h, router_w, bias, w_gate_up, w_down, held=(),
                    top_k: int = 1, scale: float = 1.0, rows: int = 0,
-                   weight_eps: float = 0.0):
+                   weight_eps: float = 0.0, n_group: int = 1,
+                   topk_group: int = 1):
     """The held experts' part of a sparse expert layer. ``h``: ``(..., d)``;
     ``router_w``: ``(E, d)``; ``bias``: ``(E,)``, added to the scores for the
     choice alone; ``w_gate_up``: ``(len(held), d, 2 f)``, ``w_down``:
@@ -437,12 +456,16 @@ def sparse_experts(h, router_w, bias, w_gate_up, w_down, held=(),
     h))`` with ``w_e = scale * s_e / (sum of the chosen s + weight_eps)``,
     and the tokens that chose each of the ``E`` experts, held or not
     (``count[held]`` are the rows each held expert got). ``rows``: the
-    static row buffer of a pass (0: ``expert_rows``)."""
+    static row buffer of a pass (0: ``expert_rows``). ``n_group`` > 1: the
+    ``E`` experts in that many equal groups of neighbours, a group's score
+    the sum of its two largest score + ``bias``, a token's ``top_k`` taken
+    inside its ``topk_group`` best groups."""
     d, n_held = h.shape[-1], len(held)
     x = h.reshape(-1, d)
     T, E = x.shape[0], router_w.shape[0]
     with jax.named_scope("route"):
-        chosen, weights = _route(x, router_w, bias, top_k, scale, weight_eps)
+        chosen, weights = _route(x, router_w, bias, top_k, scale,
+                                 weight_eps, n_group, topk_group)
         count = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(E), axis=0,
                         dtype=jnp.int32)
     with jax.named_scope("dispatch"):
@@ -472,8 +495,12 @@ class SparseExperts(HybridBlock):
     Sigmoid scores in float32, the choice by score + ``select_bias``,
     weights ``routed_scale`` times the chosen scores over their sum (plus
     ``weight_eps``, 0 unless the family's router adds one), no token dropped
-    for any routing. ``shared_ffn_units`` > 0 adds a shared
-    expert (child ``shared``, a SwiGLU of that width every token goes
+    for any routing. ``n_group`` > 1 limits the choice to groups: the experts
+    in ``n_group`` equal groups of neighbouring ids, a group's score the sum
+    of its two largest score + ``select_bias``, the ``top_k`` taken inside
+    the ``topk_group`` best groups (scope ``route/groups``; 1, the default,
+    leaves the traced program as it was). ``shared_ffn_units`` > 0 adds a
+    shared expert (child ``shared``, a SwiGLU of that width every token goes
     through, added unweighted): every chip of a deployment computes it
     alike, so the shares' sum counts it once.
 
@@ -533,7 +560,8 @@ class SparseExperts(HybridBlock):
     def __init__(self, units: int, ffn_units: int, num_experts: int,
                  top_k: int, held=None, shared_ffn_units: int = 0,
                  routed_scale: float = 1.0, bias_update_rate: float = 0.0,
-                 weight_eps: float = 0.0, prefix=None, params=None):
+                 weight_eps: float = 0.0, n_group: int = 1,
+                 topk_group: int = 1, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if bias_update_rate < 0:
             raise ValueError(f"bias_update_rate {bias_update_rate} < 0")
@@ -544,7 +572,14 @@ class SparseExperts(HybridBlock):
                              f"0..{num_experts - 1}: {list(held)}")
         if not 0 < top_k <= num_experts:
             raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        if num_experts % n_group or not 0 < topk_group <= n_group \
+                or top_k > topk_group * (num_experts // n_group) \
+                or (n_group > 1 and num_experts // n_group < 2):
+            raise ValueError(
+                f"{num_experts} experts in {n_group} groups of which "
+                f"{topk_group} are kept for {top_k} experts a token")
         self.held = held
+        self._groups = (n_group, topk_group)
         self._top_k, self._experts = top_k, num_experts
         self._scale, self._bias_rate = float(routed_scale), \
             float(bias_update_rate)
@@ -576,7 +611,8 @@ class SparseExperts(HybridBlock):
             _SPARSE_EXPERTS, x, self.router.data(), bias,
             self.gate_up.data(), self.down.data(), held=self.held,
             top_k=self._top_k, scale=self._scale, rows=self._rows,
-            weight_eps=self._weight_eps)
+            weight_eps=self._weight_eps, n_group=self._groups[0],
+            topk_group=self._groups[1])
         with jax.named_scope("balance"):
             if self._bias_rate and autograd.is_training():
                 even = tokens * self._top_k / self._experts

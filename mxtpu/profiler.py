@@ -46,7 +46,7 @@ from .observability.metrics import (  # noqa: F401  (re-exported surface)
     _stats_lock,
     add_commit_hook,
     get_checkpoint_stats, get_comm_stats, get_feed_stats,
-    get_kernel_path_counts,
+    get_kda_stats, get_kernel_path_counts,
     get_memory_stats, get_quant_stats, get_remat_stats, get_resilience_stats,
     get_retention_stats, get_router_stats, get_sanitizer_stats, get_sched_stats,
     get_serving_stats,
@@ -54,12 +54,13 @@ from .observability.metrics import (  # noqa: F401  (re-exported surface)
     record_checkpoint_save, record_checkpoint_shard_write,
     record_collective, record_comm_step,
     record_feed_consume, record_feed_prefetch, record_feed_resident,
-    record_feed_transfer, record_kernel_path, record_memory_stats,
+    record_feed_transfer, record_kda_launch, record_kernel_path,
+    record_memory_stats,
     record_quant_error, record_quant_matmuls, record_quant_range,
     record_remat, record_resilience, record_retention_launch, record_router, record_sanitizer, record_sched,
     record_serving, record_serving_occupancy, record_tenant,
     reset_checkpoint_stats, reset_comm_stats, reset_feed_stats,
-    reset_kernel_path_counts,
+    reset_kda_stats, reset_kernel_path_counts,
     reset_memory_stats, reset_quant_stats, reset_remat_stats,
     reset_resilience_stats,
     reset_retention_stats, reset_router_stats, reset_sanitizer_stats, reset_sched_stats,

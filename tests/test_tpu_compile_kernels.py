@@ -182,3 +182,26 @@ def test_retention_kernels_compile_at_the_retention_cells_size(one_chip,
     # linear in T: the chunk starts (0.55 GB) are the largest thing kept,
     # and nothing the size of T x T (40 heads: 5.4 GB) or T x 8256 exists
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+def test_kda_kernels_compile_at_the_ling_cells_size(one_chip, quiet_cache):
+    """32 heads of 128, T = 4096: a ``kda`` mixer of
+    ``lingflash_train_t4096``, forward and (under grad) backward: a chunk's
+    128 x 128 matrices, its eight sub-chunks' decayed keys and the
+    triangular system's float32 products in VMEM beside the head's state."""
+    from mxtpu.ops import kda as K
+    bf, f32 = jnp.bfloat16, jnp.float32
+    wide = (1, 4096, 32 * 128)
+    avals = _avals(one_chip, (wide, bf), (wide, bf), (wide, bf), (wide, f32),
+                   ((1, 4096, 32), f32))
+
+    def loss(*a):
+        return jnp.sum(K._kda_pallas(*a).astype(f32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *avals).compile()
+    text = compiled.as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    # linear in T: the chunk starts (67 MB) and the float32 decays and their
+    # gradient (67 MB each) are the largest things kept
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9

@@ -277,7 +277,10 @@ def test_step_async_ends_before_the_readback(traced):
     tracer.start()
     loss = traced["dpt"].step_async(traced["x"], traced["y"])
     tracer.stop()
-    names = [e["name"] for e in _ring()]
+    # the trainer's spans alone: an eager conversion that a process first ran
+    # under a tracer (``nd.contrib.MultiBoxTarget``'s vmap does) stays off
+    # jit's fast path there, and the step's key then adds a ``jax/trace``
+    names = [e["name"] for e in _ring() if e["name"].startswith("train/")]
     assert names == ["train/place", "train/prepare", "train/dispatch",
                      "train/adopt", "train/step"]
     assert np.isfinite(float(loss.data))

@@ -268,6 +268,47 @@ def test_five_steps_are_bit_identical_with_donation_on_and_off(
 
 
 # ---------------------------------------------------------------------------
+# the scalars and the key are not made between a read-back and a dispatch
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("donate", [True, False])
+def test_scalars_are_made_once_and_the_key_a_step_ahead(monkeypatch, donate):
+    """The learning rate, the decay, the rescale and the clip go to the
+    device when their value changes and not every step (never donated, so
+    the array of the step before is whole); the key of step ``t + 1`` is
+    made after step ``t`` is dispatched and is ``jax.random.key(t + 1)``;
+    a learning rate set between two steps is the one the next step uses."""
+    dpt, x, y = _trainer(monkeypatch, "one_device", "adam", donate=donate)
+    dpt.step(x, y)
+    first = {k: v[1] for k, v in dpt._scalars.items()}
+    assert set(first) == {"lr", "wd", "rescale", "clip"}
+    dpt.step(x, y)
+    assert all(dpt._scalars[k][1] is a and not a.is_deleted()
+               for k, a in first.items())
+    t, key = dpt._key_ahead
+    assert t == 3 and np.array_equal(jax.random.key_data(key),
+                                     jax.random.key_data(jax.random.key(3)))
+    # Adam at a rate of 0 moves nothing
+    before = [np.asarray(p.data().data) for p in dpt._param_handles]
+    dpt.optimizer.lr = 0.0
+    dpt.step(x, y)
+    assert dpt._scalars["lr"][1] is not first["lr"] \
+        and float(dpt._scalars["lr"][1]) == 0.0 \
+        and dpt._scalars["rescale"][1] is first["rescale"]
+    assert all(np.array_equal(np.asarray(p.data().data), b)
+               for p, b in zip(dpt._param_handles, before))
+    dpt.optimizer.lr = 0.05
+    dpt.step(x, y)
+    assert not any(np.array_equal(np.asarray(p.data().data), b)
+                   for p, b in zip(dpt._param_handles, before))
+    # a key asked for out of turn (a restored step count) is made afresh
+    dpt._t = 10
+    dpt.step(x, y)
+    assert dpt._key_ahead[0] == 12
+
+
+# ---------------------------------------------------------------------------
 # a stale read is named on the CPU, where nothing was donated
 # ---------------------------------------------------------------------------
 
