@@ -826,13 +826,16 @@ def leg_kda_train(sz, on_chip: bool) -> dict:
     """One step of a tiny ``HybridDecoderLM`` of the fifth family (two
     Kimi-Delta-Attention layers and a latent-attention layer, pre-norm
     RMSNorm, an untied head) through ``DataParallelTrainer``, twice from the same
-    weights: as it runs (on the chip the ``kda_fwd`` / ``kda_bwd`` launches,
-    the state carried over three chunks, and the flash launches at keys of
-    192 and values of 128) and with every kernel site on its XLA
-    formulation (the delta rule's chunked ``lax`` form). The first loss and
-    every parameter's first gradient must agree, with dense MLPs in every
-    layer; the expert layers under their group-limited router run a step of
-    their own (their grouped matmuls Pallas call sites on the chip)."""
+    weights: as it runs (on the chip the ``kda_fwd`` / ``kda_bwd`` launches
+    on raw operands, the norms, the gate and the chunks' cumulative decays
+    made inside them, the state carried over three chunks, and the flash
+    launches at keys of 192 and values of 128) and with every kernel site
+    on its XLA formulation (the delta rule's ``lax`` form, which runs the
+    same prologue). The first loss and every parameter's first gradient must
+    agree (``A_log``, ``dt_bias`` and ``f_proj`` among them: on the chip the
+    backward kernel makes those gradients), with dense MLPs in every layer;
+    the expert layers under their group-limited router run a step of their
+    own (their grouped matmuls Pallas call sites on the chip)."""
     import mxtpu as mx
     from mxtpu import nd, optimizer, profiler
     from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
@@ -883,7 +886,8 @@ def leg_kda_train(sz, on_chip: bool) -> dict:
     check(abs(loss - want_loss) <= tol * want_loss,
           f"kda train: first loss {loss} against lax's {want_loss}")
     check(set(moments) == set(want)
-          and any("dt_bias" in k for k in moments)
+          and all(any(leaf in k for k in moments)
+                  for leaf in ("dt_bias", "A_log", "f_proj"))
           and any("latentattention" in k for k in moments),
           f"kda train: parameters {sorted(moments)}")
     gaps = {k: float(np.linalg.norm(moments[k] - want[k])

@@ -80,7 +80,13 @@ own (``tie_head=False``, float32 logits). The mixers, by kind:
     x)_t[h])``; a state ``S`` of ``D x D`` a head, zero at the start: ``S' =
     Diag(exp(a_t)) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t - S'^T k_t)^T``,
     ``o_t = S_t^T q_t``; output ``W_o (RMSNorm_D(o_t; gain) * sigmoid(g_t))``,
-    the norm over each head with one gain vector.
+    the norm over each head with one gain vector. The layer hands
+    ``contrib.kda`` its operands RAW: q and k as the convolution's SiLU left
+    them, the logits ``W_f x`` as their product made them (float32),
+    ``A_log`` and ``dt_bias``; the two norms, the gate's sigmoid and bound
+    and the chunks' cumulative decays are the op's, made inside its kernels
+    on the TPU (and their gradients too), so nothing of ``(B, T, H D)`` is
+    written between the convolution / the gate's product and the kernels.
 ``mla``
     multi-head latent attention as it trains (``LatentAttention``; the
     keyword ``mla``: ``latent_dim``, ``nope_dim``, ``rope_dim``, ``v_dim``,
@@ -121,7 +127,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ... import autograd, initializer
 from ... import ndarray as nd
@@ -500,42 +505,37 @@ class PowerRetention(GroupedQueryAttention):
 
 
 @registry.register("kda_gate", namespace="contrib", num_outputs=2)
-def kda_gate(x, f_weight, b_weight, a_log, dt_bias, lower_bound: float = -5.0):
-    """The delta rule's two gates, in float32 whatever ``x`` and the
-    matrices are stored in. ``(a (B, T, H, D), beta (B, T, H))``: the
-    log-decay a channel ``lower_bound * sigmoid(exp(A_log[h]) * ((W_f x)[h,
-    c] + dt_bias[h, c]))``, which lies in ``(lower_bound, 0)`` (the bound
-    ``ops.kda`` relies on), and ``sigmoid(W_beta x)``."""
-    H = a_log.shape[0]
+def kda_gate(x, f_weight, b_weight):
+    """The delta rule's two gates as far as their matrix products, in
+    float32 whatever ``x`` and the matrices are stored in. ``(z (B, T, H,
+    D), beta (B, T, H))``: the decay's logits ``W_f x`` as the product makes
+    them (``ops.kda`` adds ``dt_bias`` and makes the bounded log-decay from
+    them, inside its kernels on the TPU), and ``sigmoid(W_beta x)``."""
+    H = b_weight.shape[0]
     f32 = jnp.float32
-    logits = jnp.einsum("btd,cd->btc", x, f_weight,
-                        preferred_element_type=f32)
-    logits = (logits + dt_bias.astype(f32)).reshape(x.shape[:2] + (H, -1))
-    a = lower_bound * jax.nn.sigmoid(
-        jnp.exp(a_log.astype(f32))[:, None] * logits)
+    z = jnp.einsum("btd,cd->btc", x, f_weight, preferred_element_type=f32)
     beta = jax.nn.sigmoid(jnp.einsum("btd,hd->bth", x, b_weight,
                                      preferred_element_type=f32))
-    return a, beta
+    return z.reshape(x.shape[:2] + (H, -1)), beta
 
 
 @registry.register("kda_scan", namespace="contrib")
-def kda_scan(qkv, conv_weight, a, beta, eps: float = 1e-6):
+def kda_scan(qkv, conv_weight, z, beta, a_log, dt_bias,
+             lower_bound: float = -5.0, eps: float = 1e-6):
     """``qkv`` ``(B, T, 3 H D)`` through its short convolution and SiLU
-    (scope ``conv``), q and k L2-normalised over each head (q scaled by ``D
-    ** -0.5``), then ``ops.kda`` (scope ``scan``). ``(B, T, H * D)``."""
+    (scope ``conv``), then ``ops.kda`` on what that leaves (scope ``scan``):
+    the op L2-norms q and k over each head (q scaled by ``D ** -0.5``) and
+    makes the log-decay ``lower_bound * sigmoid(exp(A_log[h]) * (z +
+    dt_bias))`` from the logits ``z`` of ``kda_gate`` itself. ``(B, T, H *
+    D)``."""
     B, T, _ = qkv.shape
-    H, D = a.shape[2:]
+    H, D = z.shape[2:]
     with jax.named_scope("conv"):
         q, k, v = jnp.split(jax.nn.silu(causal_conv1d(qkv, conv_weight)), 3,
                             axis=-1)
     with jax.named_scope("scan"):
-        def unit(x, scale):
-            wide = x.reshape(B, T, H, D).astype(jnp.float32)
-            return (wide * (scale * lax.rsqrt(jnp.sum(
-                jnp.square(wide), axis=-1, keepdims=True) + eps))
-            ).astype(x.dtype)
-        return _kda(unit(q, D ** -0.5), unit(k, 1.0),
-                    v.reshape(B, T, H, D), a, beta)
+        return _kda(*(x.reshape(B, T, H, D) for x in (q, k, v)), z, beta,
+                    a_log, dt_bias, lower_bound=lower_bound, eps=eps)
 
 
 @registry.register("gated_head_norm", namespace="contrib")
@@ -579,9 +579,10 @@ class KimiDeltaAttention(HybridBlock):
     ``head_dim``, keys and values as many as queries. ``in_proj`` makes
     ``[q, k, v, g]`` (four times ``num_heads * head_dim``, no bias); q, k
     and v go through a depthwise causal convolution of ``d_conv`` taps and a
-    SiLU; q and k are L2-normalised a head; the decay and ``beta`` are
-    float32 (``contrib.kda_gate``: full matrices ``f_proj`` / ``b_proj``,
-    ``A_log`` a head and ``dt_bias`` a channel kept in float32); the output
+    SiLU; the decay's logits and ``beta`` are float32 (``contrib.kda_gate``:
+    full matrices ``f_proj`` / ``b_proj``); ``contrib.kda`` L2-normalises q
+    and k a head and bounds the decay (``A_log`` a head and ``dt_bias`` a
+    channel, kept in float32) from those raw operands itself; the output
     is RMS-normed a head (one gain vector) and gated by ``sigmoid(g)``
     before ``out_proj``. ``forward`` returns the output alone. A device
     trace reads ``block<i>/kda/proj|conv|gate|scan|out``."""
@@ -617,11 +618,11 @@ class KimiDeltaAttention(HybridBlock):
             qkv, g = _split(self.in_proj(x), (self.conv_weight.shape[0],
                                               self.dt_bias.shape[0]))
         with jax.named_scope("gate"):
-            a, beta = registry.invoke(
-                _KDA_GATE, x, self.f_proj.data(), self.b_proj.data(),
-                self.A_log.data(), self.dt_bias.data(),
-                lower_bound=self._bound)
-        o = registry.invoke(_KDA_SCAN, qkv, self.conv_weight.data(), a, beta)
+            z, beta = registry.invoke(
+                _KDA_GATE, x, self.f_proj.data(), self.b_proj.data())
+        o = registry.invoke(
+            _KDA_SCAN, qkv, self.conv_weight.data(), z, beta,
+            self.A_log.data(), self.dt_bias.data(), lower_bound=self._bound)
         with jax.named_scope("out"):
             return self.out_proj(registry.invoke(
                 _GATED_HEAD_NORM, o, g, self.o_norm.data(),

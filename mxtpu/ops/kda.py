@@ -35,8 +35,21 @@ One grid step of a kernel is one (batch row, head, chunk); a head's chunks
 run in order with the state in VMEM. The forward KEEPS the state at every
 chunk start (float32, 64 KB a head a chunk: 67 MB a layer at 4096 tokens and
 32 heads); the backward walks the chunks from the last to the first, makes
-the chunk's matrices again and carries the state's gradient. The cumulative
-sums (``g`` from ``a``, and ``da`` from ``dg``) are XLA's, in float32.
+the chunk's matrices again and carries the state's gradient.
+
+The kernels take their operands RAW and make the rest in VMEM, from the tiles
+they load anyway (``_prologue`` and ``_running``, which the ``lax`` form runs
+too): q and k as the convolution left them, L2-normed over a head's 128
+channels in float32 (q scaled by ``D ** -0.5``) and not rounded again before
+the products cast them; the gate's logits ``z`` with a row of ``dt_bias`` and
+one of ``exp(A_log)``, from which ``a = lower_bound * sigmoid(exp(A_log) * (z
++ dt_bias))`` and ``g``, its cumulative sum over the chunk's rows (shifted
+adds along the sublanes, float32). ``kda_bwd`` makes them
+again and sends its gradients back through them before it stores: ``dq``,
+``dk``, ``dz`` and the two rows' gradients, summed over a head's chunks in an
+output block that stays in VMEM. Between the convolution's output / the
+gate's product and the kernels no ``(B, T, H * D)`` array exists, forward or
+backward.
 
 The token-by-token recurrence is not here: the benchmark's reference and the
 tests hold it.
@@ -57,10 +70,12 @@ __all__ = ["kda", "kda_stats"]
 
 _LANES = 128
 # v5e, one layer of the cell (T 4096, 32 heads of 128, bf16; my chip runs,
-# PR 41): ms a launch, forward / backward, beside the constant it was read at
-CHUNK = 128         # rows of T a grid step: 3.39 / 4.17. Every matrix of a
-                    # chunk is one 128 x 128 tile; the kept chunk starts (67
-                    # MB a layer) halve against 64. No other length was run
+# PR 42): ms a launch, forward / backward, beside the constant it was read at
+CHUNK = 128         # rows of T a grid step: 3.60 / 4.51 with the prologue
+                    # in the kernels (3.39 / 4.17 without it, PR 41). Every
+                    # matrix of a chunk is one 128 x 128 tile; the kept chunk
+                    # starts (67 MB a layer) halve against 64. No other
+                    # length was run
 SUB = 16            # rows of a sub-chunk: 15 steps of the gate's bound -5
 _CLAMP = 80.0       # exp's argument for the keys a sub-chunk does NOT see
                     # (masked to zero afterwards): finite in float32
@@ -113,8 +128,60 @@ def _as_row(col):
                    axis=0, keepdims=True)
 
 
-def _blocks(x):
-    return [x[i:i + SUB] for i in range(0, x.shape[0], SUB)]
+def _prologue(q, k, z, bias, rate, bound: float, eps: float):
+    """What lies between the convolution's output / the gate's product and
+    the chunk's matrices, on rows of a head's ``D`` channels: ``(C, D)``
+    tiles with ``bias`` / ``rate`` ``(1, D)`` in a kernel, ``(B, T, H, D)``
+    with ``(H, D)`` in the ``lax`` form. Returns ``(q, k, a)``, float32: q
+    and k L2-normed over the channels (q scaled by ``D ** -0.5``), and the
+    log-decay ``a = bound * sigmoid(rate * (z + bias))``, which lies in
+    ``[bound, 0]``: the bound the sub-chunks rely on is made where it is
+    used."""
+    def unit(x, scale):
+        x = x.astype(_F32)
+        return x * (scale * lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True) + eps))
+
+    a = bound * jax.nn.sigmoid(rate * (z.astype(_F32) + bias))
+    return unit(q, q.shape[-1] ** -0.5), unit(k, 1.0), a
+
+
+def _shifted_sum(x, back: bool):
+    """``(C, D)`` float32 -> its sum over the rows from the first row on
+    (``back``: from the last row back), by doubling shifted adds along the
+    rows: seven rounds at 128."""
+    C = x.shape[0]
+    row = _iota(x.shape, 0)
+    shift = 1
+    while shift < C:
+        if back:
+            x = x + jnp.where(row < C - shift, jnp.roll(x, -shift, 0), 0.0)
+        else:
+            x = x + jnp.where(row >= shift, jnp.roll(x, shift, 0), 0.0)
+        shift *= 2
+    return x
+
+
+@jax.custom_vjp
+def _running(a):
+    """The chunk's cumulative log-decay ``g`` from its rows' ``a``: ``(C,
+    D)`` float32, the sum from the chunk's first row on; its transpose sums
+    from the last row back. (Shifted adds, not a product with a triangle of
+    ones: at full precision that is six MXU passes, and a launch was 0.12
+    ms forward and 0.20 backward slower with it; with the addend in three
+    bf16 pieces 0.03 / 0.07 slower: my chip run, PR 42.)"""
+    return _shifted_sum(a, False)
+
+
+_running.defvjp(lambda a: (_running(a), None),
+                lambda _, dg: (_shifted_sum(dg, True),))
+
+
+def _chunk_operands(q, k, z, bias, rate, bound: float, eps: float):
+    """A kernel's tiles -> what its chunk is computed from: ``(q, k, g)``,
+    float32, normed and with the cumulative log-decay."""
+    q, k, a = _prologue(q, k, z, bias, rate, bound, eps)
+    return q, k, _running(a)
 
 
 def _chunk_parts(q, k, g, dt):
@@ -252,20 +319,14 @@ def _chunk_backward(q, k, v, g, beta, s0, do, ds1, dt):
 # ---------------------------------------------------------------------------
 
 
-def _chunk_cumsum(a, chunk: int):
-    """``(B, T, ...)`` float32 -> the cumulative sum along ``T`` from each
-    chunk's first row on."""
-    B, T = a.shape[:2]
-    return jnp.cumsum(a.astype(_F32).reshape((B, T // chunk, chunk)
-                                             + a.shape[2:]),
-                      axis=2).reshape(a.shape)
-
-
-def _kda_lax(q, k, v, a, beta, chunk: int):
-    """``_chunk_forward`` over ``(B, H)`` in a ``lax.scan`` over chunks that
+def _kda_lax(q, k, v, z, beta, bias, rate, bound, eps, chunk: int):
+    """``_prologue`` on the whole arrays, then ``_running`` and
+    ``_chunk_forward`` over ``(B, H)`` in a ``lax.scan`` over chunks that
     carries the states; each chunk is a ``jax.checkpoint``, so JAX's
     transpose keeps the chunk starts alone and walks them backwards."""
     B, T, H, D = q.shape
+    dt = q.dtype
+    q, k, a = _prologue(q, k, z, bias, rate, bound, eps)
     c = min(chunk, -(-T // SUB) * SUB)
     pad = -T % c
     if pad:     # rows past T: no key, no value, no decay, no write
@@ -273,24 +334,26 @@ def _kda_lax(q, k, v, a, beta, chunk: int):
                       for x in (q, k, v, a))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
     n = (T + pad) // c
-    g = _chunk_cumsum(a, c)
 
     def by_chunk(x):        # (B, T, H, ...) -> (n, B, H, c, ...)
         x = x.reshape((B, n, c) + x.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
 
-    one = jax.vmap(jax.vmap(functools.partial(_chunk_forward, dt=q.dtype)))
+    def chunk_of(q, k, v, a, beta, s0):
+        return _chunk_forward(q, k, v, _running(a), beta, s0, dt)
+
+    one = jax.vmap(jax.vmap(chunk_of))
 
     @jax.checkpoint
     def body(s0, xs):
         o, s1 = one(*xs, s0)
         return s1, o
 
-    xs = (by_chunk(q), by_chunk(k), by_chunk(v), by_chunk(g),
+    xs = (by_chunk(q), by_chunk(k), by_chunk(v), by_chunk(a),
           by_chunk(beta.astype(_F32))[..., None])
     _, o = lax.scan(body, jnp.zeros((B, H, D, D), _F32), xs)
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)       # (B, n, c, H, D)
-    return o.reshape(B, T + pad, H * D)[:, :T].astype(q.dtype)
+    return o.reshape(B, T + pad, H * D)[:, :T].astype(dt)
 
 
 # ---------------------------------------------------------------------------
@@ -298,7 +361,8 @@ def _kda_lax(q, k, v, a, beta, chunk: int):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, s_ref):
+def _fwd_kernel(q_ref, k_ref, v_ref, z_ref, b_ref, bias_ref, rate_ref,
+                o_ref, s0_ref, s_ref, *, bound, eps):
     """One (batch row, head, chunk); a head's chunks run in order and hand
     the state on in ``s_ref``."""
     from jax.experimental import pallas as pl
@@ -309,39 +373,49 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, s_ref):
 
     s0 = s_ref[...]
     s0_ref[0, 0, 0] = s0        # kept for the backward
-    o, s1 = _chunk_forward(q_ref[0], k_ref[0], v_ref[0], g_ref[0],
-                           _column(b_ref[0, 0, 0]), s0, q_ref.dtype)
+    q, k, g = _chunk_operands(q_ref[0], k_ref[0], z_ref[0], bias_ref[...],
+                              rate_ref[...], bound, eps)
+    o, s1 = _chunk_forward(q, k, v_ref[0], g, _column(b_ref[0, 0, 0]), s0,
+                           q_ref.dtype)
     o_ref[0] = o.astype(o_ref.dtype)
     s_ref[...] = s1
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
+def _bwd_kernel(q_ref, k_ref, v_ref, z_ref, b_ref, bias_ref, rate_ref,
+                s0_ref, do_ref, dq_ref, dk_ref, dv_ref, dz_ref, db_ref,
+                dbias_ref, drate_ref, ds_ref, *, bound, eps):
     """One (batch row, head, chunk), chunks from the last to the first:
     ``ds_ref`` carries the gradient of the state at the END of the chunk in
-    hand."""
+    hand, and the two rows' gradients add up over a head's chunks in their
+    output blocks. The prologue runs again, and JAX transposes it here."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
+        dbias_ref[...] = jnp.zeros_like(dbias_ref)
+        drate_ref[...] = jnp.zeros_like(drate_ref)
 
+    (q, k, g), back = jax.vjp(
+        functools.partial(_chunk_operands, bound=bound, eps=eps),
+        q_ref[0], k_ref[0], z_ref[0], bias_ref[...], rate_ref[...])
     dq, dk, dv, dg, dbeta, ds0 = _chunk_backward(
-        q_ref[0], k_ref[0], v_ref[0], g_ref[0], _column(b_ref[0, 0, 0]),
-        s0_ref[0, 0, 0], do_ref[0], ds_ref[...], q_ref.dtype)
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+        q, k, v_ref[0], g, _column(b_ref[0, 0, 0]), s0_ref[0, 0, 0],
+        do_ref[0], ds_ref[...], q_ref.dtype)
+    dq_ref[0], dk_ref[0], dz_ref[0], dbias, drate = back((dq, dk, dg))
     dv_ref[0] = dv.astype(dv_ref.dtype)
-    dg_ref[0] = dg
     db_ref[0, 0, 0] = _as_row(dbeta)
+    dbias_ref[0] += dbias
+    drate_ref[0] += drate
     ds_ref[...] = ds0
 
 
 def _specs(chunk: int, order):
-    """Block specs of the operands as the model holds them: ``q`` .. ``g``
+    """Block specs of the operands as the model holds them: ``q`` .. ``z``
     ``(B, T, H * D)`` (a head's channels side by side), ``beta`` and the kept
-    states by chunk. ``order`` maps the grid's chunk index to the chunk (the
-    backward runs them reversed)."""
+    states by chunk, the gate's two rows ``(1, H * D)`` and their gradients
+    ``(B, 1, H * D)`` by head. ``order`` maps the grid's chunk index to the
+    chunk (the backward runs them reversed)."""
     from jax.experimental import pallas as pl
     D = _LANES
     return dict(
@@ -349,7 +423,9 @@ def _specs(chunk: int, order):
         beta=pl.BlockSpec((1, 1, 1, 1, chunk),
                           lambda b, h, c: (b, h, order(c), 0, 0)),
         s=pl.BlockSpec((1, 1, 1, D, D),
-                       lambda b, h, c: (b, h, order(c), 0, 0)))
+                       lambda b, h, c: (b, h, order(c), 0, 0)),
+        row=pl.BlockSpec((1, D), lambda b, h, c: (0, h)),
+        drow=pl.BlockSpec((1, 1, D), lambda b, h, c: (b, 0, h)))
 
 
 def _beta_rows(beta, chunk: int):
@@ -366,11 +442,13 @@ def _params():
         vmem_limit_bytes=VMEM_LIMIT)
 
 
-def _forward_pallas(q, k, v, g, beta, interpret=False, chunk=None):
-    """``q``, ``k``, ``v``: ``(B, T, H * D)``; ``g`` like them, float32, the
-    chunks' cumulative log-decays; ``beta`` ``(B, T, H)``. Returns ``(o, S0)``:
-    the output like ``q`` and the state at the start of every chunk, ``(B,
-    H, T / chunk, D, D)`` float32."""
+def _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps,
+                    interpret=False, chunk=None):
+    """``q``, ``k``, ``v``: ``(B, T, H * D)``, q and k un-normed; ``z`` like
+    them, float32, the gate's logits; ``beta`` ``(B, T, H)``; ``bias`` and
+    ``rate`` ``(H, D)`` float32 (``dt_bias`` and ``exp(A_log)`` a channel).
+    Returns ``(o, S0)``: the output like ``q`` and the state at the start of
+    every chunk, ``(B, H, T / chunk, D, D)`` float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -379,9 +457,9 @@ def _forward_pallas(q, k, v, g, beta, interpret=False, chunk=None):
     D, n_c = _LANES, T // chunk
     sp = _specs(chunk, lambda c: c)
     return pl.pallas_call(
-        _fwd_kernel,
+        functools.partial(_fwd_kernel, bound=bound, eps=eps),
         grid=(B, H, n_c),
-        in_specs=[sp["x"]] * 4 + [sp["beta"]],
+        in_specs=[sp["x"]] * 4 + [sp["beta"]] + [sp["row"]] * 2,
         out_specs=[sp["x"], sp["s"]],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((B, H, n_c, D, D), _F32)],
@@ -389,13 +467,15 @@ def _forward_pallas(q, k, v, g, beta, interpret=False, chunk=None):
         compiler_params=_params(),
         name="kda_fwd",
         interpret=interpret,
-    )(q, k, v, g, _beta_rows(beta, chunk))
+    )(q, k, v, z, _beta_rows(beta, chunk), bias.reshape(1, H * D),
+      rate.reshape(1, H * D))
 
 
-def _backward_pallas(q, k, v, g, beta, s0, do, interpret=False, chunk=None):
-    """``(dq, dk, dv, dg, dbeta)``: the first three like ``q``, ``dg``
-    float32 like ``g`` (of the CUMULATIVE log-decay), ``dbeta`` ``(B, T,
-    H)`` float32."""
+def _backward_pallas(q, k, v, z, beta, bias, rate, s0, do, bound, eps,
+                     interpret=False, chunk=None):
+    """``(dq, dk, dv, dz, dbeta, dbias, drate)``: the first three like ``q``
+    (of the un-normed q and k), ``dz`` float32 like ``z``, ``dbeta`` ``(B,
+    T, H)`` float32, the last two ``(H, D)`` float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -403,21 +483,26 @@ def _backward_pallas(q, k, v, g, beta, s0, do, interpret=False, chunk=None):
     B, T, H = beta.shape
     D, n_c = _LANES, T // chunk
     sp = _specs(chunk, lambda c: n_c - 1 - c)
-    dq, dk, dv, dg, db = pl.pallas_call(
-        _bwd_kernel,
+    row = jax.ShapeDtypeStruct((B, 1, H * D), _F32)
+    dq, dk, dv, dz, db, dbias, drate = pl.pallas_call(
+        functools.partial(_bwd_kernel, bound=bound, eps=eps),
         grid=(B, H, n_c),
-        in_specs=[sp["x"]] * 4 + [sp["beta"], sp["s"], sp["x"]],
-        out_specs=[sp["x"]] * 4 + [sp["beta"]],
+        in_specs=[sp["x"]] * 4 + [sp["beta"]] + [sp["row"]] * 2
+        + [sp["s"], sp["x"]],
+        out_specs=[sp["x"]] * 4 + [sp["beta"]] + [sp["drow"]] * 2,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3
         + [jax.ShapeDtypeStruct(q.shape, _F32),
-           jax.ShapeDtypeStruct((B, H, n_c, 1, chunk), _F32)],
+           jax.ShapeDtypeStruct((B, H, n_c, 1, chunk), _F32), row, row],
         scratch_shapes=[pltpu.VMEM((D, D), _F32)],
         compiler_params=_params(),
         name="kda_bwd",
         interpret=interpret,
-    )(q, k, v, g, _beta_rows(beta, chunk), s0, do)
-    return dq, dk, dv, dg, db[:, :, :, 0, :].transpose(0, 2, 3, 1) \
-        .reshape(B, T, H)
+    )(q, k, v, z, _beta_rows(beta, chunk), bias.reshape(1, H * D),
+      rate.reshape(1, H * D), s0, do)
+    return (dq, dk, dv, dz,
+            db[:, :, :, 0, :].transpose(0, 2, 3, 1).reshape(B, T, H),
+            jnp.sum(dbias, axis=0).reshape(H, D),
+            jnp.sum(drate, axis=0).reshape(H, D))
 
 
 # ---------------------------------------------------------------------------
@@ -434,28 +519,32 @@ def _use_pallas(q) -> bool:
             and q.shape[1] % CHUNK == 0 and _on_one_device(q))
 
 
-@jax.custom_vjp
-def _kda_pallas(q, k, v, a, beta):
-    return _forward_pallas(q, k, v, _chunk_cumsum(a, CHUNK), beta)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _kda_pallas(q, k, v, z, beta, bias, rate, bound, eps):
+    return _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps)[0]
 
 
-def _kda_pallas_fwd(q, k, v, a, beta):
-    g = _chunk_cumsum(a, CHUNK)
-    o, s0 = _forward_pallas(q, k, v, g, beta)
-    return o, (q, k, v, g, beta, s0)
+def _kda_pallas_fwd(q, k, v, z, beta, bias, rate, bound, eps):
+    o, s0 = _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps)
+    return o, (q, k, v, z, beta, bias, rate, s0)
 
 
-def _kda_pallas_bwd(res, do):
-    q, k, v, g, beta, s0 = res
-    dq, dk, dv, dg, dbeta = _backward_pallas(q, k, v, g, beta, s0, do)
-    # every later row of a chunk holds a_t in its cumulative sum
-    B, T, W = dg.shape
-    by = dg.reshape(B, T // CHUNK, CHUNK, W)
-    da = jnp.flip(jnp.cumsum(jnp.flip(by, 2), axis=2), 2).reshape(dg.shape)
-    return dq, dk, dv, da, dbeta.astype(beta.dtype)
+def _kda_pallas_bwd(bound, eps, res, do):
+    q, k, v, z, beta, bias, rate, s0 = res
+    dq, dk, dv, dz, dbeta, dbias, drate = _backward_pallas(
+        q, k, v, z, beta, bias, rate, s0, do, bound, eps)
+    return dq, dk, dv, dz, dbeta.astype(beta.dtype), dbias, drate
 
 
 _kda_pallas.defvjp(_kda_pallas_fwd, _kda_pallas_bwd)
+
+
+def _gate_rows(a_log, dt_bias):
+    """``dt_bias`` and ``exp(a_log)`` a channel, ``(H, D)`` float32 each."""
+    H = a_log.shape[0]
+    bias = dt_bias.astype(_F32).reshape(H, -1)
+    return bias, jnp.broadcast_to(jnp.exp(a_log.astype(_F32))[:, None],
+                                  bias.shape)
 
 
 def kda_stats(T: int, num_heads: int, head_dim: int = _LANES,
@@ -472,26 +561,33 @@ def kda_stats(T: int, num_heads: int, head_dim: int = _LANES,
 
 
 @register("kda", namespace="contrib")
-def kda(q, k, v, a, beta):
+def kda(q, k, v, z, beta, a_log, dt_bias, lower_bound: float = -5.0,
+        eps: float = 1e-6):
     """The gated delta rule with a decay a key channel, causal, from a zero
-    state. ``q``, ``k``, ``v``: ``(B, T, H, D)`` (``q`` and ``k`` as the
-    caller normed and scaled them); ``a`` ``(B, T, H, D)`` float32, each
-    channel's log-decay, in ``(-5, 0]``: a sub-chunk of 16 rows is computed
-    against its first row, which that bound keeps finite; ``beta`` ``(B, T,
-    H)``. Returns ``(B, T, H * D)``: ``o_t = S_t^T q_t`` with ``S_t = (I -
-    beta_t k_t k_t^T) Diag(exp(a_t)) S_{t-1} + beta_t k_t v_t^T``. Pallas
-    kernels with their own backward on the TPU where ``D == 128`` and ``T``
-    is whole chunks of ``CHUNK``; the same chunked algorithm in ``lax``
-    anywhere else. Memory is linear in ``T`` either way."""
+    state, on RAW operands. ``q``, ``k``, ``v``: ``(B, T, H, D)``, q and k
+    un-normed: the op L2-norms them over ``D`` in float32 (``x / sqrt(|x|^2
+    + eps)``, q scaled by ``D ** -0.5``). ``z`` ``(B, T, H, D)``: the
+    decay's logits; ``a_log`` ``(H,)`` and ``dt_bias`` ``(H * D,)``: each
+    channel's log-decay is ``a = lower_bound * sigmoid(exp(a_log[h]) * (z +
+    dt_bias))``, float32, in ``[lower_bound, 0]`` (a sub-chunk of 16 rows
+    is computed against its first row, which a bound of -5 keeps finite);
+    ``beta`` ``(B, T, H)``. Returns ``(B, T, H * D)``: ``o_t = S_t^T q_t``
+    with ``S_t = (I - beta_t k_t k_t^T) Diag(exp(a_t)) S_{t-1} + beta_t k_t
+    v_t^T``. Pallas kernels with their own backward on the TPU where ``D ==
+    128`` and ``T`` is whole chunks of ``CHUNK``: the norms, the gate and
+    the chunks' cumulative decays are made inside them, and their gradients
+    too; the same mathematics in ``lax`` anywhere else. Memory is linear in
+    ``T`` either way."""
     from ..observability import metrics
     pallas = _use_pallas(q)
     metrics.record_kernel_path("kda", pallas)
     B, T, H, D = q.shape
     metrics.record_kda_launch(**kda_stats(T, H, D, B))
+    bias, rate = _gate_rows(a_log, dt_bias)
     with jax.named_scope("kda"):
         if pallas:
             return _kda_pallas(
-                q.reshape(B, T, H * D), k.reshape(B, T, H * D),
-                v.reshape(B, T, H * D),
-                a.astype(_F32).reshape(B, T, H * D), beta)
-        return _kda_lax(q, k, v, a.astype(_F32), beta, CHUNK)
+                *(x.reshape(B, T, H * D) for x in (q, k, v, z)), beta, bias,
+                rate, lower_bound, eps)
+        return _kda_lax(q, k, v, z, beta, bias, rate, lower_bound, eps,
+                        CHUNK)

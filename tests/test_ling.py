@@ -1,9 +1,11 @@
 """The fifth family of ``HybridDecoderLM`` (Ling-3.0-flash's block:
 Kimi-Delta-Attention layers beside latent attention, sparse expert layers
 under a group-limited router) and its op ``contrib.kda`` (``ops/kda.py``):
-the op's chunked ``lax`` form and the Pallas kernels under ``interpret=True``
-against the token-by-token recurrence, values and all five gradients, at
-whole and ragged chunks, with the log-decay at both ends of ``(-5, 0)``; the
+the op's chunked ``lax`` form and the Pallas kernels under ``interpret=True``,
+on raw operands (q and k un-normed, the gate's logits, ``A_log`` and
+``dt_bias``), against the norms and the gate written plainly and the
+token-by-token recurrence, values and all seven gradients, at whole and ragged
+chunks, with the log-decay at both ends of ``(-5, 0)`` and AT them; the
 group-limited choice against a plain top-k over masked groups; the shares of
 a layer against the uncut reference; latent attention against the expanded
 quadratic form; then the model against the plain float32 reference the
@@ -61,6 +63,7 @@ TOL_GRAD = 5e-4         # a leaf's gradient, of that leaf's norm
 TOL_DELTA = 2e-3        # a leaf's change over two steps, relative
 T = 32
 CHUNK = 16              # the op's chunk in the model tests: two chunks of T
+GATE = (-5.0, 1e-6)     # the op's lower bound and the norms' eps
 
 
 def _load(path, name):
@@ -128,43 +131,77 @@ def recurrent(q, k, v, a, beta):
     return jnp.moveaxis(o, 0, 1).reshape(B, T_, H * D)
 
 
+def plain(q, k, v, z, beta, a_log, dt_bias):
+    """What the op makes of its raw operands before the recurrence, written
+    plainly (no cumulative sum: the recurrence needs none), then the
+    recurrence."""
+    H, D = q.shape[2:]
+    bound, eps = GATE
+
+    def unit(x):
+        return x / jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+    a = bound * jax.nn.sigmoid(jnp.exp(a_log)[:, None]
+                               * (z + dt_bias.reshape(H, D)))
+    return recurrent(unit(q) * D ** -0.5, unit(k), v, a, beta)
+
+
 ENDS = {"slow": (1e-4, 0.05), "fast": (4.5, 5.0), "mixed": (0.0, 5.0)}
-NAMES = ("value", "dq", "dk", "dv", "da", "dbeta")
+NAMES = ("value", "dq", "dk", "dv", "dz", "dbeta", "dA_log", "ddt_bias")
 
 
 def _data(T_, D, end, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    """Raw operands whose log-decays lie in the end's range."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     q, k, v = (jax.random.normal(key, (2, T_, 2, D)) for key in ks[:3])
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     lo, hi = ENDS[end]
     a = -jax.random.uniform(ks[3], (2, T_, 2, D), minval=lo, maxval=hi)
+    a_log = 0.3 * jax.random.normal(ks[6], (2,))
+    dt_bias = jax.random.normal(ks[7], (2 * D,))
+    share = jnp.clip(a / -5.0, 1e-6, 1.0 - 1e-6)
+    z = jnp.log(share / (1.0 - share)) / jnp.exp(a_log)[:, None] \
+        - dt_bias.reshape(2, D)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, T_, 2)))
     w = jax.random.normal(ks[5], (2, T_, 2 * D))
-    return (q, k, v, a, beta), w
+    return (q, k, v, z, beta, a_log, dt_bias), w
 
 
 def _value_and_grads(fn, args, w):
     value = fn(*args)
     grads = jax.grad(lambda *x: jnp.sum(fn(*x) * w),
-                     argnums=(0, 1, 2, 3, 4))(*args)
+                     argnums=tuple(range(7)))(*args)
     return dict(zip(NAMES, (value,) + grads))
+
+
+def _lax(chunk):
+    def op(q, k, v, z, beta, a_log, dt_bias):
+        return K._kda_lax(q, k, v, z, beta, *K._gate_rows(a_log, dt_bias),
+                          *GATE, chunk)
+    return op
 
 
 @functools.lru_cache(maxsize=None)
 def _forms(T_, D, end, chunk):
     args, w = _data(T_, D, end)
-    return (_value_and_grads(recurrent, args, w),
-            _value_and_grads(lambda *x: K._kda_lax(*x, chunk), args, w))
+    return (_value_and_grads(plain, args, w),
+            _value_and_grads(_lax(chunk), args, w))
 
 
-def _close(got, want, what):
+def _close(got, want, what, terms=1):
     scale = float(jnp.max(jnp.abs(want)))
     assert scale > 0, what
     # (1e-7: where every channel forgets in a token the decay's gradient is
-    # of the size of float32's rounding of the values it is made from)
+    # of the size of float32's rounding of the values it is made from; a
+    # parameter of the gate sums ``terms`` such gradients, and their rounding
+    # as the root of their number)
     assert float(jnp.max(jnp.abs(got.reshape(want.shape) - want))) \
-        <= 2e-5 * scale + 1e-7, what
+        <= 2e-5 * scale + 1e-7 * math.sqrt(terms), what
+
+
+def _summed(want, what):
+    """How many logits' gradients one entry of ``what`` adds up."""
+    return want["dz"].size // want[what].size \
+        if what in ("dA_log", "ddt_bias") else 1
 
 
 @pytest.mark.parametrize("T_", [64, 40])        # whole chunks of 32, and not
@@ -172,36 +209,47 @@ def _close(got, want, what):
 @pytest.mark.parametrize("what", NAMES)
 def test_chunked_lax_form_against_the_recurrence(T_, end, what):
     want, got = _forms(T_, 32, end, 32)
-    _close(got[what], want[what], (T_, end, what))
+    _close(got[what], want[what], (T_, end, what), _summed(want, what))
 
 
 @functools.lru_cache(maxsize=None)
 def _kernels(end):
     """The two kernels interpreted, heads of 128, two chunks of 32."""
-    (q, k, v, a, beta), w = _data(64, 128, end)
+    args, w = _data(64, 128, end)
+    q, k, v, z, beta, a_log, dt_bias = args
     B, T_, H, D = q.shape
-
-    def flat(x):
-        return x.reshape(B, T_, H * D)
-
-    g = K._chunk_cumsum(flat(a), 32)
-    o, s0 = K._forward_pallas(flat(q), flat(k), flat(v), g, beta,
-                              interpret=True, chunk=32)
-    dq, dk, dv, dg, db = K._backward_pallas(
-        flat(q), flat(k), flat(v), g, beta, s0, w, interpret=True, chunk=32)
-    by = dg.reshape(B, T_ // 32, 32, H * D)
-    da = jnp.flip(jnp.cumsum(jnp.flip(by, 2), axis=2), 2).reshape(dg.shape)
-    return (_value_and_grads(recurrent, (q, k, v, a, beta), w),
-            dict(zip(NAMES, (o, dq, dk, dv, da, db))), s0)
+    flat = [x.reshape(B, T_, H * D) for x in (q, k, v, z)]
+    rows, leaves = jax.vjp(K._gate_rows, a_log, dt_bias)
+    o, s0 = K._forward_pallas(*flat, beta, *rows, *GATE, interpret=True,
+                              chunk=32)
+    *grads, dbias, drate = K._backward_pallas(
+        *flat, beta, *rows, s0, w, *GATE, interpret=True, chunk=32)
+    return (_value_and_grads(plain, args, w),
+            dict(zip(NAMES, (o, *grads, *leaves((dbias, drate))))), s0)
 
 
 @pytest.mark.parametrize("end", list(ENDS))
 @pytest.mark.parametrize("what", NAMES)
 def test_pallas_kernels_interpreted_against_the_recurrence(end, what):
     want, got, s0 = _kernels(end)
-    _close(got[what], want[what], (end, what))
+    _close(got[what], want[what], (end, what), _summed(want, what))
     assert s0.shape == (2, 2, 2, 128, 128) and s0.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(s0[:, :, 0]))) == 0.0      # from zero
+
+
+def test_the_gate_at_its_bounds_stays_finite():
+    """Logits of +-1e4: the log-decay within rounding of ``lower_bound`` (15
+    rows of it in a sub-chunk) and of 0. Value and every gradient finite, and
+    the value the recurrence's."""
+    (q, k, v, z, *rest), w = _data(64, 32, "mixed")
+    z = 1e4 * jnp.sign(z)
+    z = z.at[:, 16:32].set(1e4).at[:, 32:48].set(-1e4)
+    args = (q, k, v, z, *rest)
+    got = _value_and_grads(_lax(32), args, w)
+    for what in NAMES:
+        assert bool(jnp.all(jnp.isfinite(got[what]))), what
+    _close(got["value"], plain(*args), "value")
+    assert float(jnp.max(jnp.abs(got["dz"]))) == 0.0    # the gate is flat
 
 
 def test_the_triangular_solve_by_blocks_is_the_inverse():
@@ -217,8 +265,8 @@ def test_the_triangular_solve_by_blocks_is_the_inverse():
 def test_op_counts_its_path_and_its_kept_states():
     profiler.reset_kernel_path_counts()
     profiler.reset_kda_stats()
-    (q, k, v, a, beta), _ = _data(40, 32, "slow")
-    out = nd.contrib.kda(*(nd.NDArray(x) for x in (q, k, v, a, beta)))
+    args, _ = _data(40, 32, "slow")
+    out = nd.contrib.kda(*(nd.NDArray(x) for x in args))
     assert out.shape == (2, 40, 64)
     assert profiler.get_kernel_path_counts()["kda"] == {"pallas": 0, "xla": 1}
     # 40 rows are one chunk of 48 (whole sub-chunks of 16)
@@ -430,11 +478,11 @@ def _drop_the_carried_state(monkeypatch):
 
 @pytest.mark.parametrize("what", NAMES)
 def test_a_dropped_carried_state_fails_the_op(what, monkeypatch):
-    """Two chunks of 32 under slow decays: the value and all five gradients
+    """Two chunks of 32 under slow decays: the value and all seven gradients
     of the faulty op are outside what the sound one is held to."""
     _drop_the_carried_state(monkeypatch)
     args, w = _data(64, 32, "slow")
-    got = _value_and_grads(lambda *x: K._kda_lax(*x, 32), args, w)[what]
+    got = _value_and_grads(_lax(32), args, w)[what]
     want = _forms(64, 32, "slow", 32)[0][what]
     assert float(jnp.max(jnp.abs(got.reshape(want.shape) - want))) \
         > 100 * 2e-5 * float(jnp.max(jnp.abs(want)))

@@ -186,22 +186,36 @@ def test_retention_kernels_compile_at_the_retention_cells_size(one_chip,
 
 def test_kda_kernels_compile_at_the_ling_cells_size(one_chip, quiet_cache):
     """32 heads of 128, T = 4096: a ``kda`` mixer of
-    ``lingflash_train_t4096``, forward and (under grad) backward: a chunk's
-    128 x 128 matrices, its eight sub-chunks' decayed keys and the
-    triangular system's float32 products in VMEM beside the head's state."""
+    ``lingflash_train_t4096`` on RAW operands (q and k un-normed, the gate's
+    logits, ``A_log``, ``dt_bias``), forward and (under grad) backward: the
+    norms, the gate and the chunk's cumulative decays, a chunk's 128 x 128
+    matrices, its eight sub-chunks' decayed keys and the triangular system's
+    float32 products in VMEM beside the head's state, and in the backward
+    the transposes of all of them."""
+    import re
     from mxtpu.ops import kda as K
     bf, f32 = jnp.bfloat16, jnp.float32
     wide = (1, 4096, 32 * 128)
     avals = _avals(one_chip, (wide, bf), (wide, bf), (wide, bf), (wide, f32),
-                   ((1, 4096, 32), f32))
+                   ((1, 4096, 32), f32), ((32,), f32), ((32 * 128,), f32))
 
-    def loss(*a):
-        return jnp.sum(K._kda_pallas(*a).astype(f32))
+    def loss(q, k, v, z, beta, a_log, dt_bias):
+        return jnp.sum(K._kda_pallas(
+            q, k, v, z, beta, *K._gate_rows(a_log, dt_bias), -5.0,
+            1e-6).astype(f32))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
         *avals).compile()
     text = compiled.as_text()
     assert "kda_fwd" in text and "kda_bwd" in text
-    # linear in T: the chunk starts (67 MB) and the float32 decays and their
-    # gradient (67 MB each) are the largest things kept
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    # linear in T, and nothing of the operands' size but the operands: the
+    # chunk starts (67.1 MB) are ALL that is kept (read: 67.2 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.08e9
+    # no cumulative sum is XLA's, and XLA makes no float32 array of the
+    # operands' size at all: the one there is is the kernel's ``dz``
+    assert "reduce-window" not in text and "cumsum" not in text
+    made = [line for line in text.splitlines()
+            if re.search(r"= f32\[1,4096,4096\]", line)
+            and not re.search(r"custom-call|get-tuple-element|parameter\(",
+                              line)]
+    assert not made, made
