@@ -896,7 +896,10 @@ def leg_kda_train(sz, on_chip: bool) -> dict:
     check(gaps[worst] <= 3 * tol,
           f"kda train: first gradient of {worst} is {gaps[worst]:.4g} from "
           f"the lax form's")
-    check(stats["launches"] >= 2 and stats["state_bytes_kept"] > 0,
+    # the kernels keep each chunk's inverse for a backward that solves
+    # nothing; the lax form keeps none
+    check(stats["launches"] >= 2 and stats["state_bytes_kept"] > 0
+          and (stats["inverse_bytes_kept"] > 0) == on_chip,
           f"kda train: {stats}")
     check(len(rows) == 2 and all(r["pairs"] > 0 for r in rows)
           and np.isfinite(sparse_loss),

@@ -864,30 +864,36 @@ def reset_retention_stats():
         _retention.update(launches=0, chunk=0, chunks=0, state_bytes_kept=0)
 
 
-_kda = {"launches": 0, "chunk": 0, "chunks": 0, "state_bytes_kept": 0}
+_KDA_ZERO = {"launches": 0, "chunk": 0, "chunks": 0, "state_bytes_kept": 0,
+             "inverse_bytes_kept": 0}
+_kda = dict(_KDA_ZERO)
 
 
-def record_kda_launch(chunk: int, chunks: int, state_bytes_kept: int):
+def record_kda_launch(chunk: int, chunks: int, state_bytes_kept: int,
+                      inverse_bytes_kept: int):
     """One call site of ``contrib.kda`` was traced (or run eagerly): its
     chunk length, the chunks a sequence and the bytes of chunk-start state
-    its forward keeps for its backward."""
+    and of chunk inverses its forward keeps for its backward."""
     with _stats_lock:
         _kda.update(launches=_kda["launches"] + 1, chunk=chunk,
-                    chunks=chunks, state_bytes_kept=state_bytes_kept)
+                    chunks=chunks, state_bytes_kept=state_bytes_kept,
+                    inverse_bytes_kept=inverse_bytes_kept)
 
 
 def get_kda_stats() -> dict:
-    """``{"launches", "chunk", "chunks", "state_bytes_kept"}``: call sites
-    of ``contrib.kda`` since the last reset, and the NEWEST one's chunk
-    length, chunks a sequence and bytes of chunk-start state kept for the
-    backward (ONE layer's: a model holds that much a ``kda`` layer)."""
+    """``{"launches", "chunk", "chunks", "state_bytes_kept",
+    "inverse_bytes_kept"}``: call sites of ``contrib.kda`` since the last
+    reset, and the NEWEST one's chunk length, chunks a sequence, bytes of
+    chunk-start state kept for the backward and bytes of chunk inverses kept
+    beside them (0 where the ``lax`` form ran: the kernels alone keep them).
+    ONE layer's: a model holds that much a ``kda`` layer."""
     with _stats_lock:
         return dict(_kda)
 
 
 def reset_kda_stats():
     with _stats_lock:
-        _kda.update(launches=0, chunk=0, chunks=0, state_bytes_kept=0)
+        _kda.update(_KDA_ZERO)
 
 
 _remat = {"blocks": 0, "recomputed": 0}

@@ -27,15 +27,25 @@ are made a SUB-CHUNK of rows at a time (``SUB`` = 16) against that
 sub-chunk's first row ``r``: ``k_t exp(g_t - r)`` never passes 1 and ``k_s
 exp(r - g_s)`` never passes ``exp(75)`` for the keys the sub-chunk sees: the
 model's gate is bounded so (``a > -5``: 15 steps of it). Nothing tighter is
-relied on. The system is solved on the MXU, in float32: the 16 x 16 diagonal
-blocks by the nilpotent product ``(I - X)(I + X^2)(I + X^4)(I + X^8)``, then
-the blocks below them the same way (``X^(C / 16) = 0``).
+relied on. The system is solved in float32, ONCE a chunk: the 16 x 16
+diagonal blocks by fifteen steps of forward substitution on the vector units,
+all eight packed side by side in two vregs (``_diagonal_blocks``: nothing for
+the MXU, whose six products of block-diagonal matrices cost what dense ones
+do), then the blocks below them on the MXU by the nilpotent product ``(I -
+M)(I + M^2)(I + M^4)`` (``M^(C / 16) = 0``: six products at full precision).
 
 One grid step of a kernel is one (batch row, head, chunk); a head's chunks
 run in order with the state in VMEM. The forward KEEPS the state at every
 chunk start (float32, 64 KB a head a chunk: 67 MB a layer at 4096 tokens and
-32 heads); the backward walks the chunks from the last to the first, makes
-the chunk's matrices again and carries the state's gradient.
+32 heads) and the chunk's INVERSE ``(I + Diag(beta) A)^-1``, rounded to the
+type its products take it in (the operands': 32 KB a head a chunk in
+bfloat16, 33.5 MB a layer). The backward walks the chunks from the last to
+the first, makes the chunk's matrices again (their gradients need them),
+READS the inverse and carries the state's gradient: it solves nothing. Both
+of its products with the inverse rounded it to that type anyway, as the
+forward's one does, so the number fetched is the number it would have made.
+The ``lax`` form keeps the chunk starts alone: JAX's transpose of its
+checkpointed scan solves each chunk again.
 
 The kernels take their operands RAW and make the rest in VMEM, from the tiles
 they load anyway (``_prologue`` and ``_running``, which the ``lax`` form runs
@@ -70,12 +80,20 @@ __all__ = ["kda", "kda_stats"]
 
 _LANES = 128
 # v5e, one layer of the cell (T 4096, 32 heads of 128, bf16; my chip runs,
-# PR 42): ms a launch, forward / backward, beside the constant it was read at
-CHUNK = 128         # rows of T a grid step: 3.60 / 4.51 with the prologue
-                    # in the kernels (3.39 / 4.17 without it, PR 41). Every
-                    # matrix of a chunk is one 128 x 128 tile; the kept chunk
-                    # starts (67 MB a layer) halve against 64. No other
-                    # length was run
+# PRs 42 and 44; PR 43's builder's figures): ms a launch, forward / backward,
+# beside the constant it was read at
+CHUNK = 128         # rows of T a grid step: 2.55 / 1.90 with the diagonal
+                    # blocks by substitution and the chunk's inverse kept
+                    # (33.5 MB a layer, bf16) for a backward that solves
+                    # nothing (PR 44); 3.62 / 1.91 with the inverse kept and
+                    # the blocks on the MXU (PR 43); 3.61 / 4.51 when the
+                    # backward solved again (PR 42); 3.39 / 4.17 without the
+                    # prologue in the kernels (PR 41). Of the forward's 2.55
+                    # the six products below the blocks are 1.38 and the
+                    # substitution 0.19: with ``_solve`` stubbed the
+                    # launches take 0.98 / 1.90. Every matrix of a chunk is
+                    # one 128 x 128 tile; the kept chunk starts (67 MB a
+                    # layer) halve against 64. No other length was run
 SUB = 16            # rows of a sub-chunk: 15 steps of the gate's bound -5
 _CLAMP = 80.0       # exp's argument for the keys a sub-chunk does NOT see
                     # (masked to zero afterwards): finite in float32
@@ -217,23 +235,47 @@ def _chunk_parts(q, k, g, dt):
                 P=jnp.where(s <= t, jnp.concatenate(p_rows, axis=0), 0.0))
 
 
+def _diagonal_blocks(n, near):
+    """The inverse of ``I +`` the ``SUB x SUB`` diagonal blocks of a strictly
+    lower triangular ``n`` ``(C, C)`` float32 (``near``: the mask of those
+    blocks), zero off the blocks: every block by forward substitution, all of
+    them a step together, in float32 on the vector units (the MXU is fed
+    nothing). The blocks are PACKED side by side as ``(SUB, C)``: with what
+    lies off them zeroed, the tile's row groups just add up. Step ``j`` takes
+    column ``j`` of every block, spread over the block's ``SUB`` lanes (a
+    lane gather), times row ``j`` of the inverse so far (a sublane
+    broadcast), off the rows below it. (``kda_fwd`` at the cell's size, ms a
+    launch, my chip run, PR 44: 2.55 so; 2.85 with the column spread by a
+    masked copy and four doubling rotates, 2.86 by a masked lane sum a block;
+    2.72 on the ``(C, C)`` tile as it stands, 3.46 that with a lane gather;
+    3.62 by the MXU's six products ``(I - X)(I + X^2)(I + X^4)(I + X^8)``;
+    2.36 with no diagonal phase at all.)"""
+    C = n.shape[0]
+    n = jnp.where(near, n, 0.0)
+    x = functools.reduce(jnp.add, [n[i:i + SUB] for i in range(0, C, SUB)])
+    row, lane = _iota((SUB, C), 0), _iota((SUB, C), 1)
+    first = jnp.bitwise_and(lane, -SUB)     # of the lane's block
+    inv = (row == lane - first).astype(_F32)
+    for j in range(SUB - 1):
+        col = jnp.take_along_axis(x, first + j, axis=1,
+                                  mode="promise_in_bounds")
+        inv = inv - col * inv[j:j + 1]
+    return jnp.where(near, jnp.concatenate([inv] * (C // SUB), axis=0), 0.0)
+
+
 def _solve(n):
     """``(I + n)^-1`` for a strictly lower triangular ``n`` ``(C, C)``,
-    float32 on the MXU: the diagonal blocks of ``SUB`` first (``X^SUB = 0``),
-    then the blocks below them (``M^(C / SUB) = 0``)."""
+    float32: the diagonal blocks of ``SUB`` first (``_diagonal_blocks``), then
+    the blocks below them on the MXU (``M^(C / SUB) = 0``)."""
     C = n.shape[0]
     t, s = _iota((C, C), 0), _iota((C, C), 1)
     eye = (t == s).astype(_F32)
     shift = SUB.bit_length() - 1
-    near = jnp.where(jnp.right_shift(t, shift) == jnp.right_shift(s, shift),
-                     n, 0.0)
-    inv, p = eye - near, near
-    for _ in range(shift - 1):
-        p = _mm(p, p)
-        inv = _mm(inv, eye + p)
+    near = jnp.right_shift(t, shift) == jnp.right_shift(s, shift)
+    inv = _diagonal_blocks(n, near)
     if C == SUB:
         return inv
-    m = _mm(inv, n - near)
+    m = _mm(inv, jnp.where(near, 0.0, n))
     out, p, power = eye - m, m, 2
     while power < C // SUB:
         p = _mm(p, p)
@@ -243,26 +285,30 @@ def _solve(n):
 
 
 def _chunk_forward(q, k, v, g, beta, s0, dt):
-    """``(o (C, D) float32, the state after the chunk)``; ``beta`` ``(C, 1)``
-    float32, ``s0`` ``(D, D)`` float32. Matmuls on the data take operands of
-    ``dt``; the triangular system is float32."""
+    """``(o (C, D) float32, the state after the chunk, the chunk's inverse
+    (C, C) of dt)``; ``beta`` ``(C, 1)`` float32, ``s0`` ``(D, D)`` float32.
+    Matmuls on the data take operands of ``dt``; the triangular system is
+    float32, and its inverse is rounded to ``dt`` ONCE: the product below
+    takes it so, and so does every product of ``_chunk_backward``."""
     C = k.shape[0]
     z = _chunk_parts(q, k, g, dt)
     eg, last = jnp.exp(g), g[C - 1:C]
-    u = _mm(_solve(beta * z["A"]),
-            beta * (v.astype(_F32) - _mm(z["kf"] * eg, s0, dt)), dt)
+    tinv = _solve(beta * z["A"]).astype(dt)
+    u = _mm(tinv, beta * (v.astype(_F32) - _mm(z["kf"] * eg, s0, dt)), dt)
     o = _mm(z["qf"] * eg, s0, dt) + _mm(z["P"], u, dt)
     s1 = _column(jnp.exp(last)) * s0 \
         + _tn(z["kf"] * jnp.exp(last - g), u, dt)
-    return o, s1
+    return o, s1, tinv
 
 
-def _chunk_backward(q, k, v, g, beta, s0, do, ds1, dt):
+def _chunk_backward(q, k, v, g, beta, s0, tinv, do, ds1, dt):
     """The chunk again, and its transpose: ``(dq, dk, dv, dg, dbeta (C, 1),
-    ds0)``, all float32. ``dg`` is the gradient of the chunk's cumulative
-    log-decay ``g`` (the caller sums it back onto ``a``). A decayed operand
-    ``x exp(+-g)`` hands ``g`` the product of the operand and its gradient,
-    so ``dg`` needs no pass of its own."""
+    ds0)``, all float32. ``tinv`` ``(C, C)`` is the inverse ``_chunk_forward``
+    made of this chunk: the matrices are made again (their gradients need
+    them), the system is NOT solved again. ``dg`` is the gradient of the
+    chunk's cumulative log-decay ``g`` (the caller sums it back onto ``a``).
+    A decayed operand ``x exp(+-g)`` hands ``g`` the product of the operand
+    and its gradient, so ``dg`` needs no pass of its own."""
     C = k.shape[0]
     z = _chunk_parts(q, k, g, dt)
     qf, kf, A, P = z["qf"], z["kf"], z["A"], z["P"]
@@ -270,7 +316,6 @@ def _chunk_backward(q, k, v, g, beta, s0, do, ds1, dt):
     elast = jnp.exp(last - g)
     kt, qt, kbar = kf * eg, qf * eg, kf * elast
     t, s = _iota((C, C), 0), _iota((C, C), 1)
-    tinv = _solve(beta * A)
     vres = v.astype(_F32) - _mm(kt, s0, dt)
     u = _mm(tinv, beta * vres, dt)
     do = do.astype(_F32)
@@ -346,7 +391,7 @@ def _kda_lax(q, k, v, z, beta, bias, rate, bound, eps, chunk: int):
 
     @jax.checkpoint
     def body(s0, xs):
-        o, s1 = one(*xs, s0)
+        o, s1, _ = one(*xs, s0)
         return s1, o
 
     xs = (by_chunk(q), by_chunk(k), by_chunk(v), by_chunk(a),
@@ -362,9 +407,10 @@ def _kda_lax(q, k, v, z, beta, bias, rate, bound, eps, chunk: int):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, z_ref, b_ref, bias_ref, rate_ref,
-                o_ref, s0_ref, s_ref, *, bound, eps):
+                o_ref, s0_ref, tinv_ref, s_ref, *, bound, eps):
     """One (batch row, head, chunk); a head's chunks run in order and hand
-    the state on in ``s_ref``."""
+    the state on in ``s_ref``. The chunk's start state and its inverse are
+    kept for the backward."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -372,18 +418,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, z_ref, b_ref, bias_ref, rate_ref,
         s_ref[...] = jnp.zeros_like(s_ref)
 
     s0 = s_ref[...]
-    s0_ref[0, 0, 0] = s0        # kept for the backward
+    s0_ref[0, 0, 0] = s0
     q, k, g = _chunk_operands(q_ref[0], k_ref[0], z_ref[0], bias_ref[...],
                               rate_ref[...], bound, eps)
-    o, s1 = _chunk_forward(q, k, v_ref[0], g, _column(b_ref[0, 0, 0]), s0,
-                           q_ref.dtype)
+    o, s1, tinv = _chunk_forward(q, k, v_ref[0], g, _column(b_ref[0, 0, 0]),
+                                 s0, q_ref.dtype)
     o_ref[0] = o.astype(o_ref.dtype)
+    tinv_ref[0, 0, 0] = tinv
     s_ref[...] = s1
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, z_ref, b_ref, bias_ref, rate_ref,
-                s0_ref, do_ref, dq_ref, dk_ref, dv_ref, dz_ref, db_ref,
-                dbias_ref, drate_ref, ds_ref, *, bound, eps):
+                s0_ref, tinv_ref, do_ref, dq_ref, dk_ref, dv_ref, dz_ref,
+                db_ref, dbias_ref, drate_ref, ds_ref, *, bound, eps):
     """One (batch row, head, chunk), chunks from the last to the first:
     ``ds_ref`` carries the gradient of the state at the END of the chunk in
     hand, and the two rows' gradients add up over a head's chunks in their
@@ -401,7 +448,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, z_ref, b_ref, bias_ref, rate_ref,
         q_ref[0], k_ref[0], z_ref[0], bias_ref[...], rate_ref[...])
     dq, dk, dv, dg, dbeta, ds0 = _chunk_backward(
         q, k, v_ref[0], g, _column(b_ref[0, 0, 0]), s0_ref[0, 0, 0],
-        do_ref[0], ds_ref[...], q_ref.dtype)
+        tinv_ref[0, 0, 0], do_ref[0], ds_ref[...], q_ref.dtype)
     dq_ref[0], dk_ref[0], dz_ref[0], dbias, drate = back((dq, dk, dg))
     dv_ref[0] = dv.astype(dv_ref.dtype)
     db_ref[0, 0, 0] = _as_row(dbeta)
@@ -412,10 +459,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, z_ref, b_ref, bias_ref, rate_ref,
 
 def _specs(chunk: int, order):
     """Block specs of the operands as the model holds them: ``q`` .. ``z``
-    ``(B, T, H * D)`` (a head's channels side by side), ``beta`` and the kept
-    states by chunk, the gate's two rows ``(1, H * D)`` and their gradients
-    ``(B, 1, H * D)`` by head. ``order`` maps the grid's chunk index to the
-    chunk (the backward runs them reversed)."""
+    ``(B, T, H * D)`` (a head's channels side by side), ``beta``, the kept
+    states and the kept inverses by chunk, the gate's two rows ``(1, H *
+    D)`` and their gradients ``(B, 1, H * D)`` by head. ``order`` maps the
+    grid's chunk index to the chunk (the backward runs them reversed)."""
     from jax.experimental import pallas as pl
     D = _LANES
     return dict(
@@ -424,6 +471,8 @@ def _specs(chunk: int, order):
                           lambda b, h, c: (b, h, order(c), 0, 0)),
         s=pl.BlockSpec((1, 1, 1, D, D),
                        lambda b, h, c: (b, h, order(c), 0, 0)),
+        inv=pl.BlockSpec((1, 1, 1, chunk, chunk),
+                         lambda b, h, c: (b, h, order(c), 0, 0)),
         row=pl.BlockSpec((1, D), lambda b, h, c: (0, h)),
         drow=pl.BlockSpec((1, 1, D), lambda b, h, c: (b, 0, h)))
 
@@ -447,8 +496,10 @@ def _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps,
     """``q``, ``k``, ``v``: ``(B, T, H * D)``, q and k un-normed; ``z`` like
     them, float32, the gate's logits; ``beta`` ``(B, T, H)``; ``bias`` and
     ``rate`` ``(H, D)`` float32 (``dt_bias`` and ``exp(A_log)`` a channel).
-    Returns ``(o, S0)``: the output like ``q`` and the state at the start of
-    every chunk, ``(B, H, T / chunk, D, D)`` float32."""
+    Returns ``(o, S0, Tinv)``: the output like ``q``, the state at the start
+    of every chunk, ``(B, H, T / chunk, D, D)`` float32, and every chunk's
+    inverse ``(I + Diag(beta) A)^-1``, ``(B, H, T / chunk, chunk, chunk)`` of
+    ``q``'s type: as the products take it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -460,9 +511,10 @@ def _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps,
         functools.partial(_fwd_kernel, bound=bound, eps=eps),
         grid=(B, H, n_c),
         in_specs=[sp["x"]] * 4 + [sp["beta"]] + [sp["row"]] * 2,
-        out_specs=[sp["x"], sp["s"]],
+        out_specs=[sp["x"], sp["s"], sp["inv"]],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, H, n_c, D, D), _F32)],
+                   jax.ShapeDtypeStruct((B, H, n_c, D, D), _F32),
+                   jax.ShapeDtypeStruct((B, H, n_c, chunk, chunk), q.dtype)],
         scratch_shapes=[pltpu.VMEM((D, D), _F32)],
         compiler_params=_params(),
         name="kda_fwd",
@@ -471,11 +523,12 @@ def _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps,
       rate.reshape(1, H * D))
 
 
-def _backward_pallas(q, k, v, z, beta, bias, rate, s0, do, bound, eps,
+def _backward_pallas(q, k, v, z, beta, bias, rate, s0, tinv, do, bound, eps,
                      interpret=False, chunk=None):
-    """``(dq, dk, dv, dz, dbeta, dbias, drate)``: the first three like ``q``
-    (of the un-normed q and k), ``dz`` float32 like ``z``, ``dbeta`` ``(B,
-    T, H)`` float32, the last two ``(H, D)`` float32."""
+    """From the forward's operands, its kept states ``s0`` and its kept
+    inverses ``tinv``: ``(dq, dk, dv, dz, dbeta, dbias, drate)``, the first
+    three like ``q`` (of the un-normed q and k), ``dz`` float32 like ``z``,
+    ``dbeta`` ``(B, T, H)`` float32, the last two ``(H, D)`` float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -488,7 +541,7 @@ def _backward_pallas(q, k, v, z, beta, bias, rate, s0, do, bound, eps,
         functools.partial(_bwd_kernel, bound=bound, eps=eps),
         grid=(B, H, n_c),
         in_specs=[sp["x"]] * 4 + [sp["beta"]] + [sp["row"]] * 2
-        + [sp["s"], sp["x"]],
+        + [sp["s"], sp["inv"], sp["x"]],
         out_specs=[sp["x"]] * 4 + [sp["beta"]] + [sp["drow"]] * 2,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3
         + [jax.ShapeDtypeStruct(q.shape, _F32),
@@ -498,7 +551,7 @@ def _backward_pallas(q, k, v, z, beta, bias, rate, s0, do, bound, eps,
         name="kda_bwd",
         interpret=interpret,
     )(q, k, v, z, _beta_rows(beta, chunk), bias.reshape(1, H * D),
-      rate.reshape(1, H * D), s0, do)
+      rate.reshape(1, H * D), s0, tinv, do)
     return (dq, dk, dv, dz,
             db[:, :, :, 0, :].transpose(0, 2, 3, 1).reshape(B, T, H),
             jnp.sum(dbias, axis=0).reshape(H, D),
@@ -525,14 +578,14 @@ def _kda_pallas(q, k, v, z, beta, bias, rate, bound, eps):
 
 
 def _kda_pallas_fwd(q, k, v, z, beta, bias, rate, bound, eps):
-    o, s0 = _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps)
-    return o, (q, k, v, z, beta, bias, rate, s0)
+    o, s0, tinv = _forward_pallas(q, k, v, z, beta, bias, rate, bound, eps)
+    return o, (q, k, v, z, beta, bias, rate, s0, tinv)
 
 
 def _kda_pallas_bwd(bound, eps, res, do):
-    q, k, v, z, beta, bias, rate, s0 = res
+    beta = res[4]
     dq, dk, dv, dz, dbeta, dbias, drate = _backward_pallas(
-        q, k, v, z, beta, bias, rate, s0, do, bound, eps)
+        *res, do, bound, eps)
     return dq, dk, dv, dz, dbeta.astype(beta.dtype), dbias, drate
 
 
@@ -548,16 +601,22 @@ def _gate_rows(a_log, dt_bias):
 
 
 def kda_stats(T: int, num_heads: int, head_dim: int = _LANES,
-              batch: int = 1) -> dict:
+              batch: int = 1, kernel_dtype=None) -> dict:
     """What one launch does at these sizes: the chunk length, the chunks a
-    sequence, and the bytes of chunk-start state the forward keeps for the
+    sequence, the bytes of chunk-start state the forward keeps for the
     backward (float32, a head a chunk, in the kernels and in the ``lax``
-    form's checkpointed scan alike)."""
+    form's checkpointed scan alike), and the bytes of chunk inverses the
+    KERNELS keep beside them (``chunk x chunk`` of ``kernel_dtype``, the
+    operands' type, a head a chunk: 33.5 MB a layer in bfloat16 at 4096
+    tokens and 32 heads; the ``lax`` form, ``kernel_dtype=None``, keeps
+    none: JAX's transpose of its scan solves each chunk again)."""
     chunk = min(CHUNK, -(-T // SUB) * SUB)
     chunks = -(-T // chunk)
+    each = batch * num_heads * chunks
     return {"chunk": chunk, "chunks": chunks,
-            "state_bytes_kept": batch * num_heads * chunks
-            * head_dim * head_dim * 4}
+            "state_bytes_kept": each * head_dim * head_dim * 4,
+            "inverse_bytes_kept": 0 if kernel_dtype is None else
+            each * chunk * chunk * jnp.dtype(kernel_dtype).itemsize}
 
 
 @register("kda", namespace="contrib")
@@ -582,7 +641,8 @@ def kda(q, k, v, z, beta, a_log, dt_bias, lower_bound: float = -5.0,
     pallas = _use_pallas(q)
     metrics.record_kernel_path("kda", pallas)
     B, T, H, D = q.shape
-    metrics.record_kda_launch(**kda_stats(T, H, D, B))
+    metrics.record_kda_launch(
+        **kda_stats(T, H, D, B, q.dtype if pallas else None))
     bias, rate = _gate_rows(a_log, dt_bias)
     with jax.named_scope("kda"):
         if pallas:

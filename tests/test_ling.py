@@ -212,29 +212,104 @@ def test_chunked_lax_form_against_the_recurrence(T_, end, what):
     _close(got[what], want[what], (T_, end, what), _summed(want, what))
 
 
+def _launch(args, w, kept=lambda tinv: tinv, dtype=jnp.float32, chunk=32):
+    """The two kernels interpreted on ``_data``'s operands, chunks of
+    ``chunk``, q, k and v of ``dtype``; ``kept`` stands between the forward's
+    inverses and the backward. Returns the value and the gradients by name,
+    the kept states and the kept inverses."""
+    q, k, v, z, beta, a_log, dt_bias = args
+    B, T_, H, D = q.shape
+    flat = [x.reshape(B, T_, H * D).astype(dtype) for x in (q, k, v)] \
+        + [z.reshape(B, T_, H * D)]
+    rows, leaves = jax.vjp(K._gate_rows, a_log, dt_bias)
+    o, s0, tinv = K._forward_pallas(*flat, beta, *rows, *GATE,
+                                    interpret=True, chunk=chunk)
+    *grads, dbias, drate = K._backward_pallas(
+        *flat, beta, *rows, s0, kept(tinv), w.astype(dtype), *GATE,
+        interpret=True, chunk=chunk)
+    return dict(zip(NAMES, (o, *grads, *leaves((dbias, drate))))), s0, tinv
+
+
 @functools.lru_cache(maxsize=None)
 def _kernels(end):
     """The two kernels interpreted, heads of 128, two chunks of 32."""
     args, w = _data(64, 128, end)
-    q, k, v, z, beta, a_log, dt_bias = args
-    B, T_, H, D = q.shape
-    flat = [x.reshape(B, T_, H * D) for x in (q, k, v, z)]
-    rows, leaves = jax.vjp(K._gate_rows, a_log, dt_bias)
-    o, s0 = K._forward_pallas(*flat, beta, *rows, *GATE, interpret=True,
-                              chunk=32)
-    *grads, dbias, drate = K._backward_pallas(
-        *flat, beta, *rows, s0, w, *GATE, interpret=True, chunk=32)
-    return (_value_and_grads(plain, args, w),
-            dict(zip(NAMES, (o, *grads, *leaves((dbias, drate))))), s0)
+    return (_value_and_grads(plain, args, w),) + _launch(args, w)
 
 
 @pytest.mark.parametrize("end", list(ENDS))
 @pytest.mark.parametrize("what", NAMES)
 def test_pallas_kernels_interpreted_against_the_recurrence(end, what):
-    want, got, s0 = _kernels(end)
+    want, got, s0, tinv = _kernels(end)
     _close(got[what], want[what], (end, what), _summed(want, what))
     assert s0.shape == (2, 2, 2, 128, 128) and s0.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(s0[:, :, 0]))) == 0.0      # from zero
+    assert tinv.shape == (2, 2, 2, 32, 32) and tinv.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("T_,C", [(64, 32), (96, 32), (48, 16)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_kept_inverse_is_the_chunks_solve_rounded_once(dtype, T_, C):
+    """What ``kda_fwd`` keeps of a chunk is ``_solve(beta * A)`` in the type
+    the products take it in, bit for bit: made here from the same tiles by
+    the same functions, outside the kernel. Chunks of two sub-chunks, and of
+    one (the diagonal phase alone)."""
+    dt = jnp.dtype(dtype)
+    args, w = _data(T_, 128, "mixed", seed=3)
+    _, _, tinv = _launch(args, w, dtype=dt, chunk=C)
+    assert tinv.shape == (2, 2, T_ // C, C, C) and tinv.dtype == dt
+    q, k, _, z, beta, a_log, dt_bias = args
+    bias, rate = K._gate_rows(a_log, dt_bias)
+
+    @jax.jit
+    def one(q, k, z, beta, bias, rate):
+        q, k, g = K._chunk_operands(q.astype(dt), k.astype(dt), z, bias,
+                                    rate, *GATE)
+        return K._solve(beta[:, None] * K._chunk_parts(q, k, g, dt)["A"]) \
+            .astype(dt)
+
+    for b, h, c in np.ndindex(*tinv.shape[:3]):
+        rows = slice(C * c, C * c + C)
+        want = one(q[b, rows, h], k[b, rows, h], z[b, rows, h],
+                   beta[b, rows, h], bias[h:h + 1], rate[h:h + 1])
+        assert np.array_equal(np.asarray(tinv[b, h, c], np.float32),
+                              np.asarray(want, np.float32)), (b, h, c)
+        assert float(jnp.max(jnp.abs(jnp.triu(want.astype(jnp.float32), 1)))) \
+            == 0.0 and bool(jnp.all(jnp.diagonal(want) == 1))
+
+
+@functools.lru_cache(maxsize=None)
+def _kept_and_solved_again(dtype):
+    """``kda_bwd`` on the kept inverse, and the same kernel with the system
+    solved again inside it from the chunk's own matrices, as it was before
+    the forward kept anything."""
+    args, w = _data(64, 128, "mixed", seed=4)
+    kept, _, _ = _launch(args, w, dtype=jnp.dtype(dtype))
+    b = K._chunk_backward
+
+    def solves(q, k, v, g, beta, s0, tinv, do, ds1, dt):
+        # (behind a barrier: the CPU compiler then fuses the rest of the
+        # body as it does without the solve, and rounds its sums alike)
+        q_, k_, g_, beta_ = lax.optimization_barrier((q, k, g, beta))
+        again = K._solve(beta_ * K._chunk_parts(q_, k_, g_, dt)["A"])
+        return b(q, k, v, g, beta, s0, again, do, ds1, dt)
+
+    K._chunk_backward = solves
+    try:
+        again, _, _ = _launch(args, w, kept=jnp.zeros_like,
+                              dtype=jnp.dtype(dtype))
+    finally:
+        K._chunk_backward = b
+    return kept, again
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("what", NAMES[1:])
+def test_the_backward_on_the_kept_inverse_is_the_one_that_solved(what, dtype):
+    kept, again = _kept_and_solved_again(dtype)
+    assert float(jnp.max(jnp.abs(kept[what].astype(jnp.float32)))) > 0
+    assert np.array_equal(np.asarray(kept[what], np.float32),
+                          np.asarray(again[what], np.float32))
 
 
 def test_the_gate_at_its_bounds_stays_finite():
@@ -252,14 +327,42 @@ def test_the_gate_at_its_bounds_stays_finite():
     assert float(jnp.max(jnp.abs(got["dz"]))) == 0.0    # the gate is flat
 
 
-def test_the_triangular_solve_by_blocks_is_the_inverse():
+def _strictly_lower(C, kind):
+    """A chunk's ``Diag(beta) A``, ``(C, C)`` float32, to stress the solve's
+    diagonal phase: seeded noise; the matrix the op makes where every key of
+    a sub-chunk is the same unit vector and ``beta`` is 1 (entries near 1 on
+    every diagonal block); -0.5 everywhere below the diagonal, whose
+    inverse's entries GROW by 1.5 a row (to 1e22 over 128 rows); nothing."""
     rs = np.random.RandomState(3)
-    for C in (16, 48, 128):
-        n = jnp.asarray(np.tril(rs.randn(C, C), -1), jnp.float32) * 0.3
-        got = K._solve(n)
-        want = np.linalg.inv(np.eye(C) + np.asarray(n, np.float64))
-        np.testing.assert_allclose(np.asarray(got), want, rtol=0,
-                                   atol=2e-4 * np.abs(want).max())
+    if kind == "random":
+        return np.tril(rs.randn(C, C), -1).astype(np.float32) * 0.3
+    if kind == "equal_keys":
+        k = rs.randn(C // K.SUB, 1, 32).repeat(K.SUB, 1).reshape(C, 32)
+        k = jnp.asarray(k / np.linalg.norm(k, axis=1, keepdims=True),
+                        jnp.float32)
+        g = jnp.cumsum(jnp.full((C, 32), -0.05, jnp.float32), axis=0)
+        return np.asarray(K._chunk_parts(k, k, g, jnp.float32)["A"])
+    return np.tril(np.full((C, C), {"grows": -0.5, "zero": 0.0}[kind],
+                           np.float32), -1)
+
+
+@pytest.mark.parametrize("C", [16, 32, 48, 128])
+@pytest.mark.parametrize("kind", ["random", "equal_keys", "grows", "zero"])
+def test_the_triangular_solve_by_blocks_is_the_inverse(kind, C):
+    """Against numpy's float64 inverse, to a float32 bound: the diagonal
+    blocks by substitution alone (``C == SUB``), with the blocks below them
+    at two, three and eight blocks a chunk; jitted and under ``vmap``, as the
+    ``lax`` form runs it."""
+    n = _strictly_lower(C, kind)
+    want = np.linalg.inv(np.eye(C) + n.astype(np.float64))
+    for got in (jax.jit(K._solve)(jnp.asarray(n)),
+                jax.vmap(K._solve)(jnp.asarray(n)[None])[0]):
+        np.testing.assert_allclose(np.asarray(got, np.float64), want, rtol=0,
+                                   atol=2e-6 * np.abs(want).max())
+        assert float(jnp.max(jnp.abs(jnp.triu(got, 1)))) == 0.0
+        if kind == "zero":
+            assert np.array_equal(np.asarray(got), np.eye(C))
+    assert kind != "grows" or np.abs(want).max() > 100
 
 
 def test_op_counts_its_path_and_its_kept_states():
@@ -270,11 +373,16 @@ def test_op_counts_its_path_and_its_kept_states():
     assert out.shape == (2, 40, 64)
     assert profiler.get_kernel_path_counts()["kda"] == {"pallas": 0, "xla": 1}
     # 40 rows are one chunk of 48 (whole sub-chunks of 16)
+    # ... and the lax form keeps no inverse: its transpose solves again
     assert profiler.get_kda_stats() == {
         "launches": 1, "chunk": 48, "chunks": 1,
-        "state_bytes_kept": 2 * 2 * 32 * 32 * 4}
-    assert K.kda_stats(4096, 32, 128) == {
-        "chunk": 128, "chunks": 32, "state_bytes_kept": 32 * 32 * 65536}
+        "state_bytes_kept": 2 * 2 * 32 * 32 * 4, "inverse_bytes_kept": 0}
+    for dtype, itemsize in ((None, 0), (jnp.bfloat16, 2), (jnp.float32, 4)):
+        assert K.kda_stats(4096, 32, 128, kernel_dtype=dtype) == {
+            "chunk": 128, "chunks": 32, "state_bytes_kept": 32 * 32 * 65536,
+            "inverse_bytes_kept": 32 * 32 * 128 * 128 * itemsize}
+    profiler.reset_kda_stats()
+    assert profiler.get_kda_stats()["inverse_bytes_kept"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -472,18 +580,32 @@ def _drop_the_carried_state(monkeypatch):
     monkeypatch.setattr(K, "_chunk_forward", lambda q, k, v, g, beta, s0, dt:
                         f(q, k, v, g, beta, s0 * 0.0, dt))
     monkeypatch.setattr(
-        K, "_chunk_backward", lambda q, k, v, g, beta, s0, do, ds1, dt:
-        b(q, k, v, g, beta, s0 * 0.0, do, ds1 * 0.0, dt))
+        K, "_chunk_backward", lambda q, k, v, g, beta, s0, tinv, do, ds1, dt:
+        b(q, k, v, g, beta, s0 * 0.0, tinv, do, ds1 * 0.0, dt))
 
 
-@pytest.mark.parametrize("what", NAMES)
-def test_a_dropped_carried_state_fails_the_op(what, monkeypatch):
+@functools.lru_cache(maxsize=None)
+def _zeroed_inverse():
+    args, w = _data(64, 128, "slow")
+    return _launch(args, w, kept=jnp.zeros_like)[0]
+
+
+# the kept inverse reaches the backward alone: the value is the sound one's
+@pytest.mark.parametrize("fault,what", [("state", what) for what in NAMES]
+                         + [("inverse", what) for what in NAMES[1:]])
+def test_a_dropped_carried_state_fails_the_op(fault, what, monkeypatch):
     """Two chunks of 32 under slow decays: the value and all seven gradients
-    of the faulty op are outside what the sound one is held to."""
-    _drop_the_carried_state(monkeypatch)
-    args, w = _data(64, 32, "slow")
-    got = _value_and_grads(_lax(32), args, w)[what]
-    want = _forms(64, 32, "slow", 32)[0][what]
+    of the faulty op are outside what the sound one is held to. And so are
+    the seven gradients of the kernels whose kept INVERSE is zeroed between
+    ``kda_fwd`` and ``kda_bwd``: the backward reads the residual, it does
+    not make its own."""
+    if fault == "state":
+        _drop_the_carried_state(monkeypatch)
+        args, w = _data(64, 32, "slow")
+        got = _value_and_grads(_lax(32), args, w)[what]
+        want = _forms(64, 32, "slow", 32)[0][what]
+    else:
+        got, want = _zeroed_inverse()[what], _kernels("slow")[0][what]
     assert float(jnp.max(jnp.abs(got.reshape(want.shape) - want))) \
         > 100 * 2e-5 * float(jnp.max(jnp.abs(want)))
 
@@ -512,6 +634,9 @@ def test_two_adam_steps_through_the_trainer(ref, system, weights, batch,
     the trainer's one program; the selection bias rides the step as an
     auxiliary state and equals the reference's after two steps."""
     x, y = batch
+    # counted for the whole process: whatever ran before in this worker
+    # (a TPU-platform lowering, say) must not be read as this step's
+    profiler.reset_kernel_path_counts()
     net = system.build_net(CFG, weights, "float32")
     w0 = system.param_arrays(net)
     trainer = system.Trainer(net, ADAM)
