@@ -88,13 +88,10 @@ SIZES = {
         # both decode programs take the Pallas kernel; the 64/160 prompt
         # buckets in between prefill through XLA
         int8=dict(slots=4, requests=((40, 80), (150, 100))),
-        # (T, dtype, MXTPU_FLASH_LSE)
+        # (T, dtype)
         flash=dict(B=4, H=16, D=64, cases=(
-            (1024, "bfloat16", "f32"),
-            (2048, "bfloat16", "f32"),
-            (1024, "float32", "f32"),
-            (2048, "float32", "f32"),
-            (2048, "bfloat16", "bf16"))),
+            (1024, "bfloat16"), (2048, "bfloat16"),
+            (1024, "float32"), (2048, "float32"))),
         decode=dict(S=4, H=16, D=64, int8_tot=(128, 512, 2048), fp8_tot=512),
         # rows, K, N, rows a group (uneven, two empty, 211 rows of no group)
         grouped=dict(M=2048, K=1024, N=2048,
@@ -124,9 +121,8 @@ SIZES = {
         train=dict(B=4, T=128, steps=7),
         serve=dict(slots=4, max_new=70, prompt_lens=(8, 40), n=2),
         int8=dict(slots=4, requests=((8, 70), (40, 88))),
-        flash=dict(B=1, H=2, D=32, cases=(
-            (128, "float32", "f32"),
-            (128, "bfloat16", "bf16"))),
+        flash=dict(B=1, H=2, D=32, cases=((128, "float32"),
+                                          (128, "bfloat16"))),
         decode=dict(S=2, H=2, D=32, int8_tot=(128,), fp8_tot=128),
         grouped=dict(M=256, K=128, N=128, sizes=(100, 0, 37, 80)),
         sparse=dict(units=32, head_dim=8, heads=2, ffn=64, moe_ffn=16,
@@ -451,20 +447,6 @@ def leg_serve_int8(sz, net, on_chip: bool) -> dict:
 
 # -- leg 4: kernels against the XLA reference --------------------------------
 
-@contextlib.contextmanager
-def environ(**values):
-    saved = {k: os.environ.get(k) for k in values}
-    os.environ.update(values)
-    try:
-        yield
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                del os.environ[k]
-            else:
-                os.environ[k] = v
-
-
 def _try_kernel(name: str, shape: str, fn, args, ref, want, rel_tol) -> dict:
     """Lower, compile and run one kernel entry; never raises for a compiler
     refusal — the leg fails at the end with every line printed."""
@@ -502,7 +484,7 @@ def leg_kernels(sz, on_chip: bool) -> list:
     fl = sz["flash"]
     B, H, D = fl["B"], fl["H"], fl["D"]
     scale = 1.0 / np.sqrt(D)
-    for T, dt, lse_dt in fl["cases"]:
+    for T, dt in fl["cases"]:
         rs = np.random.RandomState(T)
         q, k, v, g = (jnp.asarray(rs.randn(B, H, T, D), dt) for _ in range(4))
         with jax.default_matmul_precision("highest"):
@@ -518,12 +500,9 @@ def leg_kernels(sz, on_chip: bool) -> list:
             return (o,) + att._flash_backward_pallas(
                 q, k, v, o, lse, g, True, scale, interpret=interpret)
 
-        # the option is environment-only and read at trace time
-        with environ(MXTPU_FLASH_LSE=lse_dt):
-            rows.append(_try_kernel(
-                f"flash fwd+bwd, lse {lse_dt}",
-                f"B{B} H{H} T{T} D{D} {dt}", fwd_bwd, (q, k, v, g), ref,
-                (FLASH_FWD, FLASH_BWD), KERNEL_REL_TOL[dt]))
+        rows.append(_try_kernel(
+            "flash fwd+bwd", f"B{B} H{H} T{T} D{D} {dt}", fwd_bwd,
+            (q, k, v, g), ref, (FLASH_FWD, FLASH_BWD), KERNEL_REL_TOL[dt]))
 
     # dequant decode against dequantize-then-attend
     dc = sz["decode"]
@@ -791,7 +770,7 @@ def leg_retention_train(sz, on_chip: bool) -> dict:
             for name, slots in dpt.optimizer_state_by_param().items()}
 
     profiler.reset_kernel_path_counts()
-    profiler.reset_retention_stats()
+    profiler.reset_launch_stats("retention")
     loss, moments = one_step()
     paths, stats = (profiler.get_kernel_path_counts(),
                     profiler.get_retention_stats())
@@ -872,7 +851,7 @@ def leg_kda_train(sz, on_chip: bool) -> dict:
             profiler.get_moe_stats(net)
 
     profiler.reset_kernel_path_counts()
-    profiler.reset_kda_stats()
+    profiler.reset_launch_stats("kda")
     loss, moments, _ = one_step()
     stats = profiler.get_kda_stats()
     # the expert layers as they run, not compared: in bfloat16 a token's

@@ -1,24 +1,9 @@
 """Decoder language models built from a list of layer kinds: a mixer kind, an
-MLP kind and a norm for each layer. Five published families are instances:
-decoder-hybrid-decoder models (SambaY; Phi-4-mini-flash-reasoning: Mamba-1
-state-space layers, differential attention over a window, over everything
-and across layers, gated memory units, no positional encoding of any kind),
-sparse models with grouped-query window / full attention (K-EXAONE:
-``attention="gqa"``, ``norm="rms"``, ``norm_position="post"``, an untied
-head, ``mlp_kinds`` with ``"moe"``) and sparse models whose mixers are
-gated short convolutions beside grouped-query attention (LFM2's
-``lfm2_moe``: kinds ``conv`` and ``attn_full``, ``attention="gqa"``,
-``norm="rms"``, pre-norm, a tied head with float32 logits, every expert
-held and no shared one) and dense models whose every mixer is a
-power-retention layer (Brumby-14B: kind ``retention``, Qwen3's block
-otherwise: ``attention="gqa"``, ``qk_norm``, rotary positions,
-``norm="rms"``, pre-norm, an untied head; ``remat=True`` recomputes a block
-at a time in the backward) and sparse models whose mixers are
-Kimi-Delta-Attention layers beside latent attention (Ling-3.0's
-``bailing_hybrid``: kinds ``kda`` and ``mla``, ``norm="rms"``, pre-norm, an
-untied head, ``mlp_kinds`` with ``"moe"`` under a group-limited router).
+MLP kind and a norm for each layer, and the widths; nothing here is a preset.
+Five published families are instances (``docs/hybrid_decoder.md`` has each
+one's spec): SambaY / Phi-4-mini-flash, K-EXAONE, LFM2's ``lfm2_moe``,
+Brumby-14B and Ling-3.0's ``bailing_hybrid``.
 
-A model is a list of layer kinds and the widths; nothing here is a preset.
 Every layer is ``h = x + Mixer(N(x)); out = h + MLP(N'(h))``, or with
 ``norm_position="post"`` ``h = x + N(Mixer(x)); out = h + N'(MLP(h))``. ``N``
 is a LayerNorm or an RMSNorm; ``MLP`` is a SwiGLU (``W_down (up *
@@ -27,103 +12,35 @@ experts of a sparse expert layer with its shared expert
 (``parallel.moe.SparseExperts``: child ``moe``, scopes ``block<i>/moe/route|
 dispatch|experts|combine|shared|balance``). After the last layer the norm
 and the head: the token table again (``logits = h E^T``), or a matrix of its
-own (``tie_head=False``, float32 logits). The mixers, by kind:
+own (``tie_head=False``, float32 logits).
 
-``mamba``
-    ``[u, z] = W_in x``; ``u = silu(conv1d_causal(u) + b_c)``; ``[dt_r, B, C]
-    = W_x u``; ``dt = softplus(W_dt dt_r + b_dt)``; ``A = -exp(A_log)``
-    in float32; ``y = selective_scan(u, dt, A, B, C, D)`` (``ops/ssm.py``); output
-    ``W_out (y * silu(z))``. Its ``y``, before the gate, is handed on as the
-    stack's memory ``m``: a later ``gmu`` reads the newest one.
-``attn_window`` / ``attn_full``
-    ``[q, k, v] = W_qkv x + b``; differential attention
-    (``ops.attention.diff_attention``: heads pair up, two softmax maps over
-    a value twice as wide, their difference normalised), ``W_o . + b_o``.
-    ``attn_window`` sees the ``window`` newest keys, ``attn_full`` all of
-    them; ``attn_full`` hands its ``k`` and ``v`` on.
-``attn_cross``
-    only ``q = W_q x + b`` is made here; keys and values are those the
-    newest ``attn_full`` handed on; own lambdas, norm and ``W_o``.
-``gmu``
-    ``W_out (m * silu(W_in x))``: no scan, no convolution.
-``conv``
-    a gated short convolution: ``[B, C, u] = W_in x`` (three equal chunks
-    of ``units``, in that order, no bias); ``v = conv1d_causal(B * u)``
-    (depthwise, ``d_conv`` taps, no bias, zeros before the first row);
-    output ``W_out (C * v)``. Both gates are plain products: no activation
-    function anywhere in it.
-``retention``
-    gated power retention of degree 2 (``PowerRetention``;
-    ``ops/retention.py``). ``[q, k, v] = W_qkv x`` on ``H`` query heads and
-    ``Hkv`` key and value heads; q and k normed and rotated as under
-    ``attention="gqa"`` below (the same code); one decay a KEY/VALUE head a
-    token, float32: ``log g_t[j] = log_sigmoid(w_g[j] . x_t + b_g[j])``
-    (child ``gate``); for query head ``h`` in group ``j = h // (H / Hkv)``,
-    over ``s <= t``: ``a[t, s] = (q_t[h] . k_s[j] / sqrt(D))^2 * exp(log
-    g_{s+1}[j] + .. + log g_t[j])`` and ``y_t[h] = sum_s a[t, s] v_s[j] /
-    (sum_s a[t, s] + retention_eps)`` (1: one null key of average
-    weight, so the first rows fade in and nothing magnifies bf16's rounding);
-    output ``W_o concat_h y_t[h]``. Equal to a state: with ``phi(k)`` the ``D (D + 1) / 2`` products ``k_a k_b``, a
-    key/value head carries ``S_t = g_t S_{t-1} + phi(k_t) [v_t, 1]^T / D``
-    (8256 x 129 float32 at ``D`` = 128) and the query heads of a group read
-    that ONE state, which is how the op computes it: linear in ``T``.
+A kind is one row of ``MIXERS`` and one class, whose docstring has the
+kind's equations:
 
-``kda``
-    Kimi Delta Attention (``KimiDeltaAttention``; ``ops/kda.py``), ``H``
-    heads of ``D`` = ``head_dim``, keys and values as many as queries.
-    ``[q, k, v, g] = W_in x`` (four times ``H D``, no bias); ``q, k, v =
-    silu(conv1d_causal(.))`` (depthwise, ``d_conv`` taps, no bias); ``q_t <-
-    q_t / sqrt(|q_t|^2 + 1e-6) * D^-0.5`` and ``k_t <- k_t / sqrt(|k_t|^2 +
-    1e-6)`` over each head; float32: ``a_t[h, c] = kda_lower_bound *
-    sigmoid(exp(A_log[h]) * ((W_f x)_t[h, c] + dt_bias[h, c]))`` (so
-    ``kda_lower_bound < a < 0``) and ``beta_t[h] = sigmoid((W_beta
-    x)_t[h])``; a state ``S`` of ``D x D`` a head, zero at the start: ``S' =
-    Diag(exp(a_t)) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t - S'^T k_t)^T``,
-    ``o_t = S_t^T q_t``; output ``W_o (RMSNorm_D(o_t; gain) * sigmoid(g_t))``,
-    the norm over each head with one gain vector. The layer hands
-    ``contrib.kda`` its operands RAW: q and k as the convolution's SiLU left
-    them, the logits ``W_f x`` as their product made them (float32),
-    ``A_log`` and ``dt_bias``; the two norms, the gate's sigmoid and bound
-    and the chunks' cumulative decays are the op's, made inside its kernels
-    on the TPU (and their gradients too), so nothing of ``(B, T, H D)`` is
-    written between the convolution / the gate's product and the kernels.
-``mla``
-    multi-head latent attention as it trains (``LatentAttention``; the
-    keyword ``mla``: ``latent_dim``, ``nope_dim``, ``rope_dim``, ``v_dim``,
-    ``interleave``). ``q = W_q x`` on ``H`` heads of ``nope_dim +
-    rope_dim``; ``[c | k_r] = W_kva x`` (``latent_dim + rope_dim``); ``c <-
-    RMSNorm(c)``; ``[k_nope | v] = W_kvb c`` a head; an RMSNorm with a gain
-    over each query head's whole width and over ``k_nope``; rotary
-    positions (base ``rope_theta``, neighbouring pairs with ``interleave``)
-    on the last ``rope_dim`` dimensions of every query head and on the ONE
-    ``k_r``, which every head appends to its ``k_nope``; ``softmax(q k^T /
-    sqrt(nope_dim + rope_dim))``, causal, times ``v``; each head's output
-    times ``sigmoid((W_a x)[head])``; ``W_o``. The flash kernels at q/k
-    ``nope_dim + rope_dim`` and v ``v_dim``.
+=============== ===========================================================
+``mamba``       ``Mamba``
+``attn_window`` ``DiffAttention``, or with ``attention="gqa"``
+``attn_full``   ``GroupedQueryAttention`` under the same child names
+``attn_cross``  ``DiffAttention(cross=True)``
+``gmu``         ``GatedMemoryUnit``
+``conv``        ``ShortConv``
+``retention``   ``PowerRetention``
+``kda``         ``KimiDeltaAttention``
+``mla``         ``LatentAttention``
+=============== ===========================================================
 
-With ``attention="gqa"`` the kinds ``attn_window`` / ``attn_full`` are
-``GroupedQueryAttention`` under the same child names: a fused ``qkv``
-without bias, with ``qk_norm`` an RMSNorm over ``head_dim`` on every query
-and key head (scope ``qk_norm``), rotary positions (rotate-half, all of
-``head_dim``; scope ``rope``) in the kinds that ``rope_kinds`` names, plain
-softmax through ``flash_chunk`` with query head ``h`` on key/value head ``h
-// (H / Hkv)``, and ``out_proj`` without bias. ``retention`` is built from
-the same projections, norm and positions whatever ``attention`` says.
-
-Keys/values and the scan's output are made ONCE and read by every later
-layer that wants them: gradients flow back into the one producer from all
-its consumers. That is the training path, and the only one: there is no
-decode cache for any of these kinds yet (``generate`` and the serving steps
-raise), because a cache here has to hold a window's keys, one layer's full
-keys for all cross layers, scan and convolution states side by side, a
-retention layer's 8256 x 129 matrix a key/value head, a ``kda`` layer's
-``D x D`` matrix a head and an ``mla`` layer's latent rows (``_DECODE_STATE``
-has each kind's).
+The hand-over (``Mixer``): keys/values and the scan's output are made ONCE
+and read by every later layer that wants them, so gradients flow back into
+the one producer from all its consumers. That is the training path, and the
+only one: there is no decode cache for any of these kinds yet (``generate``
+and the serving steps raise), because a cache here has to hold side by side
+what each row of ``MIXERS`` says a layer of its kind would keep.
 """
 
 from __future__ import annotations
 
 import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -142,37 +59,27 @@ from ..block import HybridBlock
 from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
                                 SwiGLU)
 
-__all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS", "MLP_KINDS"]
+__all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS", "MLP_KINDS",
+           "MIXERS", "MLPS", "Mixer"]
 
-KINDS = ("mamba", "attn_window", "attn_full", "attn_cross", "gmu", "conv",
-         "retention", "kda", "mla")
-MLP_KINDS = ("mlp", "moe")
-# what a decode cache would hold for a layer of each kind, a slot
-_DECODE_STATE = {
-    "mamba": "scan and convolution states",
-    "attn_window": "a window of keys and values",
-    "attn_full": "every key and value, which attn_cross layers read too",
-    "attn_cross": "nothing of its own (an earlier attn_full layer's keys)",
-    "gmu": "nothing of its own (an earlier mamba layer's output)",
-    "conv": "a conv layer's last d_conv - 1 rows a channel",
-    "retention": "a head_dim (head_dim + 1) / 2 x (head_dim + 1) matrix a "
-                 "key/value head (8256 x 129 at 128)",
-    "kda": "a head_dim x head_dim float32 matrix a head and the last d_conv "
-           "- 1 rows of q, k and v",
-    "mla": "a latent row and one rotary key a token (latent_dim + rope_dim "
-           "numbers), read through absorbed projections",
-}
-# kinds whose block (with a dense MLP, which has no state) runs under
-# jax.checkpoint where asked: they neither hand anything on to later layers
-# nor read it. Only what a cell runs so is listed
-REMAT_KINDS = ("retention",)
+
+class Mixer(HybridBlock):
+    """What the stack asks of a mixer. ``forward(x, shared)`` returns the
+    mixed rows ``(B, T, units)`` alone; ``shared`` is the stack's hand-over,
+    one dict a forward, and the mixer itself takes from it the keys
+    ``reads`` names and puts into it those ``writes`` names (set by
+    ``__init__`` where they follow from its arguments). The classmethod
+    ``from_spec(z, kind, layer_index)`` builds one for a layer of ``kind``
+    from the stack's widths ``z``, so a kind's widths are listed with it."""
+
+    reads: tuple = ()
+    writes: tuple = ()
 
 
 class _ALog(initializer.Initializer):
     """``A_log[c, n] = log(n + 1)``: Mamba's S4D-real initialisation."""
 
     def init_array(self, name, arr):
-        import jax.numpy as jnp
         row = jnp.log(jnp.arange(1, arr.shape[1] + 1, dtype=jnp.float32))
         arr._set_data(jnp.broadcast_to(row, arr.shape).astype(arr.dtype))
 
@@ -185,7 +92,6 @@ class _DtBias(initializer.Initializer):
         self.lo, self.hi = lo, hi
 
     def init_array(self, name, arr):
-        import jax.numpy as jnp
         from ... import rng
         u = jax.random.uniform(rng.next_key(), arr.shape, jnp.float32)
         dt = jnp.exp(u * (math.log(self.hi) - math.log(self.lo))
@@ -206,8 +112,20 @@ def _split(x, sizes):
     return out
 
 
-class Mamba(HybridBlock):
-    """Mamba-1 mixer; ``forward`` returns ``(output, y before the gate)``."""
+class Mamba(Mixer):
+    """Mamba-1 mixer (kind ``mamba``). ``[u, z] = W_in x``; ``u =
+    silu(conv1d_causal(u) + b_c)``; ``[dt_r, B, C] = W_x u``; ``dt =
+    softplus(W_dt dt_r + b_dt)``; ``A = -exp(A_log)`` in float32; ``y =
+    selective_scan(u, dt, A, B, C, D)`` (``ops/ssm.py``); output ``W_out (y *
+    silu(z))``. Its ``y``, before the gate, is handed on as the stack's
+    memory: a later ``gmu`` reads the newest one."""
+
+    writes = ("memory",)
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        return cls(z["units"], z["d_inner"], z["d_state"], z["d_conv"],
+                   z["dt_rank"])
 
     def __init__(self, units: int, d_inner: int, d_state: int, d_conv: int,
                  dt_rank: int, prefix=None, params=None):
@@ -230,7 +148,7 @@ class Mamba(HybridBlock):
             self.out_proj = Dense(units, use_bias=False, flatten=False,
                                   in_units=d_inner)
 
-    def forward(self, x):
+    def forward(self, x, shared):
         u, z = _split(self.in_proj(x), (self._inner, self._inner))
         u = _silu(nd.contrib.causal_conv1d(u, self.conv_weight.data(),
                                            self.conv_bias.data()))
@@ -239,13 +157,26 @@ class Mamba(HybridBlock):
         dt = nd.Activation(self.dt_proj(dt_r), act_type="softrelu")
         y = nd.contrib.selective_scan(u, dt, self.A_log.data(), B, C,
                                       self.D.data(), log_A=True)
-        return self.out_proj(y * _silu(z)), y
+        shared["memory"] = y
+        return self.out_proj(y * _silu(z))
 
 
-class DiffAttention(HybridBlock):
-    """Differential attention; ``cross=True`` makes queries only and reads
-    the keys and values it is handed. ``forward`` returns ``(output, (k,
-    v))``, the keys and values it used, each ``(B, T, kv_heads, head_dim)``."""
+class DiffAttention(Mixer):
+    """Differential attention (kinds ``attn_window`` / ``attn_full`` /
+    ``attn_cross`` under ``attention="diff"``). ``[q, k, v] = W_qkv x + b``;
+    ``ops.attention.diff_attention`` (heads pair up, two softmax maps over a
+    value twice as wide, their difference normalised); ``W_o . + b_o``. With
+    ``window`` it sees the ``window`` newest keys, without all of them, and
+    then hands its ``k`` and ``v`` on (each ``(B, T, kv_heads, head_dim)``).
+    ``cross=True`` makes ``q = W_q x + b`` alone and reads those of the
+    newest ``attn_full``; own lambdas, norm and ``W_o``."""
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        return cls(z["units"], z["num_heads"], z["num_kv_heads"],
+                   z["head_dim"], layer_index,
+                   window=z["window"] if kind == "attn_window" else None,
+                   cross=kind == "attn_cross", norm_eps=z["eps"])
 
     def __init__(self, units: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, layer_index: int, window=None,
@@ -259,7 +190,9 @@ class DiffAttention(HybridBlock):
                 f"the first a multiple of the second")
         self._heads, self._kv_heads, self._dim = num_heads, num_kv_heads, \
             head_dim
-        self._window, self._cross, self._eps = window, cross, norm_eps
+        self._window, self._eps = window, norm_eps
+        self.reads = ("kv",) if cross else ()
+        self.writes = () if cross or window else ("kv",)
         self._lambda_init = 0.8 - 0.6 * math.exp(-0.3 * layer_index)
         kv = 0 if cross else 2 * num_kv_heads * head_dim
         with self.name_scope():
@@ -278,24 +211,26 @@ class DiffAttention(HybridBlock):
             self.subln = self.params.get(
                 "subln", shape=(2 * head_dim,), init="ones")
 
-    def forward(self, x, kv=None):
+    def forward(self, x, shared):
         B, T, _ = x.shape
         H, Hkv, D = self._heads, self._kv_heads, self._dim
-        if self._cross:
-            if kv is None:
+        if self.reads:                  # cross: queries alone are made here
+            if "kv" not in shared:
                 raise ValueError("attn_cross needs the keys and values of an "
                                  "earlier attn_full layer")
-            q, (k, v) = self.qkv(x), kv
+            q, (k, v) = self.qkv(x), shared["kv"]
         else:
             q, k, v = _split(self.qkv(x), (H * D, Hkv * D, Hkv * D))
             k, v = k.reshape((B, T, Hkv, D)), v.reshape((B, T, Hkv, D))
+            if self.writes:
+                shared["kv"] = (k, v)
         out = nd.contrib.diff_attention(
             q.reshape((B, T, H, D)), k, v, self.lambda_q1.data(),
             self.lambda_k1.data(), self.lambda_q2.data(),
             self.lambda_k2.data(), self.subln.data(),
             lambda_init=self._lambda_init, window=self._window,
             eps=self._eps)
-        return self.out_proj(out), (k, v)
+        return self.out_proj(out)
 
 
 @registry.register("as_float32", namespace="contrib")
@@ -394,10 +329,27 @@ _GQ_RETENTION = registry.get_op("contrib.gq_retention")
 _DECAY_GATE = registry.get_op("contrib.decay_gate")
 
 
-class GroupedQueryAttention(HybridBlock):
-    """Grouped-query softmax attention over a window or over everything,
-    with or without rotary positions and a per-head RMSNorm of q and k;
-    ``forward`` returns ``(output, (k, v))`` as ``DiffAttention`` does."""
+class GroupedQueryAttention(Mixer):
+    """Grouped-query softmax attention (kinds ``attn_window`` /
+    ``attn_full`` under ``attention="gqa"``, the child names
+    ``DiffAttention`` has): a fused ``qkv`` without bias, with ``qk_norm``
+    an RMSNorm over ``head_dim`` on every query and key head (scope
+    ``qk_norm``), rotary positions (rotate-half, all of ``head_dim``; scope
+    ``rope``) where ``rope_theta`` > 0 (the kinds ``rope_kinds`` names),
+    plain softmax through ``flash_chunk`` with query head ``h`` on key/value
+    head ``h // (H / Hkv)`` over the ``window`` newest keys or over all of
+    them, and ``out_proj`` without bias. Without a window it hands its ``k``
+    and ``v`` on, as ``DiffAttention`` does."""
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        if kind == "attn_cross":
+            raise ValueError("grouped-query attention has no attn_cross")
+        return cls(z["units"], z["num_heads"], z["num_kv_heads"],
+                   z["head_dim"],
+                   window=z["window"] if kind == "attn_window" else None,
+                   rope_theta=z["rope_theta"] if kind in z["rope_kinds"]
+                   else 0.0, qk_norm=z["qk_norm"], norm_eps=z["eps"])
 
     def __init__(self, units: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, window=None, rope_theta: float = 0.0,
@@ -412,6 +364,7 @@ class GroupedQueryAttention(HybridBlock):
             head_dim
         self._attrs = dict(rope_theta=float(rope_theta), window=window,
                            eps=norm_eps)
+        self.writes = () if window else ("kv",)
         with self.name_scope():
             self.qkv = Dense((num_heads + 2 * num_kv_heads) * head_dim,
                              use_bias=False, flatten=False, in_units=units)
@@ -434,10 +387,18 @@ class GroupedQueryAttention(HybridBlock):
             return heads, ()
         return heads, (self.q_norm.data(), self.k_norm.data())
 
-    def forward(self, x):
+    def forward(self, x, shared):
         (q, k, v), gains = self._qkv_heads(x)
+        if self.writes:
+            shared["kv"] = (k, v)
         out = registry.invoke(_GQ_ATTENTION, q, k, v, *gains, **self._attrs)
-        return self.out_proj(out), (k, v)
+        return self.out_proj(out)
+
+
+def _attention(z, kind, layer_index):
+    """The three ``attn_*`` kinds' mixer, of the class ``attention`` names."""
+    cls = GroupedQueryAttention if z["attention"] == "gqa" else DiffAttention
+    return cls.from_spec(z, kind, layer_index)
 
 
 class _HalfLives(initializer.Initializer):
@@ -477,13 +438,31 @@ class DecayGate(HybridBlock):
 
 
 class PowerRetention(GroupedQueryAttention):
-    """A power-retention layer of degree 2 on grouped heads:
-    ``GroupedQueryAttention``'s fused ``qkv``, q/k RMSNorm, rotary positions
-    and ``out_proj``, with the softmax replaced by
+    """A gated power-retention layer of degree 2 on grouped heads (kind
+    ``retention``; ``ops/retention.py``): ``GroupedQueryAttention``'s fused
+    ``qkv``, q/k RMSNorm, rotary positions and ``out_proj`` whatever
+    ``attention`` says (the same code), with the softmax replaced by
     ``contrib.power_retention`` and its learned decay (child ``gate``: one
-    logit a KEY/VALUE head a token, so that a group shares a state).
-    ``forward`` returns the output alone: nothing is handed on. A device
-    trace reads ``block<i>/retention/qkv|qk_norm|rope|gate|scan|out_proj``."""
+    logit a KEY/VALUE head a token, float32, so that a group shares a
+    state): ``log g_t[j] = log_sigmoid(w_g[j] . x_t + b_g[j])``; for query
+    head ``h`` in group ``j = h // (H / Hkv)``, over ``s <= t``: ``a[t, s] =
+    (q_t[h] . k_s[j] / sqrt(D))^2 * exp(log g_{s+1}[j] + .. + log g_t[j])``
+    and ``y_t[h] = sum_s a[t, s] v_s[j] / (sum_s a[t, s] + retention_eps)``
+    (1: one null key of average weight, so the first rows fade in and
+    nothing magnifies bf16's rounding); output ``W_o concat_h y_t[h]``.
+    Equal to a state (the op's docstring): a key/value head carries ONE
+    ``D (D + 1) / 2 x (D + 1)`` matrix (8256 x 129 float32 at ``D`` = 128)
+    for the query heads of its group, so the op is linear in ``T``. It
+    inherits the projections and not the hand-over. A device trace reads
+    ``block<i>/retention/qkv|qk_norm|rope|gate|scan|out_proj``."""
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        return cls(z["units"], z["num_heads"], z["num_kv_heads"],
+                   z["head_dim"],
+                   rope_theta=z["rope_theta"] if kind in z["rope_kinds"]
+                   else 0.0, qk_norm=z["qk_norm"], norm_eps=z["eps"],
+                   retention_eps=z["retention_eps"])
 
     def __init__(self, units: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, rope_theta: float = 0.0,
@@ -494,10 +473,11 @@ class PowerRetention(GroupedQueryAttention):
                          norm_eps=norm_eps, prefix=prefix, params=params)
         self._attrs = dict(rope_theta=float(rope_theta), eps=norm_eps,
                            retention_eps=retention_eps)
+        self.writes = ()
         with self.name_scope():
             self.gate = DecayGate(units, num_kv_heads)
 
-    def forward(self, x):
+    def forward(self, x, shared):
         (q, k, v), gains = self._qkv_heads(x)
         out = registry.invoke(_GQ_RETENTION, q, k, v, self.gate(x), *gains,
                               **self._attrs)
@@ -573,19 +553,33 @@ class _ChannelHalfLives(initializer.Initializer):
                       .astype(arr.dtype))
 
 
-class KimiDeltaAttention(HybridBlock):
-    """A Kimi-Delta-Attention layer: a gated delta rule whose decay is one
-    factor a key channel a token (``ops/kda.py``) on ``num_heads`` heads of
-    ``head_dim``, keys and values as many as queries. ``in_proj`` makes
-    ``[q, k, v, g]`` (four times ``num_heads * head_dim``, no bias); q, k
-    and v go through a depthwise causal convolution of ``d_conv`` taps and a
-    SiLU; the decay's logits and ``beta`` are float32 (``contrib.kda_gate``:
-    full matrices ``f_proj`` / ``b_proj``); ``contrib.kda`` L2-normalises q
-    and k a head and bounds the decay (``A_log`` a head and ``dt_bias`` a
-    channel, kept in float32) from those raw operands itself; the output
-    is RMS-normed a head (one gain vector) and gated by ``sigmoid(g)``
-    before ``out_proj``. ``forward`` returns the output alone. A device
-    trace reads ``block<i>/kda/proj|conv|gate|scan|out``."""
+class KimiDeltaAttention(Mixer):
+    """A Kimi-Delta-Attention layer (kind ``kda``; ``ops/kda.py``): a gated
+    delta rule whose decay is one factor a key channel a token, on ``H`` =
+    ``num_heads`` heads of ``D`` = ``head_dim``, keys and values as many as
+    queries. ``[q, k, v, g] = W_in x`` (``in_proj``: four times ``H D``, no
+    bias); ``q, k, v = silu(conv1d_causal(.))`` (depthwise, ``d_conv`` taps,
+    no bias); ``q_t <- q_t / sqrt(|q_t|^2 + 1e-6) * D^-0.5`` and ``k_t <-
+    k_t / sqrt(|k_t|^2 + 1e-6)`` over each head; float32
+    (``contrib.kda_gate``: full matrices ``f_proj`` / ``b_proj``): ``a_t[h,
+    c] = lower_bound * sigmoid(exp(A_log[h]) * ((W_f x)_t[h, c] + dt_bias[h,
+    c]))`` (so ``lower_bound < a < 0``; ``A_log`` a head and ``dt_bias`` a
+    channel are kept in float32) and ``beta_t[h] = sigmoid((W_beta
+    x)_t[h])``; a state ``S`` of ``D x D`` a head, zero at the start: ``S' =
+    Diag(exp(a_t)) S_{t-1}``, ``S_t = S' + beta_t k_t (v_t - S'^T k_t)^T``,
+    ``o_t = S_t^T q_t``; output ``W_o (RMSNorm_D(o_t; gain) *
+    sigmoid(g_t))``, the norm over each head with one gain vector. The layer
+    hands ``contrib.kda`` its operands RAW (``kda_scan``): the two norms,
+    the gate's sigmoid and bound and the chunks' cumulative decays are made
+    inside the op's kernels on the TPU (and their gradients too), so nothing
+    of ``(B, T, H D)`` is written between the convolution / the gate's
+    product and the kernels. A device trace reads
+    ``block<i>/kda/proj|conv|gate|scan|out``."""
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        return cls(z["units"], z["num_heads"], z["head_dim"], z["d_conv"],
+                   lower_bound=z["kda_lower_bound"], norm_eps=z["eps"])
 
     def __init__(self, units: int, num_heads: int, head_dim: int,
                  d_conv: int = 4, lower_bound: float = -5.0,
@@ -613,7 +607,7 @@ class KimiDeltaAttention(HybridBlock):
             self.out_proj = Dense(units, use_bias=False, flatten=False,
                                   in_units=wide)
 
-    def forward(self, x):
+    def forward(self, x, shared):
         with jax.named_scope("proj"):
             qkv, g = _split(self.in_proj(x), (self.conv_weight.shape[0],
                                               self.dt_bias.shape[0]))
@@ -663,16 +657,29 @@ def latent_attention(q, kv, k_rope, q_gain, k_gain, gate, nope_dim: int,
 _LATENT_ATTENTION = registry.get_op("contrib.latent_attention")
 
 
-class LatentAttention(HybridBlock):
-    """Multi-head latent attention as it TRAINS: ``q_proj`` makes ``num_heads``
-    queries of ``nope_dim + rope_dim`` (no query latent); ``kva_proj`` a
-    latent of ``latent_dim`` (RMS-normed, gain ``kv_norm``) and one rotary
-    key of ``rope_dim`` for all heads; ``kvb_proj`` expands the latent to
-    each head's ``nope_dim`` key and ``v_dim`` value; ``gate_proj`` one
-    logit a head a token, whose sigmoid scales the head's output; then
-    ``out_proj``. The flash kernels take q/k of ``nope_dim + rope_dim`` and
-    v of ``v_dim``. ``forward`` returns the output alone: nothing is handed
-    on. A device trace reads ``block<i>/mla/proj|rope|attn|out``."""
+class LatentAttention(Mixer):
+    """Multi-head latent attention as it TRAINS (kind ``mla``; the stack's
+    keyword ``mla`` holds ``latent_dim``, ``nope_dim``, ``rope_dim``,
+    ``v_dim``, ``interleave``). ``q = W_q x`` on ``H`` heads of ``nope_dim +
+    rope_dim`` (no query latent); ``[c | k_r] = W_kva x`` (``latent_dim +
+    rope_dim``); ``c <- RMSNorm(c)`` (gain ``kv_norm``); ``[k_nope | v] =
+    W_kvb c`` a head; an RMSNorm with a gain over each query head's whole
+    width and over ``k_nope``; rotary positions (base ``rope_theta``,
+    neighbouring pairs with ``interleave``) on the last ``rope_dim``
+    dimensions of every query head and on the ONE ``k_r``, which every head
+    appends to its ``k_nope``; ``softmax(q k^T / sqrt(nope_dim +
+    rope_dim))``, causal, times ``v``; each head's output times
+    ``sigmoid((W_a x)[head])`` (``gate_proj``); ``W_o``. The flash kernels
+    take q/k of ``nope_dim + rope_dim`` and v of ``v_dim``. Nothing is
+    handed on. A device trace reads ``block<i>/mla/proj|rope|attn|out``."""
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        if not z["mla"]:
+            raise ValueError("an mla layer: give mla= (latent_dim, "
+                             "nope_dim, rope_dim, v_dim)")
+        return cls(z["units"], z["num_heads"], rope_theta=z["rope_theta"],
+                   norm_eps=z["eps"], **z["mla"])
 
     def __init__(self, units: int, num_heads: int, latent_dim: int,
                  nope_dim: int, rope_dim: int, v_dim: int,
@@ -701,7 +708,7 @@ class LatentAttention(HybridBlock):
             self.out_proj = Dense(units, use_bias=False, flatten=False,
                                   in_units=num_heads * v_dim)
 
-    def forward(self, x):
+    def forward(self, x, shared):
         B, T, _ = x.shape
         H = self._heads
         with jax.named_scope("proj"):
@@ -718,8 +725,16 @@ class LatentAttention(HybridBlock):
             return self.out_proj(out)
 
 
-class GatedMemoryUnit(HybridBlock):
-    """``W_out (m * silu(W_in x))``: the memory ``m`` gated by this layer."""
+class GatedMemoryUnit(Mixer):
+    """``W_out (m * silu(W_in x))`` (kind ``gmu``): the memory ``m`` the
+    newest ``mamba`` handed on, gated by this layer; no scan, no
+    convolution."""
+
+    reads = ("memory",)
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        return cls(z["units"], z["d_inner"])
 
     def __init__(self, units: int, d_inner: int, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
@@ -729,24 +744,30 @@ class GatedMemoryUnit(HybridBlock):
             self.out_proj = Dense(units, use_bias=False, flatten=False,
                                   in_units=d_inner)
 
-    def forward(self, x, memory):
-        if memory is None:
+    def forward(self, x, shared):
+        if "memory" not in shared:
             raise ValueError("gmu needs the memory of an earlier mamba layer")
-        return self.out_proj(memory * _silu(self.in_proj(x)))
+        return self.out_proj(shared["memory"] * _silu(self.in_proj(x)))
 
 
-class ShortConv(HybridBlock):
-    """A gated short convolution (LFM2's mixer): ``W_out (C * conv(B *
-    u))`` with ``[B, C, u] = W_in x`` and a depthwise causal convolution of
-    ``width`` taps, no bias anywhere. The two products and the convolution
-    run under the scope ``gate`` (a device trace reads
+class ShortConv(Mixer):
+    """A gated short convolution (kind ``conv``; LFM2's mixer): ``W_out (C *
+    conv(B * u))`` with ``[B, C, u] = W_in x`` (three equal chunks of
+    ``units``, in that order) and a depthwise causal convolution of
+    ``width`` taps (the stack's ``d_conv``; zeros before the first row), no
+    bias anywhere. Both gates are plain products: no activation function
+    anywhere in it. The two products and the convolution run under the
+    scope ``gate`` (a device trace reads
     ``block<i>/conv/in_proj|gate|out_proj``). There is no kernel for it:
-    the gate is three elementwise passes over ``(T, units)`` values (under
-    0.1 ms at 4096 x 2048 bf16 and 819 GB/s), ``width`` shifted copies that
-    XLA fuses, and fuses partly INTO the projections' matmuls, so time
-    under ``gate`` is not the gate's own; the benchmark's
+    the gate is three elementwise passes over ``(T, units)`` values and
+    ``width`` shifted copies that XLA fuses, partly INTO the projections'
+    matmuls, so time under ``gate`` is not the gate's own; the benchmark's
     ``conv_mixer_roofline_pct.train`` sets the whole mixer against its
     roofline."""
+
+    @classmethod
+    def from_spec(cls, z, kind, layer_index):
+        return cls(z["units"], z["d_conv"])
 
     def __init__(self, units: int, width: int, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
@@ -759,11 +780,61 @@ class ShortConv(HybridBlock):
             self.out_proj = Dense(units, use_bias=False, flatten=False,
                                   in_units=units)
 
-    def forward(self, x):
+    def forward(self, x, shared):
         B, C, u = _split(self.in_proj(x), (self._units,) * 3)
         with jax.named_scope("gate"):
             y = C * nd.contrib.causal_conv1d(B * u, self.conv_weight.data())
         return self.out_proj(y)
+
+
+class _Kind(NamedTuple):
+    """A row of ``MIXERS``: all the stack knows of a mixer kind."""
+    build: Callable         # (z, kind, layer_index) -> the kind's ``Mixer``
+    decode_state: str       # what a decode cache would hold for a layer, a slot
+    # may its block run under jax.checkpoint (``may_remat`` has the rest of
+    # the rule). Only what a cell runs so says yes
+    remat: bool = False
+
+
+MIXERS = {
+    "mamba": _Kind(Mamba.from_spec, "scan and convolution states"),
+    "attn_window": _Kind(_attention, "a window of keys and values"),
+    "attn_full": _Kind(_attention, "every key and value, which attn_cross "
+                                   "layers read too"),
+    "attn_cross": _Kind(_attention, "nothing of its own (an earlier "
+                                    "attn_full layer's keys)"),
+    "gmu": _Kind(GatedMemoryUnit.from_spec,
+                 "nothing of its own (an earlier mamba layer's output)"),
+    "conv": _Kind(ShortConv.from_spec,
+                  "a conv layer's last d_conv - 1 rows a channel"),
+    "retention": _Kind(PowerRetention.from_spec,
+                       "a head_dim (head_dim + 1) / 2 x (head_dim + 1) "
+                       "matrix a key/value head (8256 x 129 at 128)",
+                       remat=True),
+    "kda": _Kind(KimiDeltaAttention.from_spec,
+                 "a head_dim x head_dim float32 matrix a head and the last "
+                 "d_conv - 1 rows of q, k and v"),
+    "mla": _Kind(LatentAttention.from_spec,
+                 "a latent row and one rotary key a token (latent_dim + "
+                 "rope_dim numbers), read through absorbed projections"),
+}
+KINDS = tuple(MIXERS)
+
+
+def _swiglu(z):
+    return SwiGLU(z["units"], z["ffn_units"])
+
+
+def _experts(z):
+    if not z["moe"]:
+        raise ValueError("mlp_kinds names an expert layer: give moe=")
+    return SparseExperts(z["units"], **z["moe"])
+
+
+# MLP kind: (how it is built from z, ``remat`` as in ``_Kind``: a dense MLP
+# holds no state)
+MLPS = {"mlp": (_swiglu, True), "moe": (_experts, False)}
+MLP_KINDS = tuple(MLPS)
 
 
 class HybridDecoderBlock(HybridBlock):
@@ -771,15 +842,15 @@ class HybridDecoderBlock(HybridBlock):
     its MLP the child named by ``mlp_kind`` (``mlp`` or ``moe``), so a
     device trace reads ``block3/attn_window/...``, ``block3/moe/experts``.
     ``z`` holds the model's widths and options (``HybridDecoderLM`` builds
-    it). ``shared`` is the stack's hand-over: ``{"memory": y of the newest
-    mamba, "kv": (k, v) of the newest attn_full}``."""
+    it). ``shared`` is the stack's hand-over (``Mixer``): ``{"memory": y of
+    the newest mamba, "kv": (k, v) of the newest attn_full}``."""
 
     def __init__(self, kind: str, layer_index: int, mlp_kind: str, z: dict,
                  prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
-        if kind not in KINDS:
+        if kind not in MIXERS:
             raise ValueError(f"unknown layer kind {kind!r}; one of {KINDS}")
-        if mlp_kind not in MLP_KINDS:
+        if mlp_kind not in MLPS:
             raise ValueError(f"unknown MLP kind {mlp_kind!r}; one of "
                              f"{MLP_KINDS}")
         self.kind, self.mlp_kind = kind, mlp_kind
@@ -788,77 +859,27 @@ class HybridDecoderBlock(HybridBlock):
         norm = RMSNorm if z["norm"] == "rms" else LayerNorm
         with self.name_scope():
             self.ln1 = norm(epsilon=eps, in_channels=units)
-            if kind == "mamba":
-                mixer = Mamba(units, z["d_inner"], z["d_state"], z["d_conv"],
-                              z["dt_rank"])
-            elif kind == "gmu":
-                mixer = GatedMemoryUnit(units, z["d_inner"])
-            elif kind == "conv":
-                mixer = ShortConv(units, z["d_conv"])
-            elif kind == "retention":
-                mixer = PowerRetention(
-                    units, z["num_heads"], z["num_kv_heads"], z["head_dim"],
-                    rope_theta=z["rope_theta"] if kind in z["rope_kinds"]
-                    else 0.0, qk_norm=z["qk_norm"], norm_eps=eps,
-                    retention_eps=z["retention_eps"])
-            elif kind == "kda":
-                mixer = KimiDeltaAttention(
-                    units, z["num_heads"], z["head_dim"], z["d_conv"],
-                    lower_bound=z["kda_lower_bound"], norm_eps=eps)
-            elif kind == "mla":
-                if not z["mla"]:
-                    raise ValueError("an mla layer: give mla= (latent_dim, "
-                                     "nope_dim, rope_dim, v_dim)")
-                mixer = LatentAttention(
-                    units, z["num_heads"], rope_theta=z["rope_theta"],
-                    norm_eps=eps, **z["mla"])
-            elif z["attention"] == "gqa":
-                if kind == "attn_cross":
-                    raise ValueError("grouped-query attention has no "
-                                     "attn_cross")
-                mixer = GroupedQueryAttention(
-                    units, z["num_heads"], z["num_kv_heads"], z["head_dim"],
-                    window=z["window"] if kind == "attn_window" else None,
-                    rope_theta=z["rope_theta"] if kind in z["rope_kinds"]
-                    else 0.0, qk_norm=z["qk_norm"], norm_eps=eps)
-            else:
-                mixer = DiffAttention(
-                    units, z["num_heads"], z["num_kv_heads"], z["head_dim"],
-                    layer_index,
-                    window=z["window"] if kind == "attn_window" else None,
-                    cross=kind == "attn_cross", norm_eps=eps)
+            mixer = MIXERS[kind].build(z, kind, layer_index)
             setattr(self, kind, mixer)
             self.ln2 = norm(epsilon=eps, in_channels=units)
-            if mlp_kind == "moe":
-                self.moe = SparseExperts(units, **z["moe"])
-            else:
-                self.mlp = SwiGLU(units, z["ffn_units"])
+            build_mlp, mlp_remat = MLPS[mlp_kind]
+            setattr(self, mlp_kind, build_mlp(z))
+        # both rows allow it and the mixer neither reads ``shared`` nor writes
+        self.may_remat = MIXERS[kind].remat and mlp_remat \
+            and not (mixer.reads or mixer.writes)
 
     def forward(self, x, shared):
-        mixer = getattr(self, self.kind)
-        h = x if self._post else self.ln1(x)
-        if self.kind == "mamba":
-            mixed, shared["memory"] = mixer(h)
-        elif self.kind == "gmu":
-            mixed = mixer(h, shared.get("memory"))
-        elif self.kind in ("conv", "retention", "kda", "mla"):
-            mixed = mixer(h)
-        elif self.kind == "attn_cross":
-            mixed, _ = mixer(h, shared.get("kv"))
-        else:
-            mixed, kv = mixer(h)
-            if self.kind == "attn_full":
-                shared["kv"] = kv
-        mlp = getattr(self, self.mlp_kind)
+        mixer, mlp = getattr(self, self.kind), getattr(self, self.mlp_kind)
         if self._post:
-            h = x + self.ln1(mixed)
+            h = x + self.ln1(mixer(x, shared))
             return h + self.ln2(mlp(h))
-        h = x + mixed
+        h = x + mixer(self.ln1(x), shared)
         return h + mlp(self.ln2(h))
 
 
 class HybridDecoderLM(HybridBlock):
-    """Decoder LM over token ids from a list of layer kinds (``KINDS``).
+    """Decoder LM over token ids from a list of layer kinds (``KINDS``, the
+    keys of ``MIXERS``).
 
     Input ``(B, T)`` int tokens, output ``(B, T, vocab)`` logits; no position
     table, so ``T`` is bounded by memory alone. Trains through
@@ -874,10 +895,9 @@ class HybridDecoderLM(HybridBlock):
     ``conv`` kind's (LFM2's ``conv_L_cache``).
 
     The layer spec beside ``layer_kinds``. ``attention="gqa"`` makes
-    ``attn_window`` / ``attn_full`` plain grouped-query softmax attention
-    (``GroupedQueryAttention``): ``qk_norm`` an RMSNorm over ``head_dim`` on
-    q and k, ``rope_kinds`` the kinds whose q and k get rotary positions
-    (base ``rope_theta``; the others get none). ``norm="rms"`` takes RMSNorm
+    ``attn_window`` / ``attn_full`` ``GroupedQueryAttention``s: ``qk_norm``
+    norms q and k, ``rope_kinds`` names the kinds whose q and k get rotary
+    positions (base ``rope_theta``). ``norm="rms"`` takes RMSNorm
     for LayerNorm; ``norm_position="post"`` puts each norm on its
     sub-layer's OUTPUT (``h = x + N(Mixer(x))``). ``tie_head=False`` gives
     the head a matrix of its own (child ``head``) and float32 logits;
@@ -904,15 +924,13 @@ class HybridDecoderLM(HybridBlock):
     traced step had and how many it recomputes. It is for models whose
     kept activations do not fit beside their state (five 330M-parameter
     layers at 8192 tokens keep 7.5 GB); only layers that hand nothing on
-    and hold no state take it (``REMAT_KINDS`` with a dense MLP).
+    and hold no state take it (the rows of ``MIXERS`` and ``MLPS`` that say
+    so: ``HybridDecoderBlock.may_remat``).
 
     ``kda_lower_bound`` bounds the ``kda`` kind's log-decay a channel;
     ``mla`` holds the ``mla`` kind's widths (``latent_dim``, ``nope_dim``,
     ``rope_dim``, ``v_dim`` and ``interleave`` for the pairing of its rotary
     positions, whose base is ``rope_theta``).
-
-    ``len(KINDS)`` mixer kinds; the module's docstring has the specs of the
-    families built from them.
     """
 
     def __init__(self, vocab_size: int, layer_kinds, units: int,
@@ -940,15 +958,7 @@ class HybridDecoderLM(HybridBlock):
         if len(self.mlp_kinds) != len(self.layer_kinds):
             raise ValueError(f"{len(self.layer_kinds)} layers, "
                              f"{len(self.mlp_kinds)} MLP kinds")
-        if "moe" in self.mlp_kinds and not moe:
-            raise ValueError("mlp_kinds names an expert layer: give moe=")
         self._remat = remat
-        if remat and (set(self.layer_kinds) - set(REMAT_KINDS)
-                      or "moe" in self.mlp_kinds):
-            raise ValueError(
-                f"remat=True recomputes blocks that hand nothing on and "
-                f"hold no state: kinds {REMAT_KINDS} with a dense MLP, not "
-                f"{self.layer_kinds} / {self.mlp_kinds}")
         z = dict(units=units, ffn_units=ffn_units, num_heads=num_heads,
                  num_kv_heads=num_kv_heads,
                  head_dim=head_dim or units // num_heads, window=window,
@@ -968,6 +978,12 @@ class HybridDecoderLM(HybridBlock):
                 blk = HybridDecoderBlock(kind, i, mlp_kind, z)
                 setattr(self, f"block{i}", blk)   # registers child + params
                 self.blocks.append(blk)
+            if remat and not all(blk.may_remat for blk in self.blocks):
+                kinds = tuple(k for k, row in MIXERS.items() if row.remat)
+                raise ValueError(
+                    f"remat=True recomputes blocks that hand nothing on and "
+                    f"hold no state: kinds {kinds} with a dense MLP, not "
+                    f"{self.layer_kinds} / {self.mlp_kinds}")
             self.ln_f = (RMSNorm if norm == "rms" else LayerNorm)(
                 epsilon=layer_norm_eps, in_channels=units)
             self.head = None if tie_head else Dense(
@@ -981,9 +997,7 @@ class HybridDecoderLM(HybridBlock):
         if remat:
             metrics.record_remat(len(self.blocks), len(self.blocks) - 1)
         for blk in self.blocks:
-            # the block again in the backward, from its input; not the last
-            # one, whose backward comes first: its activations are live then
-            # either way
+            # not the last one, whose backward comes first (the docstring)
             if remat and blk is not self.blocks[-1]:
                 h = nd.NDArray(jax.checkpoint(
                     lambda x, blk=blk: blk(nd.NDArray(x), {}).data)(h.data))
@@ -1005,8 +1019,9 @@ class HybridDecoderLM(HybridBlock):
             return logits
 
     def _no_decode(self, what: str):
-        states = "; ".join(f"{kind}: {_DECODE_STATE[kind]}"
-                           for kind in KINDS if kind in self.layer_kinds)
+        states = "; ".join(f"{kind}: {row.decode_state}"
+                           for kind, row in MIXERS.items()
+                           if kind in self.layer_kinds)
         raise NotImplementedError(
             f"HybridDecoderLM.{what}: this family trains only. Decoding "
             f"needs a cache that holds, side by side, for this model's "

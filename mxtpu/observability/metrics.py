@@ -804,13 +804,22 @@ def reset_sanitizer_stats():
 
 
 # ---------------------------------------------------------------------------
-# kernel paths (ops/attention.py, ops/ssm.py, ops/grouped_matmul.py,
-# ops/retention.py, ops/kda.py): Pallas or XLA, per call site
+# kernels: a kernel file registers its kinds where its op is defined
+# (``register_kernel``); nothing here names one
 # ---------------------------------------------------------------------------
 
-KERNEL_KINDS = ("flash", "flash_window", "ssm_scan", "grouped_matmul",
-                "retention", "kda")
-_kernel_paths = {kind: {"pallas": 0, "xla": 0} for kind in KERNEL_KINDS}
+_kernel_paths: Dict[str, dict] = {}     # kind -> call sites by path
+_launches: Dict[str, dict] = {}         # kind -> its newest call site's row
+
+
+def register_kernel(kind: str, launch_keys: tuple = ()):
+    """A kernel file names a ``kind`` whose call sites it counts by path
+    and, with ``launch_keys``, the keys of the row it records for its newest
+    call site. At import: a reader finds zeros before any launch."""
+    with _stats_lock:
+        _kernel_paths[kind] = {"pallas": 0, "xla": 0}
+        if launch_keys:
+            _launches[kind] = dict.fromkeys(("launches",) + launch_keys, 0)
 
 
 def record_kernel_path(kind: str, pallas: bool):
@@ -822,11 +831,11 @@ def record_kernel_path(kind: str, pallas: bool):
 
 
 def get_kernel_path_counts() -> dict:
-    """``{"flash" | "flash_window" | "ssm_scan" | "grouped_matmul" |
-    "retention" | "kda": {"pallas": n, "xla": n}}`` since the last reset: how many call sites
-    took the Pallas kernels and how many the XLA formulation (another backend than the TPU, or a shape
-    the kernels do not take). A TPU step that should run kernels reads
-    ``xla == 0``."""
+    """``{kind: {"pallas": n, "xla": n}}`` for every registered kind since
+    the last reset: how many call sites took the Pallas kernels and how many
+    the XLA formulation (another backend than the TPU, or a shape the
+    kernels do not take). A TPU step that should run kernels reads ``xla ==
+    0``."""
     with _stats_lock:
         return {kind: dict(row) for kind, row in _kernel_paths.items()}
 
@@ -837,63 +846,24 @@ def reset_kernel_path_counts():
             row.update(pallas=0, xla=0)
 
 
-_retention = {"launches": 0, "chunk": 0, "chunks": 0, "state_bytes_kept": 0}
-
-
-def record_retention_launch(chunk: int, chunks: int, state_bytes_kept: int):
-    """One call site of ``contrib.power_retention`` was traced (or run
-    eagerly): its chunk length, the chunks a sequence and the bytes of
-    chunk-start state its forward keeps for its backward."""
+def record_launch(kind: str, **row):
+    """One call site of ``kind``'s op was traced (or run eagerly): ``row``
+    holds its registered keys (the op's file says what each counts)."""
     with _stats_lock:
-        _retention.update(launches=_retention["launches"] + 1, chunk=chunk,
-                          chunks=chunks, state_bytes_kept=state_bytes_kept)
+        mine = _launches[kind]
+        mine.update(row, launches=mine["launches"] + 1)
 
 
-def get_retention_stats() -> dict:
-    """``{"launches", "chunk", "chunks", "state_bytes_kept"}``: call sites
-    of ``contrib.power_retention`` since the last reset, and the NEWEST
-    one's chunk length, chunks a sequence and bytes of chunk-start state
-    kept for the backward (one layer's: a model that recomputes a block at a
-    time holds that much at a time)."""
+def get_launch_stats(kind: str) -> dict:
+    """``{"launches", *registered keys}``: call sites of ``kind``'s op
+    since the last reset and the NEWEST one's row; zeros before any."""
     with _stats_lock:
-        return dict(_retention)
+        return dict(_launches[kind])
 
 
-def reset_retention_stats():
+def reset_launch_stats(kind: str):
     with _stats_lock:
-        _retention.update(launches=0, chunk=0, chunks=0, state_bytes_kept=0)
-
-
-_KDA_ZERO = {"launches": 0, "chunk": 0, "chunks": 0, "state_bytes_kept": 0,
-             "inverse_bytes_kept": 0}
-_kda = dict(_KDA_ZERO)
-
-
-def record_kda_launch(chunk: int, chunks: int, state_bytes_kept: int,
-                      inverse_bytes_kept: int):
-    """One call site of ``contrib.kda`` was traced (or run eagerly): its
-    chunk length, the chunks a sequence and the bytes of chunk-start state
-    and of chunk inverses its forward keeps for its backward."""
-    with _stats_lock:
-        _kda.update(launches=_kda["launches"] + 1, chunk=chunk,
-                    chunks=chunks, state_bytes_kept=state_bytes_kept,
-                    inverse_bytes_kept=inverse_bytes_kept)
-
-
-def get_kda_stats() -> dict:
-    """``{"launches", "chunk", "chunks", "state_bytes_kept",
-    "inverse_bytes_kept"}``: call sites of ``contrib.kda`` since the last
-    reset, and the NEWEST one's chunk length, chunks a sequence, bytes of
-    chunk-start state kept for the backward and bytes of chunk inverses kept
-    beside them (0 where the ``lax`` form ran: the kernels alone keep them).
-    ONE layer's: a model holds that much a ``kda`` layer."""
-    with _stats_lock:
-        return dict(_kda)
-
-
-def reset_kda_stats():
-    with _stats_lock:
-        _kda.update(_KDA_ZERO)
+        _launches[kind].update(dict.fromkeys(_launches[kind], 0))
 
 
 _remat = {"blocks": 0, "recomputed": 0}

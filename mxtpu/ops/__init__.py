@@ -20,8 +20,8 @@ from . import spatial  # noqa: F401
 from . import rnn  # noqa: F401
 from . import attention  # noqa: F401
 from . import ssm  # noqa: F401
+from . import grouped_matmul  # noqa: F401
 from . import retention  # noqa: F401
 from . import kda  # noqa: F401
-from . import grouped_matmul  # noqa: F401
 from . import image_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401

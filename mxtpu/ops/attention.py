@@ -38,7 +38,6 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-import os
 import threading
 from typing import Optional
 
@@ -46,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import metrics
 from .registry import register
 
 __all__ = ["attention_reference", "diff_attention", "flash_attention",
@@ -123,17 +123,6 @@ def _block_cap(dp: int) -> int:
     the padded head dim so the per-program tiles stay in the same budget
     (Dp=256 → 256, Dp≥512 → 128, the previously-validated floor)."""
     return max(128, 512 * 128 // max(dp, 128))
-
-
-def _lse_store_dtype():
-    """Storage dtype for the sublane-broadcast lse/delta rows the backward
-    kernels stream: f32 (default, exact) or bf16 (``MXTPU_FLASH_LSE=bf16``)
-    which halves that HBM traffic at long T. Kernels accumulate in f32
-    either way — only the stored rows round. Softmax weights are exp(s-lse),
-    so a bf16 lse (rel err ~2^-8) perturbs weights ~0.4% — fine for
-    training steps, not for bit-exactness guards, hence opt-in."""
-    return jnp.bfloat16 if os.environ.get(
-        "MXTPU_FLASH_LSE", "").strip().lower() == "bf16" else jnp.float32
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -221,8 +210,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         tile = pl.dslice(qs, block_q)
         q = q_ref[0, tile, :].astype(jnp.float32) * scale
         do = do_ref[0, tile, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, tile].astype(jnp.float32)[:, None]
-        delta = delta_ref[0, 0, tile].astype(jnp.float32)[:, None]
+        lse = lse_ref[0, 0, tile][:, None]
+        delta = delta_ref[0, 0, tile][:, None]
         s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
         if causal:
             rows = qs + lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -356,11 +345,11 @@ def _flash_bwd_dq_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         q = q_ref[0].astype(jnp.float32) * scale
         k_blk = k_ref[0].astype(jnp.float32)
         s = _window_scores(q, k_blk, qi * block, kb * block, window)
-        p = jnp.exp(s - lse_ref[0, 0].astype(jnp.float32)[:, None])
+        p = jnp.exp(s - lse_ref[0, 0][:, None])
         dp = jnp.dot(do_ref[0].astype(jnp.float32),
                      v_ref[0].astype(jnp.float32).T,
                      preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0].astype(jnp.float32)[:, None])
+        ds = p * (dp - delta_ref[0, 0][:, None])
         acc_ref[...] += jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
 
     @pl.when(j == n_w - 1)
@@ -390,11 +379,11 @@ def _flash_bwd_dkv_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         do = do_ref[0].astype(jnp.float32)
         s = _window_scores(q, k_ref[0].astype(jnp.float32), qi * block,
                            kb * block, window)
-        p = jnp.exp(s - lse_ref[0, 0].astype(jnp.float32)[:, None])
+        p = jnp.exp(s - lse_ref[0, 0][:, None])
         dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v_ref[0].astype(jnp.float32).T,
                      preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0].astype(jnp.float32)[:, None])
+        ds = p * (dp - delta_ref[0, 0][:, None])
         dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
 
     @pl.when(j == n_w - 1)
@@ -625,13 +614,11 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if lse_cot is not None:
         delta = delta - lse_cot.astype(jnp.float32)
-    # lse/delta ride (BH, 8, T): sublane-broadcast to satisfy Mosaic tiling;
-    # MXTPU_FLASH_LSE=bf16 halves this streamed traffic (kernels re-widen)
-    row_dt = _lse_store_dtype()
-    delta = jnp.broadcast_to(
-        delta.astype(row_dt).reshape(B * H, 1, T), (B * H, 8, T))
+    # lse/delta ride (BH, 8, T), float32: sublane-broadcast to satisfy
+    # Mosaic tiling
+    delta = jnp.broadcast_to(delta.reshape(B * H, 1, T), (B * H, 8, T))
     lse = jnp.broadcast_to(
-        lse.astype(row_dt).reshape(B * H, 1, T), (B * H, 8, T))
+        lse.astype(jnp.float32).reshape(B * H, 1, T), (B * H, 8, T))
     qq = _pad_d(q.reshape(B * H, T, D))
     kk = _pad_d(k.reshape(B * Hkv, Tk, D))
     vv = _pad_d(v.reshape(B * Hkv, Tk, Dv))
@@ -771,11 +758,14 @@ def _takes_kernels(q, k, v) -> bool:
     return _use_pallas(q, k) and v.shape[3] <= 512
 
 
+metrics.register_kernel("flash")
+metrics.register_kernel("flash_window")
+
+
 def _count_path(k, window, pallas: bool) -> bool:
     """A forward call site chose its path (its backward follows it); counted
     (``profiler.get_kernel_path_counts()``), so a step that fell back to XLA
     says so instead of only running slower."""
-    from ..observability import metrics
     metrics.record_kernel_path(
         "flash_window" if _windowed(window, k.shape[2]) else "flash", pallas)
     return pallas

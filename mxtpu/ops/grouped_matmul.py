@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import metrics
 from .registry import register
 
 __all__ = ["grouped_matmul", "grouped_matmul_grads"]
@@ -281,6 +282,9 @@ def _grouped_bwd(res, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+metrics.register_kernel("grouped_matmul")
+
+
 @register("grouped_matmul", namespace="contrib")
 def grouped_matmul(x, w, group_sizes):
     """``out[r] = x[r] @ w[g(r)]`` for rows sorted by group: ``x`` ``(M,
@@ -290,7 +294,6 @@ def grouped_matmul(x, w, group_sizes):
     ``M``, ``K`` and ``N`` are multiples of 128, ``lax.ragged_dot``
     anywhere else; the choice is counted as ``grouped_matmul``
     (``profiler.get_kernel_path_counts()``)."""
-    from ..observability import metrics
     metrics.record_kernel_path("grouped_matmul", _use_pallas(x, w))
     return _grouped(x, w, group_sizes)
 

@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import metrics
 from .registry import register
 from .retention import _on_one_device
 
@@ -619,6 +620,12 @@ def kda_stats(T: int, num_heads: int, head_dim: int = _LANES,
             each * chunk * chunk * jnp.dtype(kernel_dtype).itemsize}
 
 
+# ``profiler.get_kda_stats()``: the op's call sites and the newest one's
+# ``kda_stats`` (ONE layer's bytes: a model holds that much a ``kda`` layer)
+metrics.register_kernel("kda", ("chunk", "chunks", "state_bytes_kept",
+                                "inverse_bytes_kept"))
+
+
 @register("kda", namespace="contrib")
 def kda(q, k, v, z, beta, a_log, dt_bias, lower_bound: float = -5.0,
         eps: float = 1e-6):
@@ -637,12 +644,11 @@ def kda(q, k, v, z, beta, a_log, dt_bias, lower_bound: float = -5.0,
     the chunks' cumulative decays are made inside them, and their gradients
     too; the same mathematics in ``lax`` anywhere else. Memory is linear in
     ``T`` either way."""
-    from ..observability import metrics
     pallas = _use_pallas(q)
     metrics.record_kernel_path("kda", pallas)
     B, T, H, D = q.shape
-    metrics.record_kda_launch(
-        **kda_stats(T, H, D, B, q.dtype if pallas else None))
+    metrics.record_launch(
+        "kda", **kda_stats(T, H, D, B, q.dtype if pallas else None))
     bias, rate = _gate_rows(a_log, dt_bias)
     with jax.named_scope("kda"):
         if pallas:

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import metrics
 from .registry import register
 
 __all__ = ["power_retention", "retention_stats"]
@@ -572,6 +573,12 @@ def retention_stats(T: int, num_kv_heads: int, head_dim: int = _LANES,
             "state_bytes_kept": batch * num_kv_heads * chunks * per}
 
 
+# ``profiler.get_retention_stats()``: the op's call sites and the newest
+# one's ``retention_stats`` (ONE layer's: what a recomputing model holds)
+metrics.register_kernel("retention",
+                        ("chunk", "chunks", "state_bytes_kept"))
+
+
 @register("power_retention", namespace="contrib")
 def power_retention(q, k, v, log_g, eps=None):
     """Gated power retention of degree 2, causal. ``q``: ``(B, T, H, D)``;
@@ -584,13 +591,12 @@ def power_retention(q, k, v, log_g, eps=None):
     chunks of ``CHUNK``; the same chunked algorithm in ``lax`` anywhere
     else. Memory is linear in ``T`` either way. ``eps`` ``None`` is
     ``EPS``."""
-    from ..observability import metrics
     eps = EPS if eps is None else float(eps)
     pallas = _use_pallas(q, k)
     metrics.record_kernel_path("retention", pallas)
     B, T, H, D = q.shape
-    metrics.record_retention_launch(
-        **retention_stats(T, k.shape[2], D, B, pallas))
+    metrics.record_launch(
+        "retention", **retention_stats(T, k.shape[2], D, B, pallas))
     with jax.named_scope("retention"):
         if pallas:
             return _retention_pallas(

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import metrics
 from .registry import register
 
 __all__ = ["causal_conv1d", "selective_scan", "selective_scan_reference"]
@@ -324,6 +325,9 @@ def _scan_pallas_bwd(res, dy):
 _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
 
 
+metrics.register_kernel("ssm_scan")
+
+
 @register("selective_scan", namespace="contrib")
 def selective_scan(u, dt, A, B, C, D, log_A: bool = False):
     """Mamba-1's selective scan. ``u``, ``dt`` (after its softplus):
@@ -334,7 +338,6 @@ def selective_scan(u, dt, A, B, C, D, log_A: bool = False):
     parameter is stored in. Pallas kernels with their own backward on the
     TPU where ``channels % 128 == 0`` and ``states % 8 == 0``; a ``lax.scan``
     anywhere else."""
-    from ..observability import metrics
     if log_A:
         A = -jnp.exp(A.astype(jnp.float32))
     pallas = _use_pallas(u, A)

@@ -31,6 +31,7 @@ AND emit real spans onto the unified timeline when tracing is armed.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import time
@@ -46,27 +47,31 @@ from .observability.metrics import (  # noqa: F401  (re-exported surface)
     _stats_lock,
     add_commit_hook,
     get_checkpoint_stats, get_comm_stats, get_feed_stats,
-    get_kda_stats, get_kernel_path_counts,
+    get_kernel_path_counts, get_launch_stats,
     get_memory_stats, get_quant_stats, get_remat_stats, get_resilience_stats,
-    get_retention_stats, get_router_stats, get_sanitizer_stats, get_sched_stats,
+    get_router_stats, get_sanitizer_stats, get_sched_stats,
     get_serving_stats,
     record_checkpoint_commit, record_checkpoint_restore,
     record_checkpoint_save, record_checkpoint_shard_write,
     record_collective, record_comm_step,
     record_feed_consume, record_feed_prefetch, record_feed_resident,
-    record_feed_transfer, record_kda_launch, record_kernel_path,
+    record_feed_transfer, record_kernel_path,
     record_memory_stats,
     record_quant_error, record_quant_matmuls, record_quant_range,
-    record_remat, record_resilience, record_retention_launch, record_router, record_sanitizer, record_sched,
+    record_remat, record_resilience, record_router, record_sanitizer, record_sched,
     record_serving, record_serving_occupancy, record_tenant,
     reset_checkpoint_stats, reset_comm_stats, reset_feed_stats,
-    reset_kda_stats, reset_kernel_path_counts,
+    reset_kernel_path_counts, reset_launch_stats,
     reset_memory_stats, reset_quant_stats, reset_remat_stats,
     reset_resilience_stats,
-    reset_retention_stats, reset_router_stats, reset_sanitizer_stats, reset_sched_stats,
+    reset_router_stats, reset_sanitizer_stats, reset_sched_stats,
     reset_serving_stats,
     sanitizer_violations, set_feed_depth,
 )
+
+# the two launch rows the benchmark reads by name (systems/brumby.py, ling.py)
+get_retention_stats = functools.partial(get_launch_stats, "retention")
+get_kda_stats = functools.partial(get_launch_stats, "kda")
 
 # MFU/step-latency surface (observability.flops is the store)
 get_mfu_stats = _flops.get_mfu_stats

@@ -96,3 +96,36 @@ def _step_jaxpr_hash(net, system, x, y) -> str:
 
     text = str(jax.make_jaxpr(jax.value_and_grad(loss_of))(saved))
     return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.fixture
+def param_names_hash():
+    """``hash(net)`` -> ``(hash, listing)``, a fixture for the reason
+    ``step_jaxpr_hash`` is one."""
+    return _param_names_hash
+
+
+def _param_names_hash(net):
+    """``(first 16 hex digits of the hash, the listing hashed)`` of every
+    parameter of ``net`` as ``(attribute path, saved name, shape)``, sorted:
+    the path is what the benchmark's ``systems/*.py`` walk with ``getattr``
+    to load the reference's weights (``block0/conv/in_proj/weight``), the
+    saved name what ``save_parameters`` writes (the root's prefix cut off).
+    A renamed child or parameter fails here and not first on the chip."""
+    import hashlib
+    from mxtpu.gluon.parameter import Parameter
+    rows = []
+
+    def walk(block, path):
+        for attr, value in vars(block).items():
+            if isinstance(value, Parameter):
+                name = value.name
+                if name.startswith(net.prefix):
+                    name = name[len(net.prefix):]
+                rows.append((f"{path}{attr}", name, tuple(value.shape)))
+        for attr, child in block._children.items():
+            walk(child, f"{path}{attr}/")
+
+    walk(net, "")
+    listing = "\n".join(f"{p} {n} {s}" for p, n, s in sorted(rows))
+    return hashlib.sha256(listing.encode()).hexdigest()[:16], listing

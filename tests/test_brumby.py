@@ -460,3 +460,30 @@ def test_decoding_raises_and_names_the_retention_state(system, weights):
     assert "8256 x 129" in str(err.value)
     with pytest.raises(NotImplementedError, match="trains only"):
         net.serving_step()
+
+
+# The mixer protocol and the table of kinds (PR 45) are shared by every
+# family: this family's step has to trace to the program it traced to before
+# them (hash of the printed jaxpr of loss and gradient at dict(CFG, recompute_blocks=True), taken on
+# the parent tree, commit a1cb520).
+BRUMBY_STEP = "8311347cb39cd7fc"
+
+
+def test_brumby_step_traces_to_the_same_jaxpr(ref, system, weights, batch,
+                                              step_jaxpr_hash):
+    net = system.build_net(dict(CFG, recompute_blocks=True), weights, "float32")
+    assert step_jaxpr_hash(net, system, *batch) == BRUMBY_STEP
+
+
+# The parameters by attribute path, saved name and shape (tests/conftest.py:
+# _param_names_hash): the benchmark's systems/brumby.py loads the reference's
+# weights by these paths, and a renamed child would show first as a cell
+# without a result on the chip. Taken at commit a1cb520 (PR 44).
+BRUMBY_NAMES = "5cc380559231316a"
+
+
+def test_brumby_parameters_keep_their_names_and_shapes(system, weights,
+                                                       param_names_hash):
+    net = system.build_net(dict(CFG, recompute_blocks=True), weights, "float32")
+    got, listing = param_names_hash(net)
+    assert got == BRUMBY_NAMES, f"{got}\n{listing}"

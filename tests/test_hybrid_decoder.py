@@ -250,3 +250,90 @@ def test_phi4_step_traces_to_the_same_jaxpr(ref, system, weights, batch,
                                             step_jaxpr_hash):
     net = system.build_net(CFG, weights, "float32")
     assert step_jaxpr_hash(net, system, *batch) == PHI4_STEP
+
+
+# The parameters by attribute path, saved name and shape (tests/conftest.py:
+# _param_names_hash): the benchmark's systems/phi4flash.py loads the reference's
+# weights by these paths, and a renamed child would show first as a cell
+# without a result on the chip. Taken at commit a1cb520 (PR 44).
+PHI4_NAMES = "e949b3f393c0edbf"
+
+
+def test_phi4_parameters_keep_their_names_and_shapes(system, weights,
+                                                     param_names_hash):
+    net = system.build_net(CFG, weights, "float32")
+    got, listing = param_names_hash(net)
+    assert got == PHI4_NAMES, f"{got}\n{listing}"
+
+
+# The hand-over contract (PR 45), one case a row of ``MIXERS``: a mixer is
+# called ``mixer(x, shared)``, takes what its ``reads`` names from ``shared``
+# and puts what its ``writes`` names there itself; the block compares no kind.
+SPEC = dict(vocab_size=32, units=64, ffn_units=64, num_heads=4,
+            num_kv_heads=2, head_dim=16, window=4, d_inner=32, d_state=4,
+            d_conv=3, dt_rank=2,
+            mla=dict(latent_dim=8, nope_dim=8, rope_dim=4, v_dim=8))
+NEEDS = {"attn_cross": "attn_cross needs the keys and values of an earlier "
+                       "attn_full layer",
+         "gmu": "gmu needs the memory of an earlier mamba layer"}
+
+
+def _kinds():
+    from mxtpu.gluon.model_zoo.hybrid_decoder import MIXERS
+    return [(kind, "diff") for kind in MIXERS] \
+        + [("attn_window", "gqa"), ("attn_full", "gqa")]
+
+
+@pytest.mark.parametrize("kind,attention", _kinds())
+def test_a_mixer_takes_and_hands_on_what_it_declares(kind, attention):
+    from mxtpu.gluon.model_zoo.hybrid_decoder import (HybridDecoderLM, KINDS,
+                                                      MIXERS, Mixer)
+    assert KINDS == tuple(MIXERS)
+    net = HybridDecoderLM(layer_kinds=[kind], attention=attention, **SPEC)
+    net.initialize()
+    blk = net.blocks[0]
+    mixer = getattr(blk, kind)
+    assert isinstance(mixer, Mixer)
+    rs = np.random.RandomState(3)
+    x = nd.array(rs.randn(2, 8, 64).astype(np.float32))
+    given = {"memory": nd.array(rs.randn(2, 8, 32).astype(np.float32)),
+             "kv": tuple(nd.array(rs.randn(2, 8, 2, 16).astype(np.float32))
+                         for _ in range(2))}
+    assert set(mixer.reads) | set(mixer.writes) <= set(given)
+    shared = {key: given[key] for key in mixer.reads}
+    out = blk(x, shared)
+    assert out.shape == x.shape and bool(jnp.isfinite(out.data).all())
+    assert sorted(shared) == sorted(mixer.reads + mixer.writes)
+    for key in mixer.reads:                 # read, not replaced
+        assert shared[key] is given[key]
+    if mixer.reads:
+        assert kind in NEEDS
+        with pytest.raises(ValueError, match=NEEDS[kind]):
+            blk(x, {})
+    else:
+        assert kind not in NEEDS
+        alone = {}
+        blk(x, alone)
+        assert sorted(alone) == sorted(mixer.writes)
+    # the row's other two columns: the decode cache's text and recomputation
+    with pytest.raises(NotImplementedError, match="trains only") as err:
+        net.generate()
+    assert f"{kind}: {MIXERS[kind].decode_state}; the engine" \
+        in str(err.value)
+    assert blk.may_remat == (MIXERS[kind].remat
+                             and not (mixer.reads or mixer.writes))
+    if blk.may_remat:
+        assert kind == "retention"          # only what a cell runs so
+        HybridDecoderLM(layer_kinds=[kind] * 2, attention=attention,
+                        remat=True, **SPEC)
+        with pytest.raises(ValueError, match="with a dense MLP"):
+            HybridDecoderLM(layer_kinds=[kind], attention=attention,
+                            remat=True, mlp_kinds=["moe"],
+                            moe=dict(ffn_units=16, num_experts=4, top_k=2),
+                            **SPEC)
+    else:
+        with pytest.raises(ValueError, match=r"remat=True recomputes blocks "
+                           r"that hand nothing on and hold no state: kinds "
+                           r"\('retention',\) with a dense MLP, not"):
+            HybridDecoderLM(layer_kinds=[kind], attention=attention,
+                            remat=True, **SPEC)

@@ -140,7 +140,7 @@ def test_conv_mixer_forward_gradients_and_causality(ref):
     xin = nd.NDArray(x)
     xin.attach_grad()
     with autograd.record():
-        out = mixer(xin)
+        out = mixer(xin, {})
         loss = nd.sum(out * nd.NDArray(dy))
     loss.backward()
     want, vjp = jax.vjp(lambda p, x_: ref.conv_sublayer(cfg, p, x_), lp, x)
@@ -165,7 +165,7 @@ def test_conv_mixer_forward_gradients_and_causality(ref):
                                    atol=1e-4, err_msg=leaf)
     np.testing.assert_allclose(np.asarray(xin.grad.data),
                                np.asarray(want_dx), rtol=1e-4, atol=1e-4)
-    moved = np.abs(np.asarray(mixer(nd.NDArray(x.at[:, 7].add(1.0))).data
+    moved = np.abs(np.asarray(mixer(nd.NDArray(x.at[:, 7].add(1.0)), {}).data
                               - out.data)).max(axis=(0, 2))
     assert not moved[:7].any() and moved[7:10].all() and not moved[10:].any()
 
@@ -417,3 +417,30 @@ def test_decoding_raises_and_names_the_convolution_state(system, weights):
         net.serving_step()
     with pytest.raises(ValueError, match="unknown layer kind"):
         HybridDecoderLM(32, ["convolution"], 64, 128, 4, 2)
+
+
+# The mixer protocol and the table of kinds (PR 45) are shared by every
+# family: this family's step has to trace to the program it traced to before
+# them (hash of the printed jaxpr of loss and gradient at CFG, taken on
+# the parent tree, commit a1cb520).
+LFM2_STEP = "c93bcad41b2e4138"
+
+
+def test_lfm2_step_traces_to_the_same_jaxpr(ref, system, weights, batch,
+                                            step_jaxpr_hash):
+    net = system.build_net(CFG, weights, "float32")
+    assert step_jaxpr_hash(net, system, *batch) == LFM2_STEP
+
+
+# The parameters by attribute path, saved name and shape (tests/conftest.py:
+# _param_names_hash): the benchmark's systems/lfm2.py loads the reference's
+# weights by these paths, and a renamed child would show first as a cell
+# without a result on the chip. Taken at commit a1cb520 (PR 44).
+LFM2_NAMES = "6bcee65500ad987a"
+
+
+def test_lfm2_parameters_keep_their_names_and_shapes(system, weights,
+                                                     param_names_hash):
+    net = system.build_net(CFG, weights, "float32")
+    got, listing = param_names_hash(net)
+    assert got == LFM2_NAMES, f"{got}\n{listing}"

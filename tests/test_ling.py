@@ -367,7 +367,7 @@ def test_the_triangular_solve_by_blocks_is_the_inverse(kind, C):
 
 def test_op_counts_its_path_and_its_kept_states():
     profiler.reset_kernel_path_counts()
-    profiler.reset_kda_stats()
+    profiler.reset_launch_stats("kda")
     args, _ = _data(40, 32, "slow")
     out = nd.contrib.kda(*(nd.NDArray(x) for x in args))
     assert out.shape == (2, 40, 64)
@@ -381,7 +381,7 @@ def test_op_counts_its_path_and_its_kept_states():
         assert K.kda_stats(4096, 32, 128, kernel_dtype=dtype) == {
             "chunk": 128, "chunks": 32, "state_bytes_kept": 32 * 32 * 65536,
             "inverse_bytes_kept": 32 * 32 * 128 * 128 * itemsize}
-    profiler.reset_kda_stats()
+    profiler.reset_launch_stats("kda")
     assert profiler.get_kda_stats()["inverse_bytes_kept"] == 0
 
 
@@ -513,7 +513,7 @@ def test_latent_attention_against_the_expanded_quadratic_form(ref, weights):
         p.set_data(nd.NDArray(lp[leaf]))
     x = jnp.asarray(np.random.RandomState(4).randn(2, T, 64), jnp.float32)
     want = ref.mla_sublayer(CFG, lp, x)
-    got = att(nd.NDArray(x)).data
+    got = att(nd.NDArray(x), {}).data
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4,
                                atol=1e-5 * float(jnp.abs(want).max()))
     # the positional part alone: the last 8 of 24 turn, by neighbours
@@ -722,8 +722,9 @@ def test_step_carries_scopes_and_kernel_names(ref, system, weights, batch,
 
 def test_decoding_raises_and_names_both_states(system, weights):
     from mxtpu.gluon.model_zoo.hybrid_decoder import (HybridDecoderLM, KINDS,
-                                                      _DECODE_STATE)
-    assert {"kda", "mla"} <= set(KINDS) and set(_DECODE_STATE) == set(KINDS)
+                                                      MIXERS)
+    assert {"kda", "mla"} <= set(KINDS) and tuple(MIXERS) == KINDS
+    assert all(row.decode_state for row in MIXERS.values())
     net = system.build_net(CFG, weights, "float32")
     with pytest.raises(NotImplementedError, match="trains only") as err:
         net.generate(nd.array(np.zeros((1, 4))), 4)
@@ -736,3 +737,31 @@ def test_decoding_raises_and_names_both_states(system, weights):
     with pytest.raises(ValueError, match="give mla="):
         HybridDecoderLM(96, ["mla"], units=64, ffn_units=96, num_heads=4,
                         num_kv_heads=4)
+
+
+# The mixer protocol and the table of kinds (PR 45) are shared by every
+# family: this family's step has to trace to the program it traced to before
+# them (hash of the printed jaxpr of loss and gradient at CFG, taken on
+# the parent tree, commit a1cb520).
+LING_STEP = "42aaee5b909bb9cc"
+
+
+def test_ling_step_traces_to_the_same_jaxpr(ref, system, weights, batch,
+                                            step_jaxpr_hash,
+                                            small_chunks):
+    net = system.build_net(CFG, weights, "float32")
+    assert step_jaxpr_hash(net, system, *batch) == LING_STEP
+
+
+# The parameters by attribute path, saved name and shape (tests/conftest.py:
+# _param_names_hash): the benchmark's systems/ling.py loads the reference's
+# weights by these paths, and a renamed child would show first as a cell
+# without a result on the chip. Taken at commit a1cb520 (PR 44).
+LING_NAMES = "f256c388516d4f73"
+
+
+def test_ling_parameters_keep_their_names_and_shapes(system, weights,
+                                                     param_names_hash):
+    net = system.build_net(CFG, weights, "float32")
+    got, listing = param_names_hash(net)
+    assert got == LING_NAMES, f"{got}\n{listing}"
