@@ -31,6 +31,9 @@ at the full width of the ``flagship`` preset (d1024, L8, H16, vocab 16384):
    heads beside a latent-attention layer, expert layers under a
    group-limited router) does the same: ``kda_fwd`` / ``kda_bwd`` over three
    chunks and the flash launches at 192 / 128 against their ``lax`` forms.
+   A fifth (latent attention with a query latent in both layers, a sparse
+   layer, and a multi-token-prediction block trained beside the head through
+   ``gluon.loss.NextTokenLoss``) does the same, and both tables must move.
 5. four chips, when there are four — leg 1 at B32 over dp=4 and a 2x2
    fsdp×tp serving engine, with where the bytes actually landed.
 
@@ -114,6 +117,13 @@ SIZES = {
                  topk_group=2, vocab=1024, T=384,
                  mla=dict(latent_dim=128, nope_dim=128, rope_dim=64,
                           v_dim=128)),
+        # a latent-attention decoder of the DeepSeek-V3 family with its
+        # prediction block: keys of 128 + 64 from latents of 128 (kv) and
+        # 256 (q), 16 experts of which 4 are held
+        mtp=dict(units=256, heads=2, ffn=512, moe_ffn=128, experts=16,
+                 held=(0, 1, 2, 3), top_k=2, vocab=1024, T=384,
+                 mla=dict(latent_dim=128, nope_dim=128, rope_dim=64,
+                          v_dim=128, q_latent_dim=256)),
         multi=dict(B=32, serve_n=4),
     ),
     "rehearsal": dict(
@@ -135,6 +145,10 @@ SIZES = {
                  experts=8, held=(0, 1), top_k=2, n_group=4, topk_group=2,
                  vocab=50, T=32,
                  mla=dict(latent_dim=16, nope_dim=8, rope_dim=4, v_dim=8)),
+        mtp=dict(units=32, heads=4, ffn=64, moe_ffn=16, experts=8,
+                 held=(0, 1), top_k=2, vocab=50, T=32,
+                 mla=dict(latent_dim=16, nope_dim=8, rope_dim=4, v_dim=8,
+                          q_latent_dim=24)),
         multi=dict(B=8, serve_n=2),
     ),
 }
@@ -894,6 +908,106 @@ def leg_kda_train(sz, on_chip: bool) -> dict:
             "kda": stats, "pairs": [r["pairs"] for r in rows]}
 
 
+# -- leg 4f: a tiny latent decoder with its prediction block, trained ---------
+
+def leg_mtp_train(sz, on_chip: bool) -> dict:
+    """One step of a tiny ``HybridDecoderLM`` of the sixth family (latent
+    attention with a query latent and neither q/k norms nor a head gate in
+    both layers, a dense and a sparse MLP, an untied head, and a
+    multi-token-prediction block: ``mtp_layers=1``) through
+    ``DataParallelTrainer`` under ``NextTokenLoss``, twice from the same
+    weights: as it runs (on the chip three flash launches at keys of 192
+    and values of 128, the block's among them, and the grouped matmuls) and
+    with every kernel site on its XLA formulation, dense MLPs in every
+    layer: first loss and every parameter's first gradient must agree, the
+    block's and both tables' among them; with the second loss's weight at 0
+    the loss must read lower and the block's parameters get no gradient. The
+    expert layers run a step of their own."""
+    import mxtpu as mx
+    from mxtpu import nd, optimizer, profiler
+    from mxtpu.gluon.loss import NextTokenLoss
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    from mxtpu.parallel import DataParallelTrainer
+    from mxtpu.parallel.mesh import data_parallel_mesh
+    ms = sz["mtp"]
+    seq = np.random.RandomState(4).randint(0, ms["vocab"], (1, ms["T"] + 1))
+    x, y = nd.array(seq[:, :-1]), nd.array(seq[:, 1:].astype(np.float32))
+
+    def one_step(mlp_kinds=("mlp", "mlp"), weight=0.3):
+        mx.random.seed(7)           # the same draw both times
+        net = HybridDecoderLM(
+            ms["vocab"], ["mla", "mla"], units=ms["units"],
+            ffn_units=ms["ffn"], num_heads=ms["heads"],
+            num_kv_heads=ms["heads"], layer_norm_eps=1e-6, rope_theta=32e6,
+            norm="rms", tie_head=False,
+            mla=dict(ms["mla"], interleave=True, qk_norm=False,
+                     head_gate=False),
+            mlp_kinds=list(mlp_kinds),
+            moe=dict(ffn_units=ms["moe_ffn"], num_experts=ms["experts"],
+                     top_k=ms["top_k"], held=ms["held"],
+                     shared_ffn_units=ms["moe_ffn"], routed_scale=2.5,
+                     bias_update_rate=0.03),
+            mtp_layers=1)
+        net.initialize()
+        if on_chip:
+            net.cast("bfloat16")
+        dpt = DataParallelTrainer(net, NextTokenLoss(weight=weight),
+                                  optimizer.Adam(learning_rate=1e-3),
+                                  data_parallel_mesh(1))
+        loss = float(dpt.step(x, y))
+        return loss, {
+            name.split("_", 1)[1]: np.asarray(slots[0], np.float32)
+            for name, slots in dpt.optimizer_state_by_param().items()}, \
+            profiler.get_moe_stats(net)
+
+    profiler.reset_kernel_path_counts()
+    profiler.reset_launch_stats("mtp")
+    loss, moments, _ = one_step()
+    stats = profiler.get_launch_stats("mtp")
+    sparse_loss, _, rows = one_step(("mlp", "moe"))
+    paths = profiler.get_kernel_path_counts()
+    main_loss, main_only, _ = one_step(weight=0.0)
+    with xla_formulations():
+        want_loss, want, _ = one_step()
+    tol = 3e-2 if on_chip else 1e-4     # bfloat16 against bfloat16 / float32
+    check(abs(loss - want_loss) <= tol * want_loss,
+          f"mtp train: first loss {loss} against XLA's {want_loss}")
+    block = [k for k in moments if "multitokenprediction" in k]
+    check(set(moments) == set(want) and len(block) > 10
+          and any("latentattention0_dense1" in k for k in block),
+          f"mtp train: parameters {sorted(moments)}")
+    gaps = {k: float(np.linalg.norm(moments[k] - want[k])
+                     / max(np.linalg.norm(want[k]), 1e-30)) for k in want}
+    worst = max(gaps, key=gaps.get)
+    check(gaps[worst] <= 3 * tol,
+          f"mtp train: first gradient of {worst} is {gaps[worst]:.4g} from "
+          f"the XLA formulation's")
+    # the second loss is a term of its own: without it the loss reads lower
+    # by about 0.3 ln(vocab) and the block gets no gradient at all
+    check(main_loss < loss - 0.2 * np.log(ms["vocab"])
+          and all(not np.any(main_only[k]) for k in block)
+          and all(np.any(moments[k]) for k in block),
+          f"mtp train: loss {loss} against {main_loss} without the second "
+          f"term")
+    check(stats["launches"] >= 1 and stats["depth"] == 1
+          and stats["positions"] == ms["T"] - 1
+          and stats["logits_bytes"] == ms["T"] * ms["vocab"] * 4,
+          f"mtp train: {stats}")
+    check(len(rows) == 2 and all(r["pairs"] > 0 for r in rows)
+          and np.isfinite(sparse_loss),
+          f"mtp train: sparse loss {sparse_loss}, {rows}")
+    if on_chip:
+        for kind in ("flash", "grouped_matmul"):
+            check(paths[kind]["pallas"] > 0 and paths[kind]["xla"] == 0,
+                  f"mtp train: {kind} call sites {paths[kind]}")
+    return {"loss": round(loss, 4), "xla_loss": round(want_loss, 4),
+            "main_loss": round(main_loss, 4),
+            "sparse_loss": round(sparse_loss, 4),
+            "worst_gradient_gap": [worst, round(gaps[worst], 5)],
+            "kernel_paths": {"flash": paths["flash"]}, "mtp": stats,
+            "pairs": [r["pairs"] for r in rows]}
+
+
 # -- leg 5: four chips -------------------------------------------------------
 
 def placement(arrays: dict, devices) -> dict:
@@ -1042,6 +1156,8 @@ def main(argv=None) -> int:
         rep, out = leg("retention_train", leg_retention_train, sz, on_chip)
         rep.update(out)
         rep, out = leg("kda_train", leg_kda_train, sz, on_chip)
+        rep.update(out)
+        rep, out = leg("mtp_train", leg_mtp_train, sz, on_chip)
         rep.update(out)
 
         if len(devs) >= 4:

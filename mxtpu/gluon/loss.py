@@ -118,6 +118,45 @@ class SoftmaxCrossEntropyLoss(Loss):
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
 
 
+class NextTokenLoss(Loss):
+    """Next-token training of a language model that may also predict further
+    ahead (a ``HybridDecoderLM(mtp_layers=1)`` in train mode). ``label``
+    ``(B, T)``: ``label[:, i]`` is the token after position ``i``.
+
+    ``pred`` ONE array ``(B, T, V)``: the cross entropy of every position,
+    ``(B * T,)``: ``SoftmaxCrossEntropyLoss`` over the flattened rows.
+
+    ``pred`` a tuple ``(logits, logits2, ...)``, depth ``k`` at index ``k``:
+    a scalar, ``mean_i CE(logits_i, label_i) + weight * sum_k mean_{i < T -
+    k} CE(logits{k+1}_i, label_{i+k})``: depth ``k`` predicts one token
+    further, so its targets are the labels rolled left by ``k`` and its last
+    ``k`` positions, which have none, are masked out of its mean. ``weight``
+    is DeepSeek-V3's lambda. Scopes ``main`` and ``mtp``."""
+
+    def __init__(self, weight: float = 0.3, batch_axis: int = 0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+        self.rows = SoftmaxCrossEntropyLoss()
+
+    def _rows(self, pred, label):
+        b, t, v = pred.shape
+        return self.rows(pred.reshape((b * t, v)), label.reshape((b * t,)))
+
+    def forward(self, pred, label):
+        if not isinstance(pred, (tuple, list)):
+            return self._rows(pred, label)
+        B, T = label.shape
+        with jax.named_scope("main"):
+            total = nd.mean(self._rows(pred[0], label))
+        for k, further in enumerate(pred[1:], start=1):
+            with jax.named_scope("mtp"):
+                ahead = NDArray(jnp.roll(label.data, -k, axis=1))
+                seen = NDArray(jnp.tile(jnp.arange(T) < T - k, B)
+                               .astype(jnp.float32))
+                rows = self._rows(further, ahead) * seen
+                total = total + nd.sum(rows) * (self._weight / (B * (T - k)))
+        return total
+
+
 class KLDivLoss(Loss):
     def __init__(self, from_logits: bool = True, axis: int = -1,
                  weight: Optional[float] = None, batch_axis: int = 0, **kwargs):

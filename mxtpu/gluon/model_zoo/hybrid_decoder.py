@@ -1,8 +1,9 @@
 """Decoder language models built from a list of layer kinds: a mixer kind, an
 MLP kind and a norm for each layer, and the widths; nothing here is a preset.
-Five published families are instances (``docs/hybrid_decoder.md`` has each
+Six published families are instances (``docs/hybrid_decoder.md`` has each
 one's spec): SambaY / Phi-4-mini-flash, K-EXAONE, LFM2's ``lfm2_moe``,
-Brumby-14B and Ling-3.0's ``bailing_hybrid``.
+Brumby-14B, Ling-3.0's ``bailing_hybrid`` and JoyAI-LLM-Flash's (DeepSeek-V3's
+block with its multi-token-prediction block, ``MultiTokenPrediction``).
 
 Every layer is ``h = x + Mixer(N(x)); out = h + MLP(N'(h))``, or with
 ``norm_position="post"`` ``h = x + N(Mixer(x)); out = h + N'(MLP(h))``. ``N``
@@ -60,7 +61,7 @@ from ..nn.basic_layers import (Dense, Embedding, LayerNorm, RMSNorm,
                                 SwiGLU)
 
 __all__ = ["HybridDecoderBlock", "HybridDecoderLM", "KINDS", "MLP_KINDS",
-           "MIXERS", "MLPS", "Mixer"]
+           "MIXERS", "MLPS", "Mixer", "MultiTokenPrediction"]
 
 
 class Mixer(HybridBlock):
@@ -633,15 +634,20 @@ def latent_attention(q, kv, k_rope, q_gain, k_gain, gate, nope_dim: int,
     position-free key and its value; ``k_rope`` ``(B, T, rope)``: ONE
     rotary key, which every head appends to its own; ``q_gain`` / ``k_gain``:
     an RMSNorm over a query head's whole width and over ``k_nope``, before
-    the positions; rotary positions on the last ``rope`` dimensions of q and
-    on ``k_rope``; scores ``q k^T / sqrt(nope + rope)``; ``gate`` ``(B, T,
-    H)`` logits: the head's output times their sigmoid. Returns ``(B, T, H *
-    v)``. Scopes ``rope`` (norms and positions) and ``attn``."""
+    the positions (``None``: not normed); rotary positions on
+    the last ``rope`` dimensions of q and on ``k_rope``; scores ``q k^T /
+    sqrt(nope + rope)``; ``gate`` ``(B, T, H)`` logits: the head's output
+    times their sigmoid (``None``: no gate). Returns ``(B, T, H * v)``.
+    Scopes ``rope`` (norms and positions) and ``attn``."""
     B, T, H, W = q.shape
     rope_dim = W - nope_dim
     with jax.named_scope("rope"):
-        q = _rope(rms_norm(q, q_gain, eps), rope_theta, interleave, rope_dim)
-        k_nope = rms_norm(kv[..., :nope_dim], k_gain, eps)
+        if q_gain is not None:
+            q = rms_norm(q, q_gain, eps)
+        q = _rope(q, rope_theta, interleave, rope_dim)
+        k_nope = kv[..., :nope_dim]
+        if k_gain is not None:
+            k_nope = rms_norm(k_nope, k_gain, eps)
         k_rope = _rope(k_rope[:, :, None, :], rope_theta, interleave)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (B, T, H, rope_dim))], axis=-1)
@@ -649,8 +655,9 @@ def latent_attention(q, kv, k_rope, q_gain, k_gain, gate, nope_dim: int,
         qh, kh, vh = (x.transpose(0, 2, 1, 3)
                       for x in (q, k, kv[..., nope_dim:]))
         out, _ = flash_chunk(qh, kh, vh, True, 1.0 / math.sqrt(W), None)
-        out = out.transpose(0, 2, 1, 3) \
-            * jax.nn.sigmoid(gate)[..., None].astype(out.dtype)
+        out = out.transpose(0, 2, 1, 3)
+        if gate is not None:
+            out = out * jax.nn.sigmoid(gate)[..., None].astype(out.dtype)
     return out.reshape(B, T, -1)
 
 
@@ -659,19 +666,26 @@ _LATENT_ATTENTION = registry.get_op("contrib.latent_attention")
 
 class LatentAttention(Mixer):
     """Multi-head latent attention as it TRAINS (kind ``mla``; the stack's
-    keyword ``mla`` holds ``latent_dim``, ``nope_dim``, ``rope_dim``,
-    ``v_dim``, ``interleave``). ``q = W_q x`` on ``H`` heads of ``nope_dim +
-    rope_dim`` (no query latent); ``[c | k_r] = W_kva x`` (``latent_dim +
-    rope_dim``); ``c <- RMSNorm(c)`` (gain ``kv_norm``); ``[k_nope | v] =
-    W_kvb c`` a head; an RMSNorm with a gain over each query head's whole
-    width and over ``k_nope``; rotary positions (base ``rope_theta``,
-    neighbouring pairs with ``interleave``) on the last ``rope_dim``
-    dimensions of every query head and on the ONE ``k_r``, which every head
-    appends to its ``k_nope``; ``softmax(q k^T / sqrt(nope_dim +
-    rope_dim))``, causal, times ``v``; each head's output times
-    ``sigmoid((W_a x)[head])`` (``gate_proj``); ``W_o``. The flash kernels
-    take q/k of ``nope_dim + rope_dim`` and v of ``v_dim``. Nothing is
-    handed on. A device trace reads ``block<i>/mla/proj|rope|attn|out``."""
+    keyword ``mla`` holds this class's arguments after ``num_heads``:
+    ``latent_dim``, ``nope_dim``, ``rope_dim``, ``v_dim``, ``interleave``,
+    ``q_latent_dim``, ``qk_norm``, ``head_gate``). The query: ``q = W_q x``
+    on ``H`` heads of ``nope_dim + rope_dim``, or with ``q_latent_dim`` > 0
+    through a latent of its own (DeepSeek-V3's ``q_lora_rank``): ``c_q =
+    RMSNorm(W_qa x)`` (gain ``qa_norm``), ``q = W_qb c_q``. ``[c | k_r] =
+    W_kva x`` (``latent_dim + rope_dim``); ``c <- RMSNorm(c)`` (gain
+    ``kv_norm``); ``[k_nope | v] = W_kvb c`` a head; with ``qk_norm`` an
+    RMSNorm with a gain over each query head's whole width and over
+    ``k_nope``; rotary positions (base ``rope_theta``, neighbouring pairs
+    with ``interleave``) on the last ``rope_dim`` dimensions of every query
+    head and on the ONE ``k_r``, which every head appends to its ``k_nope``;
+    ``softmax(q k^T / sqrt(nope_dim + rope_dim))``, causal, times ``v``;
+    with ``head_gate`` each head's output times ``sigmoid((W_a x)[head])``
+    (``gate_proj``); ``W_o``. Ling-3.0's variant is the defaults (one query
+    matrix, both norms, the gate); DeepSeek-V3's is ``q_latent_dim`` with
+    ``qk_norm=False, head_gate=False``. The flash kernels take q/k of
+    ``nope_dim + rope_dim`` and v of ``v_dim``. Nothing is handed on. A
+    device trace reads ``block<i>/mla/proj|rope|attn|out`` (``proj`` holds
+    both query matrices)."""
 
     @classmethod
     def from_spec(cls, z, kind, layer_index):
@@ -684,27 +698,41 @@ class LatentAttention(Mixer):
     def __init__(self, units: int, num_heads: int, latent_dim: int,
                  nope_dim: int, rope_dim: int, v_dim: int,
                  rope_theta: float = 1e4, interleave: bool = True,
-                 norm_eps: float = 1e-6, prefix=None, params=None):
+                 norm_eps: float = 1e-6, q_latent_dim: int = 0,
+                 qk_norm: bool = True, head_gate: bool = True, prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         self._heads, self._latent = num_heads, latent_dim
         self._nope, self._rope, self._v = nope_dim, rope_dim, v_dim
         self._attrs = dict(nope_dim=nope_dim, rope_theta=float(rope_theta),
                            interleave=bool(interleave), eps=norm_eps)
+        wide = num_heads * (nope_dim + rope_dim)
+        self.q_proj = self.gate_proj = self.q_norm = self.k_norm = None
         with self.name_scope():
-            self.q_proj = Dense(num_heads * (nope_dim + rope_dim),
-                                use_bias=False, flatten=False, in_units=units)
+            if q_latent_dim:
+                self.qa_proj = Dense(q_latent_dim, use_bias=False,
+                                     flatten=False, in_units=units)
+                self.qa_norm = RMSNorm(epsilon=norm_eps,
+                                       in_channels=q_latent_dim)
+                self.qb_proj = Dense(wide, use_bias=False, flatten=False,
+                                     in_units=q_latent_dim)
+            else:
+                self.q_proj = Dense(wide, use_bias=False, flatten=False,
+                                    in_units=units)
             self.kva_proj = Dense(latent_dim + rope_dim, use_bias=False,
                                   flatten=False, in_units=units)
             self.kv_norm = RMSNorm(epsilon=norm_eps, in_channels=latent_dim)
             self.kvb_proj = Dense(num_heads * (nope_dim + v_dim),
                                   use_bias=False, flatten=False,
                                   in_units=latent_dim)
-            self.gate_proj = Dense(num_heads, use_bias=False, flatten=False,
-                                   in_units=units)
-            self.q_norm = self.params.get(
-                "q_norm", shape=(nope_dim + rope_dim,), init="ones")
-            self.k_norm = self.params.get(
-                "k_norm", shape=(nope_dim,), init="ones")
+            if head_gate:
+                self.gate_proj = Dense(num_heads, use_bias=False,
+                                       flatten=False, in_units=units)
+            if qk_norm:
+                self.q_norm = self.params.get(
+                    "q_norm", shape=(nope_dim + rope_dim,), init="ones")
+                self.k_norm = self.params.get(
+                    "k_norm", shape=(nope_dim,), init="ones")
             self.out_proj = Dense(units, use_bias=False, flatten=False,
                                   in_units=num_heads * v_dim)
 
@@ -712,15 +740,18 @@ class LatentAttention(Mixer):
         B, T, _ = x.shape
         H = self._heads
         with jax.named_scope("proj"):
-            q = self.q_proj(x).reshape((B, T, H, self._nope + self._rope))
+            q = self.q_proj(x) if self.q_proj is not None \
+                else self.qb_proj(self.qa_norm(self.qa_proj(x)))
+            q = q.reshape((B, T, H, self._nope + self._rope))
             latent, k_rope = _split(self.kva_proj(x),
                                     (self._latent, self._rope))
             kv = self.kvb_proj(self.kv_norm(latent)).reshape(
                 (B, T, H, self._nope + self._v))
-            gate = self.gate_proj(x)
-        out = registry.invoke(_LATENT_ATTENTION, q, kv, k_rope,
-                              self.q_norm.data(), self.k_norm.data(), gate,
-                              **self._attrs)
+            gate = None if self.gate_proj is None else self.gate_proj(x)
+        q_gain, k_gain = (None, None) if self.q_norm is None \
+            else (self.q_norm.data(), self.k_norm.data())
+        out = registry.invoke(_LATENT_ATTENTION, q, kv, k_rope, q_gain,
+                              k_gain, gate, **self._attrs)
         with jax.named_scope("out"):
             return self.out_proj(out)
 
@@ -816,7 +847,9 @@ MIXERS = {
                  "d_conv - 1 rows of q, k and v"),
     "mla": _Kind(LatentAttention.from_spec,
                  "a latent row and one rotary key a token (latent_dim + "
-                 "rope_dim numbers), read through absorbed projections"),
+                 "rope_dim numbers), read through absorbed projections; "
+                 "nothing of the query side, whose latent is made anew a "
+                 "token"),
 }
 KINDS = tuple(MIXERS)
 
@@ -877,11 +910,71 @@ class HybridDecoderBlock(HybridBlock):
         return h + mlp(self.ln2(h))
 
 
+# ``profiler.get_launch_stats("mtp")``: the training forwards that ran a
+# prediction block, and of the newest one its depth, the positions its loss
+# counts and the bytes of its float32 logits
+metrics.register_launch("mtp", ("depth", "positions", "logits_bytes"))
+
+
+class MultiTokenPrediction(HybridBlock):
+    """DeepSeek-V3's multi-token-prediction module of depth 1 (the child
+    ``mtp0`` of ``HybridDecoderLM(mtp_layers=1)``), which predicts token ``i
+    + 2`` at position ``i`` beside the head's ``i + 1``, in training only.
+    With ``g`` the hidden state the head reads (after ``ln_f``): ``e_i =
+    N_e(Emb(x_{i+1}))`` for ``i < T - 1`` and the zero row at ``T - 1``
+    (whose loss is masked), ``Emb`` the TRUNK's table; ``u = W_eh [e ;
+    N_h(g)]`` (``eh_proj``, ``2 units -> units``, no bias); ``w =
+    Block(u)``: one more whole layer of the stack's last layer's kinds,
+    causal over the same positions, with weights, router and selection bias
+    of its own; ``logits2 = Head(N_s(w))`` through the TRUNK's head. Both
+    tables are the trunk's parameters, used twice a step: their gradients
+    are the sums of both uses. Its layer is the child ``block<L>`` (``L`` the
+    trunk's depth), so a device trace reads ``mtp0/embed``, ``mtp0/proj``,
+    ``mtp0/block<L>/<kind>/...`` and ``mtp0/head``."""
+
+    def __init__(self, kind: str, layer_index: int, mlp_kind: str, z: dict,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        units, eps = z["units"], z["eps"]
+        norm = RMSNorm if z["norm"] == "rms" else LayerNorm
+        with self.name_scope():
+            self.enorm = norm(epsilon=eps, in_channels=units)
+            self.hnorm = norm(epsilon=eps, in_channels=units)
+            self.eh_proj = Dense(units, use_bias=False, flatten=False,
+                                 in_units=2 * units)
+            # under the name the trunk would give its next layer
+            self._layer = f"block{layer_index}"
+            setattr(self, self._layer,
+                    HybridDecoderBlock(kind, layer_index, mlp_kind, z))
+            self.norm = norm(epsilon=eps, in_channels=units)
+        mixer = getattr(getattr(self, self._layer), kind)
+        if mixer.reads or mixer.writes:
+            raise ValueError(f"a prediction block of kind {kind!r} would "
+                             f"read or write the trunk's hand-over")
+
+    def forward(self, tokens, g, embedding, logits):
+        """``tokens`` ``(B, T)``, ``g`` ``(B, T, units)``; ``embedding`` and
+        ``logits`` are the trunk's table and head (``HybridDecoderLM
+        ._logits``). Returns ``(B, T, vocab)``."""
+        B, T = tokens.shape
+        with jax.named_scope("embed"):
+            e = embedding(nd.slice_axis(tokens, axis=1, begin=1, end=T))
+            zero_row = nd.NDArray(jnp.zeros((B, 1, e.shape[-1]),
+                                            e.data.dtype))
+            e = self.enorm(nd.concat(e, zero_row, dim=1))
+        with jax.named_scope("proj"):
+            u = self.eh_proj(nd.concat(e, self.hnorm(g), dim=2))
+        w = getattr(self, self._layer)(u, {})
+        with jax.named_scope("head"):
+            return logits(self.norm(w))
+
+
 class HybridDecoderLM(HybridBlock):
     """Decoder LM over token ids from a list of layer kinds (``KINDS``, the
     keys of ``MIXERS``).
 
-    Input ``(B, T)`` int tokens, output ``(B, T, vocab)`` logits; no position
+    Input ``(B, T)`` int tokens, output ``(B, T, vocab)`` logits (and in
+    training the prediction block's, with ``mtp_layers``); no position
     table, so ``T`` is bounded by memory alone. Trains through
     ``DataParallelTrainer`` like ``TransformerLM``. Multiples of 128 in ``T``
     engage the flash kernels, ``d_inner % 128 == 0`` the scan kernels and
@@ -928,9 +1021,16 @@ class HybridDecoderLM(HybridBlock):
     so: ``HybridDecoderBlock.may_remat``).
 
     ``kda_lower_bound`` bounds the ``kda`` kind's log-decay a channel;
-    ``mla`` holds the ``mla`` kind's widths (``latent_dim``, ``nope_dim``,
-    ``rope_dim``, ``v_dim`` and ``interleave`` for the pairing of its rotary
-    positions, whose base is ``rope_theta``).
+    ``mla`` holds the ``mla`` kind's arguments, those of ``LatentAttention``
+    after ``num_heads`` (its docstring lists them; the rotary base is
+    ``rope_theta``).
+
+    ``mtp_layers=1`` adds a multi-token-prediction block (child ``mtp0``,
+    ``MultiTokenPrediction``: one more layer of the last layer's kinds on
+    the final hidden state and the next token's embedding, through the same
+    table and head). In train mode (``autograd.is_training()``) ``forward``
+    then returns ``(logits, logits2)``, for ``gluon.loss.NextTokenLoss``;
+    outside it the block does not run and the output is the logits alone.
     """
 
     def __init__(self, vocab_size: int, layer_kinds, units: int,
@@ -943,8 +1043,11 @@ class HybridDecoderLM(HybridBlock):
                  tie_head: bool = True, mlp_kinds=None, moe=None,
                  float32_logits: bool = False, remat: bool = False,
                  retention_eps=None, kda_lower_bound: float = -5.0,
-                 mla=None, prefix=None, params=None):
+                 mla=None, mtp_layers: int = 0, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        if mtp_layers not in (0, 1):
+            raise ValueError(f"mtp_layers {mtp_layers}: 0 or 1 (deeper "
+                             f"predictions chain their blocks: ROADMAP M7)")
         for what, value, known in (
                 ("attention", attention, ("diff", "gqa")),
                 ("norm", norm, ("layer", "rms")),
@@ -988,6 +1091,9 @@ class HybridDecoderLM(HybridBlock):
                 epsilon=layer_norm_eps, in_channels=units)
             self.head = None if tie_head else Dense(
                 vocab_size, use_bias=False, flatten=False, in_units=units)
+            self.mtp0 = MultiTokenPrediction(
+                self.layer_kinds[-1], len(self.blocks), self.mlp_kinds[-1],
+                z) if mtp_layers else None
 
     def forward(self, tokens):
         B, T = tokens.shape
@@ -1004,6 +1110,18 @@ class HybridDecoderLM(HybridBlock):
             else:
                 h = blk(h, shared)
         h = self.ln_f(h)
+        logits = self._logits(h)
+        if self.mtp0 is None or not autograd.is_training():
+            return logits
+        further = self.mtp0(tokens, h, self.embedding, self._logits)
+        metrics.record_launch(
+            "mtp", depth=1, positions=B * (T - 1),
+            logits_bytes=further.size * further.data.dtype.itemsize)
+        return logits, further
+
+    def _logits(self, h):
+        """The head on normed rows ``h`` ``(B, T, units)``."""
+        B, T, _ = h.shape
         if self.head is not None:
             # float32 logits: in a TPU step with bfloat16 logits XLA adds
             # log_softmax's exponentials (a row of the vocabulary) in a
@@ -1026,7 +1144,11 @@ class HybridDecoderLM(HybridBlock):
             f"HybridDecoderLM.{what}: this family trains only. Decoding "
             f"needs a cache that holds, side by side, for this model's "
             f"layers: {states}; the engine has one cache geometry (ROADMAP "
-            f"D1/D2, M3, M5, M6). Layer kinds: {self.layer_kinds}")
+            f"D1/D2, M3, M5, M6). Layer kinds: {self.layer_kinds}"
+            + ("" if self.mtp0 is None else
+               ". Its prediction block (mtp0) runs in training alone; once "
+               "there is a cache it could draft for speculative decoding "
+               "(ROADMAP M7)"))
 
     def generate(self, *args, **kwargs):
         self._no_decode("generate")

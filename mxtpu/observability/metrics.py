@@ -818,8 +818,16 @@ def register_kernel(kind: str, launch_keys: tuple = ()):
     call site. At import: a reader finds zeros before any launch."""
     with _stats_lock:
         _kernel_paths[kind] = {"pallas": 0, "xla": 0}
-        if launch_keys:
-            _launches[kind] = dict.fromkeys(("launches",) + launch_keys, 0)
+    if launch_keys:
+        register_launch(kind, launch_keys)
+
+
+def register_launch(kind: str, launch_keys: tuple):
+    """A row of ``launch_keys`` for ``kind``'s newest call site WITHOUT a
+    kernel path: for what is traced into a step and is no kernel (the model
+    stack's prediction block, kind ``mtp``)."""
+    with _stats_lock:
+        _launches[kind] = dict.fromkeys(("launches",) + launch_keys, 0)
 
 
 def record_kernel_path(kind: str, pallas: bool):
