@@ -39,7 +39,7 @@ import contextlib
 import functools
 import math
 import threading
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,8 +86,9 @@ def _pick_block(t: int, cap: int = 512) -> int:
 
     Cap 512 measured fastest on v5e at production shapes (B4 H16 T2048 D64
     fwd+bwd: 15.1 ms @128 → 6.7 ms @512, vs 20.7 ms XLA reference); 1024
-    exceeds VMEM and fails to compile. Launch sites scale the cap down with
-    the padded head dim (`_block_cap`) so large-D shapes stay inside VMEM.
+    failed to compile under the 16 MiB of VMEM a program gets when it asks
+    for nothing. Launch sites take their tiles from ``_flash_tiles``, which
+    lowers the cap only where the VMEM bytes of the shapes ask for it.
 
     Raises :exc:`ValueError` when no Mosaic-legal block exists — launch
     sites gate on ``_use_pallas``/``_legal_bucket`` first, so hitting this
@@ -116,13 +117,6 @@ def _pick_block(t: int, cap: int = 512) -> int:
         f"axis, so t must be a multiple of 128, or t <= 128 with "
         f"t % 8 == 0. Pad the sequence (e.g. to {-(-t // 128) * 128}) or "
         f"take the XLA reference path")
-
-
-def _block_cap(dp: int) -> int:
-    """VMEM-aware block cap: 512 validated at Dp=128; scale down linearly in
-    the padded head dim so the per-program tiles stay in the same budget
-    (Dp=256 → 256, Dp≥512 → 128, the previously-validated floor)."""
-    return max(128, 512 * 128 // max(dp, 128))
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -238,9 +232,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
+def _lanes(d: int) -> int:
+    """``d`` rounded up to whole 128-lane registers."""
+    return -(-d // 128) * 128
+
+
 def _pad_d(x):
     d = x.shape[-1]
-    dp = -(-d // 128) * 128
+    dp = _lanes(d)
     if dp == d:
         return x
     return jnp.pad(x, ((0, 0), (0, 0), (0, dp - d)))
@@ -392,14 +391,15 @@ def _flash_bwd_dkv_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _window_params():
+def _window_params(vmem_bytes: int):
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_bytes)
 
 
 def _flash_window_forward(qq, kk, vv, group: int, window: int, scale: float,
-                          block: int, interpret: bool):
+                          block: int, vmem_bytes: int, interpret: bool):
     """Forward launch over flattened, lane-padded ``(B·H, T, Dp)`` q and
     ``(B·Hkv, T, D[v]p)`` k, v; returns padded ``(out, lse (B·H, 8, T))``."""
     from jax.experimental import pallas as pl
@@ -435,7 +435,7 @@ def _flash_window_forward(qq, kk, vv, group: int, window: int, scale: float,
         scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
                         pltpu.VMEM((block, 1), jnp.float32),
                         pltpu.VMEM((block, Dvp), jnp.float32)],
-        compiler_params=_window_params(),
+        compiler_params=_window_params(vmem_bytes),
         name="flash_fwd_window",
         interpret=interpret,
     )(qq, kk, vv)
@@ -443,7 +443,7 @@ def _flash_window_forward(qq, kk, vv, group: int, window: int, scale: float,
 
 def _flash_window_backward(qq, kk, vv, gg, lse, delta, group: int,
                            window: int, scale: float, block: int,
-                           interpret: bool):
+                           vmem_bytes: int, interpret: bool):
     """Backward launches over the flattened, padded operands; dk and dv
     come out per QUERY head."""
     from jax.experimental import pallas as pl
@@ -478,7 +478,7 @@ def _flash_window_backward(qq, kk, vv, gg, lse, delta, group: int,
         out_specs=pl.BlockSpec((1, block, Dp), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, Dp), qq.dtype),
         scratch_shapes=[pltpu.VMEM((block, Dp), jnp.float32)],
-        compiler_params=_window_params(),
+        compiler_params=_window_params(vmem_bytes),
         name="flash_bwd_dq_window",
         interpret=interpret,
     )(qq, kk, vv, gg, lse, delta)
@@ -510,7 +510,7 @@ def _flash_window_backward(qq, kk, vv, gg, lse, delta, group: int,
         ],
         scratch_shapes=[pltpu.VMEM((block, Dp), jnp.float32),
                         pltpu.VMEM((block, Dvp), jnp.float32)],
-        compiler_params=_window_params(),
+        compiler_params=_window_params(vmem_bytes),
         name="flash_bwd_dkv_window",
         interpret=interpret,
     )(qq, kk, vv, gg, lse, delta)
@@ -523,12 +523,116 @@ def _windowed(window, Tk: int) -> bool:
     return window is not None and window < Tk
 
 
+# -- tiles: the largest that the VMEM bytes of the shapes allow --------------
+
+_VMEM_BYTES = 128 << 20     # a v5e core's VMEM
+_VMEM_DEFAULT = 16 << 20    # what a program gets there when it asks for nothing
+# what a flash launch may plan for: three quarters of the chip's, since the
+# reckonings below are estimates and Mosaic wants room of its own beside them
+_VMEM_BUDGET = _VMEM_BYTES // 4 * 3
+
+
+def _fwd_vmem_bytes(Tk: int, Dp: int, Dvp: int, block_q: int, block_k: int,
+                    itemsize: int) -> int:
+    """VMEM the forward program holds, from its shapes: the whole K and V
+    rows and the q / o / lse blocks (two buffers each), the float32 q, k
+    and v tiles, room for a score tile's float32 temporaries (s, p, the
+    mask's iotas) and the carried accumulator with its rescaled copy.
+    Never under the default."""
+    rows = 2 * Tk * (Dp + Dvp) * itemsize
+    blocks = 2 * block_q * (Dp + Dvp) * itemsize + 2 * 8 * block_q * 4
+    wide = (block_q * Dp + block_k * (Dp + Dvp)) * 4
+    temps = 6 * block_q * block_k * 4
+    acc = 2 * block_q * Dvp * 4
+    return max(_VMEM_DEFAULT, rows + blocks + wide + temps + acc + (2 << 20))
+
+
+def _bwd_vmem_bytes(T: int, Dp: int, Dvp: int, block_q: int, block_k: int,
+                    itemsize: int) -> int:
+    """VMEM the backward program holds, from its shapes: the query row's q,
+    dO and dq blocks and the key tile's k, v, dk, dv blocks (two buffers
+    each), the lse / delta rows, the float32 dq accumulator, and room for a
+    score tile's float32 temporaries (s, p, dp, ds, their transposes, the
+    mask's iotas) and the carried dk / dv. Never under the default."""
+    row = 2 * (2 * T * Dp + T * Dvp) * itemsize + 2 * 2 * 8 * T * 4
+    tile = 2 * 2 * block_k * (Dp + Dvp) * itemsize
+    acc = T * Dp * 4
+    temps = 10 * block_q * block_k * 4 \
+        + 4 * (block_q + block_k) * (Dp + Dvp) * 4
+    return max(_VMEM_DEFAULT, row + tile + acc + temps + (2 << 20))
+
+
+def _window_vmem_bytes(Dp: int, Dvp: int, block: int, itemsize: int):
+    """``(forward, backward)`` VMEM of the window launches, which hold
+    blocks alone: q, k, v and o (forward) or q, k, v, dO, dk and dv (the
+    larger backward launch) in two buffers each, the lse / delta blocks, the
+    float32 accumulators, the widened operands and a score tile's
+    temporaries as in the causal launches."""
+    side = block * (Dp + Dvp)
+    rows = 2 * 8 * block * 4
+    fwd = 2 * 2 * side * itemsize + rows + block * Dvp * 4 \
+        + (side + block * Dp) * 4 + 6 * block * block * 4
+    bwd = 2 * 3 * side * itemsize + 2 * rows + side * 4 \
+        + 2 * side * 4 + 10 * block * block * 4
+    return tuple(max(_VMEM_DEFAULT, b + (2 << 20)) for b in (fwd, bwd))
+
+
+class FlashTiles(NamedTuple):
+    """What a call site launches with; the row ``profiler.get_launch_stats``
+    shows under ``flash`` / ``flash_window`` for the newest one."""
+    block_q: int
+    block_k: int
+    dp: int
+    dvp: int
+    fwd_vmem_bytes: int     # the launches' ``vmem_limit_bytes``
+    bwd_vmem_bytes: int
+
+
+def _flash_tiles(T: int, Tk: int, Dp: int, Dvp: int, itemsize: int,
+                 block_q: int = 512, block_k: int = 512,
+                 window=None) -> FlashTiles:
+    """THE rule every flash launch takes its tiles from: the largest legal
+    ``(block_q, block_k)``, both under one cap that starts at the larger of
+    the two asked for (512: ``_pick_block``) and comes down by 128 at a
+    time, whose forward and backward both fit ``_VMEM_BUDGET`` by the
+    reckonings above. A window launch runs square tiles, the smaller of the
+    pair. Never under 128 rows (or the whole of a shorter axis): where even
+    those pass the budget they are taken as long as the chip's VMEM holds
+    them, and a launch that it cannot hold raises (``_takes_kernels`` asks
+    first, so ``flash_chunk`` takes the XLA path there). On the v5e 512 x
+    512 was the fastest of seven pairs up to 1024 x 1024 at q/k 192, v 128
+    (forward + backward 7.2 ms against 9.1 at 256 x 256, B1 H32 T4096) and
+    within 1.2% of the fastest at 128 lanes (PERF.md §5, PR 47)."""
+    windowed = _windowed(window, Tk)
+    for cap in range(max(block_q, block_k, 128), 127, -128):
+        bq = _pick_block(T, min(cap, block_q))
+        bk = _pick_block(Tk, min(cap, block_k))
+        if windowed:
+            bq = bk = min(bq, bk)
+            fwd, bwd = _window_vmem_bytes(Dp, Dvp, bq, itemsize)
+        else:
+            fwd = _fwd_vmem_bytes(Tk, Dp, Dvp, bq, bk, itemsize)
+            bwd = _bwd_vmem_bytes(T, Dp, Dvp, bq, bk, itemsize)
+        need = max(fwd, bwd)
+        if need <= _VMEM_BUDGET:
+            break
+    if need > _VMEM_BYTES:
+        raise ValueError(
+            f"no flash tiles for T={T}, Tk={Tk} at padded widths {Dp} / "
+            f"{Dvp} and {itemsize}-byte operands: the rows a program keeps "
+            f"in VMEM take {need} bytes at the smallest tiles, the chip has "
+            f"{_VMEM_BYTES}. Take the XLA path")
+    return FlashTiles(bq, bk, Dp, Dvp, fwd, bwd)
+
+
 def _flash_attention_pallas(q, k, v, causal: bool, scale: float,
                             block_q: int = 512, block_k: int = 512,
                             interpret: bool = False, window=None):
     """Forward kernel launch; returns (out, lse). q: (B, H, T, D); k:
-    (B, Hkv, Tk, D) with ``H % Hkv == 0``; v: (B, Hkv, Tk, Dv)."""
+    (B, Hkv, Tk, D) with ``H % Hkv == 0``; v: (B, Hkv, Tk, Dv). The tiles
+    are ``_flash_tiles``'s; ``block_q`` / ``block_k`` cap them."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     Hkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[3]
@@ -536,14 +640,17 @@ def _flash_attention_pallas(q, k, v, causal: bool, scale: float,
     qq = _pad_d(q.reshape(B * H, T, D))
     kk = _pad_d(k.reshape(B * Hkv, Tk, D))
     vv = _pad_d(v.reshape(B * Hkv, Tk, Dv))
-    Dp, Dvp = qq.shape[-1], vv.shape[-1]
-    cap = _block_cap(max(Dp, Dvp))
-    block_q = _pick_block(T, min(block_q, cap))
-    block_k = _pick_block(Tk, min(block_k, cap))
-    if _windowed(window, Tk):
+    tiles = _flash_tiles(T, Tk, qq.shape[-1], vv.shape[-1], q.dtype.itemsize,
+                         block_q, block_k, window)
+    block_q, block_k, Dp, Dvp = tiles[:4]
+    windowed = _windowed(window, Tk)
+    # the call site's row: its backward takes the same tiles from the rule
+    metrics.record_launch("flash_window" if windowed else "flash",
+                          **tiles._asdict())
+    if windowed:
         out, lse = _flash_window_forward(
-            qq, kk, vv, group, window, scale, min(block_q, block_k),
-            interpret)
+            qq, kk, vv, group, window, scale, block_q,
+            tiles.fwd_vmem_bytes, interpret)
         return out[..., :Dv].reshape(B, H, T, Dv), lse[:, 0, :]
     grid = (B * H, T // block_q)
     kv = _kv_head(group)
@@ -566,26 +673,12 @@ def _flash_attention_pallas(q, k, v, causal: bool, scale: float,
             jax.ShapeDtypeStruct((B * H, T, Dvp), q.dtype),
             jax.ShapeDtypeStruct((B * H, 8, T), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tiles.fwd_vmem_bytes),
         name="flash_fwd",
         interpret=interpret,
     )(qq, kk, vv)
     return out[..., :Dv].reshape(B, H, T, Dv), lse[:, 0, :]
-
-
-def _bwd_vmem_bytes(T: int, Dp: int, Dvp: int, block_q: int, block_k: int,
-                    itemsize: int) -> int:
-    """VMEM the backward program holds, from its shapes: the query row's q,
-    dO and dq blocks and the key tile's k, v, dk, dv blocks (two buffers
-    each), the lse / delta rows, the float32 dq accumulator, and room for a
-    score tile's float32 temporaries (s, p, dp, ds, their transposes, the
-    mask's iotas) and the carried dk / dv. Never under the 16 MiB a v5e
-    program gets by default; the chip has 128 MiB."""
-    row = 2 * (2 * T * Dp + T * Dvp) * itemsize + 2 * 2 * 8 * T * 4
-    tile = 2 * 2 * block_k * (Dp + Dvp) * itemsize
-    acc = T * Dp * 4
-    temps = 10 * block_q * block_k * 4 \
-        + 4 * (block_q + block_k) * (Dp + Dvp) * 4
-    return max(16 << 20, row + tile + acc + temps + (2 << 20))
 
 
 def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
@@ -596,11 +689,11 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
     key tile) grid whose key axis is sequential; each score tile is visited
     once and feeds dq, dk and dv (``_flash_bwd_kernel``). The query row's q
     and dO stay in VMEM beside a float32 dq accumulator, so the launch asks
-    for the VMEM its shapes need (``_bwd_vmem_bytes``). With a causal
-    window the ``flash_bwd_dq_window`` / ``flash_bwd_dkv_window`` pair runs
-    instead. Shapes as in the forward; with fewer key/value heads than query
-    heads the kernels make dk and dv per query head and a group's are added
-    up here.
+    for the VMEM its shapes need (``_bwd_vmem_bytes``, through
+    ``_flash_tiles``). With a causal window the ``flash_bwd_dq_window`` /
+    ``flash_bwd_dkv_window`` pair runs instead. Shapes as in the forward;
+    with fewer key/value heads than query heads the kernels make dk and dv
+    per query head and a group's are added up here.
 
     ``lse_cot`` (B,H,T): optional cotangent of the log-sum-exp output (ring
     merges differentiate through lse); it folds into the delta term exactly —
@@ -623,11 +716,9 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
     kk = _pad_d(k.reshape(B * Hkv, Tk, D))
     vv = _pad_d(v.reshape(B * Hkv, Tk, Dv))
     gg = _pad_d(g.reshape(B * H, T, Dv))
-    Dp, Dvp = qq.shape[-1], vv.shape[-1]
-    # same padded-D cap as the forward (blocks must match its VMEM budget)
-    cap = _block_cap(max(Dp, Dvp))
-    block_q = _pick_block(T, min(block_q, cap))
-    block_k = _pick_block(Tk, min(block_k, cap))
+    tiles = _flash_tiles(T, Tk, qq.shape[-1], vv.shape[-1], q.dtype.itemsize,
+                         block_q, block_k, window)
+    block_q, block_k, Dp, Dvp = tiles[:4]
     kv = _kv_head(group)
 
     def unflatten(dq, dk, dv):
@@ -637,8 +728,8 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
 
     if _windowed(window, Tk):
         return unflatten(*_flash_window_backward(
-            qq, kk, vv, gg, lse, delta, group, window, scale,
-            min(block_q, block_k), interpret))
+            qq, kk, vv, gg, lse, delta, group, window, scale, block_q,
+            tiles.bwd_vmem_bytes, interpret))
 
     return unflatten(*pl.pallas_call(
         functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
@@ -666,8 +757,7 @@ def _flash_backward_pallas(q, k, v, o, lse, g, causal: bool, scale: float,
         scratch_shapes=[pltpu.VMEM((T, Dp), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_bytes(
-                T, Dp, Dvp, block_q, block_k, q.dtype.itemsize)),
+            vmem_limit_bytes=tiles.bwd_vmem_bytes),
         name="flash_bwd_fused",
         interpret=interpret,
     )(qq, kk, vv, gg, lse, delta))
@@ -754,12 +844,20 @@ def _use_pallas(q, k) -> bool:
             and q.shape[1] % k.shape[1] == 0)
 
 
-def _takes_kernels(q, k, v) -> bool:
-    return _use_pallas(q, k) and v.shape[3] <= 512
+def _takes_kernels(q, k, v, window=None) -> bool:
+    """The shapes the kernels take, whose rows the chip's VMEM can hold."""
+    if not (_use_pallas(q, k) and v.shape[3] <= 512):
+        return False
+    try:
+        _flash_tiles(q.shape[2], k.shape[2], _lanes(q.shape[3]),
+                     _lanes(v.shape[3]), q.dtype.itemsize, window=window)
+    except ValueError:
+        return False
+    return True
 
 
-metrics.register_kernel("flash")
-metrics.register_kernel("flash_window")
+metrics.register_kernel("flash", FlashTiles._fields)
+metrics.register_kernel("flash_window", FlashTiles._fields)
 
 
 def _count_path(k, window, pallas: bool) -> bool:
@@ -823,7 +921,7 @@ def flash_chunk(q, k, v, causal, scale, window=None):
     cotangents (out and lse), so lse-merges differentiate exactly.
     ``window`` (causal only): key ``j`` is visible to query ``i`` iff
     ``0 <= i - j < window``."""
-    if _count_path(k, window, _takes_kernels(q, k, v)):
+    if _count_path(k, window, _takes_kernels(q, k, v, window)):
         def local(q, k, v):
             out, lse = _flash_attention_pallas(q, k, v, causal, scale,
                                                window=window)
@@ -840,7 +938,7 @@ def _flash_chunk_fwd(q, k, v, causal, scale, window):
 def _flash_chunk_bwd(causal, scale, window, res, cots):
     q, k, v, out, lse = res
     g_o, g_lse = cots
-    if _takes_kernels(q, k, v):
+    if _takes_kernels(q, k, v, window):
         def local(q, k, v, out, lse, g_o, g_lse):
             B, H, T, _ = q.shape
             return _flash_backward_pallas(

@@ -13,7 +13,7 @@ execution paths:
   structure as ``attention.py``'s forward, specialised to the one-query
   decode shape). The per-row f32 scales ride as an 8-sublane broadcast
   (Mosaic's row-block tiling rule, see ``_flash_fwd_kernel``'s lse); block
-  legality reuses ``_pick_block``/``_block_cap``. The full-precision KV
+  legality reuses ``_pick_block``. The full-precision KV
   view never exists anywhere — not in HBM, not in VMEM.
 * **xla** — the A/B + CPU/interpret fallback. No Pallas, but the scales
   fold into the einsums as per-row scalars (``q . (data*s) == (q . data)*s``
@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .attention import _NEG_INF, _block_cap, _pick_block
+from .attention import _NEG_INF, _lanes, _pick_block
 
 __all__ = ["decode_kernel_mode", "resolve_decode_kernel",
            "dequant_attention_decode"]
@@ -163,8 +163,11 @@ def _decode_pallas(q, kd, ks, vd, vs, pc, scale: float, interpret: bool):
 
     S, H, TOT, D = kd.shape
     BH = S * H
-    dp = -(-D // 128) * 128
-    block_t = _pick_block(TOT, _block_cap(dp))
+    dp = _lanes(D)
+    # one query row: the float32 k and v tiles are (block_t, dp), 2 MiB
+    # together at the widest head (512 lanes) and 512 positions, so no
+    # width asks for a smaller tile
+    block_t = _pick_block(TOT)
     q8 = _pad_last(jnp.broadcast_to(q.reshape(BH, 1, D), (BH, 8, D)), dp)
     kd2 = _pad_last(kd.reshape(BH, TOT, D), dp)
     vd2 = _pad_last(vd.reshape(BH, TOT, D), dp)

@@ -162,6 +162,118 @@ def test_flash_one_pass_backward_matches_reference(H, Hkv, T, Tk, D, Dv,
             atol=tol * float(jnp.max(jnp.abs(b))), err_msg="d" + name)
 
 
+MIB = 1 << 20
+
+# (T, Dp, Dvp, itemsize, window) -> (block_q, block_k). The first seven are
+# what the benchmark's cells launch (``brumby_train_t8192`` launches none):
+# 512 x 512 everywhere, at the latent widths (q/k 192 in 256 lanes, v 128) as
+# at 128 lanes. Then float32 chunks (``parallel/ring_attention.py``), 384 and
+# 512 lanes, and rows long or wide enough that the bytes bring the tiles down
+TILE_CASES = [
+    ((1024, 128, 128, 2, None), (512, 512)),    # gpt2m_train_t1024
+    ((2048, 128, 128, 2, None), (512, 512)),    # cgpt13_train_t2048
+    ((8192, 128, 128, 2, None), (512, 512)),    # phi4flash_train_t8192
+    ((8192, 128, 128, 2, 512), (512, 512)),     # ... its window layers
+    ((4096, 128, 128, 2, None), (512, 512)),    # kexaone / lfm2moe
+    ((4096, 128, 128, 2, 128), (512, 512)),     # kexaone's window layers
+    ((4096, 256, 128, 2, None), (512, 512)),    # lingflash / joyai
+    ((2048, 128, 128, 4, None), (512, 512)),
+    ((4096, 256, 128, 4, None), (512, 512)),
+    ((4096, 384, 384, 2, None), (512, 512)),
+    ((4096, 512, 512, 2, None), (512, 512)),
+    ((4096, 512, 512, 4, None), (512, 512)),
+    ((4096, 512, 512, 4, 128), (512, 512)),
+    ((8192, 512, 512, 2, None), (256, 256)),
+    ((16384, 384, 384, 2, None), (128, 128)),
+    ((8192, 512, 512, 4, None), (128, 128)),
+    ((1536, 128, 128, 2, None), (512, 512)),
+    ((640, 128, 128, 2, None), (128, 128)),     # 128 alone divides 640
+    ((64, 128, 128, 4, None), (64, 64)),        # a whole short axis
+]
+
+
+@pytest.mark.parametrize("shape,want", TILE_CASES)
+def test_flash_tiles_follow_the_vmem_bytes_of_the_shapes(shape, want):
+    """One rule for every launch: the largest legal tiles up to 512 whose
+    reckoned VMEM fits the budget; never under 128 rows of a long axis,
+    never a limit under the default or over the chip's VMEM."""
+    from mxtpu.ops import attention as A
+    T, Dp, Dvp, itemsize, window = shape
+    tiles = A._flash_tiles(T, T, Dp, Dvp, itemsize, window=window)
+    assert (tiles.block_q, tiles.block_k) == want
+    assert (tiles.dp, tiles.dvp) == (Dp, Dvp)
+    assert T % tiles.block_q == 0 and tiles.block_q >= min(T, 128)
+    for limit in (tiles.fwd_vmem_bytes, tiles.bwd_vmem_bytes):
+        assert A._VMEM_DEFAULT <= limit <= A._VMEM_BYTES
+    if want == (512, 512):
+        assert max(tiles[4:]) <= A._VMEM_BUDGET
+    # a caller's cap still holds (the tests' 128-row tiles)
+    capped = A._flash_tiles(T, T, Dp, Dvp, itemsize, 128, 128, window)
+    assert capped.block_q == capped.block_k == min(want[0], 128)
+
+
+def test_flash_tiles_reckon_todays_backward_bytes_and_refuse_what_no_chip_holds(
+        monkeypatch):
+    from mxtpu.ops import attention as A
+    # the backward's limit at 128 lanes, as the launch asked since PR 29
+    for T, want in ((1024, 20054016), (2048, 22282240), (4096, 26738688),
+                    (8192, 35651584)):
+        tiles = A._flash_tiles(T, T, 128, 128, 2)
+        assert tiles.bwd_vmem_bytes == want \
+            == A._bwd_vmem_bytes(T, 128, 128, 512, 512, 2)
+    # the forward, which asked for nothing and got the default: its bytes
+    # grow with the K and V rows
+    assert A._flash_tiles(1024, 1024, 128, 128, 2).fwd_vmem_bytes \
+        == A._VMEM_DEFAULT
+    assert A._fwd_vmem_bytes(8192, 128, 128, 512, 512, 2) \
+        > A._fwd_vmem_bytes(4096, 128, 128, 512, 512, 2) > 8 * MIB
+    # rows that no tile makes fit: the launch raises, the op takes XLA
+    with pytest.raises(ValueError, match="no flash tiles"):
+        A._flash_tiles(32768, 32768, 512, 512, 4)
+    q = jax.ShapeDtypeStruct((1, 2, 32768, 512), jnp.float32)
+    small = jax.ShapeDtypeStruct((1, 2, 4096, 192), jnp.bfloat16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not A._takes_kernels(q, q, q)
+    assert A._takes_kernels(small, small, small)
+
+
+@pytest.mark.parametrize("block", [256, 512])
+def test_flash_at_the_latent_widths_matches_reference(block):
+    """q/k 192 wide (256 lanes), v 128, causal, T = 1024: four or two tiles
+    a side, so both sizes cross a tile edge and the diagonal. Forward and
+    the three gradients against the XLA reference, and the counter's row."""
+    from mxtpu import profiler
+    from mxtpu.ops.attention import _flash_backward_pallas
+    rs = np.random.RandomState(192 + block)
+    q, k, v, g = (jnp.asarray(rs.randn(*shape).astype(np.float32))
+                  for shape in ((1, 4, 1024, 192), (1, 4, 1024, 192),
+                                (1, 4, 1024, 128), (1, 4, 1024, 128)))
+    scale = 1.0 / np.sqrt(192)
+    profiler.reset_launch_stats("flash")
+    assert set(profiler.get_launch_stats("flash").values()) == {0}
+    out, lse = _flash_attention_pallas(q, k, v, True, scale, block, block,
+                                       interpret=True)
+    row = profiler.get_launch_stats("flash")
+    assert (row["launches"], row["block_q"], row["block_k"], row["dp"],
+            row["dvp"]) == (1, block, block, 256, 128)
+    assert row["bwd_vmem_bytes"] >= row["fwd_vmem_bytes"] >= 16 * MIB
+    got = _flash_backward_pallas(q, k, v, out, lse, g, True, scale, block,
+                                 block, interpret=True)
+    # the backward takes the call site's tiles and records no row of its own
+    assert profiler.get_launch_stats("flash") == row
+    (want_out, want_lse), vjp = jax.vjp(
+        lambda *a: _reference_out_lse(*a, True, scale), q, k, v)
+    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse.reshape(1, 4, 1024), want_lse, rtol=2e-5,
+                               atol=2e-5)
+    for name, a, b in zip("qkv", got, vjp((g, jnp.zeros((1, 4, 1024))))):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-3,
+            atol=1e-4 * float(jnp.max(jnp.abs(b))), err_msg="d" + name)
+    profiler.reset_launch_stats("flash")
+    assert set(profiler.get_launch_stats("flash").values()) == {0}
+
+
 def _count_primitive(jaxpr, name: str) -> int:
     """Equations called ``name`` in ``jaxpr`` and every jaxpr nested in it."""
     n = 0

@@ -142,6 +142,31 @@ def test_flash_kernels_compile_at_the_conv_cells_size(one_chip, quiet_cache):
     assert "flash_fwd" in text and "flash_bwd_fused" in text
 
 
+def test_flash_kernels_compile_at_the_latent_cells_size(one_chip, quiet_cache):
+    """32 heads, q/k 192 wide (256 lanes) on a value of 128, T = 4096: a
+    latent-attention layer of ``joyai_train_t4096`` / ``lingflash_train_t4096``
+    on the 512 x 512 tiles its VMEM bytes allow (a rule that read the padded
+    key width gave it 256 until PR 47)."""
+    from mxtpu import profiler
+    from mxtpu.ops import attention as A
+    bf = jnp.bfloat16
+    q, k, v, g = _avals(one_chip, ((1, 32, 4096, 192), bf),
+                        ((1, 32, 4096, 192), bf), ((1, 32, 4096, 128), bf),
+                        ((1, 32, 4096, 128), bf))
+    scale = 192 ** -0.5
+
+    def both(q, k, v, g):
+        out, lse = A._flash_attention_pallas(q, k, v, True, scale)
+        return A._flash_backward_pallas(q, k, v, out, lse, g, True, scale)
+
+    profiler.reset_launch_stats("flash")
+    text = jax.jit(both).lower(q, k, v, g).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    row = profiler.get_launch_stats("flash")
+    assert row["block_q"] == row["block_k"] == 512
+    assert (row["dp"], row["dvp"]) == (256, 128)
+
+
 @pytest.mark.parametrize("K,N", [(2048, 3584), (1792, 2048)])
 def test_grouped_matmul_kernels_compile_at_the_conv_cells_size(
         one_chip, quiet_cache, K, N):
