@@ -20,10 +20,18 @@ for each (row tile, group) pair that shares rows, at most ``M / tile + G``
 of them, handed to the kernels as prefetched scalars that steer the block
 index maps. Row tiles past the last group get no item, so the time follows
 the rows there are and not the buffer; a tile that two groups share is
-visited once for each and each visit stores its own rows. Everywhere else,
-and for shapes the kernels do not take, the products are ``lax.ragged_dot``
-/ ``lax.ragged_dot_general``. Which path a call site took is counted as the
-kind ``grouped_matmul`` (``profiler.get_kernel_path_counts()``).
+visited once for each and each visit stores its own rows. What a visit
+costs follows the rows of ITS group in the tile: it multiplies the 128-row
+blocks of a window that starts at the group's first row there (rounded
+down to the sublane packing), not the whole tile, and ``moe_gmm`` holds the
+whole of K beside a column tile sized from the VMEM bytes (``_gmm_tiles``),
+so a group's weights are fetched once a column tile whatever its visits.
+``mxu_rows`` counts the rows one launch multiplies by the same arithmetic
+(``SparseExperts.stats()["tile_fill"]`` is the pairs over it). Everywhere
+else, and for shapes the kernels do not take, the products are
+``lax.ragged_dot`` / ``lax.ragged_dot_general``. Which path a call site took
+is counted as the kind ``grouped_matmul``
+(``profiler.get_kernel_path_counts()``).
 """
 
 from __future__ import annotations
@@ -32,20 +40,65 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..observability import metrics
 from .registry import register
 
-__all__ = ["grouped_matmul", "grouped_matmul_grads"]
+__all__ = ["grouped_matmul", "grouped_matmul_grads", "mxu_rows"]
 
-_ROW_TILE = 256      # rows of x a work item multiplies
-_TILE = 2048         # the K and N tiles, where they divide
+_ROW_TILE = 1024     # rows of x a work item has in VMEM
+_SUB = 128           # ... of which it multiplies blocks of so many: the MXU's height
+_TILE = 2048         # the most columns of a tile, and of ``moe_tgmm``'s K tile
+_VMEM_BUDGET = 48 << 20   # what a launch may plan for of a v5e core's 128 MiB
 
 
 # ---------------------------------------------------------------------------
-# the work list
+# the work list, and what a visit costs
 # ---------------------------------------------------------------------------
+
+
+def _spans(sizes, tm: int, xp):
+    """``(starts, ends, first, tiles)`` of every group, with ``xp`` (NumPy
+    or ``jnp``): its rows ``[start, end)``, its first row tile and how many
+    row tiles hold a row of it, 0 for an empty group."""
+    ends = xp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    tiles = xp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    return starts, ends, first, tiles
+
+
+def _align(itemsize: int) -> int:
+    """Rows of one packed sublane tile: 8 float32, 16 bfloat16."""
+    return 32 // itemsize
+
+
+def _blocks(lo, hi, align: int):
+    """How many ``_SUB``-row blocks a visit multiplies whose group has rows
+    ``[lo, hi)`` of the tile: those of a window that starts at ``lo``
+    rounded down to the sublane packing ``align``. Integer arithmetic
+    alone, so the kernels (traced scalars) and the counter (NumPy) share
+    it."""
+    return (hi - lo + lo % align + _SUB - 1) // _SUB
+
+
+def mxu_rows(group_sizes, m: int, itemsize: int = 2) -> int:
+    """Rows the MXU multiplies in ONE ``moe_gmm`` launch over a buffer of
+    ``m`` rows whose groups have ``group_sizes`` rows (concrete numbers):
+    every visit's blocks, by the arithmetic the work list and the kernels
+    run on. Never under ``sum(group_sizes)``; the pairs over it is the
+    share of the multiplied rows that are somebody's."""
+    sizes = np.asarray(group_sizes, np.int64)
+    tm, align = _pick(m, _ROW_TILE), _align(itemsize)
+    starts, ends, first, tiles = _spans(sizes, tm, np)
+    lo, hi = starts - first * tm, ends - (first + tiles - 1) * tm
+    alone = _blocks(lo, hi, align)
+    across = (_blocks(lo, tm, align) + _blocks(0, hi, align)
+              + (tiles - 2) * (tm // _SUB))
+    blocks = np.where(tiles == 1, alone, np.where(tiles > 1, across, 0))
+    return int(blocks.sum()) * _SUB
 
 
 def _work_list(group_sizes, m: int, tm: int, visit_empty: bool):
@@ -57,12 +110,8 @@ def _work_list(group_sizes, m: int, tm: int, visit_empty: bool):
     has to be written as zeros). The lists are ``m // tm + G`` long, the
     most there can be; past ``n_work`` they repeat the last item."""
     G = group_sizes.shape[0]
-    sizes = group_sizes.astype(jnp.int32)
-    ends = jnp.cumsum(sizes)
-    starts = ends - sizes
-    first = starts // tm
-    last = jnp.where(sizes > 0, (ends - 1) // tm, first - 1)
-    tiles = last - first + 1                       # 0 for an empty group
+    starts, ends, first, tiles = _spans(group_sizes.astype(jnp.int32), tm,
+                                        jnp)
     if visit_empty:
         tiles = jnp.maximum(tiles, 1)
         first = jnp.minimum(first, m // tm - 1)
@@ -87,60 +136,140 @@ def _pick(n: int, cap: int) -> int:
     return t
 
 
+def _gmm_vmem_bytes(tm: int, tk: int, tn: int, k: int, itemsize: int) -> int:
+    """What a ``moe_gmm`` program keeps in VMEM: the row tile, the weight
+    block and the output tile, each twice (the pipeline's two buffers),
+    an output tile's worth of float32 for the blocks' products before they
+    are stored and whatever else Mosaic keeps, and the accumulator where K
+    is more than one step."""
+    return (2 * (tm * tk + tk * tn + tm * tn) * itemsize + tm * tn * 4
+            + (tm * tn * 4 if tk < k else 0))
+
+
+def _gmm_tiles(m: int, k: int, n: int, itemsize: int):
+    """``(tm, tk, tn, vmem bytes)`` of a ``moe_gmm`` launch, from its
+    shapes: the K tile is the WHOLE of K wherever a column tile of 512 (or
+    all of a narrower N) beside it fits ``_VMEM_BUDGET``, the column tile
+    then the widest under ``_TILE`` that does. The weight block's index is
+    then ``(g, 0, n)`` in every visit of a group, so the pipeline fetches a
+    group's weights once a column tile, not once a visit (at K 6144 in
+    three steps each visit fetched its 8.4 MB tiles again). A K too long
+    for that is halved until it fits, and accumulated over its steps."""
+    tm = _pick(m, _ROW_TILE)
+    tk = k
+    while True:
+        for tn in range(_pick(n, _TILE), 127, -128):
+            need = _gmm_vmem_bytes(tm, tk, tn, k, itemsize)
+            if n % tn == 0 and (need <= _VMEM_BUDGET or tn <= 512):
+                break
+        if need <= _VMEM_BUDGET or tk % 256:
+            return tm, tk, tn, need
+        tk //= 2
+
+
 # ---------------------------------------------------------------------------
 # kernels
 # ---------------------------------------------------------------------------
 
 
-def _gmm_kernel(group_of, tile_of, starts, ends, x_ref, w_ref, o_ref,
-                acc_ref, *, tm: int, transpose_rhs: bool):
-    """Work item ``i`` of column tile ``n``, ``k`` innermost: row tile
-    ``tile_of[i]`` times ``w[group_of[i]]``, stored for the rows of the tile
-    that are that group's. The output block stays resident while the tile
-    does, so the rows an earlier group stored are still there."""
+def _visit(i, group_of, tile_of, starts, ends, tm: int, align: int):
+    """Of work item ``i``: its group's rows ``[lo, hi)`` within its tile,
+    and the window of ``_SUB``-row blocks that holds them as ``(first row,
+    blocks)``: from ``lo`` rounded down to the sublane packing, moved up
+    where it would pass the tile's end; no block for an empty group's
+    item."""
+    g = group_of[i]
+    base = tile_of[i] * tm
+    lo = jnp.maximum(starts[g] - base, 0)
+    hi = jnp.minimum(ends[g] - base, tm)
+    blocks = jnp.where(hi > lo, _blocks(lo, hi, align), 0)
+    return lo, hi, jnp.minimum(lo - lo % align, tm - blocks * _SUB), blocks
+
+
+def _each_block(first, blocks, align: int, body):
+    """``body(rows, at)`` for each block of a visit's window, ``rows`` the
+    block as a slice from row ``at`` of the tile. A loop with a traced
+    extent: its body is one block's product whatever the tile."""
     from jax.experimental import pallas as pl
 
-    i, k = pl.program_id(1), pl.program_id(2)
+    def turn(j, _):
+        at = pl.multiple_of(first + j * _SUB, align)
+        body(pl.ds(at, _SUB), at)
+        return 0
 
-    @pl.when(k == 0)
-    def _():
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    lax.fori_loop(0, blocks, turn, 0)
 
+
+def _gmm_kernel(group_of, tile_of, starts, ends, x_ref, w_ref, o_ref,
+                *acc_ref, tm: int, align: int, transpose_rhs: bool):
+    """Work item ``i`` of column tile ``n``, ``k`` innermost: the blocks of
+    row tile ``tile_of[i]`` that hold a row of group ``group_of[i]`` times
+    ``w[group_of[i]]``, stored for the rows that are that group's. The
+    output block stays resident while the tile does, so the rows an earlier
+    group stored are still there."""
+    from jax.experimental import pallas as pl
+
+    i, k, last_k = pl.program_id(1), pl.program_id(2), pl.num_programs(2) - 1
+    lo, hi, first, blocks = _visit(i, group_of, tile_of, starts, ends, tm,
+                                   align)
     dims = (((1,), (1,)), ((), ())) if transpose_rhs \
         else (((1,), (0,)), ((), ()))
-    acc_ref[...] += lax.dot_general(x_ref[...], w_ref[0], dims,
-                                    preferred_element_type=jnp.float32)
 
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _():
-        g = group_of[i]
-        rows = tile_of[i] * tm + lax.broadcasted_iota(
-            jnp.int32, acc_ref.shape, 0)
-        mine = (rows >= starts[g]) & (rows < ends[g])
-        o_ref[...] = jnp.where(mine, acc_ref[...].astype(o_ref.dtype),
-                               o_ref[...])
+    def body(rows, at):
+        part = lax.dot_general(x_ref[rows, :], w_ref[0], dims,
+                               preferred_element_type=jnp.float32)
+        r = at + lax.broadcasted_iota(jnp.int32, part.shape, 0)
+
+        def store(acc):
+            o_ref[rows, :] = jnp.where((r >= lo) & (r < hi),
+                                       acc.astype(o_ref.dtype),
+                                       o_ref[rows, :])
+
+        if not acc_ref:                 # K in one step: nothing to add up
+            return store(part)
+        acc, = acc_ref
+
+        @pl.when(k == 0)
+        def _():
+            acc[rows, :] = part
+
+        @pl.when(k > 0)
+        def _():
+            acc[rows, :] += part
+
+        @pl.when(k == last_k)
+        def _():
+            store(acc[rows, :])
+
+    _each_block(first, blocks, align, body)
 
 
 def _tgmm_kernel(group_of, tile_of, starts, ends, x_ref, dy_ref, o_ref,
-                 acc_ref, *, tm: int):
+                 acc_ref, *, tm: int, align: int):
     """Work item ``i`` (innermost) of the ``(k, n)`` tile of ``dw``: the
     rows of tile ``tile_of[i]`` that are group ``group_of[i]``'s, ``x^T dy``
-    added up over the group's items and stored with its last."""
+    over the blocks that hold them, added up over the group's items and
+    stored with its last."""
     from jax.experimental import pallas as pl
 
     i, last_item = pl.program_id(2), pl.num_programs(2) - 1
     g = group_of[i]
+    lo, hi, first, blocks = _visit(i, group_of, tile_of, starts, ends, tm,
+                                   align)
 
     @pl.when((i == 0) | (group_of[jnp.maximum(i - 1, 0)] != g))
     def _():
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    x = x_ref[...]
-    rows = tile_of[i] * tm + lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    x = jnp.where((rows >= starts[g]) & (rows < ends[g]), x,
-                  jnp.zeros_like(x))
-    acc_ref[...] += jnp.dot(x.T, dy_ref[...],
-                            preferred_element_type=jnp.float32)
+    def body(rows, at):
+        x = x_ref[rows, :]
+        r = at + lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        x = jnp.where((r >= lo) & (r < hi), x, jnp.zeros_like(x))
+        acc_ref[...] += lax.dot_general(
+            x, dy_ref[rows, :], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _each_block(first, blocks, align, body)
 
     @pl.when((i == last_item)
              | (group_of[jnp.minimum(i + 1, last_item)] != g))
@@ -155,6 +284,12 @@ def _params(semantics, vmem_bytes: int):
         vmem_limit_bytes=max(32 << 20, vmem_bytes + (8 << 20)))
 
 
+# A launch is jitted so that it is traced and lowered ONCE for its shapes,
+# however many layers call it, and ``inline`` so that the step's jaxpr holds
+# the launch itself: behind a ``call`` XLA added a copy a call site (40 in
+# ``joyai_train_t4096``'s step, 6% of it; PERF.md section 6, PR 48).
+@functools.partial(jax.jit, static_argnames=("transpose_rhs", "interpret"),
+                   inline=True)
 def _gmm_pallas(x, w, group_sizes, transpose_rhs: bool = False,
                 interpret: bool = False):
     """``x`` (M, K) by ``w`` (G, K, N), or (G, N, K) with
@@ -164,7 +299,8 @@ def _gmm_pallas(x, w, group_sizes, transpose_rhs: bool = False,
 
     M, K = x.shape
     N = w.shape[1] if transpose_rhs else w.shape[2]
-    tm, tk, tn = _pick(M, _ROW_TILE), _pick(K, _TILE), _pick(N, _TILE)
+    item = x.dtype.itemsize
+    tm, tk, tn, vmem = _gmm_tiles(M, K, N, item)
     *work, n_items = _work_list(group_sizes, M, tm, visit_empty=False)
     if transpose_rhs:
         w_spec = pl.BlockSpec(
@@ -172,9 +308,9 @@ def _gmm_pallas(x, w, group_sizes, transpose_rhs: bool = False,
     else:
         w_spec = pl.BlockSpec(
             (1, tk, tn), lambda n, i, k, g_of, *_: (g_of[i], k, n))
-    item = x.dtype.itemsize
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, tm=tm, transpose_rhs=transpose_rhs),
+        functools.partial(_gmm_kernel, tm=tm, align=_align(item),
+                          transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(N // tn, n_items, K // tk),
@@ -184,16 +320,17 @@ def _gmm_pallas(x, w, group_sizes, transpose_rhs: bool = False,
                 w_spec],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda n, i, k, g_of, t_of, *_: (t_of[i], n)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]
+            if tk < K else []),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=_params(
-            ("parallel", "arbitrary", "arbitrary"),
-            2 * (tm * tk + tk * tn + tm * tn) * item + tm * tn * 4),
+            ("parallel", "arbitrary", "arbitrary"), vmem),
         name="moe_gmm",
         interpret=interpret,
     )(*work, x, w)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
 def _tgmm_pallas(x, dy, group_sizes, interpret: bool = False):
     """``dw[g] = x_g^T dy_g``: ``x`` (M, K), ``dy`` (M, N) -> (G, K, N)."""
     from jax.experimental import pallas as pl
@@ -204,7 +341,7 @@ def _tgmm_pallas(x, dy, group_sizes, interpret: bool = False):
     *work, n_items = _work_list(group_sizes, M, tm, visit_empty=True)
     item = x.dtype.itemsize
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm),
+        functools.partial(_tgmm_kernel, tm=tm, align=_align(item)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(K // tk, N // tn, n_items),
@@ -219,7 +356,7 @@ def _tgmm_pallas(x, dy, group_sizes, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((G, K, N), x.dtype),
         compiler_params=_params(
             ("parallel", "parallel", "arbitrary"),
-            2 * (tm * tk + tm * tn + tk * tn) * item + tk * tn * 4),
+            2 * (tm * tk + tm * tn + tk * tn) * item + 2 * tk * tn * 4),
         name="moe_tgmm",
         interpret=interpret,
     )(*work, x, dy)

@@ -40,7 +40,8 @@ from .. import autograd
 from ..gluon.block import HybridBlock
 from ..gluon.nn.basic_layers import SwiGLU
 from ..ops import registry
-from ..ops.grouped_matmul import grouped_matmul, grouped_matmul_grads
+from ..ops.grouped_matmul import (grouped_matmul, grouped_matmul_grads,
+                                  mxu_rows)
 from .collectives import all_to_all_array, shard_map_compat
 from .mesh import Mesh, get_default_mesh
 
@@ -647,13 +648,23 @@ class SparseExperts(HybridBlock):
         (2 ffn + units)`` numbers of the layer's dtype, where every expert
         is held (its pairs take one pass whatever the routing, and its
         backward multiplies nothing a second time) and 0 for a share (its
-        backward runs its passes again: ``_held_experts``). All five None
-        before the first forward. Reads ``count`` from the device: ask
-        between steps, not inside a timed loop."""
+        backward runs its passes again: ``_held_experts``); the
+        ``mxu_rows``: the rows the MXU multiplies in ONE grouped product
+        over those pairs (``ops.grouped_matmul.mxu_rows``: every visit of a
+        row tile by a group costs the 128-row blocks that hold the group's
+        rows there, so rows of OTHER groups in those blocks are multiplied
+        and thrown away), summed over the passes, and ``tile_fill`` =
+        ``pairs / mxu_rows``, the share of the multiplied rows that are
+        somebody's (1 where every group starts and ends on a block's edge;
+        0 with no pairs). All seven None before the first forward. Reads
+        ``count`` from the device: ask between steps, not inside a timed
+        loop."""
         count = self.count.data().asnumpy()
         load = count[list(self.held)]
         pairs, even = float(load.sum()), float(count.sum()) / count.size
         tile = self._rows and _row_tile(self._rows)
+        itemsize = jnp.dtype(self.gate_up.dtype).itemsize
+        multiplied = self._rows and self._mxu_rows(load, itemsize)
         all_held = len(self.held) == self._experts
         by_gathers = all_held or min(self._top_k, len(self.held)) == 1
         _, units, ffn2 = self.gate_up.shape
@@ -668,9 +679,21 @@ class SparseExperts(HybridBlock):
                 "rows_added": tile and (
                     0 if by_gathers else int(pairs - load.max())),
                 "kept_bytes": self._rows and (
-                    self._rows * (ffn2 + units)
-                    * jnp.dtype(self.gate_up.dtype).itemsize
-                    if all_held else 0)}
+                    self._rows * (ffn2 + units) * itemsize
+                    if all_held else 0),
+                "mxu_rows": multiplied,
+                "tile_fill": multiplied and pairs / multiplied}
+
+    def _mxu_rows(self, load, itemsize: int) -> int:
+        """``mxu_rows`` of the held experts' ``load``, each pass's share of
+        the sorted pairs cut out as ``_pass_rows`` cuts it."""
+        ends = np.cumsum(load.astype(np.int64))
+        starts = np.concatenate([[0], ends[:-1]])
+        return sum(
+            mxu_rows(np.clip(ends, lo, lo + self._rows)
+                     - np.clip(starts, lo, lo + self._rows),
+                     self._rows, itemsize)
+            for lo in range(0, max(int(ends[-1]), 1), self._rows))
 
 
 # ---------------------------------------------------------------------------

@@ -274,7 +274,9 @@ def get_moe_stats(block) -> list:
     them that were added onto their tokens with repeated indices: 0 for a
     layer that holds every expert, which sums by gathers) and the ``kept_bytes`` (what of a pass the layer keeps for its
     backward beyond its input and routing: its two products where every expert is held, 0 for a share, whose backward
-    multiplies them again). The numbers are a state of the layer (``count``, one float an
+    multiplies them again), and ``mxu_rows`` (the rows the MXU multiplies in one grouped product over those pairs: a
+    group's visit to a row tile costs the 128-row blocks that hold its rows there) with ``tile_fill`` = ``pairs /
+    mxu_rows``, the share of them that are somebody's. The numbers are a state of the layer (``count``, one float an
     expert) that rides the compiled step, so there is nothing to reset;
     reading them fetches it from the device: ask between steps."""
     from .parallel.moe import SparseExperts

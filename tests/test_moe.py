@@ -513,6 +513,52 @@ def test_rows_moved_follows_the_pairs_and_not_the_buffer(held, tokens):
                 <= row["rows_moved"]
 
 
+@pytest.mark.parametrize("held,tokens", [(None, 96), ([4, 5, 6, 7], 96),
+                                         ([3], 4096), ([2, 9], 1024)])
+def test_tile_fill_is_the_pairs_over_the_rows_the_mxu_multiplies(held,
+                                                                  tokens):
+    """``stats()["mxu_rows"]`` is ``ops.grouped_matmul.mxu_rows`` of the held
+    experts' counts over the layer's buffer (a count by the kernels' own
+    arithmetic, whichever path the products took), ``tile_fill`` the pairs
+    over it; both None before a forward, ``profiler.get_moe_stats`` carries
+    them."""
+    from mxtpu import nd, profiler
+    from mxtpu.ops.grouped_matmul import mxu_rows
+    blk = moe.SparseExperts(32, 48, 16, 4, held=held)
+    blk.initialize()
+    assert blk.stats()["mxu_rows"] is None is blk.stats()["tile_fill"]
+    blk(nd.array(np.random.RandomState(0).randn(1, tokens, 32)
+                 .astype(np.float32)))
+    row, = profiler.get_moe_stats(blk)
+    load = blk.count.data().asnumpy()[list(held or range(16))]
+    assert row["passes"] == 1 and row["pairs"] == load.sum() > 0
+    assert row["mxu_rows"] == mxu_rows(load, row["buffer_rows"], 4) \
+        >= row["pairs"]
+    assert row["mxu_rows"] % 128 == 0
+    assert row["tile_fill"] == row["pairs"] / row["mxu_rows"] <= 1
+
+
+def test_mxu_rows_are_summed_over_the_passes(monkeypatch):
+    """A buffer the pairs do not fit: each pass's share of the sorted pairs
+    is a launch of its own."""
+    from mxtpu import nd
+    from mxtpu.ops.grouped_matmul import mxu_rows
+    monkeypatch.setattr(moe, "expert_rows", lambda *a: 512)
+    blk = moe.SparseExperts(32, 48, 4, 2, held=[0, 1])
+    blk.initialize()
+    blk(nd.array(np.random.RandomState(0).randn(1, 2048, 32)
+                 .astype(np.float32)))
+    load = blk.count.data().asnumpy()[[0, 1]].astype(int)
+    row = blk.stats()
+    rows = row["buffer_rows"]
+    assert rows == 512 and row["passes"] == -(-load.sum() // 512) > 2
+    ends = np.cumsum(load)
+    want = sum(mxu_rows(np.clip(ends, lo, lo + rows)
+                        - np.clip(ends - load, lo, lo + rows), rows, 4)
+               for lo in range(0, int(ends[-1]), rows))
+    assert row["mxu_rows"] == want >= row["pairs"]
+
+
 # ---------------------------------------------------------------------------
 # what the layer keeps for its backward: where its pairs take one pass
 # whatever the routing (every expert held, the buffer their worst case) the

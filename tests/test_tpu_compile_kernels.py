@@ -107,22 +107,38 @@ def test_flash_kernels_compile_at_the_sparse_cells_size(one_chip, quiet_cache,
         assert name in text, name
 
 
-@pytest.mark.parametrize("K,N", [(6144, 4096), (2048, 6144)])
-def test_grouped_matmul_kernels_compile_at_the_sparse_cells_size(
-        one_chip, quiet_cache, K, N):
-    """8192 buffer rows over 8 held experts: gate/up (6144 -> 2 x 2048) and
-    down (2048 -> 6144), forward, dx and the per-group dw."""
-    from mxtpu.ops import grouped_matmul as G
+GROUPED = {   # cell: (buffer rows, groups, [(K, N) of gate/up and of down])
+    "kexaone": (8192, 8, [(6144, 4096), (2048, 6144)]),
+    "lfm2moe": (16384, 32, [(2048, 3584), (1792, 2048)]),
+    "joyai": (16384, 32, [(2048, 1536), (768, 2048)]),
+    "lingflash": (4096, 16, [(2560, 1536), (768, 2560)]),
+}
+
+
+@pytest.mark.parametrize("cell,M,G,K,N", [
+    (cell, M, G, K, N) for cell, (M, G, widths) in GROUPED.items()
+    for K, N in widths])
+def test_grouped_matmul_kernels_compile_at_the_sparse_cells_sizes(
+        one_chip, quiet_cache, cell, M, G, K, N):
+    """The grouped products of every sparse cell at its widths: the row
+    buffer of a pass over the experts one chip holds, gate/up (K -> 2 ffn)
+    and down (ffn -> K), forward, dx and the per-group dw. The forward and
+    dx hold the whole of K beside a column tile that the VMEM rule sizes
+    (``_gmm_tiles``): what it asks for is what Mosaic is given here."""
+    from mxtpu.ops import grouped_matmul as G_
     bf = jnp.bfloat16
-    x, w, gs, dy = _avals(one_chip, ((8192, K), bf), ((8, K, N), bf),
-                          ((8,), jnp.int32), ((8192, N), bf))
+    x, w, gs, dy = _avals(one_chip, ((M, K), bf), ((G, K, N), bf),
+                          ((G,), jnp.int32), ((M, N), bf))
 
     def all_three(x, w, gs, dy):
-        return (G._gmm_pallas(x, w, gs), G._gmm_pallas(dy, w, gs, True),
-                G._tgmm_pallas(x, dy, gs))
+        return (G_._gmm_pallas(x, w, gs), G_._gmm_pallas(dy, w, gs, True),
+                G_._tgmm_pallas(x, dy, gs))
 
     text = jax.jit(all_three).lower(x, w, gs, dy).compile().as_text()
     assert "moe_gmm" in text and "moe_tgmm" in text
+    for k, n in ((K, N), (N, K)):
+        tm, tk, tn, need = G_._gmm_tiles(M, k, n, 2)
+        assert tk == k and n % tn == 0 and need <= G_._VMEM_BUDGET
 
 
 def test_flash_kernels_compile_at_the_conv_cells_size(one_chip, quiet_cache):
@@ -165,25 +181,6 @@ def test_flash_kernels_compile_at_the_latent_cells_size(one_chip, quiet_cache):
     row = profiler.get_launch_stats("flash")
     assert row["block_q"] == row["block_k"] == 512
     assert (row["dp"], row["dvp"]) == (256, 128)
-
-
-@pytest.mark.parametrize("K,N", [(2048, 3584), (1792, 2048)])
-def test_grouped_matmul_kernels_compile_at_the_conv_cells_size(
-        one_chip, quiet_cache, K, N):
-    """16384 buffer rows (every pair of 4096 tokens x 4) over all 32 experts:
-    gate/up (2048 -> 2 x 1792) and down (1792 -> 2048), widths in tiles of
-    1792, forward, dx and the per-group dw."""
-    from mxtpu.ops import grouped_matmul as G
-    bf = jnp.bfloat16
-    x, w, gs, dy = _avals(one_chip, ((16384, K), bf), ((32, K, N), bf),
-                          ((32,), jnp.int32), ((16384, N), bf))
-
-    def all_three(x, w, gs, dy):
-        return (G._gmm_pallas(x, w, gs), G._gmm_pallas(dy, w, gs, True),
-                G._tgmm_pallas(x, dy, gs))
-
-    text = jax.jit(all_three).lower(x, w, gs, dy).compile().as_text()
-    assert "moe_gmm" in text and "moe_tgmm" in text
 
 
 def test_retention_kernels_compile_at_the_retention_cells_size(one_chip,
