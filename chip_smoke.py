@@ -34,6 +34,11 @@ at the full width of the ``flagship`` preset (d1024, L8, H16, vocab 16384):
    A fifth (latent attention with a query latent in both layers, a sparse
    layer, and a multi-token-prediction block trained beside the head through
    ``gluon.loss.NextTokenLoss``) does the same, and both tables must move.
+   A sixth (Mamba-1 layers with the Jamba family's inner norms around one
+   grouped-query attention layer without positions, every block but the
+   last recomputed, leg ``jamba_train``) does the same: ``ssm_scan_fwd``
+   under ``jax.checkpoint`` a second time, ``ssm_scan_bwd`` and the flash
+   launches against the ``lax.scan`` and the XLA attention.
 5. four chips, when there are four — leg 1 at B32 over dp=4 and a 2x2
    fsdp×tp serving engine, with where the bytes actually landed.
 
@@ -124,6 +129,10 @@ SIZES = {
                  held=(0, 1, 2, 3), top_k=2, vocab=1024, T=384,
                  mla=dict(latent_dim=128, nope_dim=128, rope_dim=64,
                           v_dim=128, q_latent_dim=256)),
+        # a Mamba / attention decoder of the Jamba family: d_inner 512 (four
+        # lane tiles) with 16 states, 2-on-1 heads of 128, six chunks of 64
+        jamba=dict(units=256, heads=2, kv_heads=1, ffn=512, d_state=16,
+                   dt_rank=16, vocab=1024, T=384),
         multi=dict(B=32, serve_n=4),
     ),
     "rehearsal": dict(
@@ -149,6 +158,8 @@ SIZES = {
                  held=(0, 1), top_k=2, vocab=50, T=32,
                  mla=dict(latent_dim=16, nope_dim=8, rope_dim=4, v_dim=8,
                           q_latent_dim=24)),
+        jamba=dict(units=32, heads=4, kv_heads=1, ffn=64, d_state=8,
+                   dt_rank=4, vocab=50, T=32),
         multi=dict(B=8, serve_n=2),
     ),
 }
@@ -1008,6 +1019,92 @@ def leg_mtp_train(sz, on_chip: bool) -> dict:
             "pairs": [r["pairs"] for r in rows]}
 
 
+
+# -- leg 4g: a tiny Mamba / attention decoder's step, blocks recomputed -------
+
+def leg_jamba_train(sz, on_chip: bool) -> dict:
+    """One step of a tiny ``HybridDecoderLM`` of the seventh family (Mamba-1
+    mixers with an RMSNorm on each of ``dt``, ``B`` and ``C`` around one
+    grouped-query attention layer without positions, pre-norm RMSNorm, a
+    tied head with float32 logits, every block but the last recomputed in
+    the backward) through ``DataParallelTrainer``, twice from the same
+    weights: as it runs (on the chip the ``ssm_scan_fwd`` launches, the
+    recomputed blocks' a second time, ``ssm_scan_bwd`` and the flash
+    launches) and with every kernel site on its XLA formulation. The first
+    loss and every parameter's first gradient must agree."""
+    import mxtpu as mx
+    from mxtpu import nd, optimizer, profiler
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    from mxtpu.parallel import DataParallelTrainer
+    from mxtpu.parallel.mesh import data_parallel_mesh
+    js = sz["jamba"]
+    kinds = ["mamba", "mamba", "attn_full", "mamba"]
+    seq = np.random.RandomState(4).randint(0, js["vocab"], (1, js["T"] + 1))
+    x, y = nd.array(seq[:, :-1]), nd.array(seq[:, 1:].astype(np.float32))
+
+    def one_step():
+        mx.random.seed(9)           # the same draw both times
+        net = HybridDecoderLM(
+            js["vocab"], kinds, units=js["units"], ffn_units=js["ffn"],
+            num_heads=js["heads"], num_kv_heads=js["kv_heads"],
+            d_state=js["d_state"], dt_rank=js["dt_rank"],
+            layer_norm_eps=1e-6, attention="gqa", norm="rms", tie_head=True,
+            float32_logits=True, mamba_inner_norm=True, remat=True)
+        net.initialize()
+        if on_chip:
+            net.cast("bfloat16")
+        dpt = DataParallelTrainer(net, seq_loss,
+                                  optimizer.Adam(learning_rate=1e-3),
+                                  data_parallel_mesh(1))
+        loss = float(dpt.step(x, y))
+        return loss, {
+            name.split("_", 1)[1]: np.asarray(slots[0], np.float32)
+            for name, slots in dpt.optimizer_state_by_param().items()}, \
+            mosaic_kernels(dpt.lowered().as_text())
+
+    profiler.reset_kernel_path_counts()
+    profiler.reset_launch_stats("ssm_scan")
+    profiler.reset_remat_stats()
+    loss, moments, kernels = one_step()
+    paths, stats, remat = (profiler.get_kernel_path_counts(),
+                           profiler.get_launch_stats("ssm_scan"),
+                           profiler.get_remat_stats())
+    with xla_formulations():
+        want_loss, want, _ = one_step()
+    tol = 3e-2 if on_chip else 1e-4     # bfloat16 against bfloat16 / float32
+    check(abs(loss - want_loss) <= tol * want_loss,
+          f"jamba train: first loss {loss} against XLA's {want_loss}")
+    check(set(moments) == set(want)
+          and sum("rmsnorm" in k and "mamba" in k for k in moments) == 9,
+          f"jamba train: parameters {sorted(moments)}")
+    gaps = {k: float(np.linalg.norm(moments[k] - want[k])
+                     / max(np.linalg.norm(want[k]), 1e-30)) for k in want}
+    worst = max(gaps, key=gaps.get)
+    check(gaps[worst] <= 3 * tol,
+          f"jamba train: first gradient of {worst} is {gaps[worst]:.4g} "
+          f"from the XLA formulation's")
+    check(remat == {"blocks": 4, "recomputed": 3,
+                    "kinds": {"mamba": 2, "attn_full": 1}},
+          f"jamba train: recomputed {remat}")
+    check(stats["launches"] >= 3 and stats["channels"] == 2 * js["units"],
+          f"jamba train: {stats}")
+    if on_chip:
+        # three scan call sites a trace (the step is traced for the run and
+        # again for the lowered text), none on the XLA formulation
+        check(paths["ssm_scan"]["pallas"] >= 3
+              and paths["ssm_scan"]["xla"] == 0
+              and paths["flash"]["xla"] == 0 and paths["flash"]["pallas"] > 0,
+              f"jamba train: call sites {paths}")
+        # three scans forward and the two recomputed blocks' again
+        check(kernels["ssm_scan_fwd"] == 5 and kernels["ssm_scan_bwd"] == 3
+              and kernels[FLASH_FWD] == 2 and stats["chunk_start_bytes"] > 0,
+              f"jamba train: launches {dict(kernels)}, {stats}")
+    return {"loss": round(loss, 4), "xla_loss": round(want_loss, 4),
+            "worst_gradient_gap": [worst, round(gaps[worst], 5)],
+            "kernel_paths": {k: paths[k] for k in ("ssm_scan", "flash")},
+            "ssm_scan": stats, "remat": remat}
+
+
 # -- leg 5: four chips -------------------------------------------------------
 
 def placement(arrays: dict, devices) -> dict:
@@ -1158,6 +1255,8 @@ def main(argv=None) -> int:
         rep, out = leg("kda_train", leg_kda_train, sz, on_chip)
         rep.update(out)
         rep, out = leg("mtp_train", leg_mtp_train, sz, on_chip)
+        rep.update(out)
+        rep, out = leg("jamba_train", leg_jamba_train, sz, on_chip)
         rep.update(out)
 
         if len(devs) >= 4:

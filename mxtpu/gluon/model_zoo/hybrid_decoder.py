@@ -1,9 +1,11 @@
 """Decoder language models built from a list of layer kinds: a mixer kind, an
 MLP kind and a norm for each layer, and the widths; nothing here is a preset.
-Six published families are instances (``docs/hybrid_decoder.md`` has each
+Seven published families are instances (``docs/hybrid_decoder.md`` has each
 one's spec): SambaY / Phi-4-mini-flash, K-EXAONE, LFM2's ``lfm2_moe``,
-Brumby-14B, Ling-3.0's ``bailing_hybrid`` and JoyAI-LLM-Flash's (DeepSeek-V3's
-block with its multi-token-prediction block, ``MultiTokenPrediction``).
+Brumby-14B, Ling-3.0's ``bailing_hybrid``, JoyAI-LLM-Flash's (DeepSeek-V3's
+block with its multi-token-prediction block, ``MultiTokenPrediction``) and
+Jamba's (``mamba`` layers under ``mamba_inner_norm`` around ``attn_full``
+layers without positions).
 
 Every layer is ``h = x + Mixer(N(x)); out = h + MLP(N'(h))``, or with
 ``norm_position="post"`` ``h = x + N(Mixer(x)); out = h + N'(MLP(h))``. ``N``
@@ -32,8 +34,13 @@ kind's equations:
 
 The hand-over (``Mixer``): keys/values and the scan's output are made ONCE
 and read by every later layer that wants them, so gradients flow back into
-the one producer from all its consumers. That is the training path, and the
-only one: there is no decode cache for any of these kinds yet (``generate``
+the one producer from all its consumers. What a layer hands on follows from
+the STACK: ``HybridDecoderLM`` tells each mixer which of the keys it could
+write a later layer reads before another layer writes them again (``gmu``
+reads ``memory``, ``attn_cross`` reads ``kv``), and the mixer puts only
+those into the dict. A layer that hands nothing on and reads nothing may be
+recomputed (``remat=True``). That is the training path, and the only one:
+there is no decode cache for any of these kinds yet (``generate``
 and the serving steps raise), because a cache here has to hold side by side
 what each row of ``MIXERS`` says a layer of its kind would keep.
 """
@@ -68,13 +75,20 @@ class Mixer(HybridBlock):
     """What the stack asks of a mixer. ``forward(x, shared)`` returns the
     mixed rows ``(B, T, units)`` alone; ``shared`` is the stack's hand-over,
     one dict a forward, and the mixer itself takes from it the keys
-    ``reads`` names and puts into it those ``writes`` names (set by
-    ``__init__`` where they follow from its arguments). The classmethod
+    ``reads`` names and puts into it those ``writes`` names. ``writes`` as
+    the class or ``__init__`` sets it is what the mixer CAN hand on, and
+    what one built alone does; in a stack ``HybridDecoderLM`` cuts it to
+    the keys a later layer reads (``hand_on``). The classmethod
     ``from_spec(z, kind, layer_index)`` builds one for a layer of ``kind``
     from the stack's widths ``z``, so a kind's widths are listed with it."""
 
     reads: tuple = ()
     writes: tuple = ()
+
+    def hand_on(self, wanted) -> None:
+        """Keep of ``writes`` the keys in ``wanted``: those a later layer of
+        the stack reads before another layer writes them."""
+        self.writes = tuple(k for k in self.writes if k in wanted)
 
 
 class _ALog(initializer.Initializer):
@@ -115,23 +129,30 @@ def _split(x, sizes):
 
 class Mamba(Mixer):
     """Mamba-1 mixer (kind ``mamba``). ``[u, z] = W_in x``; ``u =
-    silu(conv1d_causal(u) + b_c)``; ``[dt_r, B, C] = W_x u``; ``dt =
-    softplus(W_dt dt_r + b_dt)``; ``A = -exp(A_log)`` in float32; ``y =
-    selective_scan(u, dt, A, B, C, D)`` (``ops/ssm.py``); output ``W_out (y *
-    silu(z))``. Its ``y``, before the gate, is handed on as the stack's
-    memory: a later ``gmu`` reads the newest one."""
+    silu(conv1d_causal(u) + b_c)``; ``[dt_r, B, C] = W_x u``; with
+    ``inner_norm`` (the Jamba family's) ``dt_r = RMSNorm(dt_r)``, ``B =
+    RMSNorm(B)``, ``C = RMSNorm(C)``, each with a gain of its own and
+    ``norm_eps`` (children ``dt_norm``, ``b_norm``, ``c_norm``; scope
+    ``inner_norm``); ``dt = softplus(W_dt dt_r + b_dt)``; ``A =
+    -exp(A_log)`` in float32; ``y = selective_scan(u, dt, A, B, C, D)``
+    (``ops/ssm.py``); output ``W_out (y * silu(z))``. Its ``y``, before the
+    gate, is the stack's memory where a later ``gmu`` reads it (``writes``:
+    the newest producer before the reader hands it on, no other)."""
 
     writes = ("memory",)
 
     @classmethod
     def from_spec(cls, z, kind, layer_index):
         return cls(z["units"], z["d_inner"], z["d_state"], z["d_conv"],
-                   z["dt_rank"])
+                   z["dt_rank"], inner_norm=z["mamba_inner_norm"],
+                   norm_eps=z["eps"])
 
     def __init__(self, units: int, d_inner: int, d_state: int, d_conv: int,
-                 dt_rank: int, prefix=None, params=None):
+                 dt_rank: int, inner_norm: bool = False,
+                 norm_eps: float = 1e-5, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._inner, self._state, self._rank = d_inner, d_state, dt_rank
+        self.dt_norm = self.b_norm = self.c_norm = None
         with self.name_scope():
             self.in_proj = Dense(2 * d_inner, use_bias=False, flatten=False,
                                  in_units=units)
@@ -141,6 +162,10 @@ class Mamba(Mixer):
                 "conv_bias", shape=(d_inner,), init="zeros")
             self.x_proj = Dense(dt_rank + 2 * d_state, use_bias=False,
                                 flatten=False, in_units=d_inner)
+            if inner_norm:
+                self.dt_norm = RMSNorm(epsilon=norm_eps, in_channels=dt_rank)
+                self.b_norm = RMSNorm(epsilon=norm_eps, in_channels=d_state)
+                self.c_norm = RMSNorm(epsilon=norm_eps, in_channels=d_state)
             self.dt_proj = Dense(d_inner, flatten=False, in_units=dt_rank,
                                  bias_initializer=_DtBias())
             self.A_log = self.params.get(
@@ -155,10 +180,15 @@ class Mamba(Mixer):
                                            self.conv_bias.data()))
         dt_r, B, C = _split(self.x_proj(u),
                             (self._rank, self._state, self._state))
+        if self.dt_norm is not None:
+            with jax.named_scope("inner_norm"):
+                dt_r, B, C = self.dt_norm(dt_r), self.b_norm(B), \
+                    self.c_norm(C)
         dt = nd.Activation(self.dt_proj(dt_r), act_type="softrelu")
         y = nd.contrib.selective_scan(u, dt, self.A_log.data(), B, C,
                                       self.D.data(), log_A=True)
-        shared["memory"] = y
+        if self.writes:
+            shared["memory"] = y
         return self.out_proj(y * _silu(z))
 
 
@@ -168,7 +198,8 @@ class DiffAttention(Mixer):
     ``ops.attention.diff_attention`` (heads pair up, two softmax maps over a
     value twice as wide, their difference normalised); ``W_o . + b_o``. With
     ``window`` it sees the ``window`` newest keys, without all of them, and
-    then hands its ``k`` and ``v`` on (each ``(B, T, kv_heads, head_dim)``).
+    then hands its ``k`` and ``v`` on (each ``(B, T, kv_heads, head_dim)``)
+    where a later ``attn_cross`` reads them (``Mixer.hand_on``).
     ``cross=True`` makes ``q = W_q x + b`` alone and reads those of the
     newest ``attn_full``; own lambdas, norm and ``W_o``."""
 
@@ -340,7 +371,7 @@ class GroupedQueryAttention(Mixer):
     plain softmax through ``flash_chunk`` with query head ``h`` on key/value
     head ``h // (H / Hkv)`` over the ``window`` newest keys or over all of
     them, and ``out_proj`` without bias. Without a window it hands its ``k``
-    and ``v`` on, as ``DiffAttention`` does."""
+    and ``v`` on, as ``DiffAttention`` does and under the same rule."""
 
     @classmethod
     def from_spec(cls, z, kind, layer_index):
@@ -823,15 +854,20 @@ class _Kind(NamedTuple):
     build: Callable         # (z, kind, layer_index) -> the kind's ``Mixer``
     decode_state: str       # what a decode cache would hold for a layer, a slot
     # may its block run under jax.checkpoint (``may_remat`` has the rest of
-    # the rule). Only what a cell runs so says yes
+    # the rule: the layer reads nothing and hands nothing on IN ITS STACK,
+    # beside a dense MLP). Yes for the kinds a cell runs so, or runs beside
+    # one that is (the three ``attn_*`` classes are one mixer); the kinds
+    # that only ever read (``attn_cross``, ``gmu``) never can
     remat: bool = False
 
 
 MIXERS = {
-    "mamba": _Kind(Mamba.from_spec, "scan and convolution states"),
-    "attn_window": _Kind(_attention, "a window of keys and values"),
+    "mamba": _Kind(Mamba.from_spec, "scan and convolution states",
+                   remat=True),
+    "attn_window": _Kind(_attention, "a window of keys and values",
+                         remat=True),
     "attn_full": _Kind(_attention, "every key and value, which attn_cross "
-                                   "layers read too"),
+                                   "layers read too", remat=True),
     "attn_cross": _Kind(_attention, "nothing of its own (an earlier "
                                     "attn_full layer's keys)"),
     "gmu": _Kind(GatedMemoryUnit.from_spec,
@@ -876,7 +912,8 @@ class HybridDecoderBlock(HybridBlock):
     device trace reads ``block3/attn_window/...``, ``block3/moe/experts``.
     ``z`` holds the model's widths and options (``HybridDecoderLM`` builds
     it). ``shared`` is the stack's hand-over (``Mixer``): ``{"memory": y of
-    the newest mamba, "kv": (k, v) of the newest attn_full}``."""
+    the newest mamba, "kv": (k, v) of the newest attn_full}``, each there
+    only where a later layer reads it."""
 
     def __init__(self, kind: str, layer_index: int, mlp_kind: str, z: dict,
                  prefix=None, params=None):
@@ -895,14 +932,23 @@ class HybridDecoderBlock(HybridBlock):
             mixer = MIXERS[kind].build(z, kind, layer_index)
             setattr(self, kind, mixer)
             self.ln2 = norm(epsilon=eps, in_channels=units)
-            build_mlp, mlp_remat = MLPS[mlp_kind]
+            build_mlp, self._mlp_remat = MLPS[mlp_kind]
             setattr(self, mlp_kind, build_mlp(z))
-        # both rows allow it and the mixer neither reads ``shared`` nor writes
-        self.may_remat = MIXERS[kind].remat and mlp_remat \
+
+    @property
+    def mixer(self) -> Mixer:
+        return getattr(self, self.kind)
+
+    @property
+    def may_remat(self) -> bool:
+        """Both rows allow it and the mixer neither reads ``shared`` nor
+        writes what a later layer reads (``Mixer.hand_on``)."""
+        mixer = self.mixer
+        return MIXERS[self.kind].remat and self._mlp_remat \
             and not (mixer.reads or mixer.writes)
 
     def forward(self, x, shared):
-        mixer, mlp = getattr(self, self.kind), getattr(self, self.mlp_kind)
+        mixer, mlp = self.mixer, getattr(self, self.mlp_kind)
         if self._post:
             h = x + self.ln1(mixer(x, shared))
             return h + self.ln2(mlp(h))
@@ -947,7 +993,7 @@ class MultiTokenPrediction(HybridBlock):
             setattr(self, self._layer,
                     HybridDecoderBlock(kind, layer_index, mlp_kind, z))
             self.norm = norm(epsilon=eps, in_channels=units)
-        mixer = getattr(getattr(self, self._layer), kind)
+        mixer = getattr(self, self._layer).mixer
         if mixer.reads or mixer.writes:
             raise ValueError(f"a prediction block of kind {kind!r} would "
                              f"read or write the trunk's hand-over")
@@ -985,7 +1031,10 @@ class HybridDecoderLM(HybridBlock):
     units``), shared by ``mamba`` and ``gmu`` since the one gates the
     other's output; ``dt_rank`` defaults to ``ceil(units / 16)``. ``d_conv``
     is the taps of every depthwise causal convolution: Mamba's, and the
-    ``conv`` kind's (LFM2's ``conv_L_cache``).
+    ``conv`` kind's (LFM2's ``conv_L_cache``). ``mamba_inner_norm=True``
+    gives every ``mamba`` layer the Jamba family's three RMSNorms with gains
+    (over ``dt_r``, ``B`` and ``C``, between ``x_proj`` and ``dt_proj`` /
+    the scan; eps ``layer_norm_eps``; ``Mamba``'s docstring).
 
     The layer spec beside ``layer_kinds``. ``attention="gqa"`` makes
     ``attn_window`` / ``attn_full`` ``GroupedQueryAttention``s: ``qk_norm``
@@ -1014,11 +1063,15 @@ class HybridDecoderLM(HybridBlock):
     loss are through, so recomputing it would rebuild at once what it
     declined to keep, and free nothing (a one-layer model is the plain
     program). ``profiler.get_remat_stats()`` says how many blocks the newest
-    traced step had and how many it recomputes. It is for models whose
-    kept activations do not fit beside their state (five 330M-parameter
-    layers at 8192 tokens keep 7.5 GB); only layers that hand nothing on
-    and hold no state take it (the rows of ``MIXERS`` and ``MLPS`` that say
-    so: ``HybridDecoderBlock.may_remat``).
+    traced step had, how many it recomputes and of which kinds. It is for
+    models whose kept activations do not fit beside their state (five
+    330M-parameter layers at 8192 tokens keep 7.5 GB; fourteen 100M ones
+    with their scans' operands 7.1); only layers that read nothing, hand
+    nothing on IN THIS STACK and hold no state take it (the rows of
+    ``MIXERS`` and ``MLPS`` that say so: ``HybridDecoderBlock.may_remat``).
+    A ``mamba`` or ``attn_full`` layer hands on only what a later ``gmu`` /
+    ``attn_cross`` reads, so a stack without those readers may be
+    recomputed and one with them is refused, by layer.
 
     ``kda_lower_bound`` bounds the ``kda`` kind's log-decay a channel;
     ``mla`` holds the ``mla`` kind's arguments, those of ``LatentAttention``
@@ -1043,7 +1096,8 @@ class HybridDecoderLM(HybridBlock):
                  tie_head: bool = True, mlp_kinds=None, moe=None,
                  float32_logits: bool = False, remat: bool = False,
                  retention_eps=None, kda_lower_bound: float = -5.0,
-                 mla=None, mtp_layers: int = 0, prefix=None, params=None):
+                 mla=None, mtp_layers: int = 0,
+                 mamba_inner_norm: bool = False, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if mtp_layers not in (0, 1):
             raise ValueError(f"mtp_layers {mtp_layers}: 0 or 1 (deeper "
@@ -1071,7 +1125,8 @@ class HybridDecoderLM(HybridBlock):
                  rope_kinds=tuple(rope_kinds), rope_theta=rope_theta,
                  norm=norm, norm_position=norm_position, moe=moe,
                  retention_eps=retention_eps,
-                 kda_lower_bound=kda_lower_bound, mla=mla)
+                 kda_lower_bound=kda_lower_bound, mla=mla,
+                 mamba_inner_norm=mamba_inner_norm)
         with self.name_scope():
             self.embedding = Embedding(vocab_size, units,
                                        weight_initializer="normal")
@@ -1081,12 +1136,27 @@ class HybridDecoderLM(HybridBlock):
                 blk = HybridDecoderBlock(kind, i, mlp_kind, z)
                 setattr(self, f"block{i}", blk)   # registers child + params
                 self.blocks.append(blk)
+            # a layer hands on what a LATER layer reads before another
+            # layer writes it again: walked from the last layer back
+            wanted = set()
+            for blk in reversed(self.blocks):
+                could = blk.mixer.writes
+                blk.mixer.hand_on(wanted)
+                wanted = (wanted - set(could)) | set(blk.mixer.reads)
             if remat and not all(blk.may_remat for blk in self.blocks):
                 kinds = tuple(k for k, row in MIXERS.items() if row.remat)
+                mlps = tuple(k for k, (_, ok) in MLPS.items() if ok)
+                why = "; ".join(
+                    f"layer {i} ({blk.kind}, {blk.mlp_kind})"
+                    + "".join(f" {verb} {', '.join(keys)}" for verb, keys in
+                              (("reads", blk.mixer.reads),
+                               ("hands on", blk.mixer.writes)) if keys)
+                    for i, blk in enumerate(self.blocks)
+                    if not blk.may_remat)
                 raise ValueError(
-                    f"remat=True recomputes blocks that hand nothing on and "
-                    f"hold no state: kinds {kinds} with a dense MLP, not "
-                    f"{self.layer_kinds} / {self.mlp_kinds}")
+                    f"remat=True recomputes blocks that read nothing, hand "
+                    f"nothing on and hold no state: kinds {kinds} beside "
+                    f"MLP kinds {mlps}. Not {why}")
             self.ln_f = (RMSNorm if norm == "rms" else LayerNorm)(
                 epsilon=layer_norm_eps, in_channels=units)
             self.head = None if tie_head else Dense(
@@ -1101,7 +1171,7 @@ class HybridDecoderLM(HybridBlock):
         shared = {}
         remat = self._remat and not autograd.is_recording()
         if remat:
-            metrics.record_remat(len(self.blocks), len(self.blocks) - 1)
+            metrics.record_remat(len(self.blocks), self.layer_kinds[:-1])
         for blk in self.blocks:
             # not the last one, whose backward comes first (the docstring)
             if remat and blk is not self.blocks[-1]:

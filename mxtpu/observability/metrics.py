@@ -874,28 +874,34 @@ def reset_launch_stats(kind: str):
         _launches[kind].update(dict.fromkeys(_launches[kind], 0))
 
 
-_remat = {"blocks": 0, "recomputed": 0}
+_remat = {"blocks": 0, "recomputed": 0, "kinds": {}}
 
 
-def record_remat(blocks: int, recomputed: int):
+def record_remat(blocks: int, kinds):
     """A model that recomputes its blocks (``HybridDecoderLM(remat=True)``)
-    traced a step: how many blocks it has and how many of them run under
-    ``jax.checkpoint``. Recorded at TRACE time, as the kernel paths are; a
-    model that never asks records nothing."""
+    traced a step: how many blocks it has, and the layer kind of each block
+    that runs under ``jax.checkpoint`` (one entry a block). Recorded at
+    TRACE time, as the kernel paths are; a model that never asks records
+    nothing."""
+    count = {}
+    for kind in kinds:
+        count[kind] = count.get(kind, 0) + 1
     with _stats_lock:
-        _remat.update(blocks=blocks, recomputed=recomputed)
+        _remat.update(blocks=blocks, recomputed=len(kinds), kinds=count)
 
 
 def get_remat_stats() -> dict:
-    """``{"blocks", "recomputed"}`` of the NEWEST traced step that asked
-    for recomputation since the last reset: the model's blocks, and those
+    """``{"blocks", "recomputed", "kinds"}`` of the NEWEST traced step that
+    asked for recomputation since the last reset: the model's blocks, those
     whose forward runs again in the backward (all but the last, whose
-    activations are live at its backward either way). Zeros where no such
-    step was traced."""
+    activations are live at its backward either way) and how many of them
+    are of each layer kind (``{"mamba": 12, "attn_full": 1}``: each kind's
+    kernels launch that many more forwards a step). Zeros and ``{}`` where
+    no such step was traced."""
     with _stats_lock:
-        return dict(_remat)
+        return dict(_remat, kinds=dict(_remat["kinds"]))
 
 
 def reset_remat_stats():
     with _stats_lock:
-        _remat.update(blocks=0, recomputed=0)
+        _remat.update(blocks=0, recomputed=0, kinds={})

@@ -40,8 +40,10 @@ __all__ = ["causal_conv1d", "selective_scan", "selective_scan_reference"]
 _LANES = 128
 CHUNK = 64          # rows of T per grid step; the backward holds chunk + 1
                     # states of (N, block_d) float32 in VMEM
-BLOCK_D = 1024      # channels per grid step (v5e, T 8192 x 5120 channels: forward 2.3 ms
-                    # against 3.4 at 512 and 5.8 at 256, backward 6.7 / 7.8 / 11.8)
+BLOCK_D = 1024      # channels per grid step (v5e, T 8192 x 5120 channels: chosen
+                    # where a forward launch took 2.3 ms against 3.4 at 512 and 5.8
+                    # at 256, a backward 6.7 / 7.8 / 11.8; the ledger's PR 48 line
+                    # reads 2.06 / 6.28 ms a launch at 1024, phi4flash_train_t8192)
 
 
 def selective_scan_reference(u, dt, A, B, C, D):
@@ -202,6 +204,24 @@ def _geometry(T: int, channels: int):
     return -(-T // CHUNK) * CHUNK, block_d
 
 
+def launch_stats(batch: int, T: int, channels: int, states: int,
+                 pallas: bool) -> dict:
+    """A call site's row of ``profiler.get_launch_stats("ssm_scan")``: the
+    kernels' geometry for these shapes (``_geometry``) and
+    ``chunk_start_bytes``, the float32 states at the start of every chunk
+    that a forward launch keeps for its backward (``(batch, t_pad / chunk,
+    states, channels)``). Where the ``lax.scan`` runs there is no launch:
+    the sizes alone, the rest zeros."""
+    row = {"t_pad": T, "channels": channels, "states": states, "chunk": 0,
+           "block_d": 0, "chunk_start_bytes": 0}
+    if pallas:
+        t_pad, block_d = _geometry(T, channels)
+        row.update(t_pad=t_pad, chunk=CHUNK, block_d=block_d,
+                   chunk_start_bytes=batch * (t_pad // CHUNK) * states
+                   * channels * 4)
+    return row
+
+
 def _scan_forward_pallas(u, dt, A, B, C, D, interpret: bool = False):
     """``(y, h)``: ``h`` is the state at the start of every chunk,
     ``(Bt, T_pad / chunk, N, C)`` float32."""
@@ -325,7 +345,10 @@ def _scan_pallas_bwd(res, dy):
 _scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
 
 
-metrics.register_kernel("ssm_scan")
+# ``profiler.get_launch_stats("ssm_scan")``: the op's call sites and the
+# newest one's ``launch_stats`` (ONE layer's)
+metrics.register_kernel("ssm_scan", ("t_pad", "channels", "states", "chunk",
+                                     "block_d", "chunk_start_bytes"))
 
 
 @register("selective_scan", namespace="contrib")
@@ -342,6 +365,8 @@ def selective_scan(u, dt, A, B, C, D, log_A: bool = False):
         A = -jnp.exp(A.astype(jnp.float32))
     pallas = _use_pallas(u, A)
     metrics.record_kernel_path("ssm_scan", pallas)
+    metrics.record_launch("ssm_scan", **launch_stats(
+        u.shape[0], u.shape[1], u.shape[2], A.shape[1], pallas))
     with jax.named_scope("ssm_scan"):
         if pallas:
             return _scan_pallas(u, dt, A, B, C, D)

@@ -387,10 +387,13 @@ def test_recomputing_a_block_at_a_time_gives_the_plain_steps_gradients(
             == (i < layers - 1), i
     if layers == 1:     # nothing to free: the plain program
         assert again[2] == plain[2]
-    assert plain[3] == {"blocks": 0, "recomputed": 0}
-    assert again[3] == {"blocks": layers, "recomputed": layers - 1}
+    none = {"blocks": 0, "recomputed": 0, "kinds": {}}
+    assert plain[3] == none
+    assert again[3] == {"blocks": layers, "recomputed": layers - 1,
+                        "kinds": {"retention": layers - 1} if layers > 1
+                        else {}}
     profiler.reset_remat_stats()
-    assert profiler.get_remat_stats() == {"blocks": 0, "recomputed": 0}
+    assert profiler.get_remat_stats() == none
 
 
 def test_recomputation_leaves_the_tape_alone_and_refuses_other_kinds(
@@ -404,10 +407,16 @@ def test_recomputation_leaves_the_tape_alone_and_refuses_other_kinds(
     with autograd.record():
         out = net(nd.array(x))
     assert out.shape == (8, T, 96)
-    assert profiler.get_remat_stats() == {"blocks": 0, "recomputed": 0}
-    with pytest.raises(ValueError, match="hand nothing on"):
-        HybridDecoderLM(32, ["mamba", "retention"], 64, 128, 4, 2,
+    assert profiler.get_remat_stats() == {"blocks": 0, "recomputed": 0,
+                                          "kinds": {}}
+    # a mamba layer whose memory a gmu reads hands it on: refused, by layer
+    with pytest.raises(ValueError, match=r"hand nothing on.*Not layer 0 "
+                       r"\(mamba, mlp\) hands on memory; layer 2 \(gmu, "
+                       r"mlp\) reads memory$"):
+        HybridDecoderLM(32, ["mamba", "retention", "gmu"], 64, 128, 4, 2,
                         remat=True)
+    # beside layers that read nothing it hands nothing on and may be
+    HybridDecoderLM(32, ["mamba", "retention"], 64, 128, 4, 2, remat=True)
 
 
 def test_step_carries_scopes_and_kernel_names(ref, system, weights, batch,

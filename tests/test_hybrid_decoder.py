@@ -323,17 +323,24 @@ def test_a_mixer_takes_and_hands_on_what_it_declares(kind, attention):
     assert blk.may_remat == (MIXERS[kind].remat
                              and not (mixer.reads or mixer.writes))
     if blk.may_remat:
-        assert kind == "retention"          # only what a cell runs so
+        # what a cell runs so, or runs beside one that is; alone in a stack
+        # a mamba or a full-attention layer hands nothing on
+        assert kind in ("retention", "mamba", "attn_full", "attn_window")
+        assert mixer.writes == ()
         HybridDecoderLM(layer_kinds=[kind] * 2, attention=attention,
                         remat=True, **SPEC)
-        with pytest.raises(ValueError, match="with a dense MLP"):
+        with pytest.raises(ValueError, match=r"Not layer 0 \(\w+, moe\)$"):
             HybridDecoderLM(layer_kinds=[kind], attention=attention,
                             remat=True, mlp_kinds=["moe"],
                             moe=dict(ffn_units=16, num_experts=4, top_k=2),
                             **SPEC)
     else:
+        reads = f" reads {', '.join(mixer.reads)}" if mixer.reads else ""
         with pytest.raises(ValueError, match=r"remat=True recomputes blocks "
-                           r"that hand nothing on and hold no state: kinds "
-                           r"\('retention',\) with a dense MLP, not"):
+                           r"that read nothing, hand nothing on and hold no "
+                           r"state: kinds \('mamba', 'attn_window', "
+                           r"'attn_full', 'retention'\) beside MLP kinds "
+                           rf"\('mlp',\). Not layer 0 \({kind}, mlp\)"
+                           rf"{reads}$"):
             HybridDecoderLM(layer_kinds=[kind], attention=attention,
                             remat=True, **SPEC)

@@ -23,7 +23,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SUITE = os.path.join(ROOT, "benchmark", "suite")
 CELLS = ["gpt2m_train_t1024", "cgpt13_train_t2048", "phi4flash_train_t8192",
          "kexaone_train_t4096", "lfm2moe_train_t4096",
-         "brumby_train_t8192", "lingflash_train_t4096", "joyai_train_t4096"]
+         "brumby_train_t8192", "lingflash_train_t4096", "joyai_train_t4096",
+         "jamba2_train_t8192"]
 NEW_METRICS = [
     "import_s.train", "net_build_s.train", "first_run_s.train",
     "step_compiled_in_process.train", "device_reserved_gb.train",

@@ -5,6 +5,7 @@ VMEM than a kernel may use). Nothing runs, so these say nothing of results or
 times. One file, a fixture that skips where no topology can be described: see
 the ``on-chip-measurement`` guide, section 2."""
 
+import numpy as np
 import pytest
 
 import jax
@@ -255,3 +256,85 @@ def test_kda_kernels_compile_at_the_ling_cells_size(one_chip, quiet_cache):
             and not re.search(r"custom-call|get-tuple-element|parameter\(",
                               line)]
     assert not made, made
+
+
+def test_flash_kernels_compile_at_the_jamba_cells_size(one_chip, quiet_cache):
+    """20 query heads of 128 on ONE key/value head, T = 8192, causal over
+    everything, no positions: the attention layer of ``jamba2_train_t8192``
+    (``kexaone_train_t4096`` runs 8-on-1 at 4096)."""
+    from mxtpu import profiler
+    from mxtpu.ops import attention as A
+    bf = jnp.bfloat16
+    q, k, v, g = _avals(one_chip, ((1, 20, T, 128), bf), ((1, 1, T, 128), bf),
+                        ((1, 1, T, 128), bf), ((1, 20, T, 128), bf))
+    scale = 128 ** -0.5
+
+    def both(q, k, v, g):
+        out, lse = A._flash_attention_pallas(q, k, v, True, scale)
+        return A._flash_backward_pallas(q, k, v, out, lse, g, True, scale)
+
+    profiler.reset_launch_stats("flash")
+    text = jax.jit(both).lower(q, k, v, g).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    row = profiler.get_launch_stats("flash")
+    assert row["block_q"] == row["block_k"] == 512
+    assert (row["dp"], row["dvp"]) == (128, 128)
+
+
+def test_the_jamba_stack_launches_its_kernels_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """``jamba2_train_t8192``'s 14 layers at its widths (13 ``mamba`` layers
+    of 8192 x 5120 x 16 states under the inner norms around one 20-on-1
+    attention layer of 128, the 8192-wide SwiGLUs, the whole tied table),
+    blocks 0-12 recomputed, LOWERED for the described chip (parameters are
+    shapes; nothing is compiled or run): the launches by name, counted in
+    the lowered text. Each recomputed block's forward kernel is there a
+    second time: 13 + 12 scans forward, 13 backward, the attention layer's
+    flash forward twice and its fused backward once; and the scan's launch
+    row says what one launch keeps for its backward."""
+    import re
+    from mxtpu import autograd, nd, profiler
+    from mxtpu.gluon.model_zoo.hybrid_decoder import HybridDecoderLM
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kinds = ["mamba"] * 7 + ["attn_full"] + ["mamba"] * 6
+    net = HybridDecoderLM(
+        65536, kinds, units=2560, ffn_units=8192, num_heads=20,
+        num_kv_heads=1, head_dim=128, d_inner=CHANNELS, d_state=STATES,
+        d_conv=4, dt_rank=160, layer_norm_eps=1e-6, attention="gqa",
+        norm="rms", tie_head=True, float32_logits=True,
+        mamba_inner_norm=True, remat=True)
+    net.cast("bfloat16")
+    params = list(net.collect_params().values())
+    assert sum(int(np.prod(p.shape)) for p in params) == 1_598_556_096
+    for p in params:                    # placeholders: the step is only traced
+        p._data = nd.NDArray(jnp.zeros((1,), jnp.bfloat16))
+
+    def loss(values, tokens):
+        for p, v in zip(params, values):
+            p._data._data = v
+        with autograd.pause(train_mode=True):
+            return jnp.sum(net(nd.NDArray(tokens)).data)
+
+    avals = _avals(one_chip, *((p.shape, jnp.bfloat16) for p in params))
+    tokens, = _avals(one_chip, ((1, T), jnp.int32))
+    for kind in ("ssm_scan", "flash"):
+        profiler.reset_launch_stats(kind)
+    profiler.reset_kernel_path_counts()
+    profiler.reset_remat_stats()
+    text = jax.jit(jax.grad(loss)).lower(avals, tokens).as_text()
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert {k: names.count(k) for k in set(names)} == {
+        "ssm_scan_fwd": 25, "ssm_scan_bwd": 13, "flash_fwd": 2,
+        "flash_bwd_fused": 1}
+    paths = profiler.get_kernel_path_counts()
+    assert paths["ssm_scan"] == {"pallas": 13, "xla": 0}
+    assert paths["flash"]["xla"] == 0 and paths["flash"]["pallas"] >= 1
+    assert profiler.get_remat_stats() == {
+        "blocks": 14, "recomputed": 13,
+        "kinds": {"mamba": 12, "attn_full": 1}}
+    # 128 chunks of 64 rows: 128 x 16 x 5120 float32 chunk starts a launch
+    assert profiler.get_launch_stats("ssm_scan") == {
+        "launches": 13, "t_pad": T, "channels": CHANNELS, "states": STATES,
+        "chunk": 64, "block_d": 1024, "chunk_start_bytes": 41_943_040}
+    row = profiler.get_launch_stats("flash")
+    assert row["block_q"] == row["block_k"] == 512
